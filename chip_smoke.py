@@ -1,0 +1,718 @@
+#!/usr/bin/env python3
+"""Chip smoke: the trainer and the server on a TPU, through their own entry
+points, at the full width of a model the repo ships.
+
+    python3 chip_smoke.py [--out DIR]       # one chip: kernels, train,
+                                            #   resume, serve
+    python3 chip_smoke.py --chips 4         # four-chip host: sharded training
+                                            #   against its one-chip control,
+                                            #   and no other phase
+
+This process is the only one that touches JAX (a chip belongs to one
+process): every phase calls ``cli.train.main`` / ``cli.predict.main`` here,
+in-process, and no child is started. It fails at once when the platform is
+not ``tpu`` — there is no option that lets it pass on the CPU. Each phase is
+a plain function of (recipe, overrides, out dir), so
+``tests/test_chip_compile.py`` can rehearse it at toy size without a chip.
+
+stdout carries one JSON line per finished phase (name, wall and compile
+seconds, what was checked) and, when every phase passed, a last line
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+The entry points' own chatter goes to ``<out>/<phase>.log``; the tail of a
+failed phase's log is copied to stderr. Any failed phase means a non-zero
+exit and no last line.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import gc
+import json
+import logging
+import shutil
+import sys
+import time
+import traceback
+from pathlib import Path
+
+import numpy as np
+
+REPO = Path(__file__).resolve().parent
+L16_RECIPE = str(REPO / "recipes" / "pretrain_vit_l16_in1k_800ep.yaml")
+H14_RECIPE = str(REPO / "recipes" / "pretrain_vit_h14_in1k_fsdp.yaml")
+
+# (batch, seq, heads, head_dim) attention inputs the long-context recipes
+# reach (recipes/pretrain_vit_l16_448_longctx.yaml): the decoder at 448 px
+# (787 tokens, head_dim 32) and, for the same model at 896 px, the encoder
+# (787 tokens, head_dim 64) and the decoder (3139 tokens, head_dim 32).
+KERNEL_SHAPES = ((8, 787, 16, 32), (8, 787, 16, 64), (2, 3139, 16, 32))
+
+# bf16 carries 8 mantissa bits; the kernel and its reference round at
+# different points, so they agree to a few bf16 ulps of the largest value.
+# A wrong mask or block plan is off by the size of the values themselves.
+KERNEL_REL_TOL = 0.05
+# per-step loss of the sharded run against its one-chip control: same seed,
+# same global batch, reductions in another order
+LOSS_REL_TOL = 2e-2
+
+
+class SmokeFailure(RuntimeError):
+    """A check of the smoke did not hold."""
+
+
+def check(cond: bool, message: str) -> None:
+    if not cond:
+        raise SmokeFailure(message)
+
+
+# --------------------------------------------------------------- compiles
+
+
+class CompileWatch:
+    """Counts this process's XLA compiles and persistent-cache traffic from
+    JAX's own telemetry: ``jax.monitoring`` events for backend compiles
+    (seconds included) and cache misses, and the compiler's log line for each
+    hit, which is the only place the hit's program is named."""
+
+    MISS_EVENT = "/jax/compilation_cache/cache_misses"
+    HIT_PREFIX = "Persistent compilation cache hit for"
+
+    def __init__(self):
+        import jax.monitoring
+
+        from jumbo_mae_tpu_tpu.obs.retrace import COMPILE_EVENT
+
+        self._compile_event = COMPILE_EVENT
+        self.compiles = 0
+        self.compile_s = 0.0
+        self.cache_misses = 0
+        self.hit_programs: list[str] = []
+        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+        handler = logging.Handler(level=logging.DEBUG)
+        handler.emit = self._on_log
+        compiler_log = logging.getLogger("jax._src.compiler")
+        compiler_log.addHandler(handler)
+        compiler_log.setLevel(logging.DEBUG)
+        # the debug records stop here; warnings still reach stderr (_on_log)
+        compiler_log.propagate = False
+
+    def _on_duration(self, event: str, duration: float, **_kw) -> None:
+        if event == self._compile_event:
+            self.compiles += 1
+            self.compile_s += float(duration)
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == self.MISS_EVENT:
+            self.cache_misses += 1
+
+    def _on_log(self, record: logging.LogRecord) -> None:
+        if str(record.msg).startswith(self.HIT_PREFIX) and record.args:
+            self.hit_programs.append(str(record.args[0]))
+        elif record.levelno >= logging.WARNING:
+            print(f"{record.name}: {record.getMessage()}", file=sys.stderr)
+
+    def mark(self) -> dict:
+        return {
+            "compiles": self.compiles,
+            "compile_s": self.compile_s,
+            "cache_misses": self.cache_misses,
+            "hits": len(self.hit_programs),
+        }
+
+    def since(self, mark: dict) -> dict:
+        """Compile activity after ``mark``. A persistent-cache hit still
+        counts as one (short) backend compile event."""
+        hits = self.hit_programs[mark["hits"] :]
+        return {
+            "compiles": self.compiles - mark["compiles"],
+            "compile_s": round(self.compile_s - mark["compile_s"], 2),
+            "cache_misses": self.cache_misses - mark["cache_misses"],
+            "cache_hits": len(hits),
+            "hit_programs": hits,
+        }
+
+
+# ------------------------------------------------------------- utilities
+
+
+def _delta(before: dict, after: dict, name: str, key: str) -> int:
+    """How far one counter moved between two ``MetricsRegistry.snapshot()``s
+    (absent counts as 0)."""
+    return int(after.get(name, {}).get(key, 0) - before.get(name, {}).get(key, 0))
+
+
+def _registry_snapshot() -> dict:
+    from jumbo_mae_tpu_tpu.obs.metrics import get_registry
+
+    return get_registry().snapshot()
+
+
+def _read_metrics(run_dir: Path) -> list[dict]:
+    """The trainer's own JSONL metrics log, every record in order."""
+    (path,) = run_dir.glob("*-metrics.jsonl")
+    return [json.loads(ln) for ln in path.read_text().splitlines() if ln.strip()]
+
+
+def _logged_losses(
+    records: list[dict], first: int, last: int, who: str
+) -> dict[int, float]:
+    """The loss the trainer logged at each of steps ``first..last``: every
+    step present, every value finite."""
+    losses = {
+        int(r["step"]): float(r["train/loss"])
+        for r in records
+        if "train/loss" in r and first <= int(r.get("step", -1)) <= last
+    }
+    check(sorted(losses) == list(range(first, last + 1)),
+          f"{who}: expected a logged loss for steps {first}..{last}, got {sorted(losses)}")
+    check(all(np.isfinite(v) for v in losses.values()),
+          f"{who}: non-finite loss {losses}")
+    return losses
+
+
+def _peak_hbm_bytes() -> int | None:
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats()
+    return None if not stats else stats.get("peak_bytes_in_use")
+
+
+def _rel_err(got, want) -> float:
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-6))
+
+
+# ---------------------------------------------------------------- kernels
+
+
+def phase_kernels(shapes=KERNEL_SHAPES, *, interpret: bool = False) -> dict:
+    """Pallas flash attention forward+backward, plain and ``_with_lse``,
+    executed and compared with ``ops.flash_attention.xla_attention``; plus
+    the one-hot masking gather against the XLA gather, bit for bit.
+
+    ``interpret`` exists for the CPU rehearsal in the tests; ``main`` never
+    sets it, and without it the compiled program must hold the Mosaic
+    custom call."""
+    import jax
+    import jax.numpy as jnp
+
+    from jumbo_mae_tpu_tpu.ops.flash_attention import xla_attention
+    from jumbo_mae_tpu_tpu.ops.masking import index_sequence
+    from jumbo_mae_tpu_tpu.ops.pallas.attention import (
+        pallas_flash_attention,
+        pallas_flash_attention_with_lse,
+    )
+
+    def ref_lse(q, k):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(s, axis=-1)  # (b, h, sq)
+        return lse.reshape(-1, lse.shape[-1])
+
+    worst: dict[str, float] = {}
+    for b, s, h, d in shapes:
+        keys = jax.random.split(jax.random.key(s * d), 5)
+        q = (jax.random.normal(keys[0], (b, s, h, d)) * d**-0.5).astype(jnp.bfloat16)
+        k = jax.random.normal(keys[1], (b, s, h, d)).astype(jnp.bfloat16)
+        v = jax.random.normal(keys[2], (b, s, h, d)).astype(jnp.bfloat16)
+        # fixed random cotangents, so no gradient is trivially small
+        w_o = jax.random.normal(keys[3], (b, s, h, d))
+        w_l = jax.random.normal(keys[4], (b * h, s))
+
+        def flash(q, k, v):
+            o = pallas_flash_attention(q, k, v, 1024, 1024, interpret)
+            return (o.astype(jnp.float32) * w_o).sum(), o
+
+        def flash_lse(q, k, v):
+            o, lse = pallas_flash_attention_with_lse(q, k, v, 1024, 1024, interpret)
+            return (o.astype(jnp.float32) * w_o).sum() + (lse * w_l).sum(), (o, lse)
+
+        def ref(q, k, v):
+            o = xla_attention(q, k, v)
+            return (o.astype(jnp.float32) * w_o).sum(), o
+
+        def ref_with_lse(q, k, v):
+            o, lse = xla_attention(q, k, v), ref_lse(q, k)
+            return (o.astype(jnp.float32) * w_o).sum() + (lse * w_l).sum(), (o, lse)
+
+        for name, fn, ref_fn in (
+            ("flash", flash, ref),
+            ("flash_with_lse", flash_lse, ref_with_lse),
+        ):
+            grad = jax.value_and_grad(fn, argnums=(0, 1, 2), has_aux=True)
+            compiled = jax.jit(grad).lower(q, k, v).compile()
+            if not interpret:
+                check(
+                    "tpu_custom_call" in compiled.as_text(),
+                    f"{name} at {(b, s, h, d)}: no Mosaic custom call in the "
+                    "compiled program — the kernel is not what ran",
+                )
+            (_, aux), grads = compiled(q, k, v)
+            (_, ref_aux), ref_grads = jax.jit(
+                jax.value_and_grad(ref_fn, argnums=(0, 1, 2), has_aux=True)
+            )(q, k, v)
+            got = jax.tree_util.tree_leaves((aux, grads))
+            want = jax.tree_util.tree_leaves((ref_aux, ref_grads))
+            for g, r in zip(got, want):
+                check(bool(jnp.isfinite(g.astype(jnp.float32)).all()),
+                      f"{name} at {(b, s, h, d)}: non-finite output")
+                err = _rel_err(g, r)
+                check(
+                    err < KERNEL_REL_TOL,
+                    f"{name} at {(b, s, h, d)}: {err:.4f} off the XLA "
+                    f"reference (tolerance {KERNEL_REL_TOL})",
+                )
+                key = f"{name}@{s}x{d}"
+                worst[key] = round(max(worst.get(key, 0.0), err), 5)
+
+    # gather_impl="onehot" claims bit-identity with the XLA gather; the 0/1
+    # matmuls must keep it through the MXU (ViT-H/14 shapes, per-sample and
+    # shared mask modes)
+    key = jax.random.key(0)
+    x = jax.random.normal(key, (8, 259, 1280), jnp.bfloat16)
+    ids = jax.random.permutation(
+        jax.random.fold_in(key, 1), jnp.arange(259)[None].repeat(8, 0),
+        axis=1, independent=True,
+    )[:, :65]
+    shared = jax.random.permutation(jax.random.fold_in(key, 2), jnp.arange(259))[:65]
+    for i in (ids, shared):
+        take = jax.jit(lambda x, i: index_sequence(x, i, impl="take"))(x, i)
+        onehot = jax.jit(lambda x, i: index_sequence(x, i, impl="onehot"))(x, i)
+        check(
+            bool((np.asarray(take) == np.asarray(onehot)).all()),
+            "one-hot masking gather differs from the XLA gather",
+        )
+    return {
+        "shapes": [list(s) for s in shapes],
+        "mosaic_custom_call": not interpret,
+        "max_rel_err_vs_xla": worst,
+        "onehot_gather_bit_identical": True,
+    }
+
+
+# ------------------------------------------------------------------ train
+
+
+def _train_argv(recipe: str, overrides: list[str], out_dir: Path) -> list[str]:
+    return ["--config", recipe, "--set", *overrides, f"run.output_dir={out_dir}"]
+
+
+def _load(recipe: str, overrides: list[str]):
+    from jumbo_mae_tpu_tpu.config import load_config
+
+    return load_config(recipe, overrides)
+
+
+def phase_train(
+    recipe: str, overrides: list[str], out_dir: Path, *, steps: int
+) -> dict:
+    """``cli.train`` for ``steps`` steps ending in one eval and one
+    checkpoint save. Every logged loss finite, the last below the first, no
+    unexpected recompile; the rates the trainer logs ride along as
+    information."""
+    from jumbo_mae_tpu_tpu.cli import train as cli_train
+
+    before = _registry_snapshot()
+    cli_train.main(_train_argv(recipe, overrides, out_dir))
+    after = _registry_snapshot()
+
+    run = _load(recipe, overrides).run
+    run_dir = out_dir / run.name
+    records = _read_metrics(run_dir)
+    losses = _logged_losses(records, 1, steps, "train")
+    check(losses[steps] < losses[1],
+          f"loss did not fall: {losses[1]:.4f} -> {losses[steps]:.4f}")
+    evals = [r for r in records if "val/loss" in r]
+    check(len(evals) == 1 and np.isfinite(evals[0]["val/loss"]),
+          f"expected one finite eval, got {evals}")
+    check((run_dir / "ckpt" / "last").is_dir(), "no checkpoint was saved")
+    retraces = _delta(before, after, "retrace_events_total", "train")
+    check(retraces == 0, f"{retraces} unexpected recompile(s) after warmup")
+
+    # the rates of the last log window as the trainer logged them (every
+    # step fetched, log_interval=1): information, not a benchmark
+    last = max((r for r in records if "perf/images_per_sec" in r),
+               key=lambda r: r["step"])
+    return {
+        "steps": steps,
+        "loss_first": round(losses[1], 4),
+        "loss_last": round(losses[steps], 4),
+        "val_loss": round(float(evals[0]["val/loss"]), 4),
+        "unexpected_recompiles": 0,
+        "images_per_sec_per_chip": round(last["perf/images_per_sec_per_chip"], 1),
+        "step_ms": round(1e3 * run.train_batch_size / last["perf/images_per_sec"], 1),
+        "mfu_trainer_reported": last.get("perf/mfu"),
+        "peak_hbm_bytes_this_process": _peak_hbm_bytes(),
+    }
+
+
+def phase_resume(
+    recipe: str, overrides: list[str], out_dir: Path, *,
+    start: int, steps: int, watch: CompileWatch,
+) -> dict:
+    """The same command with ``run.resume=true`` for ``steps`` more: the
+    orbax checkpoint restores onto the device, and the second build of the
+    step program is a persistent-compile-cache hit."""
+    from jumbo_mae_tpu_tpu.cli import train as cli_train
+    from jumbo_mae_tpu_tpu.obs.journal import read_merged_journal
+
+    mark = watch.mark()
+    cli_train.main(_train_argv(recipe, [*overrides, "run.resume=true"], out_dir))
+    compiled = watch.since(mark)
+
+    run_dir = out_dir / _load(recipe, overrides).run.name
+    starts = [e for e in read_merged_journal(run_dir) if e.get("type") == "run_start"]
+    check(starts[-1].get("resumed") is True and starts[-1].get("start_step") == start,
+          f"the run did not resume from step {start}: {starts[-1]}")
+    losses = _logged_losses(_read_metrics(run_dir), start + 1, start + steps, "resume")
+    step_hits = [p for p in compiled["hit_programs"] if "train_step" in p]
+    check(bool(step_hits),
+          "the resumed run compiled its step program again instead of finding "
+          f"it in the persistent cache (hits: {compiled['hit_programs']})")
+    return {
+        "resumed_from": start,
+        "steps": steps,
+        "loss_last": round(losses[start + steps], 4),
+        "train_step_cache_hits": len(step_hits),
+    }
+
+
+# ------------------------------------------------------------------ serve
+
+
+def phase_serve(
+    recipe: str, overrides: list[str], out_dir: Path, *,
+    requests: int = 48, max_batch: int = 8, replicas: int = 2,
+) -> dict:
+    """``cli.predict`` features over ``requests`` synthetic images: bf16,
+    then int8, then a ``--serve --replicas`` pool (threads of this process).
+    Every request answered, features finite, int8 within the tolerance
+    ``quant.parity_report`` uses, nothing compiled after warmup."""
+    from jumbo_mae_tpu_tpu.cli import predict as cli_predict
+    from jumbo_mae_tpu_tpu.infer.bucketing import pow2_rungs
+    from jumbo_mae_tpu_tpu.infer.quant import FEATURE_COSINE_MIN, feature_cosine
+
+    base = [
+        "--config", recipe, "--task", "features", "--synthetic", str(requests),
+        "--max-batch", str(max_batch), "--warmup", "--set", *overrides,
+    ]
+    ladder = len(pow2_rungs(max_batch))
+    dispatches = -(-requests // max_batch)
+    task = "features:cls"
+    feats: dict[str, np.ndarray] = {}
+    result: dict = {"requests": requests, "ladder": ladder}
+
+    for leg, extra in (("bf16", []), ("int8", ["--quant", "int8"])):
+        before = _registry_snapshot()
+        out = cli_predict.main([*base, *extra, "--out", str(out_dir / f"features_{leg}.npz")])
+        after = _registry_snapshot()
+        f = np.load(out)["features"]
+        check(f.shape[0] == requests, f"{leg}: {f.shape[0]}/{requests} answered")
+        check(bool(np.isfinite(f).all()), f"{leg}: non-finite features")
+        feats[leg] = f
+
+        compiled = _delta(before, after, "infer_bucket_cache_misses_total", task)
+        loaded = _delta(before, after, "infer_warmcache_events_total", "hit")
+        resident = _delta(before, after, "infer_bucket_cache_hits_total", task)
+        # the warmup builds the ladder (compiled or loaded from the warm
+        # cache); every dispatch after it must find its executable resident
+        hot_path_compiles = compiled + loaded - ladder
+        check(hot_path_compiles == 0 and resident == dispatches,
+              f"{leg}: {hot_path_compiles} compile(s) on the request path "
+              f"({resident}/{dispatches} dispatches found their executable)")
+        result[leg] = {
+            "answered": int(f.shape[0]),
+            "warmup_compiled": compiled,
+            "warmup_loaded": loaded,
+            "hot_path_compiles": 0,
+        }
+
+    cos = feature_cosine(feats["bf16"], feats["int8"])
+    check(float(cos.min()) >= FEATURE_COSINE_MIN,
+          f"int8 features drifted: cosine min {cos.min():.5f} < {FEATURE_COSINE_MIN}")
+    result["int8_cosine_min"] = round(float(cos.min()), 5)
+
+    if replicas:
+        before = _registry_snapshot()
+        out = cli_predict.main([
+            *base, "--serve", "--replicas", str(replicas),
+            "--out", str(out_dir / "features_pool.npz"),
+        ])
+        after = _registry_snapshot()
+        f = np.load(out)["features"]
+        check(f.shape[0] == requests, f"pool: {f.shape[0]}/{requests} answered")
+        check(bool(np.isfinite(f).all()), "pool: non-finite features")
+        retraces = _delta(before, after, "retrace_events_total", "predict")
+        check(retraces == 0, f"pool: {retraces} compile(s) after warmup")
+        # both replicas serve the weights the direct path served
+        cos = feature_cosine(feats["bf16"], f)
+        check(float(cos.min()) >= FEATURE_COSINE_MIN,
+              f"pool features differ from the direct path: cosine min {cos.min():.5f}")
+        result["pool"] = {
+            "replicas": replicas,
+            "answered": int(f.shape[0]),
+            "hot_path_compiles": 0,
+        }
+    return result
+
+
+# ------------------------------------------------------------- four chips
+
+
+def phase_fsdp(
+    recipe: str, overrides: list[str], out_dir: Path, *,
+    chips: int, steps: int,
+) -> dict:
+    """``cli.train`` with the state sharded over ``chips`` devices
+    (``mesh.fsdp=chips``) against its control in this same process: the same
+    seed and global batch on ``mesh.fsdp=1``, where ``create_mesh`` takes
+    the first device as a sub-mesh. Losses must agree step for step, the
+    state must really be spread, and the step must hold the collectives."""
+    import jax
+
+    from jumbo_mae_tpu_tpu.cli import train as cli_train
+
+    losses: dict[int, dict[int, float]] = {}
+    in_use: dict[int, dict[str, float]] = {}
+    for n in (chips, 1):
+        run = [*overrides, "mesh.data=1", f"mesh.fsdp={n}", f"run.name=fsdp{n}"]
+        cli_train.main(_train_argv(recipe, run, out_dir))
+        losses[n] = _logged_losses(
+            _read_metrics(out_dir / f"fsdp{n}"), 1, steps, f"fsdp={n}"
+        )
+        # the trainer always saves at its last step; one state on disk at a
+        # time is enough
+        shutil.rmtree(out_dir / f"fsdp{n}" / "ckpt")
+        # the finished run's state sits in reference cycles (engine <-> its
+        # hooks); collect them so its device memory is free for the next run
+        gc.collect()
+        # memwatch sampled every device at the last log window, state live
+        in_use[n] = dict(_registry_snapshot().get("mem_device_bytes", {}))
+    for s in range(1, steps + 1):
+        a, b = losses[chips][s], losses[1][s]
+        check(abs(a - b) <= LOSS_REL_TOL * abs(b),
+              f"step {s}: sharded loss {a:.5f} vs one-chip {b:.5f}")
+
+    result = {
+        "chips": chips,
+        "steps": steps,
+        "loss_sharded": [round(losses[chips][s], 5) for s in range(1, steps + 1)],
+        "loss_one_chip": [round(losses[1][s], 5) for s in range(1, steps + 1)],
+    }
+    if in_use[1]:
+        sharded_max = max(in_use[chips].values())
+        control = max(in_use[1].values())
+        check(sharded_max < control,
+              f"per-device bytes in use {sharded_max:.3g} not below the "
+              f"one-chip control's {control:.3g}")
+        result["bytes_in_use_per_device_sharded"] = int(sharded_max)
+        result["bytes_in_use_one_chip"] = int(control)
+    else:
+        # XLA:CPU reports no memory_stats(); a TPU always does
+        check(jax.devices()[0].platform != "tpu", "no device memory stats on a TPU")
+        result["bytes_in_use_per_device_sharded"] = "not reported by this backend"
+
+    result.update(_check_fsdp_placement(
+        recipe, [*overrides, "mesh.data=1", f"mesh.fsdp={chips}"], chips
+    ))
+    return result
+
+
+def _check_fsdp_placement(recipe: str, overrides: list[str], chips: int) -> dict:
+    """Build the state and the step program with the trainer's own factories
+    and arguments (``cli.train.train`` keeps both to itself) and look at
+    where they landed: code that never ran on more than one chip may put
+    everything on the first."""
+    import jax
+
+    from jumbo_mae_tpu_tpu.cli.train import _example_batch, build_model
+    from jumbo_mae_tpu_tpu.data import synthetic_batches
+    from jumbo_mae_tpu_tpu.parallel import create_mesh
+    from jumbo_mae_tpu_tpu.train import (
+        create_sharded_state,
+        make_optimizer,
+        make_train_step,
+    )
+
+    cfg = _load(recipe, overrides)
+    run = cfg.run
+    mesh = create_mesh(cfg.mesh)
+    check(mesh.devices.size == chips, f"mesh spans {mesh.devices.size} devices")
+    model, enc_cfg, _ = build_model(cfg)
+    tx = make_optimizer(cfg.optim, run.train_batch_size, num_layers=enc_cfg.layers)
+    state, sharding = create_sharded_state(
+        model, tx, _example_batch(cfg, run.train_batch_size), mesh,
+        mode="pretrain", init_seed=run.init_seed, rng_seed=run.seed,
+        param_dtype=cfg.optim.param_dtype,
+    )
+
+    flat = jax.tree_util.tree_flatten_with_path(state.params)[0]
+    path, largest = max(flat, key=lambda kv: kv[1].size)
+    moments = [
+        leaf
+        for p, leaf in jax.tree_util.tree_flatten_with_path(state.opt_state)[0]
+        if p[-len(path):] == path and leaf.shape == largest.shape
+    ]
+    check(len(moments) >= 2, f"found {len(moments)} Adam moments of the largest param")
+    for arr in (largest, *moments):
+        shards = arr.addressable_shards
+        check(len({s.device for s in shards}) == chips,
+              f"{jax.tree_util.keystr(path)}: shards on "
+              f"{len({s.device for s in shards})} device(s), not {chips}")
+        check(len({str(s.index) for s in shards}) == chips
+              and all(s.data.nbytes * chips == arr.nbytes for s in shards),
+              f"{jax.tree_util.keystr(path)}: shards do not hold 1/{chips} each")
+
+    step = make_train_step(
+        mesh, sharding, mode="pretrain", grad_accum=run.grad_accum,
+        guard_nonfinite=run.sentinel,
+    )
+    batch = next(synthetic_batches(run.train_batch_size, cfg.data.image_size, seed=run.seed))
+    step(state, {"images": batch["images"]})
+    (compiled,) = step.executables.values()
+    text = compiled.as_text()
+    collectives = {
+        op: text.count(f" {op}(") + text.count(f" {op}-start(")
+        for op in ("all-gather", "reduce-scatter", "all-reduce")
+    }
+    check(collectives["all-gather"] > 0, "no all-gather in the sharded step")
+    check(collectives["reduce-scatter"] + collectives["all-reduce"] > 0,
+          "no gradient reduction collective in the sharded step")
+    return {
+        "largest_param": jax.tree_util.keystr(path),
+        "largest_param_bytes_per_shard": int(largest.addressable_shards[0].data.nbytes),
+        "moments_checked": len(moments),
+        "collectives": collectives,
+    }
+
+
+# ------------------------------------------------------------------ driver
+
+
+def run_phase(name: str, fn, out_dir: Path, watch: CompileWatch) -> bool:
+    """Run one phase with the entry points' stdout in ``<out>/<name>.log``;
+    print its JSON line; never raise."""
+    log_path = out_dir / f"{name}.log"
+    gc.collect()  # free the previous phase's device memory (see phase_fsdp)
+    mark = watch.mark()
+    t0 = time.perf_counter()
+    line: dict = {"phase": name}
+    try:
+        with open(log_path, "w") as log, contextlib.redirect_stdout(log):
+            checked = fn()
+        line |= {"passed": True}
+    except (Exception, SystemExit) as e:  # a CLI's SystemExit fails a phase too
+        traceback.print_exc(file=sys.stderr)
+        tail = log_path.read_text()[-4000:] if log_path.exists() else ""
+        print(f"--- tail of {log_path} ---\n{tail}", file=sys.stderr)
+        checked = None
+        line |= {"passed": False, "error": f"{type(e).__name__}: {e}"[:600]}
+    compiled = watch.since(mark)
+    line |= {
+        "wall_s": round(time.perf_counter() - t0, 1),
+        "compile_s": compiled["compile_s"],
+        "compiles": compiled["compiles"],
+        "cache_hits": compiled["cache_hits"],
+        "cache_misses": compiled["cache_misses"],
+    }
+    if checked is not None:
+        line["checked"] = checked
+    print(json.dumps(line), flush=True)
+    return line["passed"]
+
+
+# run-control overrides shared by every trainer phase. The recipes count in
+# epochs; with dataset_size equal to the global batch an epoch is one step,
+# so ``run.epochs`` is the step count. The schedule's horizon is pinned
+# (optim.training_steps) so that a resumed run builds the same program.
+def _trainer_overrides(batch: int, steps: int, horizon: int) -> list[str]:
+    return [
+        "run.synthetic_data=true",
+        "run.use_wandb=false",
+        "run.sanity_eval=false",
+        f"run.train_batch_size={batch}",
+        f"run.valid_batch_size={batch}",
+        f"data.dataset_size={batch}",
+        f"run.epochs={steps}",
+        f"run.eval_interval={steps}",
+        "run.log_interval=1",
+        f"optim.training_steps={horizon}",
+        "optim.warmup_epochs=1",
+    ]
+
+
+def main(argv: list[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--out", default=str(REPO / "runs" / "chip_smoke"),
+                    help="work directory (logs, checkpoints, features)")
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: only the sharded-training phase and its control")
+    args = ap.parse_args(argv)
+
+    from jumbo_mae_tpu_tpu.utils.procenv import enable_compile_cache
+
+    cache = enable_compile_cache()
+    import jax
+
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        print(f"chip_smoke: needs a TPU, found platform {devices[0].platform!r}",
+              file=sys.stderr)
+        return 2
+    if len(devices) < args.chips:
+        print(f"chip_smoke: --chips {args.chips} on {len(devices)} device(s)",
+              file=sys.stderr)
+        return 2
+    out = Path(args.out) / time.strftime("%Y%m%d-%H%M%S")
+    out.mkdir(parents=True)
+    print(f"chip_smoke: work dir {out}, compile cache {cache}", file=sys.stderr)
+    watch = CompileWatch()
+
+    if args.chips == 4:
+        steps = 3
+        phases = [(
+            "fsdp",
+            lambda: phase_fsdp(
+                H14_RECIPE, _trainer_overrides(64, steps, steps), out,
+                chips=4, steps=steps,
+            ),
+        )]
+    else:
+        steps, more = 8, 2
+        train = _trainer_overrides(128, steps, steps + more)
+        resume = _trainer_overrides(128, steps + more, steps + more)
+        phases = [
+            ("kernels", phase_kernels),
+            ("train", lambda: phase_train(L16_RECIPE, train, out, steps=steps)),
+            ("resume", lambda: phase_resume(
+                L16_RECIPE, resume, out, start=steps, steps=more, watch=watch)),
+            ("serve", lambda: phase_serve(L16_RECIPE, [], out)),
+        ]
+
+    passed = {}
+    for name, fn in phases:
+        if name == "resume" and not passed.get("train"):
+            print(json.dumps({"phase": name, "passed": False,
+                              "error": "skipped: the train phase failed"}), flush=True)
+            passed[name] = False
+            continue
+        passed[name] = run_phase(name, fn, out, watch)
+    if not all(passed.values()):
+        return 1
+    print(json.dumps({
+        "ok": True,
+        "device": {
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+        },
+    }))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

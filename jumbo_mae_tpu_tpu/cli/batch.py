@@ -101,7 +101,9 @@ def main(argv: list[str] | None = None) -> int:
     if args.config:
         from jumbo_mae_tpu_tpu.config import load_config
         from jumbo_mae_tpu_tpu.infer import InferenceEngine
+        from jumbo_mae_tpu_tpu.utils.procenv import enable_compile_cache
 
+        enable_compile_cache()
         cfg = load_config(args.config, [])
 
         def provider(idx):

@@ -248,9 +248,10 @@ def build_parser() -> argparse.ArgumentParser:
         "--warmcache",
         default=None,
         metavar="DIR",
-        help="persistent executable cache directory (default: the per-host "
-        "dir under ~/.cache/jumbo_mae_tpu/warmcache; restarted replicas "
-        "load instead of compiling)",
+        help="persistent executable cache directory (default: warmcache/ "
+        "under the compile cache — $JAX_COMPILATION_CACHE_DIR, else "
+        "<checkout>/.jax_cache; restarted replicas load instead of "
+        "compiling)",
     )
     p.add_argument(
         "--no-warmcache",
@@ -310,7 +311,9 @@ def main(argv: list[str] | None = None) -> Path | None:
 
     from jumbo_mae_tpu_tpu.config import load_config
     from jumbo_mae_tpu_tpu.infer import InferenceEngine, MicroBatcher
+    from jumbo_mae_tpu_tpu.utils.procenv import enable_compile_cache
 
+    enable_compile_cache()
     if jax.process_count() > 1:
         raise SystemExit("predict is a single-process tool; run it on one host")
     if bool(args.images) == bool(args.synthetic):

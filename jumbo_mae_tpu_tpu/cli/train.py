@@ -112,10 +112,12 @@ from jumbo_mae_tpu_tpu.utils import (
     MetricLogger,
     StepTimer,
     classify_flops_per_image,
+    detect_peak_tflops,
     mfu_report,
     param_summary,
     pretrain_flops_per_image,
 )
+from jumbo_mae_tpu_tpu.utils.procenv import enable_compile_cache
 
 
 def build_model(cfg: TrainConfig):
@@ -547,7 +549,9 @@ def _apply_override_resume(
             world=process_count,
             host=host_index,
         )
-    except Exception as e:  # noqa: BLE001 - epoch resume still works
+    except (OSError, ValueError, KeyError) as e:
+        # no usable shard cursors in the journal (pre-elastic checkpoint,
+        # journal disabled or unreadable): epoch resume still works
         print(
             f"[train] WARNING: resize-consistent resume unavailable "
             f"({e}); falling back to epoch resume"
@@ -972,6 +976,15 @@ def train(cfg: TrainConfig) -> dict:
 
         retrace_sentinel = RetraceSentinel("train", journal=journal)
 
+    def _rt_expected(reason: str):
+        """Compiles inside are legitimate: a one-off eval, and the small
+        slice programs a checkpoint save of a sharded state compiles."""
+        return (
+            retrace_sentinel.expected(reason)
+            if retrace_sentinel is not None
+            else contextlib.nullcontext()
+        )
+
     if journal is not None:
         health.probe("journal", lambda: str(journal.path))
     _emit(
@@ -1094,7 +1107,10 @@ def train(cfg: TrainConfig) -> dict:
     )
     meter = AverageMeter()
     timer = StepTimer(warmup_steps=min(2, max(1, run.training_steps - 1)))
-    n_chips = len(jax.devices())
+    # the chips this run computes on: a sub-mesh (mesh.data=1 mesh.fsdp=1 on
+    # a four-chip host) leaves the other devices idle, and they must not
+    # dilute the per-chip rates
+    n_chips = mesh.devices.size
     last_metrics: dict[str, float] = {}
     # divergence sentinel (faults/sentinel.py): the device guard inside the
     # step skips non-finite updates; this host half watches the fetched
@@ -1150,6 +1166,9 @@ def train(cfg: TrainConfig) -> dict:
     # journaled, and folded into the MFU/HFU split + drift gauge below.
     step_cost = None  # None = not yet extracted, False = gave up
     chip = detect_chip()
+    # None on the CPU backend: a CPU count is not a device rate, so no
+    # perf/mfu, perf/*_utilization or perf/tflops_per_chip is published there
+    peak_tflops = detect_peak_tflops()
     # memory observability (obs/memwatch.py): log-boundary device/host
     # samples + per-component byte accounting + the leak sentinel. The
     # fault ballast probe makes the injected host.leak chaos site show up
@@ -1366,25 +1385,38 @@ def train(cfg: TrainConfig) -> dict:
         sps = timer.steps_per_sec
         if sps:
             imgs = sps * run.train_batch_size
-            rep = mfu_report(flops_per_image, imgs / n_chips)
             summary |= {
                 "perf/images_per_sec": imgs,
                 "perf/images_per_sec_per_chip": imgs / n_chips,
-                "perf/mfu": rep.mfu,
-                "perf/tflops_per_chip": rep.achieved_tflops,
             }
-            g_mfu.set(rep.mfu)
             g_ips.set(imgs)
-            if step_cost:
-                # MFU (analytic model flops) vs HFU (XLA-counted,
-                # remat recompute included) + roofline drift
-                util = utilization_report(
-                    flops_per_image * run.train_batch_size,
-                    step_cost.flops,
-                    sps,
-                    n_chips=n_chips,
-                    peak_tflops=rep.peak_tflops,
+            if peak_tflops is not None:
+                rep = mfu_report(
+                    flops_per_image, imgs / n_chips, peak_tflops=peak_tflops
                 )
+                summary |= {
+                    "perf/mfu": rep.mfu,
+                    "perf/tflops_per_chip": rep.achieved_tflops,
+                }
+                g_mfu.set(rep.mfu)
+            if step_cost:
+                # roofline drift; on an accelerator also MFU (analytic
+                # model flops) vs HFU (XLA-counted, remat recompute included)
+                if peak_tflops is not None:
+                    util = utilization_report(
+                        flops_per_image * run.train_batch_size,
+                        step_cost.flops,
+                        sps,
+                        n_chips=n_chips,
+                        peak_tflops=peak_tflops,
+                    )
+                    summary |= {
+                        "perf/model_flops_utilization": rep.mfu,
+                        "perf/hardware_flops_utilization": (
+                            util.hardware_flops_utilization
+                        ),
+                    }
+                    g_hfu.set(util.hardware_flops_utilization)
                 pred = roofline(
                     step_cost.flops,
                     step_cost.bytes_accessed,
@@ -1395,14 +1427,9 @@ def train(cfg: TrainConfig) -> dict:
                     pred.step_time_s, 1.0 / sps, program="train_step"
                 )
                 summary |= {
-                    "perf/model_flops_utilization": rep.mfu,
-                    "perf/hardware_flops_utilization": (
-                        util.hardware_flops_utilization
-                    ),
                     "perf/predicted_step_ms": pred.step_time_s * 1e3,
                     "perf/predict_vs_measured": drift,
                 }
-                g_hfu.set(util.hardware_flops_utilization)
         now = time.perf_counter()
         wait_frac = window_wait / max(now - window_t0, 1e-9)
         g_wait_frac.set(wait_frac)
@@ -1597,14 +1624,8 @@ def train(cfg: TrainConfig) -> dict:
         if valid_factory is None:
             return None
         t0_eval = time.perf_counter()
-        with _hw_expected("eval"):
-            if retrace_sentinel is not None:
-                with retrace_sentinel.expected("eval"):
-                    val = evaluate(
-                        eval_step, state_now, valid_factory(), pad_batch
-                    )
-            else:
-                val = evaluate(eval_step, state_now, valid_factory(), pad_batch)
+        with _hw_expected("eval"), _rt_expected("eval"):
+            val = evaluate(eval_step, state_now, valid_factory(), pad_batch)
         ledger.add("eval", time.perf_counter() - t0_eval)
         logger.log(val, step=step)
         last_metrics |= val
@@ -1624,7 +1645,7 @@ def train(cfg: TrainConfig) -> dict:
         step = cev.step
         if cev.reason == "preemption":
             snap = _gather_data_cursor(cursor_log.get(step))
-            with _hw_expected("checkpoint"), sp_ckpt:
+            with _hw_expected("checkpoint"), _rt_expected("checkpoint"), sp_ckpt:
                 ckpt.save(
                     step,
                     eng.state,
@@ -1638,7 +1659,7 @@ def train(cfg: TrainConfig) -> dict:
         extra = {"data_cursor": snap} if snap is not None else None
         for k in [k for k in cursor_log if k <= step]:
             del cursor_log[k]
-        with _hw_expected("checkpoint"), sp_ckpt:
+        with _hw_expected("checkpoint"), _rt_expected("checkpoint"), sp_ckpt:
             ckpt.save(step, eng.state, metrics=cev.metrics, extra=extra)
         cev.save_seconds = round(sp_ckpt.last_s, 3)
         ledger.add("ckpt_save", sp_ckpt.last_s)
@@ -1925,7 +1946,10 @@ def _run_elastic(args) -> int:
 def main(argv: list[str] | None = None):
     args = build_parser().parse_args(argv)
     if args.elastic:
+        # the supervisor only starts children: it must never call into jax,
+        # or it would hold the chip its children need
         raise SystemExit(_run_elastic(args))
+    enable_compile_cache()
     if args.distributed:
         if os.environ.get("JAX_PLATFORMS", "") == "cpu":
             # multi-process CPU (the CI fleet smoke): cross-process

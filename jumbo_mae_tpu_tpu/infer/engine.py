@@ -1,6 +1,6 @@
 """The batched inference engine: shape-bucketed AOT executables.
 
-Training drove the per-step roofline (PERF.md); this module is the serving
+Training drove the per-step roofline (PERF_ARCHIVE.md); this module is the serving
 counterpart. The design moves every once-per-model cost out of the request
 path:
 
@@ -20,8 +20,9 @@ path:
   default every compile is published to the persistent
   :class:`~jumbo_mae_tpu_tpu.infer.warmcache.WarmCache` and a restarted
   replica's warmup deserializes the ladder instead of recompiling it
-  (``warm_cache=False`` opts out; the ``JUMBO_WARMCACHE*`` env knobs are
-  documented on ``utils/procenv.default_warmcache_dir``). Warmup runs the
+  (``warm_cache=False`` opts out; the default root and its
+  ``JUMBO_WARMCACHE=0`` switch are documented on
+  ``utils/procenv.default_warmcache_dir``). Warmup runs the
   ladder from a small thread pool — XLA compiles release the GIL.
 - **Weights can be int8.** ``quant="int8"`` quantizes each task's params
   tree (``infer/quant.py``: per-output-channel weight-only PTQ) and the
@@ -58,6 +59,7 @@ machinery stays in the training stack.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import os
 import threading
@@ -77,6 +79,7 @@ from jumbo_mae_tpu_tpu.infer.bucketing import ceil_pow2
 from jumbo_mae_tpu_tpu.infer.quant import dequantize_tree, quantize_params
 from jumbo_mae_tpu_tpu.obs import lockwatch
 from jumbo_mae_tpu_tpu.obs.metrics import RATIO_BUCKETS, get_registry
+from jumbo_mae_tpu_tpu.obs.perfmodel import detect_chip, roofline
 from jumbo_mae_tpu_tpu.models import (
     DecoderConfig,
     JumboViT,
@@ -92,11 +95,7 @@ from jumbo_mae_tpu_tpu.train.checkpoint import (
     require_loaded,
     restore_inference_state,
 )
-from jumbo_mae_tpu_tpu.utils.procenv import (
-    default_warmcache_dir,
-    enable_compile_cache,
-    host_fingerprint,
-)
+from jumbo_mae_tpu_tpu.utils.procenv import default_warmcache_dir
 
 POOLS = ("cls", "gap", "tokens")
 
@@ -138,6 +137,15 @@ def _to_state_dict(tree) -> dict:
 # exactly the ops of the fused reconstruction forward — same modules, same
 # order, same PRNG consumption — so the mask is bit-identical to the fused
 # path and the reconstruction matches to fusion-level float tolerance.
+
+
+@functools.partial(jax.jit, static_argnums=(0, 3))
+def _init_variables(model, rngs, example, static_args: tuple = ()):
+    """``model.init`` as one compiled program, not thousands of small ops
+    dispatched (and compiled) one by one. The module is a static argument,
+    so every engine of one architecture in a process — the replicas of a
+    pool, the int8 twin — shares the compile."""
+    return model.init(rngs, example, *static_args)
 
 
 def _mae_encode(mdl, images, deterministic: bool = True):
@@ -204,14 +212,12 @@ class InferenceEngine:
         encoder_cache: int = 0,
         encoder_cache_bytes: int = 0,
         on_compile: Callable[[str, int], None] | None = None,
-        compile_cache: str | None = None,
         registry=None,
     ):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
         if quant not in (None, "int8"):
             raise ValueError(f"quant must be None or 'int8', got {quant!r}")
-        enable_compile_cache(compile_cache)
         # telemetry handles resolved once (obs/metrics.py): the hot path only
         # ever pays a counter inc / histogram observe, and a NullRegistry
         # default turns every site into a no-op with no branches here
@@ -318,6 +324,9 @@ class InferenceEngine:
             "packed-parity gate failures (cosine or top-1 below threshold)",
         )
         self._registry = reg
+        # resolved here, not inside the best-effort cost publication: an
+        # accelerator that is not in the spec tables is an error
+        self._chip = detect_chip()
         self.cfg = cfg
         self.max_batch = int(max_batch)
         # packed-path token budget ceiling: the rung ladder tops out here
@@ -502,8 +511,11 @@ class InferenceEngine:
         rngs = {"params": jax.random.key(self.cfg.run.init_seed)}
         if task == "features":
             model = JumboViT(self._enc)
-            variables = model.init(
-                rngs, normalize_images(example, dtype=self._enc.compute_dtype), True
+            variables = _init_variables(
+                model,
+                rngs,
+                normalize_images(example, dtype=self._enc.compute_dtype),
+                (True,),
             )
             params = self._graft(task, variables["params"], subtree="", whole=False)
             return self._finish_task(
@@ -519,8 +531,11 @@ class InferenceEngine:
                 labels=int(self._labels), batch_norm=self._batch_norm
             )
             model = JumboViT(enc)
-            variables = model.init(
-                rngs, normalize_images(example, dtype=enc.compute_dtype), True
+            variables = _init_variables(
+                model,
+                rngs,
+                normalize_images(example, dtype=enc.compute_dtype),
+                (True,),
             )
             params = self._graft(task, variables["params"], subtree="", whole=False)
             batch_stats = variables.get("batch_stats")
@@ -542,8 +557,8 @@ class InferenceEngine:
             model = MAEPretrainModel(
                 enc, self._dec, norm_pix_loss=self.cfg.model.norm_pix_loss
             )
-            variables = model.init(
-                {**rngs, "noise": jax.random.key(0)}, example
+            variables = _init_variables(
+                model, {**rngs, "noise": jax.random.key(0)}, example
             )
             params = self._graft(task, variables["params"], subtree="", whole=True)
             return self._finish_task(
@@ -647,9 +662,10 @@ class InferenceEngine:
         """Everything the traced serving programs depend on besides their
         runtime arguments. Params and BatchNorm stats are arguments, so
         checkpoints of one architecture share warmcache entries; jax/jaxlib
-        versions and the host CPU fingerprint are included because XLA:CPU
-        executables embed machine features and PjRt serialization is not
-        stable across versions."""
+        versions are included because PjRt serialization is not stable
+        across them, and the device: its kind on an accelerator, the host
+        CPU's identity on the CPU backend (XLA:CPU executables embed machine
+        features)."""
         import jaxlib
 
         def cfg_dict(c):
@@ -667,7 +683,11 @@ class InferenceEngine:
                 "jax": jax.__version__,
                 "jaxlib": jaxlib.__version__,
                 "backend": jax.default_backend(),
-                "host": host_fingerprint(),
+                "device": (
+                    wc.host_fingerprint()
+                    if jax.default_backend() == "cpu"
+                    else jax.devices()[0].device_kind
+                ),
             }
         )
 
@@ -966,7 +986,6 @@ class InferenceEngine:
         roofline prediction for the drift gauge. Best-effort throughout."""
         try:
             from jumbo_mae_tpu_tpu.obs.costmodel import extract_cost, publish_cost
-            from jumbo_mae_tpu_tpu.obs.perfmodel import detect_chip, roofline
 
             cost = extract_cost(ex, key[0])
             if cost is None:
@@ -979,7 +998,7 @@ class InferenceEngine:
             pred = roofline(
                 cost.flops,
                 cost.bytes_accessed,
-                detect_chip(),
+                self._chip,
                 batch=key[1],
                 peak_hbm_bytes=cost.peak_bytes,
             )

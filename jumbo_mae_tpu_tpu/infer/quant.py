@@ -1,6 +1,6 @@
 """Int8 weight-only post-training quantization for the serving forward.
 
-PERF.md's per-op accounting puts the serving-relevant shapes in the
+PERF_ARCHIVE.md's per-op accounting puts the serving-relevant shapes in the
 weight-HBM-bandwidth-bound regime at small batch: every request streams the
 full parameter set through the MXU once, so halving parameter bytes halves
 the dominant term. This module converts a restored f32 params tree into

@@ -10,20 +10,19 @@ compiling.
 
 Design constraints, in order:
 
-- **A corrupt entry must never crash the process.** The seed's history
-  documents XLA:CPU aborting the whole process deserializing a truncated
-  cache entry (jax's internal compilation cache writes non-atomically; a
-  ``timeout -k``'d test run poisoned it permanently — see
-  ``utils/procenv.claim_compile_cache``). Here a sha256 digest over the
-  payload is verified *before* any bytes reach XLA, writes are atomic
-  (unique tmp + ``os.replace``), and any entry that fails the header,
+- **A corrupt entry must never crash the process.** A writer killed
+  mid-write must not leave a truncated serialized executable for XLA to
+  deserialize: a sha256 digest over the payload is verified *before* any
+  bytes reach XLA, writes are atomic (unique tmp + ``os.replace``), and
+  any entry that fails the header,
   digest, unpickle, or XLA load is moved to ``quarantine/`` — kept for a
   postmortem, never retried.
 - **Keyed so reuse is provably safe.** The entry name carries the model
   fingerprint (every architecture/config field the traced program depends
-  on, plus jax/jaxlib versions, backend, and the host CPU fingerprint —
-  XLA:CPU executables embed machine features), the task, the bucket, the
-  compute dtype, and the quant mode. Parameters are executable *arguments*,
+  on, plus jax/jaxlib versions, backend, and the device kind — on the CPU
+  backend the host CPU fingerprint, since XLA:CPU executables embed machine
+  features), the task, the bucket, the compute dtype, and the quant mode.
+  Parameters are executable *arguments*,
   not constants, so different checkpoints of the same architecture share
   entries by construction — the engine keeps anything value-dependent
   (BatchNorm stats included) out of closure constants.
@@ -55,6 +54,29 @@ from jumbo_mae_tpu_tpu.obs.journal import fsync_dir
 # cleanly (no attempt to parse an incompatible layout)
 MAGIC = b"JWC1"
 _DIGEST_LEN = 32  # sha256
+
+
+def host_fingerprint() -> str:
+    """Short stable hash of this host's CPU identity. Part of the entry key
+    on the CPU backend only: XLA:CPU executables embed the compiling
+    machine's CPU features, so an entry must never load on another CPU."""
+    import platform
+
+    parts = [platform.machine()]
+    wanted = {"model name", "flags", "Features", "CPU implementer"}
+    seen: set[str] = set()
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                key = line.split(":", 1)[0].strip()
+                if key in wanted and key not in seen:
+                    seen.add(key)
+                    parts.append(line.strip())
+                if seen == wanted:
+                    break
+    except OSError:
+        pass
+    return hashlib.sha256("\n".join(parts).encode()).hexdigest()[:12]
 
 
 def fingerprint(spec: dict) -> str:
@@ -161,8 +183,18 @@ class WarmCache:
                 deserialize_and_load,
             )
 
+            import jax
+
             serialized, in_tree, out_tree = pickle.loads(payload)
-            ex = deserialize_and_load(serialized, in_tree, out_tree)
+            # the engine's executables run on the default device; without
+            # execution_devices jax loads for EVERY local device, and on a
+            # multi-chip host the first call fails for want of N shards
+            ex = deserialize_and_load(
+                serialized,
+                in_tree,
+                out_tree,
+                execution_devices=jax.local_devices()[:1],
+            )
         except Exception as e:  # noqa: BLE001 — any corruption is a miss
             self._quarantine(path, e)
             self.misses += 1

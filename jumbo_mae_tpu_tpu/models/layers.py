@@ -8,7 +8,7 @@ FeedForward, ViTLayer, JumboLayer, LinearCLS), designed TPU-first:
 - attention scores accumulate in float32 on the MXU and softmax computes in
   float32, but the materialized score/prob tensors follow the compute dtype
   (halves the O(S²) HBM traffic under bf16; exact under f32 compute, which
-  is what every parity test runs — see PERF.md);
+  is what every parity test runs — see PERF_ARCHIVE.md);
 - attention implementation switchable between a fused Pallas flash kernel and
   the plain einsum path (the einsum path is also the parity oracle in tests).
 
@@ -98,7 +98,7 @@ class Attention(nn.Module):
     microbench (2.55 vs 2.8–3.8 ms at the H/14 encoder slice) but LOST
     7% step-level on H/14 (269–270 vs 292 img/s, two runs) — in the full
     graph XLA fuses the 4-D contraction's output layout straight into
-    the attention einsums, which the reshape breaks. PERF.md §Round 5.
+    the attention einsums, which the reshape breaks. PERF_ARCHIVE.md §Round 5.
     """
 
     cfg: ConfigT
@@ -180,7 +180,7 @@ class Attention(nn.Module):
             # (the convert fuses into the softmax chain). Under bf16 compute
             # this halves the HBM traffic of the O(S²) score tensor — the
             # single largest bandwidth item in the profile: −27 ms/step on
-            # the v5e bench workload's 8 decoder layers (PERF.md). Only the
+            # the v5e bench workload's 8 decoder layers (PERF_ARCHIVE.md). Only the
             # materialized rounding is bf16; with float32 compute (all
             # parity tests/oracles) the path is exact and unchanged.
             logits = jnp.einsum("bqhd,bkhd->bhqk", q, k)
@@ -198,7 +198,7 @@ class Attention(nn.Module):
             # Keep z head-major (B,H,S,D) — the layout the scores matmul
             # produces natively — and let the output projection contract
             # (h, d) from there: measured −17% attention fwd+bwd on v5e at
-            # the encoder shape vs transposing back to (B,S,H,D) (PERF.md).
+            # the encoder shape vs transposing back to (B,S,H,D) (PERF_ARCHIVE.md).
             z, z_head_major = jnp.einsum("bhqk,bkhd->bhqd", probs, v), True
 
         # kernel shape is (heads, head_dim, dim) for either axis choice, so

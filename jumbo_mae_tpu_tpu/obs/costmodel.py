@@ -176,15 +176,12 @@ def utilization_report(
     steps_per_sec: float,
     *,
     n_chips: int = 1,
-    peak_tflops: float | None = None,
+    peak_tflops: float,
 ) -> UtilizationReport:
     """MFU (analytic model flops) vs HFU (XLA-counted flops, remat included)
     over one throughput measurement. ``xla_flops_per_step`` is the whole
-    program's count; both are divided across ``n_chips``."""
-    if peak_tflops is None:
-        from jumbo_mae_tpu_tpu.obs.mfu import detect_peak_tflops
-
-        peak_tflops = detect_peak_tflops()
+    program's count; both are divided across ``n_chips``. ``peak_tflops``
+    is the chip's table entry (``obs.mfu.detect_peak_tflops``)."""
     peak = max(float(peak_tflops), 1e-12)
     model_t = analytic_flops_per_step / max(n_chips, 1) * steps_per_sec / 1e12
     hw_t = (

@@ -18,7 +18,7 @@ Two halves:
     events at checkpoint boundaries and shutdown. ``idle`` is the residual
     (wall − attributed), clamped at zero — so the conservation failure
     mode this catches is *over*-attribution (double counting), which is
-    exactly the bug class a bucket taxonomy invites.
+    exactly the bug class a scheme of buckets invites.
 
 ``stitch_generations`` (offline, cross-process)
     An elastic run is several process generations separated by supervisor

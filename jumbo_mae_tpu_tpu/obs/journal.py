@@ -385,7 +385,7 @@ def env_fingerprint() -> dict:
         info["process_count"] = jax.process_count()
     except Exception:  # noqa: BLE001 - fingerprint must never fail a run
         info["jax"] = "unavailable"
-    for var in ("JAX_PLATFORMS", "GRAFT_FAULTS", "JUMBO_COMPILE_CACHE"):
+    for var in ("JAX_PLATFORMS", "GRAFT_FAULTS"):
         if os.environ.get(var):
             info.setdefault("env", {})[var] = os.environ[var]
     return info

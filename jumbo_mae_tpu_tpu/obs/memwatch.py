@@ -39,7 +39,7 @@ time:
 
 Sampling is log-boundary / scrape-rate work, never per-step: one
 ``/proc/self/status`` read, one ``memory_stats()`` call per device, and
-one cheap probe per registered component (PERF.md §Memwatch overhead).
+one cheap probe per registered component (PERF_ARCHIVE.md §Memwatch overhead).
 """
 
 from __future__ import annotations

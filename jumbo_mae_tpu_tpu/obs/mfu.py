@@ -10,19 +10,17 @@ through the metrics registry (``utils/mfu.py`` remains as a compat shim).
 
 from __future__ import annotations
 
-import sys
 from dataclasses import dataclass
 
 # Peak dense bf16 TFLOP/s per chip by TPU generation (public spec sheet
-# numbers; override via ``peak_tflops=`` for other hardware).
+# numbers). A device that is not here is an error, never a default.
 PEAK_TFLOPS = {
     "v2": 46.0,
     "v3": 123.0,
     "v4": 275.0,
     "v5e": 197.0,
     # PJRT device_kind spells the e-variants "lite": 'TPU v5 lite',
-    # 'TPU v6 lite' (observed live; the v5e key alone never matched, which
-    # silently disabled bench.py's timing-plausibility guard on real v5e)
+    # 'TPU v6 lite' (the v5e key alone never matches a real v5e)
     "v5 lite": 197.0,
     "v5litepod": 197.0,
     "v5p": 459.0,
@@ -36,8 +34,6 @@ PEAK_TFLOPS = {
 # v5e" bug class can't come back per-table.
 CANONICAL_KINDS = {"v5 lite": "v5e", "v5litepod": "v5e", "v6 lite": "v6e"}
 
-_warned_kinds: set[str] = set()
-
 
 def normalize_device_kind(kind: str) -> str | None:
     """Map a raw PJRT ``device_kind`` string ('TPU v5 lite', 'TPU v4', ...)
@@ -49,34 +45,17 @@ def normalize_device_kind(kind: str) -> str | None:
     return None
 
 
-def lookup_peak_tflops(kind: str, default: float | None = None) -> float | None:
-    """Peak bf16 TFLOP/s for a device_kind string.
-
-    An unmatched kind is an observability event, not a silent default: warn
-    once per kind on stderr and set ``mfu_peak_unknown{kind}`` so a scrape
-    shows the timing-plausibility guard is running blind."""
+def lookup_peak_tflops(kind: str) -> float:
+    """Peak bf16 TFLOP/s for a device_kind string. A kind that is not in
+    the table is an error, not a default: a utilization against a guessed
+    peak is not a measurement."""
     gen = normalize_device_kind(kind)
-    if gen is not None:
-        return PEAK_TFLOPS[gen]
-    if kind not in _warned_kinds:
-        _warned_kinds.add(kind)
-        print(
-            f"[mfu] unknown device_kind {kind!r}: no peak-TFLOPS entry — "
-            f"MFU and timing-plausibility checks fall back to "
-            f"default={default}",
-            file=sys.stderr,
+    if gen is None:
+        raise ValueError(
+            f"device_kind {kind!r} has no PEAK_TFLOPS entry — add its "
+            "spec-sheet peak to obs/mfu.py before reporting utilization on it"
         )
-        try:
-            from jumbo_mae_tpu_tpu.obs.metrics import get_registry
-
-            get_registry().gauge(
-                "mfu_peak_unknown",
-                "1 when the backend device_kind has no PEAK_TFLOPS entry",
-                labels=("kind",),
-            ).labels(str(kind)).set(1)
-        except Exception:  # noqa: BLE001 - telemetry must not fail lookup
-            pass
-    return default
+    return PEAK_TFLOPS[gen]
 
 
 def _attention_flops(seq: int, dim: int, *, causal: bool = False) -> float:
@@ -135,16 +114,17 @@ def classify_flops_per_image(enc_cfg, *, training: bool = True) -> float:
     return fwd * (3.0 if training else 1.0)
 
 
-def detect_peak_tflops(default: float = 275.0) -> float:
-    """Best-effort peak bf16 TFLOP/s of the current accelerator."""
-    try:
-        import jax
+def detect_peak_tflops() -> float | None:
+    """Peak bf16 TFLOP/s of the current backend's first device, or None on
+    the CPU backend — a CPU has no table entry, and a rate against a made-up
+    peak is not a device metric. An accelerator whose kind is not in the
+    table raises (:func:`lookup_peak_tflops`)."""
+    import jax
 
-        kind = jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 - no backend → default
-        return default
-    peak = lookup_peak_tflops(kind, default=default)
-    return default if peak is None else peak
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    return lookup_peak_tflops(dev.device_kind)
 
 
 @dataclass
@@ -163,13 +143,12 @@ def mfu_report(
     flops_per_image: float,
     images_per_sec_per_chip: float,
     *,
-    peak_tflops: float | None = None,
+    peak_tflops: float,
 ) -> MfuReport:
-    peak = peak_tflops if peak_tflops is not None else detect_peak_tflops()
     achieved = flops_per_image * images_per_sec_per_chip / 1e12
     return MfuReport(
         images_per_sec=images_per_sec_per_chip,
         flops_per_image=flops_per_image,
         achieved_tflops=achieved,
-        peak_tflops=peak,
+        peak_tflops=peak_tflops,
     )

@@ -21,9 +21,10 @@ Two uses:
   window, so a run that detaches from its own roofline (input stall, host
   sync, background noise) is visible as a ratio, not a vibe.
 
-Chip tables are public spec-sheet numbers; CPU (and any unknown kind) gets
-an order-of-magnitude generic entry so the drift gauge still publishes on
-the smoke backend — predictions there are for *plumbing*, not accuracy.
+Chip tables are public spec-sheet numbers. The CPU backend gets an
+order-of-magnitude generic entry named ``cpu`` so the drift gauge still
+publishes on the smoke backend — predictions there are for *plumbing*, not
+accuracy. An accelerator that is not in the tables is an error.
 """
 
 from __future__ import annotations
@@ -81,10 +82,11 @@ class ChipSpec:
     hbm_bytes: float = 0.0  # capacity; 0 = unknown/not-an-accelerator
 
 
-def chip_spec(kind: str | None) -> ChipSpec:
-    """Resolve a PJRT ``device_kind`` string to a spec table entry; unknown
-    kinds (CPU included) get the documented generic-cpu entry."""
-    canon = normalize_device_kind(kind or "")
+def chip_spec(kind: str) -> ChipSpec:
+    """Resolve a PJRT ``device_kind`` string to its spec-table entry. The
+    CPU backend's kind (``"cpu"``) gets the documented generic entry; any
+    other kind that is not in the tables is an error, not a default."""
+    canon = normalize_device_kind(kind)
     if canon is not None and canon in HBM_GBPS:
         return ChipSpec(
             canon,
@@ -93,17 +95,19 @@ def chip_spec(kind: str | None) -> ChipSpec:
             ICI_GBPS[canon],
             HBM_GIB[canon] * 1024**3,
         )
-    return ChipSpec(*GENERIC_CPU)
+    if str(kind).lower() == "cpu":
+        return ChipSpec(*GENERIC_CPU)
+    raise ValueError(
+        f"device_kind {kind!r} has no chip-spec entry — add its spec-sheet "
+        "numbers to obs/perfmodel.py (and obs/mfu.py) before modelling it"
+    )
 
 
 def detect_chip() -> ChipSpec:
-    """ChipSpec of the current backend's first device (generic on failure)."""
-    try:
-        import jax
+    """ChipSpec of the current backend's first device."""
+    import jax
 
-        return chip_spec(jax.devices()[0].device_kind)
-    except Exception:  # noqa: BLE001 - no backend → generic
-        return chip_spec(None)
+    return chip_spec(jax.devices()[0].device_kind)
 
 
 @dataclass

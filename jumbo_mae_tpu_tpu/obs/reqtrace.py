@@ -32,7 +32,7 @@ is attached, as one JSONL row in a crash-safe rotated-segment access log
 (the ``obs/journal.py`` writer) that ``tools/serve_doctor.py`` reads
 offline. A ``MicroBatcher`` constructed without a tracer pays nothing —
 every hook site is a ``None`` check — which is the telemetry-off A/B leg
-PERF.md's overhead budget is measured against.
+PERF_ARCHIVE.md's overhead budget is measured against.
 """
 
 from __future__ import annotations

@@ -283,7 +283,8 @@ class SLOTracker:
         the worst slow-window burn rate across objectives (1.0 = budget
         spent exactly as it accrues; >1 = too fast)."""
         rep = self.evaluate(now)
-        return max((o["burn_slow"] for o in rep["objectives"]), default=0.0)
+        burns = [o["burn_slow"] for o in rep["objectives"]]
+        return max(burns) if burns else 0.0
 
     def _degraded_at(self, now: float) -> bool:
         with self._lock:

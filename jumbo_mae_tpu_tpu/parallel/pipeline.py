@@ -28,8 +28,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-
-from jumbo_mae_tpu_tpu.utils import compat
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -139,7 +137,7 @@ def gpipe(
     rng_in = rng if rng is not None else jax.random.key(0)
 
     @partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             jax.tree_util.tree_map(lambda _: P(axis), stacked_params),

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from jumbo_mae_tpu_tpu.utils import compat
-
 NEG_INF = -1e30
 
 
@@ -53,7 +51,7 @@ def ring_attention(
     log-sum-exp space — per-device score memory drops from
     O((S/n)²) to O(S/n), the right memory class for exactly the
     long-context regime ring attention targets (and the kernels are
-    faster than einsum at those chunk lengths — PERF.md §Decisions 1).
+    faster than einsum at those chunk lengths — PERF_ARCHIVE.md §Decisions 1).
     Requires ``kv_mask=None`` (even splits): the kernels mask trailing
     pad only, not arbitrary key masks.
     """
@@ -141,13 +139,13 @@ def _ring_attention_flash(
     as a two-way log-sum-exp — numerically stable and exact. Per-device
     score memory is O(local_seq), the memory class ring attention exists
     for; the kernels are also faster than einsum at long chunk lengths
-    (PERF.md §Decisions 1).
+    (PERF_ARCHIVE.md §Decisions 1).
     """
     from jumbo_mae_tpu_tpu.ops.pallas.attention import (
         pallas_flash_attention_with_lse,
     )
 
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     bq, sq, h, d = q.shape
 
@@ -208,7 +206,7 @@ def ring_self_attention(
 ) -> jax.Array:
     """Sequence-parallel self-attention, for use inside model code under
     ``jit``. Uses the *ambient* mesh by default (activate with
-    ``utils.compat.set_mesh``) or an explicitly passed ``mesh``. Handles
+    ``jax.sharding.set_mesh``) or an explicitly passed ``mesh``. Handles
     sequence lengths that don't divide the ``seq`` axis by zero-padding K/V
     and masking the pad keys (the mask ring-rotates with its block). Falls
     back to plain attention when no mesh is active or its ``seq`` axis is
@@ -216,7 +214,7 @@ def ring_self_attention(
 
     q, k, v: (batch, seq, heads, head_dim), queries pre-scaled.
     """
-    shape = (mesh or compat.ambient_mesh()).shape
+    shape = (mesh or jax.sharding.get_abstract_mesh()).shape
     n = shape.get(seq_axis, 1)
     if not n or n <= 1:
         from jumbo_mae_tpu_tpu.ops.flash_attention import xla_attention
@@ -229,7 +227,7 @@ def ring_self_attention(
     bspec = tuple(a for a in batch_axes if shape.get(a, 1) > 1) or None
     qkv_spec = P(bspec, seq_axis, None, None)
     if not pad:
-        return compat.shard_map(
+        return jax.shard_map(
             partial(
                 ring_attention,
                 axis_name=seq_axis,
@@ -250,7 +248,7 @@ def ring_self_attention(
     widths = ((0, 0), (0, pad), (0, 0), (0, 0))
     q, k, v = (jnp.pad(x, widths) for x in (q, k, v))
     kv_mask = jnp.broadcast_to(jnp.arange(s_pad) < s, (b, s_pad))
-    out = compat.shard_map(
+    out = jax.shard_map(
         partial(ring_attention, axis_name=seq_axis),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, P(bspec, seq_axis)),

@@ -118,10 +118,7 @@ class CostMeter:
         if chip is None:
             from jumbo_mae_tpu_tpu.obs.perfmodel import detect_chip
 
-            try:
-                chip = detect_chip()
-            except Exception:  # noqa: BLE001 - pricing is best-effort
-                chip = None
+            chip = detect_chip()
         self._cost_fn = cost_fn
         self._chip = chip
         self._tracer = tracer

@@ -750,27 +750,25 @@ def _restore_subtrees(mgr, step, names: tuple[str, ...]) -> dict | None:
     else (the optimizer state's ~2x-params bytes above all) is never read.
     Needs the saved tree's structure, taken from the checkpoint metadata;
     returns None when the layout doesn't expose it or ``params`` is absent
-    (caller falls back to a whole-tree restore)."""
-    try:
-        meta = mgr.item_metadata(step)
-        state_meta = None if meta is None else meta.get("state")
-        tree = getattr(state_meta, "tree", state_meta)
-        if not isinstance(tree, dict) or "params" not in tree:
-            return None
-        item = {
-            name: jax.tree_util.tree_map(
-                lambda _: ocp.RestoreArgs(restore_type=np.ndarray),
-                tree[name],
-            )
-            for name in names
-            if isinstance(tree.get(name), dict)
-        }
-        out = mgr.restore(
-            step, args=ocp.args.Composite(state=_partial_pytree_restore(item))
-        )
-        return out["state"]
-    except Exception:
+    (caller falls back to a whole-tree restore). A restore that fails
+    raises — only the layout probe decides the fallback."""
+    meta = mgr.item_metadata(step)
+    state_meta = None if meta is None else meta.get("state")
+    tree = getattr(state_meta, "tree", state_meta)
+    if not isinstance(tree, dict) or "params" not in tree:
         return None
+    item = {
+        name: jax.tree_util.tree_map(
+            lambda _: ocp.RestoreArgs(restore_type=np.ndarray),
+            tree[name],
+        )
+        for name in names
+        if isinstance(tree.get(name), dict)
+    }
+    out = mgr.restore(
+        step, args=ocp.args.Composite(state=_partial_pytree_restore(item))
+    )
+    return out["state"]
 
 
 def _restore_params_only(mgr, step) -> dict | None:

@@ -25,8 +25,6 @@ from typing import Any, Literal, NamedTuple
 import jax
 import jax.numpy as jnp
 import optax
-
-from jumbo_mae_tpu_tpu.utils import compat
 from jax.tree_util import tree_map_with_path
 
 OptimizerName = Literal["adamw", "lamb", "lars", "sgd"]
@@ -56,7 +54,7 @@ class OptimConfig:
     # dtype for the Adam second moment. The EMA itself always computes in
     # float32 (only the *stored* moment is cast), but bf16's 8-bit mantissa
     # quantizes the stored EMA between steps — an explicit opt-in perf knob
-    # for bandwidth-bound large models (PERF.md §ViT-H/14), never a silent
+    # for bandwidth-bound large models (PERF_ARCHIVE.md §ViT-H/14), never a silent
     # default.
     nu_dtype: str | None = None
     # Storage dtype for the *parameters* (forward/backward weight reads).
@@ -132,7 +130,7 @@ def scale_by_adam_dtyped(
 
     def update_fn(updates, state, params=None):
         del params
-        count = compat.safe_increment(state.count)
+        count = optax.safe_increment(state.count)
         f32 = jnp.float32
         mu_f = jax.tree.map(
             lambda g, m: b1 * m.astype(f32) + (1 - b1) * g.astype(f32),
@@ -237,7 +235,11 @@ def make_optimizer(
     """Build the full transformation chain, LR exposed in
     ``opt_state.hyperparams["learning_rate"]``."""
 
-    @optax.inject_hyperparams
+    # float32 whatever the params' dtype: left to optax, the injected LR
+    # takes the params' dtype at init and the updates' dtype afterwards, and
+    # with low-precision params the state's type then changes after the
+    # first step — which the AOT-compiled step program (rightly) refuses
+    @partial(optax.inject_hyperparams, hyperparam_dtype=jnp.float32)
     def build(learning_rate):
         wd_mask = kernel_mask
         if cfg.name == "adamw":

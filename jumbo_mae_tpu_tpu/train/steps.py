@@ -91,11 +91,6 @@ def create_sharded_state(
     half weight-read HBM traffic); pair it with ``optim.param_dtype`` so the
     optimizer keeps a float32 master copy (``with_master_weights``).
     """
-    from jumbo_mae_tpu_tpu.utils.compat import ensure_partitionable_rng
-
-    # init draws must not depend on the mesh layout (jax 0.4.x defaults
-    # non-partitionable threefry, where they do)
-    ensure_partitionable_rng()
     inputs = _model_inputs(mode, example_batch)
     init_rngs = {
         "params": jax.random.key(init_seed),
@@ -111,13 +106,17 @@ def create_sharded_state(
         if param_dtype is not None:
             dt = jnp.dtype(param_dtype)
             params = jax.tree_util.tree_map(lambda p: p.astype(dt), params)
-        return TrainState.create(
+        state = TrainState.create(
             apply_fn=module.apply,
             params=params,
             tx=tx,
             batch_stats=variables.get("batch_stats"),
             rng=make_base_rng(rng_seed),
         )
+        # flax creates ``step=0``, a weakly typed int; a restored checkpoint
+        # carries a strong int32. The two lower to different step programs,
+        # so a resumed run would recompile what the first run had cached.
+        return state.replace(step=jnp.zeros((), jnp.int32))
 
     shapes = jax.eval_shape(init_fn)
     sharding = infer_state_sharding(shapes, mesh, min_shard_size=min_shard_size)
@@ -377,11 +376,10 @@ def make_train_step(
     # jit cache, so a post-hoc ``lower().compile()`` on an already-traced
     # jit function would compile the whole program a second time;
     # (2) it makes the train loop's compile point explicit, matching the
-    # serving engine's idiom. Any AOT failure degrades permanently to the
-    # plain jit path (``Compiled.__call__`` validates avals/shardings before
-    # buffers are donated, so falling back after a raise is safe).
+    # serving engine's idiom. A compile or execution failure raises: there
+    # is no second dispatch route that could hide an HBM-limit or Mosaic
+    # error behind another multi-minute compile of the same program.
     aot: dict[tuple, Any] = {}
-    state_fallback = {"plain": False}
 
     def _batch_key(batch: dict) -> tuple:
         return tuple(
@@ -391,21 +389,11 @@ def make_train_step(
 
     def train_step(state: TrainState, batch: dict, inject=None):
         inj = no_inject if inject is None else np.asarray(inject, np.float32)
-        if not state_fallback["plain"]:
-            key = _batch_key(batch)
-            compiled = aot.get(key)
-            if compiled is None:
-                try:
-                    compiled = _train_step.lower(state, batch, inj).compile()
-                    aot[key] = compiled
-                except Exception:  # noqa: BLE001 - AOT is an optimization
-                    state_fallback["plain"] = True
-            if compiled is not None:
-                try:
-                    return compiled(state, batch, inj)
-                except Exception:  # noqa: BLE001 - pre-execution validation
-                    state_fallback["plain"] = True
-        return _train_step(state, batch, inj)
+        key = _batch_key(batch)
+        compiled = aot.get(key)
+        if compiled is None:
+            compiled = aot[key] = _train_step.lower(state, batch, inj).compile()
+        return compiled(state, batch, inj)
 
     train_step.executables = aot  # read by cli/train's cost extraction
     return train_step

@@ -12,12 +12,14 @@
 #     --command="bash jumbo_mae_tpu_tpu/scripts/setup.sh"
 set -euo pipefail
 
-# 1. Python deps. jax[tpu] pulls the matching libtpu; pin jax>=0.8 for the
-#    sharding APIs the runtime uses (jax.sharding.set_mesh, shard_map vma).
+# 1. Python deps. jax[tpu] pulls the matching libtpu. Pinned to the minor
+#    the code is written for and was run on the chip with (jax/jaxlib 0.9.0,
+#    libtpu 0.0.34, flax 0.12.3, optax 0.2.6): the runtime calls that jax's
+#    API directly and carries no shims for other versions.
 python3 -m pip install -U pip
-python3 -m pip install -U "jax[tpu]>=0.8" \
+python3 -m pip install -U "jax[tpu]==0.9.*" \
   -f https://storage.googleapis.com/jax-releases/libtpu_releases.html
-python3 -m pip install -U flax optax chex einops numpy pillow orbax-checkpoint pyyaml
+python3 -m pip install -U "flax==0.12.*" "optax==0.2.*" chex einops numpy pillow orbax-checkpoint pyyaml
 
 # 2. Fast image decode for the host-side data workers (cv2 uses SIMD
 #    libjpeg-turbo wheels; data/decode.py falls back to PIL when absent).

@@ -1,0 +1,432 @@
+"""What can be known about the chip path without a chip.
+
+- The Pallas flash-attention kernels compile (AOT, through the TPU compiler
+  that is installed here) for a *described* ``v5e:2x2`` at the widths the
+  long-context recipes reach. A compile that passes is not a chip run; it
+  catches what the interpreter cannot — misaligned slices, too much VMEM.
+- ``chip_smoke.py``'s phase functions run end to end on the CPU at
+  ``vit_t16`` size (the rehearsal the script's own chip run is preceded by),
+  and the script itself refuses to pass without a TPU.
+- The compile cache lives where ``JAX_COMPILATION_CACHE_DIR`` says, else at
+  one fixed path, and nowhere else.
+- Nothing on the training path hides a failed compile or a failed step, and
+  no parent that fans out children touches a backend itself.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import subprocess
+import sys
+from pathlib import Path
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import chip_smoke
+
+REPO = Path(__file__).resolve().parent.parent
+SMOKE_RECIPE = str(REPO / "recipes" / "smoke_cpu.yaml")
+# the rehearsals check paths, arguments and control flow, so depth is cut to
+# one block each side of vit_t16's width: tracing and compiling it is most
+# of what a toy trainer run costs
+TOY = ["model.overrides.layers=1", "model.dec_layers=1"]
+
+
+# ------------------------------------------------ AOT for a described v5e
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One device of a described (not attached) v5e 2x2. The persistent
+    compile cache is off for the whole suite (conftest), as it must be
+    around these: a described-device entry cannot be read back."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _flash_programs():
+    from jumbo_mae_tpu_tpu.ops.pallas.attention import (
+        pallas_flash_attention,
+        pallas_flash_attention_with_lse,
+    )
+
+    def fwd(q, k, v):
+        return pallas_flash_attention(q, k, v)
+
+    def loss(q, k, v):
+        return pallas_flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    def loss_lse(q, k, v):
+        o, lse = pallas_flash_attention_with_lse(q, k, v)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    return {
+        "fwd": fwd,
+        "fwd_bwd": jax.grad(loss, argnums=(0, 1, 2)),
+        "with_lse_fwd_bwd": jax.grad(loss_lse, argnums=(0, 1, 2)),
+    }
+
+
+# (batch, seq, heads, head_dim): L/16 at 448 and 896 px (encoder head_dim 64,
+# decoder 32), H/14 at 448 px (head_dim 80), and 3139 tokens at head_dim 64
+@pytest.mark.parametrize("variant", ["fwd", "fwd_bwd", "with_lse_fwd_bwd"])
+@pytest.mark.parametrize(
+    "shape", [(8, 787, 16, 64), (8, 787, 16, 32), (4, 1027, 16, 80), (2, 3139, 16, 64)]
+)
+def test_flash_kernels_compile_for_v5e(v5e_chip, shape, variant):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e_chip)
+    compiled = jax.jit(_flash_programs()[variant]).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# -------------------------------------------- chip_smoke, rehearsed on CPU
+
+
+@pytest.fixture
+def cache_dir_restored():
+    """``enable_compile_cache()`` sets a process-wide jax option; put the
+    suite's value back after a test that calls it."""
+    old_dir = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", old_dir)
+
+
+@pytest.fixture
+def compile_cache(tmp_path, monkeypatch, cache_dir_restored):
+    """Turn the persistent cache on against a tmp dir for one test, through
+    the program's own switch, and put the suite's setting back after."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from jumbo_mae_tpu_tpu.utils.procenv import enable_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+    yield Path(enable_compile_cache())
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def watch():
+    return chip_smoke.CompileWatch()
+
+
+def test_kernels_phase_rehearsal_interpreted():
+    """The comparison against the XLA reference, on a toy shape with the
+    kernels interpreted — steered from here, not by an option of the script."""
+    got = chip_smoke.phase_kernels(shapes=((1, 130, 2, 32),), interpret=True)
+    assert got["mosaic_custom_call"] is False  # interpreted: nothing to find
+    assert max(got["max_rel_err_vs_xla"].values()) < chip_smoke.KERNEL_REL_TOL
+    assert got["onehot_gather_bit_identical"]
+
+
+def test_train_then_resume_phases_rehearsal(tmp_path, compile_cache, watch, capsys):
+    """Train 3 steps (eval + checkpoint at the end), then the same command
+    with run.resume=true for 1 more: the restore works and the second build
+    of the step program is a persistent-cache hit — the test of the cache's
+    placement and of the step counter's stable type. One device: the
+    sharded layouts have their own rehearsal below."""
+    steps, more = 3, 1
+    one_device = [*TOY, "mesh.fsdp=1"]
+    train = chip_smoke._trainer_overrides(16, steps, steps + more) + one_device
+    assert chip_smoke.run_phase(
+        "train",
+        lambda: chip_smoke.phase_train(SMOKE_RECIPE, train, tmp_path, steps=steps),
+        tmp_path,
+        watch,
+    )
+    resume = chip_smoke._trainer_overrides(16, steps + more, steps + more) + one_device
+    assert chip_smoke.run_phase(
+        "resume",
+        lambda: chip_smoke.phase_resume(
+            SMOKE_RECIPE, resume, tmp_path, start=steps, steps=more, watch=watch
+        ),
+        tmp_path,
+        watch,
+    )
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    train_line, resume_line = lines
+    assert train_line["phase"] == "train" and train_line["passed"]
+    checked = train_line["checked"]
+    assert checked["loss_last"] < checked["loss_first"]
+    assert checked["unexpected_recompiles"] == 0
+    # a CPU count is not a device rate: the trainer reports no MFU here
+    assert checked["mfu_trainer_reported"] is None
+    metrics = (tmp_path / "smoke_cpu" / "smoke_cpu-metrics.jsonl").read_text()
+    assert "perf/images_per_sec" in metrics
+    for key in ("perf/mfu", "perf/tflops_per_chip", "_utilization"):
+        assert key not in metrics
+    assert resume_line["checked"]["resumed_from"] == steps
+    assert resume_line["checked"]["train_step_cache_hits"] >= 1
+    assert resume_line["cache_hits"] >= 1
+    # the entries went where the variable says and nowhere else
+    assert list(compile_cache.glob("*train_step*"))
+    assert "SmokeFailure" not in (tmp_path / "resume.log").read_text()
+
+
+def test_serve_phase_rehearsal(tmp_path):
+    got = chip_smoke.phase_serve(SMOKE_RECIPE, TOY, tmp_path, requests=6, max_batch=2)
+    for leg in ("bf16", "int8"):
+        assert got[leg] == {
+            "answered": 6,
+            "warmup_compiled": got["ladder"],
+            "warmup_loaded": 0,
+            "hot_path_compiles": 0,
+        }
+    assert got["int8_cosine_min"] >= 0.999
+    assert got["pool"] == {"replicas": 2, "answered": 6, "hot_path_compiles": 0}
+
+
+def test_four_chip_phase_rehearsal(tmp_path, devices):
+    """mesh.fsdp=4 against its one-device control on virtual CPU devices:
+    the mesh and the sharding rules, not the collectives' speed."""
+    steps = 2
+    got = chip_smoke.phase_fsdp(
+        SMOKE_RECIPE,
+        chip_smoke._trainer_overrides(16, steps, steps) + TOY,
+        tmp_path,
+        chips=4,
+        steps=steps,
+    )
+    np.testing.assert_allclose(
+        got["loss_sharded"], got["loss_one_chip"], rtol=chip_smoke.LOSS_REL_TOL
+    )
+    assert got["moments_checked"] >= 2
+    assert got["collectives"]["all-gather"] > 0
+    # XLA:CPU reports no memory_stats(); the comparison runs on the chip
+    assert got["bytes_in_use_per_device_sharded"] == "not reported by this backend"
+
+
+def test_a_failed_phase_prints_its_line_and_fails(tmp_path, watch, capsys):
+    def boom():
+        print("chatter that belongs in the log")
+        raise RuntimeError("forced")
+
+    assert chip_smoke.run_phase("boom", boom, tmp_path, watch) is False
+    out = capsys.readouterr()
+    (line,) = [json.loads(ln) for ln in out.out.splitlines()]
+    assert line["passed"] is False and "forced" in line["error"]
+    assert "chatter" in (tmp_path / "boom.log").read_text()
+    assert "chatter" in out.err  # the log's tail is copied to stderr
+
+
+class _FakeTpu:
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+
+
+@pytest.mark.parametrize("failing", [None, "train"])
+def test_main_exit_code_and_last_line(
+    tmp_path, monkeypatch, capsys, cache_dir_restored, failing
+):
+    """main()'s control flow with the phases stubbed out: the last line only
+    when every phase passed, the device as JAX reports it, a non-zero exit
+    and no ``"ok": true`` when a phase raised."""
+    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeTpu()])
+
+    def stub(name):
+        def run(*a, **k):
+            if name == failing:
+                raise RuntimeError(f"{name} forced to raise")
+            return {}
+
+        return run
+
+    for name in ("kernels", "train", "resume", "serve"):
+        monkeypatch.setattr(chip_smoke, f"phase_{name}", stub(name))
+    rc = chip_smoke.main(["--out", str(tmp_path)])
+    out = capsys.readouterr().out
+    if failing is None:
+        assert rc == 0
+        assert json.loads(out.splitlines()[-1]) == {
+            "ok": True,
+            "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+        }
+    else:
+        assert rc != 0
+        assert '"ok": true' not in out
+        lines = {json.loads(ln)["phase"]: json.loads(ln) for ln in out.splitlines()}
+        assert not lines["train"]["passed"]
+        assert "skipped" in lines["resume"]["error"]  # nothing to resume from
+        assert lines["serve"]["passed"]  # later phases still report
+
+
+def test_script_refuses_to_pass_on_cpu():
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "chip_smoke.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True,
+        text=True,
+        timeout=120,
+    )
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout and proc.stdout.strip() == ""
+    assert "needs a TPU" in proc.stderr
+
+
+# ------------------------------------------------- compile-cache placement
+
+
+@pytest.mark.parametrize("env_dir", ["/x/cache", None])
+def test_compile_cache_placement(monkeypatch, cache_dir_restored, env_dir):
+    """Variable set: that directory and no other. Unset: one fixed path in
+    the checkout — no host hash, pid, time or temp dir in it. The serving
+    warm-start cache sits under the same directory, never ``~/.cache``."""
+    from jumbo_mae_tpu_tpu.utils import procenv
+
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(REPO / ".jax_cache")
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        want = env_dir
+    monkeypatch.setenv("JUMBO_WARMCACHE", "1")
+    assert procenv.compile_cache_dir() == want
+    assert procenv.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert procenv.default_warmcache_dir() == os.path.join(want, "warmcache")
+    monkeypatch.setenv("JUMBO_WARMCACHE", "0")
+    assert procenv.default_warmcache_dir() is None
+
+
+def test_one_place_sets_the_cache_path_and_every_entry_point_calls_it():
+    sources = {
+        p: p.read_text()
+        for p in [*REPO.glob("*.py"), *REPO.glob("tools/*.py"),
+                  *(REPO / "jumbo_mae_tpu_tpu").rglob("*.py")]
+    }
+    setters = [
+        str(p.relative_to(REPO))
+        for p, text in sources.items()
+        if re.search(r"""config\.update\(\s*["']jax_compilation_cache_dir""", text)
+    ]
+    assert setters == ["jumbo_mae_tpu_tpu/utils/procenv.py"]
+    for entry in ("jumbo_mae_tpu_tpu/cli/train.py", "jumbo_mae_tpu_tpu/cli/predict.py",
+                  "jumbo_mae_tpu_tpu/cli/batch.py", "bench.py", "chip_smoke.py"):
+        assert "enable_compile_cache()" in sources[REPO / entry], entry
+    # no cache under the home directory
+    assert "expanduser" not in sources[REPO / "jumbo_mae_tpu_tpu/utils/procenv.py"]
+
+
+# ------------------------------------------- no fallback on the train path
+
+
+@pytest.fixture(scope="module")
+def tiny_train_state():
+    from jumbo_mae_tpu_tpu.models import DecoderConfig, MAEPretrainModel, preset
+    from jumbo_mae_tpu_tpu.parallel import MeshConfig, create_mesh
+    from jumbo_mae_tpu_tpu.train import (
+        OptimConfig,
+        create_sharded_state,
+        make_optimizer,
+    )
+
+    mesh = create_mesh(MeshConfig(data=1, fsdp=1), devices=jax.devices()[:1])
+    enc = preset("vit_t16", image_size=16, patch_size=8, mask_ratio=0.75,
+                 labels=None, dtype="float32", layers=1)
+    module = MAEPretrainModel(enc, DecoderConfig(layers=1, dim=16, heads=2, dtype="float32"))
+    batch = {"images": np.zeros((2, 16, 16, 3), np.uint8)}
+    tx = make_optimizer(OptimConfig(name="adamw", training_steps=4, warmup_steps=1), 2)
+    state, sharding = create_sharded_state(module, tx, batch, mesh, mode="pretrain")
+    return mesh, state, sharding, batch
+
+
+@pytest.mark.parametrize("failing", ["compile", "execute"])
+def test_train_step_failure_raises_with_no_second_route(
+    monkeypatch, tiny_train_state, failing
+):
+    """A failed ``lower().compile()`` — or a failed execution of the compiled
+    step — raises, on every call: no plain-jit route runs the step anyway."""
+    from jumbo_mae_tpu_tpu.train import make_train_step
+
+    mesh, state, sharding, batch = tiny_train_state
+    step = make_train_step(mesh, sharding, mode="pretrain")
+    calls = {"n": 0}
+
+    def boom(*a, **k):
+        calls["n"] += 1
+        raise RuntimeError(f"{failing} failed on the device")
+
+    if failing == "compile":
+        monkeypatch.setattr(jax.stages.Lowered, "compile", boom)
+    else:
+        step(state, batch)  # compiles; the state is donated, but never read again
+        monkeypatch.setattr(jax.stages.Compiled, "__call__", boom)
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match=f"{failing} failed on the device"):
+            step(state, batch)
+    assert calls["n"] == 2  # asked again, not routed around
+
+
+# --------------------------------------------------- one process per chip
+
+_GUARD = """
+import subprocess, sys
+sys.path.insert(0, {repo!r}); sys.path.insert(0, {repo!r} + "/tools")
+
+class FirstChild(Exception):
+    pass
+
+def refuse(*a, **k):
+    raise FirstChild
+
+subprocess.run = subprocess.Popen = refuse
+{body}
+"""
+
+_GUARD_BODIES = {
+    "ab_bench": """
+import ab_bench
+try:
+    ab_bench.main(["--model", "vit_t16", "--out", {out!r}])
+except FirstChild:
+    pass
+assert "jax" not in sys.modules, "the sweep parent imported jax"
+""",
+    "flash_microbench": """
+import flash_microbench
+try:
+    flash_microbench.main(["--matrix"])
+except FirstChild:
+    pass
+assert "jax" not in sys.modules, "the sweep parent imported jax"
+""",
+    "train_elastic": """
+from jumbo_mae_tpu_tpu.cli import train
+try:
+    train.main(["--elastic", "2", "--config", {recipe!r},
+                "--set", "run.output_dir=" + {out!r}])
+except FirstChild:
+    pass
+from jax._src import xla_bridge
+assert not xla_bridge.backends_are_initialized(), "the supervisor touched a backend"
+""",
+}
+
+
+@pytest.mark.parametrize("parent", sorted(_GUARD_BODIES))
+def test_parents_that_start_children_never_touch_a_backend(tmp_path, parent):
+    """A parent that has touched JAX holds the chip its children need. Run
+    each fan-out parent's ``main`` up to its first child and look."""
+    body = _GUARD_BODIES[parent].format(out=str(tmp_path / "o"), recipe=SMOKE_RECIPE)
+    proc = subprocess.run(
+        [sys.executable, "-c", _GUARD.format(repo=str(REPO), body=body)],
+        capture_output=True,
+        text=True,
+        timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]

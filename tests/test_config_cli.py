@@ -155,7 +155,9 @@ def test_smoke_pretrain_end_to_end(tmp_path):
     assert "val/loss" in metrics and metrics["val/loss"] > 0
     out = tmp_path / "smoke_cpu"
     lines = (out / "smoke_cpu-metrics.jsonl").read_text().strip().splitlines()
-    assert any("perf/mfu" in json.loads(l) for l in lines)
+    assert any("perf/images_per_sec" in json.loads(l) for l in lines)
+    # a CPU count is not a device rate: no MFU against a made-up peak
+    assert not any("perf/mfu" in json.loads(l) for l in lines)
     assert (out / "ckpt" / "last").is_dir()
 
 

@@ -331,11 +331,17 @@ class TestEngineCosts:
 class TestRoofline:
     CHIP = chip_spec("TPU v4")
 
-    def test_chip_spec_normalizes_and_defaults(self):
+    def test_chip_spec_normalizes_and_refuses_unknown_kinds(self):
         assert chip_spec("TPU v5 lite").name == "v5e"
         assert chip_spec("TPU v4").peak_tflops == 275.0
-        assert chip_spec("mystery accelerator").name == "cpu"
-        assert detect_chip().name  # never raises, whatever the backend
+        # the CPU backend's own kind gets the documented generic entry ...
+        assert chip_spec("cpu").name == "cpu"
+        assert detect_chip().name == "cpu"  # the suite runs on the CPU
+        # ... but an accelerator that is not in the tables is an error,
+        # never a made-up spec
+        for kind in ("TPU v9 mystery", "mystery accelerator", ""):
+            with pytest.raises(ValueError, match="no chip-spec entry"):
+                chip_spec(kind)
 
     def test_bound_transitions(self):
         """Small flops at big bytes → bandwidth-bound; scale flops up and
@@ -408,20 +414,11 @@ class TestDeviceKindNormalizer:
         assert normalize_device_kind("Tesla T4") is None
         assert lookup_peak_tflops("TPU v5 lite") == PEAK_TFLOPS["v5e"]
 
-    def test_unknown_kind_warns_and_sets_gauge(self, capsys):
-        from jumbo_mae_tpu_tpu.obs import metrics as M
+    def test_unknown_kind_raises(self):
         from jumbo_mae_tpu_tpu.obs.mfu import lookup_peak_tflops
 
-        reg = MetricsRegistry()
-        old = M.get_registry()
-        M.set_registry(reg)
-        try:
-            assert lookup_peak_tflops("weird-chip-x1", default=1.5) == 1.5
-        finally:
-            M.set_registry(old)
-        assert "weird-chip-x1" in capsys.readouterr().err
-        fam = reg.gauge("mfu_peak_unknown", labels=("kind",))
-        assert fam.labels("weird-chip-x1").value == 1
+        with pytest.raises(ValueError, match="weird-chip-x1"):
+            lookup_peak_tflops("weird-chip-x1")
 
 
 # ------------------------------------------------------------ perf ledger

@@ -246,7 +246,7 @@ def _probe(tmp_path, shards, name, pretrained=None, pooling="gap", steps=PR_STEP
     is 1e-7 of init and the probe reads 0.52. The reference uses the same
     flax default (its ImageNet probes run ~100k steps, where the bias is
     zero), so this is a schedule-length effect, not an architecture or
-    parity defect. Diagnosis recorded in PERF.md §Round 5.
+    parity defect. Diagnosis recorded in PERF_ARCHIVE.md §Round 5.
     """
     from jumbo_mae_tpu_tpu.cli.train import train
     from jumbo_mae_tpu_tpu.config import load_config

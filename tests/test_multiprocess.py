@@ -66,9 +66,9 @@ def _free_port() -> int:
 def _launch_workers(tmp_path, shards, *, devices_per_proc=2, mode="dp"):
     """Run 2 jax.distributed worker processes to completion; return their
     JSON results."""
-    from jumbo_mae_tpu_tpu.utils.procenv import cpu_subprocess_env, host_cache_dir
+    from jumbo_mae_tpu_tpu.utils.procenv import cpu_subprocess_env
 
-    env = cpu_subprocess_env(devices_per_proc, compile_cache=host_cache_dir(REPO))
+    env = cpu_subprocess_env(devices_per_proc)
     env["PYTHONPATH"] = f"{REPO}:{Path(__file__).parent}"
 
     port = _free_port()

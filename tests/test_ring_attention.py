@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jumbo_mae_tpu_tpu.utils import compat
 from jumbo_mae_tpu_tpu.ops.flash_attention import xla_attention
 from jumbo_mae_tpu_tpu.parallel import MeshConfig, create_mesh
 from jumbo_mae_tpu_tpu.parallel.ring_attention import (
@@ -60,7 +59,7 @@ def test_ring_self_attention_uneven_seq(devices, s):
     mesh = create_mesh(MeshConfig(data=2, fsdp=1, seq=4))
     q, k, v = _qkv(b=4, s=s)
     expected = xla_attention(q, k, v)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out = jax.jit(ring_self_attention)(q, k, v)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(expected), rtol=2e-5, atol=2e-5
@@ -90,7 +89,7 @@ def test_vit_forward_ring_equals_einsum(devices):
     params = model_ein.init(jax.random.key(0), images)
     want = model_ein.apply(params, images)
     model_ring = JumboViT(cfg.replace(attn_impl="ring"))
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         got = jax.jit(model_ring.apply)(params, images)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4

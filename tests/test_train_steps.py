@@ -11,7 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jumbo_mae_tpu_tpu.utils import compat
 from jumbo_mae_tpu_tpu.models import (
     ClassificationModel,
     DecoderConfig,
@@ -131,10 +130,8 @@ class TestPretrainStep:
             np.testing.assert_allclose(
                 float(m1["loss"]), float(m8["loss"]), rtol=2e-5
             )
-        # params agree after 3 steps (requires partitionable threefry —
-        # compat.ensure_partitionable_rng — or the sharded init itself
-        # draws different values on jax 0.4.x; measured drift with it on:
-        # ~1e-7)
+        # params agree after 3 steps (jax's partitionable threefry makes the
+        # sharded init draw the same values; measured drift ~1e-7)
         p1 = jax.tree_util.tree_leaves(s1.params)
         p8 = jax.tree_util.tree_leaves(s8.params)
         for a, b in zip(p1, p8):
@@ -188,7 +185,7 @@ class TestPretrainStep:
 
         mesh = create_mesh(MeshConfig(data=2, fsdp=1, seq=4))
         tx = make_optimizer(OPT, global_batch_size=256)
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             s_ring, sharding = create_sharded_state(
                 ring_module, tx, batch, mesh, mode="pretrain", init_seed=0, rng_seed=0
             )
@@ -217,7 +214,7 @@ class TestPretrainStep:
         )
         mesh = create_mesh(MeshConfig(data=1, fsdp=2, tensor=2, seq=2))
         tx = make_optimizer(OPT, global_batch_size=256)
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             st, sharding = create_sharded_state(
                 module, tx, batch, mesh, mode="pretrain", init_seed=0,
                 rng_seed=0, min_shard_size=128,

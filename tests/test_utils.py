@@ -175,16 +175,18 @@ def test_param_summary():
 
 
 def test_detect_peak_tflops_device_kind_spellings(monkeypatch):
-    """PJRT spells the e-variants 'lite' ('TPU v5 lite'); an unmatched kind
-    must fall back to the caller's default (bench.py passes 0.0 to disable
-    its plausibility guard rather than guess)."""
+    """PJRT spells the e-variants 'lite' ('TPU v5 lite'). The CPU backend has
+    no peak (None: a CPU count is not a device rate); an accelerator whose
+    kind is not in the table is an error, never a default."""
     import jax
+    import pytest
 
     from jumbo_mae_tpu_tpu.utils.mfu import detect_peak_tflops
 
     class _Dev:
-        def __init__(self, kind):
+        def __init__(self, kind, platform="tpu"):
             self.device_kind = kind
+            self.platform = platform
 
     cases = {
         "TPU v5 lite": 197.0,
@@ -192,8 +194,12 @@ def test_detect_peak_tflops_device_kind_spellings(monkeypatch):
         "TPU v5p": 459.0,
         "TPU v6 lite": 918.0,
         "TPU v4": 275.0,
-        "weird accelerator": 0.0,  # falls back to the default
     }
     for kind, want in cases.items():
         monkeypatch.setattr(jax, "devices", lambda k=kind: [_Dev(k)])
-        assert detect_peak_tflops(default=0.0) == want, kind
+        assert detect_peak_tflops() == want, kind
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("cpu", "cpu")])
+    assert detect_peak_tflops() is None
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("TPU v9 mystery")])
+    with pytest.raises(ValueError, match="TPU v9 mystery"):
+        detect_peak_tflops()

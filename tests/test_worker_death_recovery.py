@@ -61,9 +61,9 @@ def _write_shards(root: Path, n_shards: int = 2, per_shard: int = 32) -> int:
 
 
 def _cli_env() -> dict:
-    from jumbo_mae_tpu_tpu.utils.procenv import cpu_subprocess_env, host_cache_dir
+    from jumbo_mae_tpu_tpu.utils.procenv import cpu_subprocess_env
 
-    env = cpu_subprocess_env(8, compile_cache=host_cache_dir(REPO))
+    env = cpu_subprocess_env(8)
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
     return env
 

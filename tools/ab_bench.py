@@ -4,7 +4,7 @@
 Runs ``bench.py`` once per configuration (cartesian product of the swept
 env knobs), one subprocess each — fresh backend, no cross-run state — and
 appends every result line to a JSONL log with its knobs attached. This is
-how PERF.md A/B tables are produced without babysitting:
+how PERF_ARCHIVE.md A/B tables are produced without babysitting:
 
     python tools/ab_bench.py --model vit_h14 \
         --sweep BENCH_DEC_REMAT_POLICY=,dots \
@@ -18,8 +18,11 @@ remat off, bf16 moments, onehot gather — bench.py MODELS). To put a
 default-ON knob in its off state, sweep its explicit off spelling
 instead of the empty string: BENCH_MU_DTYPE=float32,
 BENCH_NU_DTYPE=float32, BENCH_GATHER_IMPL=take, BENCH_REMAT=1.
-Failed runs are recorded with their error line (bench.py emits
-machine-readable JSON even on failure) and the sweep continues.
+Failed runs are recorded with the tail of their stderr and the sweep
+continues.
+
+A chip belongs to one process: this parent only starts ``bench.py`` children,
+one at a time, and never imports jax itself.
 """
 
 from __future__ import annotations
@@ -108,7 +111,7 @@ def main(argv: list[str] | None = None) -> int:
                 "wall_s": round(time.monotonic() - t0, 1),
                 "result": parsed,
             }
-            if proc.returncode != 0 and parsed is None:
+            if proc.returncode != 0:
                 record["stderr_tail"] = proc.stderr[-400:]
         except subprocess.TimeoutExpired as e:
             def _tail(buf):
@@ -132,8 +135,6 @@ def main(argv: list[str] | None = None) -> int:
         val = (record.get("result") or {}).get("value")
         print(f"[ab_bench]   → rc={record['rc']} value={val}", flush=True)
 
-    # a failed run's error JSON can still carry the partial bf16-leg value —
-    # only rc==0 rows count as successes
     ok = [
         r
         for r in results

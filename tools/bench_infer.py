@@ -21,15 +21,16 @@ latency percentiles, and compile counts for each leg:
   so the accuracy cost of the throughput win is never quoted separately.
 
 ``--warm-start on`` (default) additionally runs the persistent-warmup A/B:
-two fresh subprocesses (``python -m jumbo_mae_tpu_tpu.infer.warmcache``)
-against one empty cache dir — the first compiles and publishes, the second
-must report ``compiles: 0`` — and records cold vs warm startup seconds.
+two fresh engines (the ``infer.warmcache`` restart probe, in this process —
+a chip belongs to one process) against one empty cache dir — the first
+compiles and publishes, the second must report ``compiles: 0`` — and records
+cold vs warm startup seconds.
 
     python tools/bench_infer.py                         # CPU smoke config
     python tools/bench_infer.py recipes/finetune_vit_b16.yaml --ckpt C \
         --task logits --requests 2048 --max-batch 64    # chip numbers
 
-Env-free by design — every knob is a flag; PERF.md §Inference records the
+Env-free by design — every knob is a flag; PERF_ARCHIVE.md §Inference records the
 methodology and numbers.
 """
 
@@ -77,7 +78,7 @@ def build_parser() -> argparse.ArgumentParser:
         choices=("on", "off"),
         default="on",
         help="off swaps the default registry for the no-op NullRegistry "
-        "before any engine/batcher construction — the A/B leg PERF.md's "
+        "before any engine/batcher construction — the A/B leg PERF_ARCHIVE.md's "
         "exporter-overhead number comes from",
     )
     p.add_argument(
@@ -109,7 +110,7 @@ def build_parser() -> argparse.ArgumentParser:
         "--warm-start",
         choices=("on", "off"),
         default="on",
-        help="run the persistent-warmup A/B: two fresh subprocesses against "
+        help="run the persistent-warmup A/B: two fresh engines against "
         "one empty cache dir; the second must load every executable "
         "(compiles=0) instead of compiling",
     )
@@ -363,48 +364,43 @@ def main(argv: list[str] | None = None) -> dict:
             task="logits" if args.task == "logits" else "features",
         )
 
-    # ---- persistent-warmup A/B: cold process vs restarted process -------
+    # ---- persistent-warmup A/B: cold engine vs restarted engine ----------
+    # In this process: a chip belongs to one process, and this one holds it
+    # after the legs above, so a child that needed it would fail or hang.
+    # Two fresh engines against one empty cache dir are the same contract —
+    # the first compiles and publishes, the second must load everything.
     warm_start = None
     if args.warm_start == "on":
-        import subprocess
+        import contextlib
         import tempfile
 
-        probe_cmd = [
-            sys.executable, "-m", "jumbo_mae_tpu_tpu.infer.warmcache",
+        from jumbo_mae_tpu_tpu.infer.warmcache import _probe_main
+
+        probe_args = [
             "--task", args.task,
             "--max-batch", str(min(args.max_batch, 8)),
             "--recipe", str(recipe),
         ]
         if args.ckpt:
-            probe_cmd += ["--ckpt", args.ckpt]
+            probe_args += ["--ckpt", args.ckpt]
         if args.dtype:
-            probe_cmd += ["--dtype", args.dtype]
+            probe_args += ["--dtype", args.dtype]
         if overrides:
-            probe_cmd += ["--set", *overrides]
+            probe_args += ["--set", *overrides]
         with tempfile.TemporaryDirectory(prefix="jumbo-warmstart-") as d:
             runs = {}
             for phase in ("cold", "warm"):
-                proc = subprocess.run(
-                    probe_cmd + ["--dir", d],
-                    capture_output=True, text=True, timeout=900,
-                )
-                if proc.returncode != 0:
-                    print(proc.stderr, file=sys.stderr)
-                    raise SystemExit(
-                        f"warm-start probe ({phase}) failed rc={proc.returncode}"
-                    )
-                rows = [
-                    ln for ln in proc.stdout.splitlines()
-                    if ln.startswith("{")
-                ]
-                runs[phase] = json.loads(rows[-1])
+                # the probe prints its own JSON line; stdout is this
+                # bench's one-line report
+                with contextlib.redirect_stdout(sys.stderr):
+                    runs[phase] = _probe_main(probe_args + ["--dir", d])
         cold, warm = runs["cold"], runs["warm"]
         keep = ("init_s", "warmup_s", "compiles", "warm_hits",
                 "hot_path_compiles")
         warm_start = {
             "cold": {k: cold[k] for k in keep},
             "warm": {k: warm[k] for k in keep},
-            # the contract CI asserts: a restarted replica performs zero
+            # the contract CI asserts: a restarted engine performs zero
             # compiles — warmup and hot path both served from the cache
             "warm_reused": (
                 warm["compiles"] == 0

@@ -4,7 +4,7 @@
 Captures a ``jax.profiler`` trace of the bench step (same builder as
 bench.py, so the profiled program IS the benched program) and aggregates
 device-track op durations by ``hlo_category`` plus the top self-time ops —
-the table PERF.md's "Where a step goes" is built from, as one command:
+the table PERF_ARCHIVE.md's "Where a step goes" is built from, as one command:
 
     python tools/profile_step.py --model vit_h14 --steps 5 --out /tmp/h14
 
@@ -176,7 +176,7 @@ def aggregate(trace_path: str, steps: int) -> tuple[dict, list, list, list]:
     by_src: dict[str, float] = collections.defaultdict(float)
     # tf_op → [device_us, model_flops, raw_bytes]: per-op achieved TF/s and
     # GB/s — tells FLOP-bound from HBM-bound apart op by op, which is what
-    # actually picks the next optimization (PERF.md §Round 3 workflow)
+    # actually picks the next optimization (PERF_ARCHIVE.md §Round 3 workflow)
     by_tf: dict[str, list] = collections.defaultdict(lambda: [0.0, 0.0, 0.0])
     for e in events:
         if e.get("ph") != "X" or e.get("pid") not in device_pids:
