@@ -85,13 +85,11 @@ from jumbo_mae_tpu_tpu.obs import (
     RunJournal,
     TelemetryServer,
     env_fingerprint,
-    export_chrome_trace,
     first_nonfinite_group,
     get_registry,
     group_layout,
     publish_group_stats,
     span_timer,
-    start_chrome_trace,
     stats_dict,
     trace,
 )
@@ -1199,8 +1197,6 @@ def train(cfg: TrainConfig) -> dict:
     # before an operator would spot a silent stall in the logs
     health.watch("train_step", max_age_s=3600.0)
     health.watch("data_batch", max_age_s=3600.0)
-    if run.chrome_trace and is_main:
-        start_chrome_trace()
     window_t0, window_wait = time.perf_counter(), 0.0
     window_steps = 0  # dispatches this log window (beacon step-time EMA)
     bad_total = 0  # cumulative sentinel-bad steps (beacon field)
@@ -1768,8 +1764,6 @@ def train(cfg: TrainConfig) -> dict:
     ckpt.wait()
     ckpt.close()
     logger.close()
-    if run.chrome_trace and is_main:
-        print(f"[obs] chrome trace -> {export_chrome_trace(run.chrome_trace)}")
     if telemetry is not None:
         telemetry.close()
     if source is not None:

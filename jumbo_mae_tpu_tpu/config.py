@@ -190,9 +190,6 @@ class RunConfig:
     publish_metric_key: str = ""
     publish_metric_floor: float = 0.0
     publish_metric_sense: str = "below"
-    # write the host-side span timeline (chrome://tracing / Perfetto JSON)
-    # here at the end of the run; complements profile_dir's XLA device trace
-    chrome_trace: str = ""
     use_wandb: bool = True
     wandb_project: str = ""
     wandb_entity: str = ""

@@ -859,6 +859,8 @@ def prefetch_to_device(it: Iterator[dict], sharding, buffer_size: int = 2) -> It
     of the global batch (``jax.make_array_from_process_local_data``)."""
     import jax
 
+    from jumbo_mae_tpu_tpu.obs.trace import SPAN_H2D, span_timer
+
     def put(batch):
         try:
             return jax.tree_util.tree_map(
@@ -867,9 +869,12 @@ def prefetch_to_device(it: Iterator[dict], sharding, buffer_size: int = 2) -> It
         except ValueError:
             return jax.device_put(batch, sharding)
 
+    # the host's share of a transfer (the copy itself is asynchronous)
+    sp_h2d = span_timer(SPAN_H2D)
     pending: list = []
     for batch in it:
-        pending.append(put(batch))
+        with sp_h2d:
+            pending.append(put(batch))
         if len(pending) > buffer_size:
             yield pending.pop(0)
     yield from pending

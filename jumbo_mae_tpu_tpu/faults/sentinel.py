@@ -35,6 +35,11 @@ import jax.numpy as jnp
 import optax
 
 from jumbo_mae_tpu_tpu.obs.metrics import get_registry
+from jumbo_mae_tpu_tpu.obs.trace import (
+    SCOPE_GRAD_NORM,
+    SCOPE_GUARD,
+    SCOPE_OPTIMIZER,
+)
 
 
 def guarded_apply_gradients(state, grads, loss):
@@ -45,16 +50,19 @@ def guarded_apply_gradients(state, grads, loss):
     afterwards) must be skipped — the state comes back unchanged except
     ``step + 1``.
     """
-    grad_norm = optax.global_norm(grads)
-    finite = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
+    with jax.named_scope(SCOPE_GRAD_NORM):
+        grad_norm = optax.global_norm(grads)
 
     def _update(_):
-        return state.apply_gradients(grads=grads)
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            return state.apply_gradients(grads=grads)
 
     def _skip(_):
         return state.replace(step=state.step + 1)
 
-    new_state = jax.lax.cond(finite, _update, _skip, operand=None)
+    with jax.named_scope(SCOPE_GUARD):
+        finite = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
+        new_state = jax.lax.cond(finite, _update, _skip, operand=None)
     return new_state, grad_norm, finite
 
 

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from flax.linen import initializers as init
 
 from jumbo_mae_tpu_tpu.models.config import DecoderConfig, JumboViTConfig
+from jumbo_mae_tpu_tpu.obs.trace import SCOPE_ATTN_CORE
 from jumbo_mae_tpu_tpu.ops.posemb import sincos2d_positional_embedding
 
 TRUNC_NORMAL = init.truncated_normal(0.02)
@@ -159,47 +160,48 @@ class Attention(nn.Module):
         # z_head_major tracks each branch's output layout: (B,H,S,D) for the
         # einsum path, (B,S,H,D) for flash/ring — set alongside z so a new
         # branch can't silently mismatch the out-projection's axes.
-        if impl == "ring":
-            # Sequence parallelism: tokens shard over the ambient mesh's
-            # "seq" axis, K/V ring-rotate over ICI (parallel/ring_attention).
-            from jumbo_mae_tpu_tpu.parallel.ring_attention import (
-                ring_self_attention,
-            )
+        with jax.named_scope(SCOPE_ATTN_CORE):
+            if impl == "ring":
+                # Sequence parallelism: tokens shard over the ambient mesh's
+                # "seq" axis, K/V ring-rotate over ICI (parallel/ring_attention).
+                from jumbo_mae_tpu_tpu.parallel.ring_attention import (
+                    ring_self_attention,
+                )
 
-            z, z_head_major = (
-                ring_self_attention(q, k, v, inner=cfg.ring_inner),
-                False,
-            )
-        elif impl == "flash":
-            from jumbo_mae_tpu_tpu.ops.flash_attention import flash_attention
+                z, z_head_major = (
+                    ring_self_attention(q, k, v, inner=cfg.ring_inner),
+                    False,
+                )
+            elif impl == "flash":
+                from jumbo_mae_tpu_tpu.ops.flash_attention import flash_attention
 
-            z, z_head_major = flash_attention(q, k, v), False
-        else:
-            # Scores materialize in the compute dtype; the MXU still
-            # accumulates the dot in f32, and softmax still computes in f32
-            # (the convert fuses into the softmax chain). Under bf16 compute
-            # this halves the HBM traffic of the O(S²) score tensor — the
-            # single largest bandwidth item in the profile: −27 ms/step on
-            # the v5e bench workload's 8 decoder layers (PERF_ARCHIVE.md). Only the
-            # materialized rounding is bf16; with float32 compute (all
-            # parity tests/oracles) the path is exact and unchanged.
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k)
-            scores = logits.astype(jnp.float32)
-            if mask is not None:
-                # -inf before softmax underflows to an exact 0 probability:
-                # a masked key contributes exactly 0·v, so segment isolation
-                # is bit-exact, not approximate (every query keeps at least
-                # its diagonal, so no row is all -inf)
-                scores = jnp.where(mask, scores, -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1).astype(
-                cfg.compute_dtype
-            )
-            probs = nn.Dropout(cfg.dropout)(probs, deterministic)
-            # Keep z head-major (B,H,S,D) — the layout the scores matmul
-            # produces natively — and let the output projection contract
-            # (h, d) from there: measured −17% attention fwd+bwd on v5e at
-            # the encoder shape vs transposing back to (B,S,H,D) (PERF_ARCHIVE.md).
-            z, z_head_major = jnp.einsum("bhqk,bkhd->bhqd", probs, v), True
+                z, z_head_major = flash_attention(q, k, v), False
+            else:
+                # Scores materialize in the compute dtype; the MXU still
+                # accumulates the dot in f32, and softmax still computes in f32
+                # (the convert fuses into the softmax chain). Under bf16 compute
+                # this halves the HBM traffic of the O(S²) score tensor — the
+                # single largest bandwidth item in the profile: −27 ms/step on
+                # the v5e bench workload's 8 decoder layers (PERF_ARCHIVE.md). Only the
+                # materialized rounding is bf16; with float32 compute (all
+                # parity tests/oracles) the path is exact and unchanged.
+                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+                scores = logits.astype(jnp.float32)
+                if mask is not None:
+                    # -inf before softmax underflows to an exact 0 probability:
+                    # a masked key contributes exactly 0·v, so segment isolation
+                    # is bit-exact, not approximate (every query keeps at least
+                    # its diagonal, so no row is all -inf)
+                    scores = jnp.where(mask, scores, -jnp.inf)
+                probs = jax.nn.softmax(scores, axis=-1).astype(
+                    cfg.compute_dtype
+                )
+                probs = nn.Dropout(cfg.dropout)(probs, deterministic)
+                # Keep z head-major (B,H,S,D) — the layout the scores matmul
+                # produces natively — and let the output projection contract
+                # (h, d) from there: measured −17% attention fwd+bwd on v5e at
+                # the encoder shape vs transposing back to (B,S,H,D) (PERF_ARCHIVE.md).
+                z, z_head_major = jnp.einsum("bhqk,bkhd->bhqd", probs, v), True
 
         # kernel shape is (heads, head_dim, dim) for either axis choice, so
         # both paths share the same checkpoint layout

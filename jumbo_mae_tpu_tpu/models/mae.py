@@ -26,6 +26,7 @@ from jumbo_mae_tpu_tpu.models.config import (
 )
 from jumbo_mae_tpu_tpu.models.layers import TRUNC_NORMAL, PlainBlock
 from jumbo_mae_tpu_tpu.models.vit import JumboViT
+from jumbo_mae_tpu_tpu.obs.trace import SCOPE_LOSS, SCOPE_MASK, SCOPE_PREPROCESS
 from jumbo_mae_tpu_tpu.ops.masking import unshuffle_with_mask_tokens
 from jumbo_mae_tpu_tpu.ops.patches import (
     extract_patches,
@@ -125,7 +126,8 @@ class MAEPretrainModel(nn.Module):
     ):
         enc_cfg = self.encoder_cfg
         k = enc_cfg.num_cls_tokens
-        images = normalize_images(images, dtype=enc_cfg.compute_dtype)
+        with jax.named_scope(SCOPE_PREPROCESS):
+            images = normalize_images(images, dtype=enc_cfg.compute_dtype)
 
         tokens, mask, ids_restore = self.encoder(
             images,
@@ -134,26 +136,28 @@ class MAEPretrainModel(nn.Module):
             blocks_override=blocks_override,
         )
         tokens = self.decoder_proj(tokens)
-        cls, visible = tokens[:, :k, :], tokens[:, k:, :]
-
+        with jax.named_scope(SCOPE_MASK):
+            cls, visible = tokens[:, :k, :], tokens[:, k:, :]
         full = unshuffle_with_mask_tokens(
             visible, self.mask_token, ids_restore, impl=enc_cfg.gather_impl
         )
+        with jax.named_scope(SCOPE_MASK):
+            full = jnp.concatenate([cls, full], axis=1)
         decoded = self.decoder(
-            jnp.concatenate([cls, full], axis=1),
-            deterministic,
-            blocks_override=dec_blocks_override,
+            full, deterministic, blocks_override=dec_blocks_override
         )
-        pred = self.pixel_proj(decoded[:, k:, :].astype(jnp.float32))
+        with jax.named_scope(SCOPE_LOSS):
+            patches = decoded[:, k:, :].astype(jnp.float32)
+        pred = self.pixel_proj(patches)
 
-        target = extract_patches(images.astype(jnp.float32), enc_cfg.patch_size)
-        if self.norm_pix_loss:
-            mean = target.mean(axis=-1, keepdims=True)
-            var = target.var(axis=-1, keepdims=True)
-            target = (target - mean) / jnp.sqrt(var + 1e-6)
-
-        loss_per_sample = patch_mse_loss_per_sample(pred, target, mask)
-        out = {"loss": loss_per_sample.mean(), "loss_per_sample": loss_per_sample}
+        with jax.named_scope(SCOPE_LOSS):
+            target = extract_patches(images.astype(jnp.float32), enc_cfg.patch_size)
+            if self.norm_pix_loss:
+                mean = target.mean(axis=-1, keepdims=True)
+                var = target.var(axis=-1, keepdims=True)
+                target = (target - mean) / jnp.sqrt(var + 1e-6)
+            loss_per_sample = patch_mse_loss_per_sample(pred, target, mask)
+            out = {"loss": loss_per_sample.mean(), "loss_per_sample": loss_per_sample}
         if return_reconstruction:
             out["reconstruction"] = pred
             out["mask"] = mask

@@ -4,8 +4,9 @@
   Prometheus text rendering, process default registry (+ null registry for
   telemetry-off A/B runs); hosts ``AverageMeter``.
 - ``obs.exporter`` — stdlib HTTP server for ``/metrics`` and ``/healthz``.
-- ``obs.trace``    — host-side spans aggregating into the registry, optional
-  chrome-trace export, and the XLA device-trace capture helpers.
+- ``obs.trace``    — host-side spans (registry histogram + profiler
+  annotation), the step program's scope vocabulary, the record of compiled
+  step programs, and the XLA device-trace capture helper.
 - ``obs.mfu``      — analytic FLOPs + MFU reporting (fed into the registry
   by the train loop), with one device_kind normalizer for the peak-TFLOPS
   tables.
@@ -151,12 +152,10 @@ from jumbo_mae_tpu_tpu.obs.reqtrace import (
 )
 from jumbo_mae_tpu_tpu.obs.slo import SLOObjective, SLOTracker, parse_slo
 from jumbo_mae_tpu_tpu.obs.trace import (
-    annotate,
-    export_chrome_trace,
+    note_program,
+    programs,
     span,
     span_timer,
-    start_chrome_trace,
-    stop_chrome_trace,
     trace,
 )
 
@@ -202,7 +201,6 @@ __all__ = [
     "TelemetryServer",
     "UtilizationReport",
     "advise_ckpt_interval",
-    "annotate",
     "append_row",
     "bucket_display",
     "chip_spec",
@@ -214,7 +212,6 @@ __all__ = [
     "dp_comm_bytes",
     "encoder_flops_per_image",
     "env_fingerprint",
-    "export_chrome_trace",
     "extract_cost",
     "first_nonfinite_group",
     "fsdp_comm_bytes",
@@ -229,9 +226,11 @@ __all__ = [
     "make_row",
     "mfu_report",
     "normalize_device_kind",
+    "note_program",
     "parse_slo",
     "predict_train_step",
     "pretrain_flops_per_image",
+    "programs",
     "publish_cost",
     "publish_drift",
     "publish_group_stats",
@@ -244,10 +243,8 @@ __all__ = [
     "set_registry",
     "span",
     "span_timer",
-    "start_chrome_trace",
     "stats_dict",
     "stitch_generations",
-    "stop_chrome_trace",
     "trace",
     "tree_nbytes",
     "utilization_report",

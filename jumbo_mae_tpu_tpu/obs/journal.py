@@ -68,7 +68,6 @@ JOURNAL_EVENTS = frozenset(
         "quarantine",
         "flight_record",
         "compiled_program",
-        "profile",
         "shutdown",
         "fleet_straggler",
         "fleet_host_lost",

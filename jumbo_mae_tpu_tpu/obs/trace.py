@@ -1,110 +1,76 @@
-"""Lightweight host-side spans + chrome-trace export + XLA profiler capture.
+"""The program's own names in a profiler trace: host spans, device scopes,
+and the compiled step programs a trace is joined with.
 
-Three layers of timing, cheapest first:
-
-- :func:`span` — a ``with span("data_wait"):`` context that aggregates the
-  duration into the registry's ``span_seconds{name=...}`` histogram. Always
-  on (two ``perf_counter`` calls + one histogram observe); this is the
-  per-stage timing the MoFa-style performance models start from.
-- chrome-trace — ``start_chrome_trace()`` additionally buffers every span as
-  a complete event; ``export_chrome_trace(path)`` writes the standard
-  ``{"traceEvents": [...]}`` JSON that chrome://tracing and Perfetto open.
-  Host-side complement to the XLA device traces below — one timeline shows
-  the data waits and checkpoint stalls *between* the device programs.
-- :func:`trace` / :func:`annotate` — the ``jax.profiler`` device-trace
-  helpers (moved from ``utils/profiling.py``, which remains as a shim):
-  XProf/TensorBoard captures showing MXU utilization and HBM traffic.
+- :func:`span` / :class:`span_timer` — ``with span("data_wait"):`` times a
+  host-side stage into the registry's ``span_seconds{name=...}`` histogram
+  (what an operator reads at ``/metrics``) and, for the same interval,
+  enters a ``jax.profiler.TraceAnnotation``: while a profiler runs
+  (``run.profile_dir``, the benchmark's ``--trace 1``) the span sits on a
+  host line of the ``.xplane.pb`` on the same clock as the device's
+  operations. With no profiler running the annotation is a no-op TraceMe.
+- The scope vocabulary — the ``jax.named_scope`` names the step program
+  gives to what flax's module paths leave anonymous. A scope costs nothing
+  at run time: it only prefixes the ``op_name`` metadata of the HLO
+  instructions traced under it, which is what a trace's device events are
+  joined with (``benchmarks/scope_reduce.py``).
+- :func:`note_program` / :func:`programs` — the compiled step programs of
+  this process by name, recorded where they are built, so that whoever
+  reduces a trace can ask the executable for its HLO text. Nothing is
+  serialised or parsed here.
+- :func:`trace` — capture a ``jax.profiler`` device trace into a directory.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import threading
 import time
+import weakref
 from contextlib import contextmanager
-from pathlib import Path
+
+from jax.profiler import TraceAnnotation
 
 from jumbo_mae_tpu_tpu.obs.metrics import get_registry
 
 _SPAN_HELP = "host-side span durations by stage"
 
+# Host spans placed by the library (cli/train.py's loop adds data_wait,
+# train_step and checkpoint_save). PERF.md §3 says which metric reads each.
+SPAN_STATE_INIT = "state_init"  # create_sharded_state: trace + compile/load + run
+SPAN_PROGRAM_BUILD = "program_build"  # AOT lower + compile/load; ":<program>" appended
+SPAN_H2D = "h2d"  # one batch handed to the device by prefetch_to_device
 
-class _ChromeTracer:
-    """Process-wide span event buffer (chrome trace 'X' complete events)."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._events: list[dict] | None = None  # None = disabled
-
-    @property
-    def enabled(self) -> bool:
-        return self._events is not None
-
-    def start(self) -> None:
-        with self._lock:
-            self._events = []
-
-    def add(self, name: str, start_s: float, dur_s: float) -> None:
-        evt = {
-            "name": name,
-            "ph": "X",
-            "ts": start_s * 1e6,  # chrome trace timestamps are microseconds
-            "dur": dur_s * 1e6,
-            "pid": os.getpid(),
-            "tid": threading.get_ident(),
-        }
-        with self._lock:
-            if self._events is not None:
-                self._events.append(evt)
-
-    def export(self, path: str | Path) -> Path:
-        with self._lock:
-            events = list(self._events or [])
-        path = Path(path)
-        path.parent.mkdir(parents=True, exist_ok=True)
-        path.write_text(
-            json.dumps({"traceEvents": events, "displayTimeUnit": "ms"})
-        )
-        return path
-
-    def stop(self) -> None:
-        with self._lock:
-            self._events = None
+# Device scopes (jax.named_scope) of the step program, outside the model ...
+SCOPE_RNG = "rng"  # per-step key derivation (threefry fold-ins)
+SCOPE_GRAD_ACCUM = "grad_accum"  # the micro-batch scan, its sums and scaling
+SCOPE_GRAD_SCALE = "grad_scale"  # gradients x the fault harness's multiplier
+SCOPE_GRAD_NORM = "grad_norm"  # optax.global_norm for the divergence guard
+SCOPE_GUARD = "guard"  # the guard's cond and its skip branch
+SCOPE_OPTIMIZER = "optimizer"  # tx.update + apply_updates
+SCOPE_METRICS = "metrics"  # reductions of the model's outputs to scalars
+# ... and inside it, where flax's module path says nothing.
+SCOPE_PREPROCESS = "preprocess"  # uint8 -> normalized compute dtype
+SCOPE_MASK = "mask"  # random masking, its gathers, the unshuffle
+SCOPE_PATCHIFY = "patchify"  # images -> target patches
+SCOPE_LOSS = "loss"  # pixel normalisation + masked MSE
+SCOPE_ATTN_CORE = "attn_core"  # scores, softmax, weighted sum (any implementation)
 
 
-_TRACER = _ChromeTracer()
-
-
-def start_chrome_trace() -> None:
-    """Begin buffering spans as chrome-trace events (clears prior events)."""
-    _TRACER.start()
-
-
-def stop_chrome_trace() -> None:
-    _TRACER.stop()
-
-
-def export_chrome_trace(path: str | Path) -> Path:
-    """Write buffered span events as chrome://tracing / Perfetto JSON."""
-    return _TRACER.export(path)
+def _span_hist(name: str, registry):
+    reg = registry if registry is not None else get_registry()
+    return reg.histogram("span_seconds", _SPAN_HELP, labels=("name",)).labels(name)
 
 
 @contextmanager
 def span(name: str, registry=None):
-    """Time a host-side stage into ``span_seconds{name=...}`` (and the
-    chrome-trace buffer when capturing). The histogram handle is resolved
-    per entry — for per-step hot loops, hoist with :func:`span_timer`."""
-    reg = registry if registry is not None else get_registry()
-    hist = reg.histogram("span_seconds", _SPAN_HELP, labels=("name",)).labels(name)
+    """Time a host-side stage into ``span_seconds{name=...}`` and mark it in
+    a running profiler's trace. The histogram handle is resolved per entry —
+    for per-step hot loops, hoist with :func:`span_timer`."""
+    hist = _span_hist(name, registry)
     t0 = time.perf_counter()
     try:
-        yield
+        with TraceAnnotation(name):
+            yield
     finally:
-        dur = time.perf_counter() - t0
-        hist.observe(dur)
-        if _TRACER.enabled:
-            _TRACER.add(name, t0, dur)
+        hist.observe(time.perf_counter() - t0)
 
 
 class span_timer:  # noqa: N801 - context-manager factory, used like span()
@@ -112,38 +78,53 @@ class span_timer:  # noqa: N801 - context-manager factory, used like span()
     histogram lookup happens once at construction — the shape for per-step
     loops (train step, data wait)."""
 
-    __slots__ = ("name", "_hist", "_t0", "last_s")
+    __slots__ = ("name", "_hist", "_t0", "_mark", "last_s")
 
     def __init__(self, name: str, registry=None):
-        reg = registry if registry is not None else get_registry()
         self.name = name
-        self._hist = reg.histogram(
-            "span_seconds", _SPAN_HELP, labels=("name",)
-        ).labels(name)
+        self._hist = _span_hist(name, registry)
         self._t0 = 0.0
+        self._mark = None
         self.last_s = 0.0  # duration of the most recent exit (loop bookkeeping)
 
     def __enter__(self) -> "span_timer":
+        self._mark = TraceAnnotation(self.name)
+        self._mark.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         dur = time.perf_counter() - self._t0
+        self._mark.__exit__(*exc)
         self.last_s = dur
         self._hist.observe(dur)
-        if _TRACER.enabled:
-            _TRACER.add(self.name, self._t0, dur)
 
     def observe(self, dur_s: float) -> None:
-        """Record an externally measured duration under this span's name."""
+        """Record an externally measured duration under this span's name
+        (histogram only: an interval that is over cannot be annotated)."""
         self._hist.observe(dur_s)
-        if _TRACER.enabled:
-            _TRACER.add(self.name, time.perf_counter() - dur_s, dur_s)
+
+
+# name -> jax.stages.Compiled, for as long as the step that built it lives
+_PROGRAMS: "weakref.WeakValueDictionary[str, object]" = weakref.WeakValueDictionary()
+
+
+def note_program(name: str, compiled) -> None:
+    """Record a compiled step program under ``name`` (the latest wins)."""
+    _PROGRAMS[name] = compiled
+
+
+def programs() -> dict:
+    """``{name: compiled}`` of the step programs built in this process and
+    still alive; ``compiled.as_text()`` carries the ``op_name`` of every
+    instruction a device trace shows."""
+    return dict(_PROGRAMS)
 
 
 @contextmanager
 def trace(log_dir: str | None):
-    """Capture an XLA device trace into ``log_dir`` (no-op when None)."""
+    """Capture an XLA device trace into ``log_dir`` (no-op when None). The
+    program's spans entered meanwhile are in it, on its host lines."""
     if not log_dir:
         yield
         return
@@ -154,13 +135,3 @@ def trace(log_dir: str | None):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-@contextmanager
-def annotate(name: str):
-    """Named region in the device-trace timeline
-    (``jax.profiler.TraceAnnotation``)."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield

@@ -27,6 +27,8 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 
+from jumbo_mae_tpu_tpu.obs.trace import SCOPE_MASK
+
 MaskMode = Literal["shared", "per_sample"]
 GatherImpl = Literal["take", "onehot"]
 
@@ -64,6 +66,7 @@ def index_sequence(
     return jnp.take_along_axis(x, idx, axis=1)
 
 
+@jax.named_scope(SCOPE_MASK)
 def random_masking(
     x: jax.Array,
     rng: jax.Array | None,
@@ -171,6 +174,7 @@ def mask_select(
     return jnp.where(m > 0, when_masked, when_unmasked)
 
 
+@jax.named_scope(SCOPE_MASK)
 def unshuffle_with_mask_tokens(
     visible: jax.Array,
     mask_token: jax.Array,

@@ -11,7 +11,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from jumbo_mae_tpu_tpu.obs.trace import SCOPE_LOSS, SCOPE_PATCHIFY
 
+
+@jax.named_scope(SCOPE_PATCHIFY)
 def extract_patches(images: jax.Array, patch_size: int) -> jax.Array:
     """(B, H, W, C) → (B, H/p · W/p, p²·C), row-major patch order."""
     b, h, w, c = images.shape
@@ -31,6 +34,7 @@ def merge_patches(patches: jax.Array, patch_size: int) -> jax.Array:
     return x.reshape(b, g * patch_size, g * patch_size, -1)
 
 
+@jax.named_scope(SCOPE_LOSS)
 def patch_mse_loss_per_sample(
     output: jax.Array, target: jax.Array, mask: jax.Array | None = None
 ) -> jax.Array:

@@ -37,6 +37,18 @@ from jax.sharding import Mesh
 
 from jumbo_mae_tpu_tpu.faults.sentinel import guarded_apply_gradients
 from jumbo_mae_tpu_tpu.obs.modelstats import group_stats
+from jumbo_mae_tpu_tpu.obs.trace import (
+    SCOPE_GRAD_ACCUM,
+    SCOPE_GRAD_SCALE,
+    SCOPE_GUARD,
+    SCOPE_METRICS,
+    SCOPE_OPTIMIZER,
+    SCOPE_RNG,
+    SPAN_PROGRAM_BUILD,
+    SPAN_STATE_INIT,
+    note_program,
+    span,
+)
 from jumbo_mae_tpu_tpu.parallel.sharding import (
     batch_sharding,
     infer_state_sharding,
@@ -62,6 +74,14 @@ def _tree_add(a, b):
 
 def _tree_scale(a, s):
     return jax.tree_util.tree_map(lambda x: x * s, a)
+
+
+def _batch_key(batch: dict) -> tuple:
+    """What an AOT-compiled step is keyed by: the batch's leaf shapes."""
+    return tuple(
+        (k, tuple(v.shape), str(getattr(v, "dtype", type(v))))
+        for k, v in sorted(batch.items())
+    )
 
 
 def _model_inputs(mode: Mode, batch: dict) -> tuple:
@@ -120,7 +140,8 @@ def create_sharded_state(
 
     shapes = jax.eval_shape(init_fn)
     sharding = infer_state_sharding(shapes, mesh, min_shard_size=min_shard_size)
-    state = jax.jit(init_fn, out_shardings=sharding)()
+    with span(SPAN_STATE_INIT):
+        state = jax.block_until_ready(jax.jit(init_fn, out_shardings=sharding)())
     return state, sharding
 
 
@@ -207,7 +228,8 @@ def make_train_step(
             ) > 0
 
     def loss_fn(params, batch_stats, micro_idx, batch, state, loss_mult):
-        rngs = state.step_rngs(micro=micro_idx)
+        with jax.named_scope(SCOPE_RNG):
+            rngs = state.step_rngs(micro=micro_idx)
         variables = {"params": params}
         extra = {}
         if pipe_microbatches:
@@ -252,19 +274,20 @@ def make_train_step(
                 rngs=rngs,
                 **extra,
             )
-        metrics = {
-            k: v.mean() if v.ndim else v
-            for k, v in out.items()
-            if not k.endswith("_per_sample")
-        }
-        if diag:
-            # finite fraction of the loss batch: per-sample where the model
-            # exposes it (pretrain loss_per_sample, classify per-sample
-            # loss), else the scalar's own finiteness
-            ps = out.get("loss_per_sample", out["loss"])
-            fin = jnp.isfinite(ps).astype(jnp.float32)
-            metrics["finite_frac"] = fin.mean() if fin.ndim else fin
-        return metrics["loss"] * loss_mult, (metrics, new_stats)
+        with jax.named_scope(SCOPE_METRICS):
+            metrics = {
+                k: v.mean() if v.ndim else v
+                for k, v in out.items()
+                if not k.endswith("_per_sample")
+            }
+            if diag:
+                # finite fraction of the loss batch: per-sample where the
+                # model exposes it (pretrain loss_per_sample, classify
+                # per-sample loss), else the scalar's own finiteness
+                ps = out.get("loss_per_sample", out["loss"])
+                fin = jnp.isfinite(ps).astype(jnp.float32)
+                metrics["finite_frac"] = fin.mean() if fin.ndim else fin
+            return metrics["loss"] * loss_mult, (metrics, new_stats)
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -279,7 +302,8 @@ def make_train_step(
         out_shardings=(state_sharding, None),
     )
     def _train_step(state: TrainState, batch: dict, inject):
-        loss_mult, grad_mult = inject[0], inject[1]
+        with jax.named_scope(SCOPE_GRAD_SCALE):
+            loss_mult, grad_mult = inject[0], inject[1]
         if grad_accum == 1:
             (_, (metrics, new_stats)), grads = grad_fn(
                 state.params, state.batch_stats, 0, batch, state, loss_mult
@@ -322,38 +346,44 @@ def make_train_step(
                     new_stats if new_stats is not None else stats,
                 ), None
 
-            (grads, metrics, new_stats), _ = jax.lax.scan(
-                micro, init, (jnp.arange(grad_accum), batch)
-            )
-            grads = _tree_scale(grads, 1.0 / grad_accum)
-            metrics = _tree_scale(metrics, 1.0 / grad_accum)
+            with jax.named_scope(SCOPE_GRAD_ACCUM):
+                (grads, metrics, new_stats), _ = jax.lax.scan(
+                    micro, init, (jnp.arange(grad_accum), batch)
+                )
+                grads = _tree_scale(grads, 1.0 / grad_accum)
+                metrics = _tree_scale(metrics, 1.0 / grad_accum)
 
-        grads = jax.tree_util.tree_map(
-            lambda g: g * grad_mult.astype(g.dtype), grads
-        )
+        with jax.named_scope(SCOPE_GRAD_SCALE):
+            grads = jax.tree_util.tree_map(
+                lambda g: g * grad_mult.astype(g.dtype), grads
+            )
         prev_params = state.params if diag else None
         if guard_nonfinite:
             # the guard must see the INJECTED loss (metrics keep the raw
             # one): raw_loss x loss_mult is exactly the differentiated value
-            loss_val = metrics["loss"] * loss_mult
+            with jax.named_scope(SCOPE_GUARD):
+                loss_val = metrics["loss"] * loss_mult
             state, grad_norm, finite = guarded_apply_gradients(
                 state, grads, loss_val
             )
             if new_stats is not None:
                 # BatchNorm stats from a non-finite forward are tainted too
-                state = state.replace(
-                    batch_stats=jax.tree_util.tree_map(
-                        lambda new, old: jnp.where(finite, new, old),
-                        new_stats,
-                        state.batch_stats,
+                with jax.named_scope(SCOPE_GUARD):
+                    state = state.replace(
+                        batch_stats=jax.tree_util.tree_map(
+                            lambda new, old: jnp.where(finite, new, old),
+                            new_stats,
+                            state.batch_stats,
+                        )
                     )
-                )
-            metrics = metrics | {
-                "grad_norm": grad_norm,
-                "skipped": 1.0 - finite.astype(jnp.float32),
-            }
+            with jax.named_scope(SCOPE_METRICS):
+                metrics = metrics | {
+                    "grad_norm": grad_norm,
+                    "skipped": 1.0 - finite.astype(jnp.float32),
+                }
         else:
-            state = state.apply_gradients(grads=grads)
+            with jax.named_scope(SCOPE_OPTIMIZER):
+                state = state.apply_gradients(grads=grads)
             if new_stats is not None:
                 state = state.replace(batch_stats=new_stats)
         if diag:
@@ -381,18 +411,14 @@ def make_train_step(
     # error behind another multi-minute compile of the same program.
     aot: dict[tuple, Any] = {}
 
-    def _batch_key(batch: dict) -> tuple:
-        return tuple(
-            (k, tuple(v.shape), str(getattr(v, "dtype", type(v))))
-            for k, v in sorted(batch.items())
-        )
-
     def train_step(state: TrainState, batch: dict, inject=None):
         inj = no_inject if inject is None else np.asarray(inject, np.float32)
         key = _batch_key(batch)
         compiled = aot.get(key)
         if compiled is None:
-            compiled = aot[key] = _train_step.lower(state, batch, inj).compile()
+            with span(f"{SPAN_PROGRAM_BUILD}:train_step"):
+                compiled = aot[key] = _train_step.lower(state, batch, inj).compile()
+            note_program("train_step", compiled)
         return compiled(state, batch, inj)
 
     train_step.executables = aot  # read by cli/train's cost extraction
@@ -440,7 +466,19 @@ def make_eval_step(
         sums["num_samples"] = valid.sum()
         return sums
 
-    def eval_step(state: TrainState, batch: dict, batch_idx: int = 0):
-        return _eval_step(state, batch, jnp.asarray(batch_idx, jnp.int32))
+    # AOT dispatch like the train step's: the compile point is explicit, so
+    # it can be timed (``program_build:eval_step``) and its executable noted
+    aot: dict[tuple, Any] = {}
 
+    def eval_step(state: TrainState, batch: dict, batch_idx: int = 0):
+        idx = jnp.asarray(batch_idx, jnp.int32)
+        key = _batch_key(batch)
+        compiled = aot.get(key)
+        if compiled is None:
+            with span(f"{SPAN_PROGRAM_BUILD}:eval_step"):
+                compiled = aot[key] = _eval_step.lower(state, batch, idx).compile()
+            note_program("eval_step", compiled)
+        return compiled(state, batch, idx)
+
+    eval_step.executables = aot
     return eval_step

@@ -8,7 +8,7 @@ from jumbo_mae_tpu_tpu.utils.mfu import (
     mfu_report,
     pretrain_flops_per_image,
 )
-from jumbo_mae_tpu_tpu.utils.profiling import annotate, trace
+from jumbo_mae_tpu_tpu.utils.profiling import trace
 from jumbo_mae_tpu_tpu.utils.summary import param_summary
 
 __all__ = [
@@ -16,7 +16,6 @@ __all__ = [
     "MetricLogger",
     "PEAK_TFLOPS",
     "StepTimer",
-    "annotate",
     "classify_flops_per_image",
     "detect_peak_tflops",
     "encoder_flops_per_image",

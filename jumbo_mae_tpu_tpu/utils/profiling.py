@@ -1,7 +1,7 @@
 """Compat shim: profiler capture moved to ``jumbo_mae_tpu_tpu.obs.trace``,
-which adds host-side spans and chrome-trace export alongside the XLA
-device-trace helpers that lived here."""
+which adds the host-side spans alongside the XLA device-trace helper that
+lived here."""
 
-from jumbo_mae_tpu_tpu.obs.trace import annotate, trace
+from jumbo_mae_tpu_tpu.obs.trace import trace
 
-__all__ = ["annotate", "trace"]
+__all__ = ["trace"]

@@ -33,12 +33,7 @@ from jumbo_mae_tpu_tpu.obs import (
     set_registry,
     span,
 )
-from jumbo_mae_tpu_tpu.obs.trace import (
-    export_chrome_trace,
-    span_timer,
-    start_chrome_trace,
-    stop_chrome_trace,
-)
+from jumbo_mae_tpu_tpu.obs.trace import programs, span_timer
 
 # ---------------------------------------------------------------- registry
 
@@ -217,24 +212,109 @@ def test_span_timer_reuse_and_last_s():
     assert snap["sum"] >= 0.51
 
 
-def test_chrome_trace_export(tmp_path):
+def _host_event_names(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(trace_dir / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    return {
+        e.name
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    }
+
+
+def test_spans_sit_on_the_profilers_clock(tmp_path):
+    """A span and a span_timer entered while jax.profiler runs are host
+    events of the written trace, by name, beside the device's operations —
+    and still observe into span_seconds."""
+    import jax
+
     reg = MetricsRegistry()
-    start_chrome_trace()
+    timer = span_timer("probe_timer", registry=reg)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
-        with span("traced", registry=reg):
+        with span("probe_span", registry=reg):
+            jax.block_until_ready(jax.jit(lambda x: x + 1)(np.ones(8, np.float32)))
+        with timer:
             pass
-        path = export_chrome_trace(tmp_path / "trace.json")
     finally:
-        stop_chrome_trace()
-    doc = json.loads(path.read_text())
-    events = doc["traceEvents"]
-    assert len(events) == 1
-    (evt,) = events
-    assert evt["name"] == "traced" and evt["ph"] == "X"
-    assert evt["dur"] >= 0 and "ts" in evt and "pid" in evt
-    # spans outside a capture window must not leak into a later export
-    with span("untraced", registry=reg):
+        jax.profiler.stop_trace()
+    assert {"probe_span", "probe_timer"} <= _host_event_names(tmp_path)
+    snap = reg.snapshot()["span_seconds"]
+    assert snap["probe_span"]["count"] == 1 and snap["probe_timer"]["count"] == 1
+    # with no profiler running the annotation is a no-op; the histogram stays
+    with span("probe_span", registry=reg):
         pass
+    assert reg.snapshot()["span_seconds"]["probe_span"]["count"] == 2
+
+
+def _span_count(name):
+    return get_registry().snapshot().get("span_seconds", {}).get(name, {}).get("count", 0)
+
+
+def test_first_train_step_records_its_build_and_its_program(monkeypatch):
+    """The AOT compile point times itself (``program_build:train_step``) and
+    notes the executable; the HLO text is asked for by whoever reduces a
+    trace, never by set-up or by a step."""
+    import jax
+
+    from jumbo_mae_tpu_tpu.config import MeshConfig, OptimConfig
+    from jumbo_mae_tpu_tpu.models import DecoderConfig, MAEPretrainModel, preset
+    from jumbo_mae_tpu_tpu.parallel import create_mesh
+    from jumbo_mae_tpu_tpu.train import (
+        create_sharded_state,
+        make_eval_step,
+        make_optimizer,
+        make_train_step,
+    )
+
+    asked = []
+    real = jax.stages.Compiled.as_text
+    monkeypatch.setattr(jax.stages.Compiled, "as_text",
+                        lambda self, *a, **k: asked.append(1) or real(self, *a, **k))
+    enc = preset("vit_t16", labels=None, mask_ratio=0.75, image_size=32, dtype="float32")
+    model = MAEPretrainModel(enc, DecoderConfig(layers=1, dim=32, heads=2, dtype="float32"))
+    batch = {"images": np.zeros((8, 32, 32, 3), np.uint8)}
+    mesh = create_mesh(MeshConfig(data=1, fsdp=1), devices=jax.devices()[:1])
+    tx = make_optimizer(OptimConfig(warmup_steps=2, training_steps=10), global_batch_size=8)
+    inits = _span_count("state_init")
+    state, sharding = create_sharded_state(model, tx, batch, mesh, mode="pretrain")
+    assert _span_count("state_init") == inits + 1
+    step = make_train_step(mesh, sharding, mode="pretrain", guard_nonfinite=True)
+    evaluate = make_eval_step(mesh, sharding, mode="pretrain")
+    builds = _span_count("program_build:train_step")
+    eval_builds = _span_count("program_build:eval_step")
+    sums = evaluate(state, batch)
+    state, _ = step(state, batch)
+    assert _span_count("program_build:train_step") == builds + 1
+    assert _span_count("program_build:eval_step") == eval_builds + 1
+    (compiled,) = step.executables.values()
+    assert programs()["train_step"] is compiled
+    assert programs()["eval_step"] is next(iter(evaluate.executables.values()))
+    state, metrics = step(state, batch)  # the same shapes build nothing
+    assert _span_count("program_build:train_step") == builds + 1
+    assert np.isfinite(float(metrics["loss"])) and float(sums["num_samples"]) == 8
+    assert not asked
+    assert "guard/cond" in compiled.as_text() and asked
+
+
+def test_prefetch_to_device_records_one_h2d_per_batch():
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from jumbo_mae_tpu_tpu.data.loader import prefetch_to_device
+
+    before = _span_count("h2d")
+    batches = ({"images": np.full((2, 4), i, np.float32)} for i in range(5))
+    out = list(prefetch_to_device(batches, SingleDeviceSharding(jax.devices()[0])))
+    assert [int(b["images"][0, 0]) for b in out] == [0, 1, 2, 3, 4]
+    assert _span_count("h2d") == before + 5
 
 
 # ------------------------------------------------------- compat shims

@@ -299,7 +299,6 @@ def diagnose(events: list[dict], flight: dict | None = None) -> str:
         "checkpoint_save",
         "rollback",
         "quarantine",
-        "profile",
         "compiled_program",
         "shutdown",
         # supervisor rows (train/elastic.py) live in host-0's journal dir
@@ -396,8 +395,6 @@ def diagnose(events: list[dict], flight: dict | None = None) -> str:
             detail = f"{e.get('reason')} at step {e.get('step')}"
         elif etype == "run_start":
             detail = f"start_step {e.get('start_step', 0)}"
-        elif etype == "profile":
-            detail = f"trace capture → {e.get('combined_trace') or e.get('device_trace')}"
         elif etype == "compiled_program":
             detail = (
                 f"{e.get('program')}: {_fmt_num(e.get('flops', 0))} flops, "
