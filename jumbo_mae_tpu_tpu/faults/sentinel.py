@@ -6,11 +6,16 @@ Two cooperating halves:
   step by ``make_train_step(guard_nonfinite=True)``): an all-reduced
   ``isfinite(loss) & isfinite(grad_norm)`` flag — the mean over the
   globally-sharded batch IS the cross-replica value under GSPMD, so no
-  explicit collective is needed — gates the optimizer update through
-  ``lax.cond``. A non-finite step passes the state through untouched
-  (params, opt state, BatchNorm stats) except the step counter, which still
-  advances so the data stream and LR schedule stay aligned. Both branches
-  have identical structure: **no recompile**, ever.
+  explicit collective is needed — gates the optimizer update through a
+  per-leaf select: the update is computed every step and
+  ``where(finite, new, old)`` keeps or discards it inside the optimizer's
+  own element-wise fusions. A conditional branch in its place cost the
+  L/16 step 9.2 ms of copies and DMA waits inside the branches, every step
+  (PERF.md §6, PR 25). A non-finite step
+  passes the state through untouched (params, opt state, BatchNorm stats)
+  except the step counter, which still advances so the data stream and LR
+  schedule stay aligned. One program for both outcomes: **no recompile**,
+  ever.
 
 - **Host sentinel** (:class:`DivergenceSentinel`, driven by ``cli/train.py``
   at log boundaries — per-step host sync would serialize dispatch against
@@ -42,27 +47,62 @@ from jumbo_mae_tpu_tpu.obs.trace import (
 )
 
 
+def _keep_if(finite, new, old):
+    """Per leaf ``where(finite, new, old)``, the float32 leaves first and the
+    narrower ones (a bf16 first moment, bf16 parameters beside their float32
+    master) only once those are done.
+
+    The order is for the compiler. XLA:TPU puts outputs of one element type
+    into one loop fusion, so a leaf's update comes out as one fusion per
+    dtype, and they share inputs: the float32 fusion (p', nu') reads the old
+    bf16 mu that the bf16 fusion overwrites in place. Left unordered, the
+    L/16 step ran the writer first for 454 of 577 leaves and kept a copy of
+    every old mu from the start of the step (0.8 GB, PERF.md PR 25). Behind
+    the barrier ``finite`` is the same flag; it only exists later.
+    """
+    leaves_new, treedef = jax.tree_util.tree_flatten(new)
+    leaves_old = treedef.flatten_up_to(old)
+    wide = [n.dtype.itemsize >= 4 for n in leaves_new]
+    first = [
+        jnp.where(finite, n, o)
+        for n, o, w in zip(leaves_new, leaves_old, wide)
+        if w
+    ]
+    if not all(wide):
+        finite, first = jax.lax.optimization_barrier((finite, first))
+    first = iter(first)
+    return treedef.unflatten(
+        next(first) if w else jnp.where(finite, n, o)
+        for n, o, w in zip(leaves_new, leaves_old, wide)
+    )
+
+
 def guarded_apply_gradients(state, grads, loss):
     """Optimizer update gated on finiteness, inside the jitted step.
 
-    Returns ``(new_state, grad_norm, finite)``; on a non-finite ``loss`` or
-    ``grad_norm`` the update (and any BatchNorm-stats replace the caller does
-    afterwards) must be skipped — the state comes back unchanged except
-    ``step + 1``.
+    Returns ``(new_state, grad_norm, finite)``. The update is always
+    computed; on a non-finite ``loss`` or ``grad_norm`` a per-leaf
+    ``where(finite, new, old)`` over ``params`` and ``opt_state`` throws it
+    away, so the state comes back bit-unchanged except ``step + 1`` (the
+    caller gates its BatchNorm-stats replace on ``finite`` the same way).
+    The gradients themselves are never masked: a zeroed gradient would
+    still apply weight decay and decay the moments.
     """
     with jax.named_scope(SCOPE_GRAD_NORM):
         grad_norm = optax.global_norm(grads)
-
-    def _update(_):
-        with jax.named_scope(SCOPE_OPTIMIZER):
-            return state.apply_gradients(grads=grads)
-
-    def _skip(_):
-        return state.replace(step=state.step + 1)
-
     with jax.named_scope(SCOPE_GUARD):
         finite = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
-        new_state = jax.lax.cond(finite, _update, _skip, operand=None)
+    with jax.named_scope(SCOPE_OPTIMIZER):
+        updated = state.apply_gradients(grads=grads)
+    # apply_gradients touches step, params and opt_state only: the typed
+    # ``rng`` key (no ``where`` on it) and ``batch_stats`` ride along
+    with jax.named_scope(SCOPE_GUARD):
+        params, opt_state = _keep_if(
+            finite,
+            (updated.params, updated.opt_state),
+            (state.params, state.opt_state),
+        )
+    new_state = updated.replace(params=params, opt_state=opt_state)
     return new_state, grad_norm, finite
 
 

@@ -43,7 +43,7 @@ SCOPE_RNG = "rng"  # per-step key derivation (threefry fold-ins)
 SCOPE_GRAD_ACCUM = "grad_accum"  # the micro-batch scan, its sums and scaling
 SCOPE_GRAD_SCALE = "grad_scale"  # gradients x the fault harness's multiplier
 SCOPE_GRAD_NORM = "grad_norm"  # optax.global_norm for the divergence guard
-SCOPE_GUARD = "guard"  # the guard's cond and its skip branch
+SCOPE_GUARD = "guard"  # the finite flag and the per-leaf selects that gate the update
 SCOPE_OPTIMIZER = "optimizer"  # tx.update + apply_updates
 SCOPE_METRICS = "metrics"  # reductions of the model's outputs to scalars
 # ... and inside it, where flax's module path says nothing.

@@ -20,6 +20,7 @@ the same hooks a ``GRAFT_FAULTS=`` run uses:
   resume continues from that exact step (tier-1, in-process).
 """
 
+import functools
 import math
 import os
 import signal
@@ -158,8 +159,31 @@ class TestFaultPlan:
 # ------------------------------------------------- device guard / sentinel
 
 
-def _tiny_train_setup(guard: bool, steps: int = 20):
-    from jumbo_mae_tpu_tpu.models import DecoderConfig, MAEPretrainModel, preset
+# The guard's cases (f32 compute, CPU): what the gate has to carry besides
+# the plain AdamW state. Each is built once (``_guard_case``) and shared by
+# the scenarios of ``test_guarded_step_is_bit_exact``.
+GUARD_CASES = {
+    "adamw": {},
+    "adamw_bf16_mu": {"optim": {"mu_dtype": "bfloat16"}},
+    # bf16 params: the f32 master copy is an opt_state leaf, gated like the rest
+    "master_weights": {"optim": {"param_dtype": "bfloat16", "mu_dtype": "bfloat16"}},
+    "grad_accum_2": {"grad_accum": 2},
+    # linear probe with the BatchNorm head: the running stats are gated too
+    "classify_batchnorm": {"mode": "classify"},
+    # leaves sharded over fsdp=4: finite is replicated, each device selects its shards
+    "fsdp_mesh": {"mesh": {"data": 2, "fsdp": 4}},
+}
+
+
+def _tiny_build(*, mode="pretrain", optim=None, grad_accum=1, mesh=None):
+    """``(state, sharding, make_step, batch)`` of the tiny model on a CPU mesh;
+    ``make_step(guard)`` builds the train step over the same state layout."""
+    from jumbo_mae_tpu_tpu.models import (
+        ClassificationModel,
+        DecoderConfig,
+        MAEPretrainModel,
+        preset,
+    )
     from jumbo_mae_tpu_tpu.parallel import MeshConfig, create_mesh
     from jumbo_mae_tpu_tpu.train import (
         OptimConfig,
@@ -172,27 +196,59 @@ def _tiny_train_setup(guard: bool, steps: int = 20):
         "vit_t16", image_size=32, patch_size=8, mask_ratio=0.75, labels=None,
         dtype="float32",
     )
-    module = MAEPretrainModel(
-        enc, DecoderConfig(layers=1, dim=32, heads=2, dtype="float32")
+    rng = np.random.RandomState(0)
+    batch = {"images": rng.randint(0, 256, (16, 32, 32, 3)).astype(np.uint8)}
+    if mode == "pretrain":
+        module = MAEPretrainModel(
+            enc, DecoderConfig(layers=1, dim=32, heads=2, dtype="float32")
+        )
+    else:
+        module = ClassificationModel(
+            enc.replace(mask_ratio=None, labels=10, linear_probing=True, batch_norm=True),
+            mixup_alpha=0.0, cutmix_alpha=0.0,
+        )
+        batch["labels"] = rng.randint(0, 10, (16,)).astype(np.int32)
+    cfg = OptimConfig(
+        name="adamw", learning_rate=1e-3, lr_scaling="none",
+        warmup_steps=2, training_steps=20, **(optim or {}),
     )
-    tx = make_optimizer(
-        OptimConfig(
-            name="adamw", learning_rate=1e-3, lr_scaling="none",
-            warmup_steps=2, training_steps=steps,
-        ),
-        global_batch_size=16,
-    )
-    batch = {
-        "images": np.random.RandomState(0)
-        .randint(0, 256, (16, 32, 32, 3))
-        .astype(np.uint8)
-    }
-    mesh = create_mesh(MeshConfig(data=1, fsdp=1))
+    tx = make_optimizer(cfg, global_batch_size=16)
+    mesh_cfg = MeshConfig(**(mesh or {"data": 1, "fsdp": 1}))
+    mesh = create_mesh(mesh_cfg)
     state, sharding = create_sharded_state(
-        module, tx, batch, mesh, mode="pretrain"
+        module, tx, batch, mesh, mode=mode, param_dtype=cfg.param_dtype,
+        # the tiny leaves are under the default threshold: shard them anyway
+        min_shard_size=2**16 if mesh_cfg.fsdp == 1 else 64,
     )
-    step = make_train_step(mesh, sharding, mode="pretrain", guard_nonfinite=guard)
-    return state, step, batch
+    if grad_accum > 1:
+        batch = {
+            k: v.reshape(grad_accum, -1, *v.shape[1:]) for k, v in batch.items()
+        }
+
+    def make_step(guard: bool):
+        return make_train_step(
+            mesh, sharding, mode=mode, grad_accum=grad_accum, guard_nonfinite=guard
+        )
+
+    return state, sharding, make_step, batch
+
+
+def _fresh_states(state, sharding):
+    """``fresh()`` -> a value-identical state in device buffers of its own,
+    every call. The train step DONATES its input state, and a typed PRNG key
+    comes through ``device_get`` / ``device_put`` as the same device buffer
+    (the first donating step would delete it under every later caller): the
+    key travels as its uint32 data and is wrapped anew each time. Also
+    returns the host snapshot, to compare against."""
+    import jax
+
+    host = jax.device_get(state.replace(rng=jax.random.key_data(state.rng)))
+
+    def fresh():
+        made = host.replace(rng=jax.random.wrap_key_data(jax.numpy.array(host.rng)))
+        return jax.device_put(made, sharding)
+
+    return fresh, host
 
 
 def _host_params(state):
@@ -209,26 +265,51 @@ def _params_equal(a, b) -> bool:
     return all(np.array_equal(x, y) for x, y in zip(leaves_a, leaves_b))
 
 
+def _gated_leaves(state) -> dict:
+    """Everything the guard must leave bit-unchanged on a skipped step:
+    ``{path: host array}`` over params, opt_state and BatchNorm stats."""
+    import jax
+
+    tree = {
+        "params": state.params,
+        "opt_state": state.opt_state,
+        "batch_stats": state.batch_stats,
+    }
+    flat, _ = jax.tree_util.tree_flatten_with_path(jax.device_get(tree))
+    return {jax.tree_util.keystr(path): np.asarray(leaf) for path, leaf in flat}
+
+
+def _differing(a: dict, b: dict) -> list:
+    assert a.keys() == b.keys()
+    return [
+        k for k in a
+        if a[k].dtype != b[k].dtype or not np.array_equal(a[k], b[k], equal_nan=True)
+    ]
+
+
+@functools.cache
+def _guard_case(name: str):
+    """``(fresh, host, guarded step, unguarded step, batch)``, one build per
+    case for the whole module: the ~10 s XLA compiles of the two steps are
+    paid once, not per test."""
+    state, sharding, make_step, batch = _tiny_build(**GUARD_CASES[name])
+    fresh, host = _fresh_states(state, sharding)
+    return fresh, host, make_step(True), make_step(False), batch
+
+
 class TestDeviceGuard:
-    # One compiled setup per guard flavor for the whole class — the ~10 s
-    # XLA compile is paid once instead of per test. The train step DONATES
-    # its input state, so the fixture keeps a pristine host snapshot and
-    # hands every caller a fresh device copy via fresh().
-    @staticmethod
-    def _shared(guard):
-        import jax
-
-        state, step, batch = _tiny_train_setup(guard=guard)
-        snap = jax.device_get(state)
-        return (lambda: jax.device_put(snap)), step, batch
-
+    # The plain-AdamW case's two compiled steps serve the whole class. The
+    # train step DONATES its input state, so the case keeps a pristine host
+    # snapshot and hands every caller a fresh device copy via fresh().
     @pytest.fixture(scope="class")
     def guarded(self):
-        return self._shared(guard=True)
+        fresh, _, step, _, batch = _guard_case("adamw")
+        return fresh, step, batch
 
     @pytest.fixture(scope="class")
     def unguarded(self):
-        return self._shared(guard=False)
+        fresh, _, _, step, batch = _guard_case("adamw")
+        return fresh, step, batch
 
     def test_nan_loss_step_is_skipped(self, guarded):
         """Injected NaN loss: params bit-unchanged, step still advances,
@@ -284,6 +365,57 @@ class TestDeviceGuard:
         sb, mb = step_fn(fresh(), batch, np.ones(2, np.float32))
         assert float(ma["loss"]) == float(mb["loss"])
         assert _params_equal(_host_params(sa), _host_params(sb))
+
+    @pytest.mark.parametrize("scenario", ["finite", "nan_loss", "nan_grad"])
+    @pytest.mark.parametrize("case", sorted(GUARD_CASES))
+    def test_guarded_step_is_bit_exact(self, case, scenario):
+        """The select that gates the update changes no bit. On a finite
+        batch the guarded step leaves params, every opt_state leaf and the
+        BatchNorm stats exactly as the unguarded step does; on a non-finite
+        loss or gradient every one of them is bit-unchanged (the update's
+        arithmetic ran on NaNs and was thrown away), ``step`` still
+        advances, ``skipped`` is raised — and the next clean step applies."""
+        fresh, host, guarded, unguarded, batch = _guard_case(case)
+        if scenario == "finite":
+            got, m = guarded(fresh(), batch)
+            want, mu = unguarded(fresh(), batch)
+            assert float(m["skipped"]) == 0.0
+            assert float(m["loss"]) == float(mu["loss"])
+            assert int(got.step) == int(want.step) == int(host.step) + 1
+            assert _differing(_gated_leaves(got), _gated_leaves(want)) == []
+            # ... and the update did happen: every parameter the optimizer
+            # trains moved, so "equal" is not "both unchanged"
+            assert _differing(_gated_leaves(got), _gated_leaves(host))
+            return
+        inject = {"nan_loss": [np.nan, 1.0], "nan_grad": [1.0, np.nan]}[scenario]
+        before = _gated_leaves(host)
+        state, m = guarded(fresh(), batch, np.asarray(inject, np.float32))
+        assert float(m["skipped"]) == 1.0
+        assert int(state.step) == int(host.step) + 1
+        assert _differing(_gated_leaves(state), before) == []
+        state, m = guarded(state, batch)  # the next clean step applies
+        assert float(m["skipped"]) == 0.0 and math.isfinite(float(m["grad_norm"]))
+        assert int(state.step) == int(host.step) + 2
+        moved = _differing(_gated_leaves(state), before)
+        assert any(k.startswith("['params']") for k in moved)
+        assert any(k.startswith("['opt_state']") for k in moved)
+        if host.batch_stats is not None:
+            assert any(k.startswith("['batch_stats']") for k in moved)
+
+    @pytest.mark.parametrize("guard", [True, False])
+    def test_no_branch_in_the_compiled_step(self, guarded, unguarded, guard):
+        """The gate is a select inside the update, not a branch: the compiled
+        guarded step has no ``conditional`` (a ``lax.cond`` there cost the
+        L/16 step 9.2 ms of copies and waits, PERF.md PR 25). And the
+        unguarded program names no ``guard`` scope: nothing of the guard's
+        leaks into the step that runs without it."""
+        fresh, step_fn, batch = guarded if guard else unguarded
+        step_fn(fresh(), batch)
+        (compiled,) = step_fn.executables.values()
+        text = compiled.as_text()
+        assert " conditional(" not in text
+        assert ("/guard/" in text) == guard
+        assert ("/grad_norm/" in text) == guard
 
 
 class TestHostSentinel:

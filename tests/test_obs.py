@@ -301,7 +301,7 @@ def test_first_train_step_records_its_build_and_its_program(monkeypatch):
     assert _span_count("program_build:train_step") == builds + 1
     assert np.isfinite(float(metrics["loss"])) and float(sums["num_samples"]) == 8
     assert not asked
-    assert "guard/cond" in compiled.as_text() and asked
+    assert "guard/jit(_where)/select_n" in compiled.as_text() and asked
 
 
 def test_prefetch_to_device_records_one_h2d_per_batch():
