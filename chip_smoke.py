@@ -3,7 +3,9 @@
 points, at the full width of a model the repo ships.
 
     python3 chip_smoke.py [--out DIR]       # one chip: kernels, train,
-                                            #   resume, serve
+                                            #   resume, serve, lm_kernels,
+                                            #   lm_train
+    python3 chip_smoke.py --phases lm_kernels,lm_train   # only those
     python3 chip_smoke.py --chips 4         # four-chip host: sharded training
                                             #   against its one-chip control,
                                             #   and no other phase
@@ -43,6 +45,7 @@ import numpy as np
 REPO = Path(__file__).resolve().parent
 L16_RECIPE = str(REPO / "recipes" / "pretrain_vit_l16_in1k_800ep.yaml")
 H14_RECIPE = str(REPO / "recipes" / "pretrain_vit_h14_in1k_fsdp.yaml")
+LM_RECIPE = str(REPO / "recipes" / "pretrain_joyai_flash_ep16.yaml")
 
 # (batch, seq, heads, head_dim) attention inputs the long-context recipes
 # reach (recipes/pretrain_vit_l16_448_longctx.yaml): the decoder at 448 px
@@ -190,6 +193,38 @@ def _rel_err(got, want) -> float:
 # ---------------------------------------------------------------- kernels
 
 
+def _check_against_reference(label, key, fn, ref_fn, args, worst, *, interpret: bool):
+    """``fn`` (kernels) and ``ref_fn`` (XLA) are ``(*args) -> (scalar, aux)``:
+    compile ``fn``'s value-and-gradient over every argument, require the
+    Mosaic custom call in it (unless interpreted), run both and record in
+    ``worst[key]`` the largest relative error over ``aux`` and the gradients."""
+    import jax
+    import jax.numpy as jnp
+
+    argnums = tuple(range(len(args)))
+    compiled = jax.jit(jax.value_and_grad(fn, argnums=argnums, has_aux=True)).lower(*args).compile()
+    if not interpret:
+        check(
+            "tpu_custom_call" in compiled.as_text(),
+            f"{label}: no Mosaic custom call in the compiled program — the "
+            "kernel is not what ran",
+        )
+    (_, aux), grads = compiled(*args)
+    (_, ref_aux), ref_grads = jax.jit(
+        jax.value_and_grad(ref_fn, argnums=argnums, has_aux=True)
+    )(*args)
+    got = jax.tree_util.tree_leaves((aux, grads))
+    want = jax.tree_util.tree_leaves((ref_aux, ref_grads))
+    for g, r in zip(got, want):
+        check(bool(jnp.isfinite(g.astype(jnp.float32)).all()), f"{label}: non-finite output")
+        err = _rel_err(g, r)
+        check(
+            err < KERNEL_REL_TOL,
+            f"{label}: {err:.4f} off the XLA reference (tolerance {KERNEL_REL_TOL})",
+        )
+        worst[key] = round(max(worst.get(key, 0.0), err), 5)
+
+
 def phase_kernels(shapes=KERNEL_SHAPES, *, interpret: bool = False) -> dict:
     """Pallas flash attention forward+backward, plain and ``_with_lse``,
     executed and compared with ``ops.flash_attention.xla_attention``; plus
@@ -243,31 +278,10 @@ def phase_kernels(shapes=KERNEL_SHAPES, *, interpret: bool = False) -> dict:
             ("flash", flash, ref),
             ("flash_with_lse", flash_lse, ref_with_lse),
         ):
-            grad = jax.value_and_grad(fn, argnums=(0, 1, 2), has_aux=True)
-            compiled = jax.jit(grad).lower(q, k, v).compile()
-            if not interpret:
-                check(
-                    "tpu_custom_call" in compiled.as_text(),
-                    f"{name} at {(b, s, h, d)}: no Mosaic custom call in the "
-                    "compiled program — the kernel is not what ran",
-                )
-            (_, aux), grads = compiled(q, k, v)
-            (_, ref_aux), ref_grads = jax.jit(
-                jax.value_and_grad(ref_fn, argnums=(0, 1, 2), has_aux=True)
-            )(q, k, v)
-            got = jax.tree_util.tree_leaves((aux, grads))
-            want = jax.tree_util.tree_leaves((ref_aux, ref_grads))
-            for g, r in zip(got, want):
-                check(bool(jnp.isfinite(g.astype(jnp.float32)).all()),
-                      f"{name} at {(b, s, h, d)}: non-finite output")
-                err = _rel_err(g, r)
-                check(
-                    err < KERNEL_REL_TOL,
-                    f"{name} at {(b, s, h, d)}: {err:.4f} off the XLA "
-                    f"reference (tolerance {KERNEL_REL_TOL})",
-                )
-                key = f"{name}@{s}x{d}"
-                worst[key] = round(max(worst.get(key, 0.0), err), 5)
+            _check_against_reference(
+                f"{name} at {(b, s, h, d)}", f"{name}@{s}x{d}", fn, ref_fn, (q, k, v),
+                worst, interpret=interpret,
+            )
 
     # gather_impl="onehot" claims bit-identity with the XLA gather; the 0/1
     # matmuls must keep it through the MXU (ViT-H/14 shapes, per-sample and
@@ -292,6 +306,127 @@ def phase_kernels(shapes=KERNEL_SHAPES, *, interpret: bool = False) -> dict:
         "max_rel_err_vs_xla": worst,
         "onehot_gather_bit_identical": True,
     }
+
+
+# ------------------------------------------------------- language model
+
+
+# (batch, heads, seq, nope, rope, v): the language model's published head at
+# a length that is no multiple of the kernel's block
+CAUSAL_SHAPES = ((1, 4, 2148, 128, 64, 128),)
+# (rows, k, n, group sizes): one expert takes most rows, one takes none
+GROUPED_SHAPE = (4096, 2048, 1536, (2900, 0, 517, 200, 33, 8, 1, 300))
+
+
+def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, *,
+                     interpret: bool = False) -> dict:
+    """The causal flash kernels (unequal qk and v widths, the shared rope
+    key) forward+backward against the einsum form, and the grouped product
+    forward+backward against ``lax.ragged_dot``; worst relative errors."""
+    import jax
+    import jax.numpy as jnp
+
+    from jumbo_mae_tpu_tpu.ops.flash_attention import xla_causal_attention
+    from jumbo_mae_tpu_tpu.ops.grouped_matmul import grouped_matmul
+    from jumbo_mae_tpu_tpu.ops.pallas.attention import CAUSAL_BLOCK, pallas_causal_attention
+
+    worst: dict[str, float] = {}
+
+    def compare(name, fn, ref_fn, args):
+        _check_against_reference(name, name, fn, ref_fn, args, worst, interpret=interpret)
+
+    for b, h, s, dn, dr, dv in causal:
+        keys = jax.random.split(jax.random.key(s), 6)
+        bf = lambda k, shape, scale=1.0: (jax.random.normal(k, shape) * scale).astype(jnp.bfloat16)
+        scale = (dn + dr) ** -0.5
+        args = (bf(keys[0], (b, h, s, dn), scale), bf(keys[1], (b, h, s, dr), scale),
+                bf(keys[2], (b, h, s, dn)), bf(keys[3], (b, s, dr)), bf(keys[4], (b, h, s, dv)))
+        w = jax.random.normal(keys[5], (b, h, s, dv))
+
+        def weigh(fn, w=w):
+            def weighed(*xs):
+                o = fn(*xs)
+                return (o.astype(jnp.float32) * w).sum(), o
+            return weighed
+
+        block = CAUSAL_BLOCK if not interpret else 16
+        compare(f"causal@{s}x{dn}+{dr}/{dv}",
+                weigh(lambda *xs: pallas_causal_attention(*xs, block, interpret)),
+                weigh(xla_causal_attention), args)
+
+    m, k, n, sizes = grouped
+    keys = jax.random.split(jax.random.key(m), 3)
+    lhs = jax.random.normal(keys[0], (m, k)).astype(jnp.bfloat16)
+    rhs = (jax.random.normal(keys[1], (len(sizes), k, n)) * k**-0.5).astype(jnp.bfloat16)
+    w = jax.random.normal(keys[2], (m, n))
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+
+    starts = np.concatenate([[0], np.cumsum(sizes)])
+    group_of_row = np.full((m,), len(sizes), np.int32)  # rows past the last group: none
+    for g in range(len(sizes)):
+        group_of_row[starts[g]:starts[g + 1]] = g
+
+    def kernels(a, b):
+        out = grouped_matmul(a, b, group_sizes, impl="pallas", interpret=interpret)
+        return (out.astype(jnp.float32) * w).sum(), out
+
+    def dense(a, b):
+        # every group's matrix meets every row, in float32; a mask keeps its own
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        out = sum(jnp.where((group_of_row == g)[:, None],
+                            jnp.dot(a, b[g], precision="highest"), 0.0)
+                  for g in range(len(sizes)))
+        return (out * w).sum(), out
+
+    compare(f"grouped@{m}x{k}x{n}", kernels, dense, (lhs, rhs))
+    return {"mosaic_custom_call": not interpret, "max_rel_err_vs_xla": worst}
+
+
+def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: int) -> dict:
+    """``cli.train`` on the language-model recipe for ``steps`` steps of
+    seeded tokens, every step's metrics logged: every loss finite; each of
+    the cycled batches' loss lower the second time it is seen; nothing
+    dropped by an expert layer; no step skipped by the guard."""
+    from jumbo_mae_tpu_tpu.cli import train as cli_train
+
+    before = _registry_snapshot()
+    cli_train.main(_train_argv(recipe, overrides, out_dir))
+    after = _registry_snapshot()
+    cfg = _load(recipe, overrides)
+    records = _read_metrics(out_dir / cfg.run.name)
+    losses = _logged_losses(records, 1, steps, "lm_train")
+    cycle = 8  # data/synthetic.token_batches cycles 8 distinct batches
+    check(all(losses[i + cycle] < losses[i] for i in range(1, steps - cycle + 1)),
+          f"loss did not fall from one visit of a batch to the next: {losses}")
+    by_step = {int(r["step"]): r for r in records if "train/moe_dropped" in r}
+    check(sorted(by_step) == list(range(1, steps + 1)), "missing expert counters")
+    check(all(r["train/moe_dropped"] == 0 for r in by_step.values()), "an expert layer dropped pairs")
+    skipped = _delta(before, after, "train_steps_skipped_total", "")
+    check(skipped == 0, f"{skipped} step(s) skipped by the divergence guard")
+    retraces = _delta(before, after, "retrace_events_total", "train")
+    check(retraces == 0, f"{retraces} unexpected recompile(s) after warmup")
+    last = max((r for r in records if "perf/tokens_per_sec_per_chip" in r),
+               key=lambda r: r["step"])
+    share = [r["train/moe_held_share"] for r in by_step.values()]
+    return {
+        "steps": steps,
+        "loss_first": round(losses[1], 4),
+        "loss_after_one_cycle": round(losses[1 + cycle], 4),
+        "loss_last": round(losses[steps], 4),
+        "moe_dropped": 0,
+        "moe_held_share_min_max": [round(min(share), 4), round(max(share), 4)],
+        "moe_imbalance_max": round(max(r["train/moe_imbalance"] for r in by_step.values()), 3),
+        "skipped_steps": 0,
+        "tokens_per_sec_per_chip": round(last["perf/tokens_per_sec_per_chip"], 1),
+        "mfu_trainer_reported": last.get("perf/mfu"),
+        "peak_hbm_bytes_this_process": _peak_hbm_bytes(),
+    }
+
+
+def _lm_overrides(steps: int) -> list[str]:
+    # the schedule's horizon stays the recipe's (its warm-up is longer than a smoke)
+    return ["run.use_wandb=false", "run.sanity_eval=false", f"run.training_steps={steps}",
+            f"run.eval_interval={steps}", "run.log_interval=1", "optim.training_steps=100000"]
 
 
 # ------------------------------------------------------------------ train
@@ -651,6 +786,8 @@ def main(argv: list[str] | None = None) -> int:
                     help="work directory (logs, checkpoints, features)")
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: only the sharded-training phase and its control")
+    ap.add_argument("--phases", default="",
+                    help="comma-separated names: run only these one-chip phases")
     args = ap.parse_args(argv)
 
     from jumbo_mae_tpu_tpu.utils.procenv import enable_compile_cache
@@ -691,7 +828,16 @@ def main(argv: list[str] | None = None) -> int:
             ("resume", lambda: phase_resume(
                 L16_RECIPE, resume, out, start=steps, steps=more, watch=watch)),
             ("serve", lambda: phase_serve(L16_RECIPE, [], out)),
+            ("lm_kernels", phase_lm_kernels),
+            ("lm_train", lambda: phase_lm_train(LM_RECIPE, _lm_overrides(24), out, steps=24)),
         ]
+        if args.phases:
+            only = args.phases.split(",")
+            unknown = set(only) - {name for name, _ in phases}
+            if unknown:
+                print(f"chip_smoke: unknown phase(s) {sorted(unknown)}", file=sys.stderr)
+                return 2
+            phases = [(name, fn) for name, fn in phases if name in only]
 
     passed = {}
     for name, fn in phases:

@@ -43,6 +43,7 @@ from jumbo_mae_tpu_tpu.data import (
     synthetic_batches,
     valid_loader,
 )
+from jumbo_mae_tpu_tpu.data.synthetic import token_batches
 from jumbo_mae_tpu_tpu.data.tario import QUARANTINE
 from jumbo_mae_tpu_tpu.faults import (
     DivergenceError,
@@ -61,6 +62,8 @@ from jumbo_mae_tpu_tpu.models import (
     MAEPretrainModel,
     preset,
 )
+from jumbo_mae_tpu_tpu.models.lm import MlaMoeConfig, MlaMoeLM
+from jumbo_mae_tpu_tpu.obs.mfu import lm_flops_per_token
 from jumbo_mae_tpu_tpu.parallel import batch_sharding, create_mesh
 from jumbo_mae_tpu_tpu.train import (
     EXIT_FATAL,
@@ -115,13 +118,22 @@ from jumbo_mae_tpu_tpu.utils import (
     param_summary,
     pretrain_flops_per_image,
 )
+from jumbo_mae_tpu_tpu.train.modes import MODES, STEP_MODE
 from jumbo_mae_tpu_tpu.utils.procenv import enable_compile_cache
 
 
 def build_model(cfg: TrainConfig):
-    """Construct the mode's flax module and its per-image train FLOPs."""
+    """Construct the mode's flax module and its per-sample train FLOPs (a
+    sample is an image, or in mode ``lm`` a sequence of ``data.seq_len``
+    tokens)."""
     m = cfg.model
     mode = cfg.run.mode
+    if mode == "lm":
+        lm = MlaMoeConfig(**m.lm)
+        if cfg.data.seq_len <= 0:
+            raise ValueError("run.mode=lm needs data.seq_len > 0")
+        flops = cfg.data.seq_len * lm_flops_per_token(lm, cfg.data.seq_len)
+        return MlaMoeLM(lm), lm, flops
     if mode == "pretrain":
         enc = preset(m.preset, labels=None, **{"mask_ratio": 0.75, **m.overrides})
         dec = DecoderConfig(
@@ -156,18 +168,52 @@ def build_model(cfg: TrainConfig):
     return model, enc, classify_flops_per_image(enc)
 
 
+def _mode_inputs(cfg: TrainConfig) -> tuple[str, ...]:
+    return MODES[STEP_MODE[cfg.run.mode]].inputs
+
+
+def _token_row(cfg: TrainConfig) -> int:
+    """Ids a batch row holds in mode ``lm``: the trained tokens, the next
+    one, and one more per multi-token-prediction module."""
+    return cfg.data.seq_len + 1 + MlaMoeConfig(**cfg.model.lm).mtp_layers
+
+
+def _zero_batch(cfg: TrainConfig, rows: int) -> dict:
+    """An all-zero batch of the mode's input leaves."""
+    size = cfg.data.image_size
+    leaves = {
+        "images": lambda: np.zeros((rows, size, size, 3), np.uint8),
+        "labels": lambda: np.zeros((rows,), np.int32),
+        "tokens": lambda: np.zeros((rows, _token_row(cfg)), np.int32),
+    }
+    return {name: leaves[name]() for name in _mode_inputs(cfg)}
+
+
 def _example_batch(cfg: TrainConfig, per_process: int) -> dict:
-    shape = (per_process, cfg.data.image_size, cfg.data.image_size, 3)
-    batch = {"images": np.zeros(shape, np.uint8)}
-    if cfg.run.mode != "pretrain":
-        batch["labels"] = np.zeros((per_process,), np.int32)
-    return split_for_accum(batch, cfg.run.grad_accum)
+    return split_for_accum(_zero_batch(cfg, per_process), cfg.run.grad_accum)
 
 
 def _strip_for_model(cfg: TrainConfig, batch: dict) -> dict:
-    if cfg.run.mode == "pretrain":
-        return {"images": batch["images"]}
-    return {k: batch[k] for k in ("images", "labels") if k in batch}
+    return {k: batch[k] for k in _mode_inputs(cfg) if k in batch}
+
+
+def _synthetic(cfg: TrainConfig, per_process: int, num_labels: int, *, seed: int,
+               grad_accum: int = 1):
+    """The mode's seeded synthetic batches."""
+    if cfg.run.mode == "lm":
+        return token_batches(
+            per_process, _token_row(cfg), vocab_rows=MlaMoeConfig(**cfg.model.lm).rows,
+            grad_accum=grad_accum, seed=seed,
+        )
+    return synthetic_batches(
+        per_process,
+        cfg.data.image_size,
+        # the MODEL's class count — labels >= cfg.labels one-hot to
+        # all-zero rows, silently zeroing CE loss and pinning acc at 1
+        labels=num_labels if "labels" in _mode_inputs(cfg) else None,
+        grad_accum=grad_accum,
+        seed=seed,
+    )
 
 
 def make_train_iterator(
@@ -227,16 +273,12 @@ def make_train_iterator(
     cursor_log: dict[int, dict] = {}
     shard_log: dict[int, dict] = {}
     if cfg.run.synthetic_data:
-        it = synthetic_batches(
-            per_process,
-            cfg.data.image_size,
-            # the MODEL's class count — labels >= cfg.labels one-hot to
-            # all-zero rows, silently zeroing CE loss and pinning acc at 1
-            labels=num_labels if cfg.run.mode != "pretrain" else None,
-            grad_accum=cfg.run.grad_accum,
-            seed=cfg.run.seed,
-        )
+        it = _synthetic(cfg, per_process, num_labels, seed=cfg.run.seed,
+                        grad_accum=cfg.run.grad_accum)
         source = None
+    elif cfg.run.mode == "lm":
+        raise ValueError("run.mode=lm reads run.synthetic_data only: there is "
+                         "no token loader yet")
     else:
         data_cursor = _pick_process_cursor(data_cursor)
         loader_kwargs = dict(
@@ -293,12 +335,7 @@ def make_valid_iterator(
     sharding = batch_sharding(mesh, accum=False)
     if cfg.run.synthetic_data:
         def gen():
-            it = synthetic_batches(
-                per_process,
-                cfg.data.image_size,
-                labels=num_labels if cfg.run.mode != "pretrain" else None,
-                seed=cfg.run.seed + 1,
-            )
+            it = _synthetic(cfg, per_process, num_labels, seed=cfg.run.seed + 1)
             for _, batch in zip(range(4), it):
                 batch["valid"] = np.ones((per_process,), bool)
                 yield batch
@@ -745,7 +782,7 @@ def train(cfg: TrainConfig) -> dict:
         tx,
         example,
         mesh,
-        mode="pretrain" if run.mode == "pretrain" else "classify",
+        mode=STEP_MODE[run.mode],
         init_seed=run.init_seed,
         rng_seed=run.seed,
         param_dtype=cfg.optim.param_dtype,
@@ -821,7 +858,7 @@ def train(cfg: TrainConfig) -> dict:
             ledger.add("ckpt_restore", ckpt.last_restore_s or 0.0)
         print(f"[train] resumed from step {start_step}")
 
-    mode_key = "pretrain" if run.mode == "pretrain" else "classify"
+    mode_key = STEP_MODE[run.mode]
     # mesh.pipe_decoder additionally depth-shards the MAE decoder stack
     # (pretrain only; mesh.pipe must divide dec_layers)
     dec_cfg = model.decoder_cfg if cfg.mesh.pipe_decoder else None
@@ -886,18 +923,16 @@ def train(cfg: TrainConfig) -> dict:
         wandb_id=run.wandb_id,
     )
     valid_factory = make_valid_iterator(
-        cfg, mesh, per_process_valid, num_labels=enc_cfg.labels or 1000
+        cfg, mesh, per_process_valid, num_labels=getattr(enc_cfg, "labels", None) or 1000
     )
     # all-padding eval batch, pre-sharded by EVERY process at setup so
     # exhausted hosts can keep stepping the collective eval program
     pad_batch = None
     if valid_factory is not None and process_count > 1:
-        size = cfg.data.image_size
-        host_pad = {
-            "images": np.zeros((per_process_valid, size, size, 3), np.uint8),
-            "labels": np.full((per_process_valid,), -1, np.int32),
-            "valid": np.zeros((per_process_valid,), bool),
-        }
+        host_pad = _zero_batch(cfg, per_process_valid)
+        host_pad["valid"] = np.zeros((per_process_valid,), bool)
+        if "labels" in host_pad:
+            host_pad["labels"] -= 1
         pad_batch = next(
             prefetch_to_device(iter([host_pad]), batch_sharding(mesh, accum=False))
         )
@@ -1099,7 +1134,7 @@ def train(cfg: TrainConfig) -> dict:
 
     train_iter, source, cursor_log, shard_log = make_train_iterator(
         cfg, mesh, per_process, start_step, data_cursor,
-        num_labels=enc_cfg.labels or 1000,
+        num_labels=getattr(enc_cfg, "labels", None) or 1000,
         shard_override=shard_override,
         shard_preconsumed=shard_preconsumed,
     )
@@ -1149,6 +1184,12 @@ def train(cfg: TrainConfig) -> dict:
         "train_grad_norm", "global gradient norm of the last fetched step"
     )
     c_steps = reg.counter("train_steps_total", "optimizer steps this process")
+    g_moe = reg.gauge(
+        "train_moe",
+        "expert-layer counters of the last fetched step (mode lm): rows per "
+        "held expert, imbalance, held share, dropped, per layer and overall",
+        labels=("counter",),
+    )
     g_hfu = reg.gauge(
         "train_hardware_flops_utilization",
         "XLA-counted flops (remat recompute included) / peak (log-window)",
@@ -1328,6 +1369,9 @@ def train(cfg: TrainConfig) -> dict:
             gn = m.get("grad_norm")
             if gn is not None:
                 g_grad_norm.set(float(gn))
+            for key, value in m.items():
+                if key.startswith("moe_"):
+                    g_moe.labels(key[len("moe_"):]).set(float(value))
             if flightrec is not None:
                 entry = {"loss": loss_v}
                 if gn is not None:
@@ -1385,6 +1429,10 @@ def train(cfg: TrainConfig) -> dict:
                 "perf/images_per_sec": imgs,
                 "perf/images_per_sec_per_chip": imgs / n_chips,
             }
+            if run.mode == "lm":
+                summary["perf/tokens_per_sec_per_chip"] = (
+                    imgs / n_chips * cfg.data.seq_len
+                )
             g_ips.set(imgs)
             if peak_tflops is not None:
                 rep = mfu_report(
@@ -1608,7 +1656,7 @@ def train(cfg: TrainConfig) -> dict:
             train_iter, source, cursor_log, shard_log = make_train_iterator(
                 cfg, mesh, per_process, new_step,
                 rb_cursor,
-                num_labels=enc_cfg.labels or 1000,
+                num_labels=getattr(enc_cfg, "labels", None) or 1000,
                 shard_override=rb_override,
                 shard_preconsumed=rb_preconsumed,
             )

@@ -24,11 +24,12 @@ import yaml
 from jumbo_mae_tpu_tpu.data.loader import DataConfig
 from jumbo_mae_tpu_tpu.parallel.mesh import MeshConfig
 from jumbo_mae_tpu_tpu.train.checkpoint import CheckpointConfig
+from jumbo_mae_tpu_tpu.train.modes import MODES, STEP_MODE
 from jumbo_mae_tpu_tpu.train.optim import OptimConfig
 
 IMAGENET_TRAIN_SIZE = 1_281_167
 
-Mode = Literal["pretrain", "finetune", "linear"]
+Mode = Literal["pretrain", "finetune", "linear", "lm"]
 
 
 @dataclass(frozen=True)
@@ -49,6 +50,9 @@ class ModelConfig:
     dec_dtype: str = "bfloat16"
     dec_overrides: dict[str, Any] = field(default_factory=dict)
     norm_pix_loss: bool = True
+    # mode lm: fields of models/lm.MlaMoeConfig (preset and the fields
+    # above are the vision models' and are not read)
+    lm: dict[str, Any] = field(default_factory=dict)
     # classifier head (finetune/linear only)
     mixup: float = 0.0
     cutmix: float = 0.0
@@ -206,11 +210,11 @@ class TrainConfig:
     mesh: MeshConfig = field(default_factory=MeshConfig)
 
     def checkpoint_config(self) -> CheckpointConfig:
-        best_by_loss = self.run.mode == "pretrain"
+        spec = MODES[STEP_MODE[self.run.mode]]
         return CheckpointConfig(
             directory=str(Path(self.run.output_dir) / self.run.name / "ckpt"),
-            best_mode="min" if best_by_loss else "max",
-            metric_key="val/loss" if best_by_loss else "val/acc1",
+            best_mode=spec.best_mode,
+            metric_key=spec.best_metric,
         )
 
 

@@ -55,6 +55,9 @@ class DataConfig:
     train_shards: str | list[str] = ""
     valid_shards: str | list[str] = ""
     image_size: int = 224
+    # mode lm: tokens a sequence is trained on (a batch row holds seq_len + 1
+    # + the model's multi-token-prediction depth ids)
+    seq_len: int = 0
     labeled: bool = True
     crop_mode: str = "rrc"  # rrc | src | none
     min_scale: float = 0.2

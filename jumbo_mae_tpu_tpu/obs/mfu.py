@@ -114,6 +114,44 @@ def classify_flops_per_image(enc_cfg, *, training: bool = True) -> float:
     return fwd * (3.0 if training else 1.0)
 
 
+def lm_flops_per_token(cfg, seq: int, *, training: bool = True) -> float:
+    """Matmul FLOPs one token of the latent-attention sparse-expert language
+    model (``models/lm.MlaMoeConfig``) requires at sequence length ``seq``,
+    on this chip's share: the experts and vocabulary rows held. 2·m·n·k per
+    matmul; the causal core counts its lower triangle once (mean context
+    ``seq / 2``); a routed expert is counted for the share of (token, expert)
+    pairs expected here (``k · held / experts``); the embedding is a lookup;
+    backward = 2 x forward, recomputation not counted."""
+    d, h = cfg.dim, cfg.heads
+    qk, dv = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim
+    latent = 2 * (
+        d * cfg.q_lora_rank
+        + cfg.q_lora_rank * h * qk
+        + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+        + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + dv)
+        + h * dv * d
+    )
+    core = 2 * (seq / 2) * h * (qk + dv)
+    gated = lambda hidden: 2 * 3 * d * hidden
+    pairs_here = cfg.experts_per_token * cfg.held[1] / cfg.n_routed_experts
+    sparse = (
+        2 * d * cfg.n_routed_experts  # router
+        + gated(cfg.n_shared_experts * cfg.expert_hidden)
+        + pairs_here * gated(cfg.expert_hidden)
+    )
+    dense_layers = min(cfg.first_k_dense, cfg.layers)
+    sparse_layers = cfg.layers - dense_layers + cfg.mtp_layers
+    head = 2 * d * cfg.rows[1]
+    fwd = (
+        (cfg.layers + cfg.mtp_layers) * (latent + core)
+        + dense_layers * gated(cfg.dense_hidden)
+        + sparse_layers * sparse
+        + (1 + cfg.mtp_layers) * head
+        + cfg.mtp_layers * 2 * (2 * d) * d  # W_eh
+    )
+    return fwd * (3.0 if training else 1.0)
+
+
 def detect_peak_tflops() -> float | None:
     """Peak bf16 TFLOP/s of the current backend's first device, or None on
     the CPU backend — a CPU has no table entry, and a rate against a made-up

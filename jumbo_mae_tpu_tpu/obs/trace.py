@@ -52,6 +52,18 @@ SCOPE_MASK = "mask"  # random masking, its gathers, the unshuffle
 SCOPE_PATCHIFY = "patchify"  # images -> target patches
 SCOPE_LOSS = "loss"  # pixel normalisation + masked MSE
 SCOPE_ATTN_CORE = "attn_core"  # scores, softmax, weighted sum (any implementation)
+# ... and in the latent-attention sparse-expert language model (models/lm.py)
+SCOPE_EMBED = "embed"  # token embedding lookup over the vocabulary rows held
+SCOPE_MLA_LATENT = "mla_latent"  # the four latent q/kv projections and their norms
+SCOPE_ROPE = "rope"  # rotary embedding of the rope columns of q and of the shared k
+SCOPE_ATTN_OUT = "attn_out"  # the output projection over (heads, v width)
+SCOPE_ROUTER = "router"  # float32 scores, top-k, weights, counts, the bias rule
+SCOPE_MOE_DISPATCH = "moe_dispatch"  # sort/gather in, scatter/combine out
+SCOPE_EXPERTS = "experts"  # grouped products over the experts held, and their SwiGLU
+SCOPE_SHARED_EXPERT = "shared_expert"  # the expert every token passes
+SCOPE_DENSE_MLP = "dense_mlp"  # the SwiGLU MLP of a leading dense layer
+SCOPE_MTP_MERGE = "mtp_merge"  # multi-token prediction: norms, concatenation, W_eh
+SCOPE_LM_HEAD = "lm_head"  # final norm, logits over the rows held, cross-entropy
 
 
 def _span_hist(name: str, registry):

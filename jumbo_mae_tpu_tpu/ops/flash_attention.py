@@ -55,3 +55,26 @@ def flash_attention(
     from jumbo_mae_tpu_tpu.ops.pallas.attention import pallas_flash_attention
 
     return pallas_flash_attention(q, k, v, block_q, block_k)
+
+
+def xla_causal_attention(q_a, q_b, k_a, k_b, v) -> jax.Array:
+    """The einsum form of :func:`causal_attention`: the (seq, seq) scores
+    exist, so it is for the CPU's tests and short sequences only."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q_a, k_a, preferred_element_type=jnp.float32)
+    s = s + jnp.einsum("bhqd,bkd->bhqk", q_b, k_b, preferred_element_type=jnp.float32)
+    keep = jnp.tril(jnp.ones(s.shape[-2:], bool))
+    probs = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+def causal_attention(q_a, q_b, k_a, k_b, v, *, impl: str) -> jax.Array:
+    """Causal softmax(q_a·k_aᵀ + q_b·k_bᵀ)·v, head-major: ``q_a``/``k_a``
+    (batch, heads, seq, d_a), ``q_b`` (batch, heads, seq, d_b), ``k_b``
+    (batch, seq, d_b) shared by all heads, ``v`` (batch, heads, seq, d_v);
+    queries pre-scaled. ``impl`` is ``"flash"`` (the Pallas kernels, scores
+    never materialised) or ``"einsum"``, as ``resolve_attn_impl`` resolved it."""
+    if impl == "flash":
+        from jumbo_mae_tpu_tpu.ops.pallas.attention import pallas_causal_attention
+
+        return pallas_causal_attention(q_a, q_b, k_a, k_b, v)
+    return xla_causal_attention(q_a, q_b, k_a, k_b, v)

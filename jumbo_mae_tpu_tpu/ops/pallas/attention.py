@@ -37,6 +37,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 # Minor-dim width for the per-row scalar residuals (lse, D). 8 (one f32
@@ -431,3 +432,304 @@ def _vjp_lse_bwd(block_q, block_k, interpret, residuals, gs):
 
 
 pallas_flash_attention_with_lse.defvjp(_vjp_lse_fwd, _vjp_lse_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Causal form, with query/key and value head widths that differ.
+#
+# The score of a (query, key) pair is the sum of two products: a per-head
+# part (``q_a·k_aᵀ``, width ``d_a``) and a part whose key is one vector a
+# token shared by every head (``q_b·k_bᵀ``, width ``d_b``; multi-head latent
+# attention's rotary columns). ``k_b`` is never replicated per head: its
+# BlockSpec ignores the head index. The value width ``d_v`` is independent.
+#
+# Layout is head-major, (batch, heads, seq, width): the projections that
+# feed the kernel write it directly, so there is no fold/transposition here.
+#
+# Causality is in the grid, not in a mask applied after the fact: the third
+# grid axis walks only the (query block, key block) pairs on or below the
+# diagonal, in an order two scalar-prefetched tables give, so a block above
+# the diagonal costs neither a DMA nor a grid step. Only the diagonal blocks
+# mask, with a local iota. Running maximum, denominator and the output
+# accumulator live in VMEM scratch across the key blocks of a query block;
+# scores never leave VMEM.
+#
+# A sequence that is no multiple of the block is padded with zero rows: a
+# pad key lies after every real query, so causality already hides it, and a
+# pad query's output is sliced off, so its cotangent is zero and every
+# backward contribution from it vanishes.
+# ---------------------------------------------------------------------------
+
+
+# what one causal kernel may hold in VMEM: a 1024-block's float32 scores,
+# probabilities and their bf16 copies pass the 16 MiB default
+CAUSAL_VMEM_BYTES = 64 * 1024 * 1024
+CAUSAL_BLOCK = 1024
+
+
+def _lower_triangle(n: int, *, by_key: bool):
+    """The block pairs on or below the diagonal as two int32 tables.
+    ``by_key=False``: query block outer, key blocks 0..i inner (the diagonal
+    comes last). ``by_key=True``: key block outer, query blocks j..n-1 inner
+    (the diagonal comes first)."""
+    import numpy as np
+
+    if by_key:
+        pairs = [(i, j) for j in range(n) for i in range(j, n)]
+    else:
+        pairs = [(i, j) for i in range(n) for j in range(i + 1)]
+    qi, kj = zip(*pairs)
+    return np.asarray(qi, np.int32), np.asarray(kj, np.int32)
+
+
+def _scores(qa, qb, ka, kb, diagonal: bool):
+    """(block, block) float32 scores of one block pair; on a diagonal block
+    the entries above the diagonal are −inf."""
+    dims = (((1,), (1,)), ((), ()))
+    s = jax.lax.dot_general(qa, ka, dims, preferred_element_type=jnp.float32)
+    s = s + jax.lax.dot_general(qb, kb, dims, preferred_element_type=jnp.float32)
+    if diagonal:
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(row >= col, s, NEG_INF)
+    return s
+
+
+def _causal_fwd_kernel(qi_ref, kj_ref, qa_ref, qb_ref, ka_ref, kb_ref, v_ref,
+                       o_ref, lse_ref, m_sc, l_sc, acc_sc):
+    t = pl.program_id(2)
+    i, j = qi_ref[t], kj_ref[t]
+    mm = _mm_dtype(qa_ref)
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    def step(diagonal: bool):
+        s = _scores(qa_ref[...].astype(mm), qb_ref[...].astype(mm),
+                    ka_ref[...].astype(mm), kb_ref[...].astype(mm), diagonal)
+        m_prev = m_sc[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_sc[...] = alpha * l_sc[...] + p.sum(axis=-1, keepdims=True)
+        acc_sc[...] = alpha * acc_sc[...] + jax.lax.dot_general(
+            p.astype(mm), v_ref[...].astype(mm), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_sc[...] = m_new
+
+    @pl.when(j < i)
+    def _():
+        step(False)
+
+    @pl.when(j == i)  # the diagonal is the last key block of a query block
+    def _():
+        step(True)
+        l = l_sc[...]
+        o_ref[...] = (acc_sc[...] / l).astype(o_ref.dtype)
+        lse_ref[...] = jnp.broadcast_to(m_sc[...] + jnp.log(l), lse_ref.shape)
+
+
+def _causal_dq_kernel(qi_ref, kj_ref, qa_ref, qb_ref, ka_ref, kb_ref, v_ref,
+                      do_ref, lse_ref, dd_ref, dqa_ref, dqb_ref, dqa_sc, dqb_sc):
+    t = pl.program_id(2)
+    i, j = qi_ref[t], kj_ref[t]
+    mm = _mm_dtype(qa_ref)
+
+    @pl.when(j == 0)
+    def _():
+        dqa_sc[...] = jnp.zeros(dqa_sc.shape, jnp.float32)
+        dqb_sc[...] = jnp.zeros(dqb_sc.shape, jnp.float32)
+
+    def step(diagonal: bool):
+        ka, kb = ka_ref[...].astype(mm), kb_ref[...].astype(mm)
+        s = _scores(qa_ref[...].astype(mm), qb_ref[...].astype(mm), ka, kb, diagonal)
+        p = jnp.exp(s - lse_ref[...][:, :1])
+        dp = jax.lax.dot_general(
+            do_ref[...].astype(mm), v_ref[...].astype(mm), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (p * (dp - dd_ref[...][:, :1])).astype(mm)
+        dims = (((1,), (0,)), ((), ()))
+        dqa_sc[...] += jax.lax.dot_general(ds, ka, dims, preferred_element_type=jnp.float32)
+        dqb_sc[...] += jax.lax.dot_general(ds, kb, dims, preferred_element_type=jnp.float32)
+
+    @pl.when(j < i)
+    def _():
+        step(False)
+
+    @pl.when(j == i)
+    def _():
+        step(True)
+        dqa_ref[...] = dqa_sc[...].astype(dqa_ref.dtype)
+        dqb_ref[...] = dqb_sc[...].astype(dqb_ref.dtype)
+
+
+def _causal_dkv_kernel(qi_ref, kj_ref, qa_ref, qb_ref, ka_ref, kb_ref, v_ref,
+                       do_ref, lse_ref, dd_ref, dka_ref, dkb_ref, dv_ref,
+                       dka_sc, dkb_sc, dv_sc, *, last: int):
+    t = pl.program_id(2)
+    i, j = qi_ref[t], kj_ref[t]
+    mm = _mm_dtype(qa_ref)
+
+    def step(diagonal: bool):
+        qa, qb = qa_ref[...].astype(mm), qb_ref[...].astype(mm)
+        do = do_ref[...].astype(mm)
+        s = _scores(qa, qb, ka_ref[...].astype(mm), kb_ref[...].astype(mm), diagonal)
+        p = jnp.exp(s - lse_ref[...][:, :1])
+        dp = jax.lax.dot_general(
+            do, v_ref[...].astype(mm), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (p * (dp - dd_ref[...][:, :1])).astype(mm)
+        dims = (((0,), (0,)), ((), ()))
+        dv_sc[...] += jax.lax.dot_general(p.astype(mm), do, dims,
+                                          preferred_element_type=jnp.float32)
+        dka_sc[...] += jax.lax.dot_general(ds, qa, dims, preferred_element_type=jnp.float32)
+        dkb_sc[...] += jax.lax.dot_general(ds, qb, dims, preferred_element_type=jnp.float32)
+
+    @pl.when(i == j)  # the diagonal is the first query block of a key block
+    def _():
+        dka_sc[...] = jnp.zeros(dka_sc.shape, jnp.float32)
+        dkb_sc[...] = jnp.zeros(dkb_sc.shape, jnp.float32)
+        dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
+        step(True)
+
+    @pl.when(i > j)
+    def _():
+        step(False)
+
+    @pl.when(i == last)
+    def _():
+        dka_ref[...] = dka_sc[...].astype(dka_ref.dtype)
+        dkb_ref[...] = dkb_sc[...].astype(dkb_ref.dtype)
+        dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
+
+
+def _causal_plan(seq: int, block: int) -> tuple[int, int]:
+    """(padded length, block): the block is clamped to the 128-padded length
+    and the sequence padded up to a multiple of it. Sub-128 blocks are for
+    the interpreter's tests."""
+    block = min(block, _round_up(seq, 128)) if block >= 128 else block
+    return _round_up(seq, block), block
+
+
+def _pad_rows(x, to: int):
+    pad = to - x.shape[-2]
+    if not pad:
+        return x
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 2) + ((0, pad), (0, 0)))
+
+
+def _causal_call(kernel, tables, operands, out_widths, scratch, *, dtype, b, h,
+                 s_pad, block, by_key, interpret, name):
+    """One ``pallas_call`` over (batch, heads, lower-triangle block pairs).
+    ``operands`` are ``(array, kind)`` with kind ``"q"`` (blocked by the
+    query index), ``"k"`` (by the key index) or ``"k_shared"`` (by the key
+    index, no head axis); ``out_widths`` are ``(width, dtype)`` of outputs
+    blocked by the outer index."""
+    def spec(width, kind):
+        if kind == "k_shared":
+            return pl.BlockSpec((None, block, width), lambda bi, hi, t, qi, kj: (bi, kj[t], 0))
+        if kind == "k":
+            return pl.BlockSpec((None, None, block, width),
+                                lambda bi, hi, t, qi, kj: (bi, hi, kj[t], 0))
+        return pl.BlockSpec((None, None, block, width),
+                            lambda bi, hi, t, qi, kj: (bi, hi, qi[t], 0))
+
+    out_kind = "k" if by_key else "q"
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, h, len(tables[0])),
+            in_specs=[spec(x.shape[-1], kind) for x, kind in operands],
+            out_specs=[spec(w, out_kind) for w, _ in out_widths],
+            scratch_shapes=scratch,
+        ),
+        out_shape=[jax.ShapeDtypeStruct((b, h, s_pad, w), dt or dtype) for w, dt in out_widths],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=CAUSAL_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name=name,
+    )(*tables, *(x for x, _ in operands))
+
+
+def _causal_fwd(qa, qb, ka, kb, v, block, interpret):
+    b, h, s, _ = qa.shape
+    s_pad, block = _causal_plan(s, block)
+    qa, qb, ka, kb, v = (_pad_rows(x, s_pad) for x in (qa, qb, ka, kb, v))
+    d_v = v.shape[-1]
+    o, lse = _causal_call(
+        _causal_fwd_kernel, _lower_triangle(s_pad // block, by_key=False),
+        [(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k")],
+        [(d_v, None), (LANE, jnp.float32)],
+        [pltpu.VMEM((block, 1), jnp.float32), pltpu.VMEM((block, 1), jnp.float32),
+         pltpu.VMEM((block, d_v), jnp.float32)],
+        dtype=qa.dtype, b=b, h=h, s_pad=s_pad, block=block, by_key=False,
+        interpret=interpret, name="causal_attention_fwd",
+    )
+    return o[:, :, :s], lse
+
+
+def _causal_bwd(qa, qb, ka, kb, v, o, lse, g, block, interpret):
+    b, h, s, d_a = qa.shape
+    d_b, d_v = qb.shape[-1], v.shape[-1]
+    s_pad, block = _causal_plan(s, block)
+    qa, qb, ka, kb, v, o, g = (_pad_rows(x, s_pad) for x in (qa, qb, ka, kb, v, o, g))
+    # D = rowsum(dO ∘ O), as for the non-causal kernels: tiny, elementwise
+    dd = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1, keepdims=True)
+    dd = jnp.broadcast_to(dd, (b, h, s_pad, LANE))
+    n = s_pad // block
+    operands = [(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k"),
+                (g, "q"), (lse, "q"), (dd, "q")]
+    common = dict(dtype=qa.dtype, b=b, h=h, s_pad=s_pad, block=block, interpret=interpret)
+    f32 = lambda w: pltpu.VMEM((block, w), jnp.float32)
+    dqa, dqb = _causal_call(
+        _causal_dq_kernel, _lower_triangle(n, by_key=False), operands,
+        [(d_a, None), (d_b, None)], [f32(d_a), f32(d_b)],
+        by_key=False, name="causal_attention_dq", **common,
+    )
+    # the shared key part's gradient comes out per head and is summed outside
+    dka, dkb, dv = _causal_call(
+        functools.partial(_causal_dkv_kernel, last=n - 1),
+        _lower_triangle(n, by_key=True), operands,
+        [(d_a, None), (d_b, jnp.float32), (d_v, None)], [f32(d_a), f32(d_b), f32(d_v)],
+        by_key=True, name="causal_attention_dkv", **common,
+    )
+    dkb = dkb.sum(axis=1).astype(kb.dtype)
+    return tuple(x[..., :s, :] for x in (dqa, dqb, dka, dkb, dv))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def pallas_causal_attention(
+    q_a: jax.Array,
+    q_b: jax.Array,
+    k_a: jax.Array,
+    k_b: jax.Array,
+    v: jax.Array,
+    block: int = CAUSAL_BLOCK,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal softmax(q_a·k_aᵀ + q_b·k_bᵀ)·v; queries pre-scaled.
+
+    ``q_a``, ``k_a``: (batch, heads, seq, d_a); ``q_b``: (batch, heads, seq,
+    d_b); ``k_b``: (batch, seq, d_b), one vector a token for all heads;
+    ``v``: (batch, heads, seq, d_v). Returns (batch, heads, seq, d_v).
+    Forward and backward are Pallas kernels over the lower triangle of
+    block pairs (see the section comment above)."""
+    return _causal_fwd(q_a, q_b, k_a, k_b, v, block, interpret)[0]
+
+
+def _causal_vjp_fwd(q_a, q_b, k_a, k_b, v, block, interpret):
+    o, lse = _causal_fwd(q_a, q_b, k_a, k_b, v, block, interpret)
+    return o, (q_a, q_b, k_a, k_b, v, o, lse)
+
+
+def _causal_vjp_bwd(block, interpret, residuals, g):
+    return _causal_bwd(*residuals, g, block, interpret)
+
+
+pallas_causal_attention.defvjp(_causal_vjp_fwd, _causal_vjp_bwd)

@@ -27,7 +27,7 @@ what makes ViT-H-scale FSDP init feasible on small hosts.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Callable, Literal
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -53,14 +53,13 @@ from jumbo_mae_tpu_tpu.parallel.sharding import (
     batch_sharding,
     infer_state_sharding,
 )
+from jumbo_mae_tpu_tpu.train.modes import MODES, StepMode, model_inputs
 from jumbo_mae_tpu_tpu.train.state import (
     EVAL_DOMAIN,
     STREAMS,
     TrainState,
     make_base_rng,
 )
-
-Mode = Literal["pretrain", "classify"]
 
 # Folded into the "dropout" stream before it enters the gpipe key
 # derivation ("pipe" in ASCII) — keeps pipeline keys out of any integer
@@ -84,19 +83,13 @@ def _batch_key(batch: dict) -> tuple:
     )
 
 
-def _model_inputs(mode: Mode, batch: dict) -> tuple:
-    if mode == "pretrain":
-        return (batch["images"],)
-    return (batch["images"], batch["labels"])
-
-
 def create_sharded_state(
     module,
     tx: optax.GradientTransformation,
     example_batch: dict,
     mesh: Mesh,
     *,
-    mode: Mode,
+    mode: StepMode,
     init_seed: int = 0,
     rng_seed: int = 0,
     min_shard_size: int = 2**16,
@@ -111,7 +104,7 @@ def create_sharded_state(
     half weight-read HBM traffic); pair it with ``optim.param_dtype`` so the
     optimizer keeps a float32 master copy (``with_master_weights``).
     """
-    inputs = _model_inputs(mode, example_batch)
+    inputs = model_inputs(mode, example_batch)
     init_rngs = {
         "params": jax.random.key(init_seed),
         **{
@@ -149,7 +142,7 @@ def make_train_step(
     mesh: Mesh,
     state_sharding: Any,
     *,
-    mode: Mode,
+    mode: StepMode,
     grad_accum: int = 1,
     pipe_microbatches: int = 0,
     encoder_cfg: Any = None,
@@ -261,7 +254,7 @@ def make_train_step(
             variables["batch_stats"] = batch_stats
             out, updated = state.apply_fn(
                 variables,
-                *_model_inputs(mode, batch),
+                *model_inputs(mode, batch),
                 deterministic=False,
                 rngs=rngs,
                 mutable=["batch_stats"],
@@ -271,7 +264,7 @@ def make_train_step(
         else:
             out = state.apply_fn(
                 variables,
-                *_model_inputs(mode, batch),
+                *model_inputs(mode, batch),
                 deterministic=False,
                 rngs=rngs,
                 **extra,
@@ -431,7 +424,7 @@ def make_train_step(
 
 
 def make_eval_step(
-    mesh: Mesh, state_sharding: Any, *, mode: Mode
+    mesh: Mesh, state_sharding: Any, *, mode: StepMode
 ) -> Callable[[TrainState, dict], dict]:
     """Jitted eval step returning SUMS over valid samples + the valid count;
     the host-side loop divides at the end (exact weighted mean even with
@@ -449,23 +442,16 @@ def make_eval_step(
         variables = {"params": state.params}
         if state.batch_stats is not None:
             variables["batch_stats"] = state.batch_stats
+        inputs = {name: batch[name] for name in MODES[mode].inputs}
         valid = batch.get("valid")
         if valid is None:
-            valid = jnp.ones(batch["images"].shape[0], jnp.float32)
+            valid = jnp.ones(next(iter(inputs.values())).shape[0], jnp.float32)
         else:
             valid = valid.astype(jnp.float32)
-
-        if mode == "pretrain":
-            out = state.apply_fn(
-                variables, batch["images"], deterministic=True, rngs=rngs
-            )
-            per_sample = {"loss": out["loss_per_sample"]}
-        else:
-            labels = jnp.where(batch["labels"] >= 0, batch["labels"], 0)
-            out = state.apply_fn(
-                variables, batch["images"], labels, deterministic=True
-            )
-            per_sample = {k: out[k] for k in ("loss", "acc1", "acc5")}
+        if "labels" in inputs:  # padding rows carry label -1
+            inputs["labels"] = jnp.where(inputs["labels"] >= 0, inputs["labels"], 0)
+        out = state.apply_fn(variables, *inputs.values(), deterministic=True, rngs=rngs)
+        per_sample = {k: out[name] for k, name in MODES[mode].eval_outputs.items()}
 
         sums = {k: jnp.sum(v * valid) for k, v in per_sample.items()}
         sums["num_samples"] = valid.sum()

@@ -172,6 +172,8 @@ GUARD_CASES = {
     "classify_batchnorm": {"mode": "classify"},
     # leaves sharded over fsdp=4: finite is replicated, each device selects its shards
     "fsdp_mesh": {"mesh": {"data": 2, "fsdp": 4}},
+    # the language model: the router biases ride in batch_stats and are gated too
+    "lm": {"mode": "lm"},
 }
 
 
@@ -198,7 +200,18 @@ def _tiny_build(*, mode="pretrain", optim=None, grad_accum=1, mesh=None):
     )
     rng = np.random.RandomState(0)
     batch = {"images": rng.randint(0, 256, (16, 32, 32, 3)).astype(np.uint8)}
-    if mode == "pretrain":
+    if mode == "lm":
+        from jumbo_mae_tpu_tpu.models.lm import MlaMoeConfig, MlaMoeLM
+
+        lm = MlaMoeConfig(
+            vocab_size=256, vocab_rows=(64, 64), dim=32, layers=2, heads=2,
+            q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, dense_hidden=64, expert_hidden=16, n_routed_experts=16,
+            experts_held=(4, 4), experts_per_token=4, dtype="float32",
+        )
+        module = MlaMoeLM(lm)
+        batch = {"tokens": rng.randint(64, 128, (16, 14)).astype(np.int32)}
+    elif mode == "pretrain":
         module = MAEPretrainModel(
             enc, DecoderConfig(layers=1, dim=32, heads=2, dtype="float32")
         )

@@ -92,6 +92,75 @@ def test_flash_kernels_compile_for_v5e(v5e_chip, shape, variant):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _causal_programs():
+    from jumbo_mae_tpu_tpu.ops.pallas.attention import pallas_causal_attention
+
+    def loss(*xs):
+        return pallas_causal_attention(*xs).astype(jnp.float32).sum()
+
+    return {"fwd": pallas_causal_attention, "fwd_bwd": jax.grad(loss, argnums=(0, 1, 2, 3, 4))}
+
+
+# (batch, heads, seq): the language model's published head (qk 128 + 64 with
+# the 64 rope columns of k shared by all heads, v 128) at the cell's 8192
+# tokens and at a length that is no multiple of the block
+@pytest.mark.parametrize("variant", ["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("shape", [(2, 32, 8192), (1, 8, 2148)])
+def test_causal_kernels_compile_for_v5e(v5e_chip, shape, variant):
+    b, h, s = shape
+    x = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=v5e_chip)
+    args = (x(b, h, s, 128), x(b, h, s, 64), x(b, h, s, 128), x(b, s, 64), x(b, h, s, 128))
+    compiled = jax.jit(_causal_programs()[variant]).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == (1 if variant == "fwd" else 3)
+
+
+def test_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):
+    """The real cut of the shipped recipe (680 M parameters, 2 x 8192 tokens)
+    through the trainer's own step factory, for a described v5e: the flash
+    and grouped-product kernels are in it, the guard adds no ``conditional``,
+    and what the step holds fits the chip with room (15.5 GB)."""
+    from jumbo_mae_tpu_tpu.cli.train import build_model
+    from jumbo_mae_tpu_tpu.config import load_config
+    from jumbo_mae_tpu_tpu.parallel import MeshConfig, create_mesh
+    from jumbo_mae_tpu_tpu.parallel.sharding import batch_sharding, infer_state_sharding
+    from jumbo_mae_tpu_tpu.train import make_optimizer, make_train_step
+    from jumbo_mae_tpu_tpu.train.state import TrainState, make_base_rng
+
+    # jax.default_backend() is the CPU here; the program picks its kernels by
+    # it, so the test answers for the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = load_config(chip_smoke.LM_RECIPE)
+    mesh = create_mesh(MeshConfig(data=1, fsdp=1), devices=list(v5e_chip.device_set))
+    model, lm, _ = build_model(cfg)
+    tx = make_optimizer(cfg.optim, cfg.run.train_batch_size, num_layers=lm.layers)
+    rows, length = cfg.run.train_batch_size, cfg.data.seq_len + 1 + lm.mtp_layers
+
+    def init():
+        v = model.init(jax.random.key(0), jnp.zeros((rows, length), jnp.int32))
+        state = TrainState.create(apply_fn=model.apply, params=v["params"], tx=tx,
+                                  batch_stats=v["batch_stats"], rng=make_base_rng(0))
+        return state.replace(step=jnp.zeros((), jnp.int32))
+
+    shapes = jax.eval_shape(init)  # shapes only: nothing can be put on the chip
+    assert sum(int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(shapes.params)) \
+        == 680_437_760
+    sharding = infer_state_sharding(shapes, mesh)
+    described = jax.tree_util.tree_map(
+        lambda s, d: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=d), shapes, sharding)
+    tokens = jax.ShapeDtypeStruct((rows, length), jnp.int32,
+                                  sharding=batch_sharding(mesh, accum=False))
+    step = make_train_step(mesh, sharding, mode="lm", guard_nonfinite=True)
+    compiled = step.lower(described, {"tokens": tokens}).compile()
+    text = compiled.as_text()
+    assert " conditional(" not in text and "/guard/" in text
+    for kernel in ("causal_attention_fwd", "causal_attention_dq", "causal_attention_dkv", "gmm"):
+        assert kernel in text, kernel
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes)
+    assert 6.8e9 < held < 15.5e9, held
+
+
 # -------------------------------------------- chip_smoke, rehearsed on CPU
 
 
@@ -132,6 +201,48 @@ def test_kernels_phase_rehearsal_interpreted():
     assert got["mosaic_custom_call"] is False  # interpreted: nothing to find
     assert max(got["max_rel_err_vs_xla"].values()) < chip_smoke.KERNEL_REL_TOL
     assert got["onehot_gather_bit_identical"]
+
+
+def test_lm_kernels_phase_rehearsal_interpreted():
+    got = chip_smoke.phase_lm_kernels(
+        causal=((1, 2, 40, 16, 8, 16),), grouped=(64, 32, 24, (41, 0, 9, 6)), interpret=True)
+    assert got["mosaic_custom_call"] is False
+    assert set(got["max_rel_err_vs_xla"]) == {"causal@40x16+8/16", "grouped@64x32x24"}
+    assert max(got["max_rel_err_vs_xla"].values()) < chip_smoke.KERNEL_REL_TOL
+
+
+LM_TOY = [
+    "data.seq_len=16", "run.train_batch_size=8", "run.valid_batch_size=8", "mesh.fsdp=1",
+    "optim.learning_rate=3e-3", "optim.init_lr=3e-3", "optim.warmup_steps=1",
+    *(f"model.lm.{k}={v}" for k, v in dict(
+        vocab_size=512, vocab_rows=[64, 64], dim=32, layers=2, heads=2, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        dense_hidden=64, expert_hidden=16, n_routed_experts=16, experts_held=[4, 4],
+        experts_per_token=4, dtype="float32").items()),
+]
+
+
+def test_lm_train_phase_rehearsal(tmp_path, watch, capsys):
+    """The language-model recipe through ``cli.train`` at toy size: every
+    batch's loss lower on its second visit, nothing dropped, nothing skipped,
+    the counters logged and published."""
+    steps = 10
+    overrides = chip_smoke._lm_overrides(steps) + LM_TOY
+    assert chip_smoke.run_phase(
+        "lm_train",
+        lambda: chip_smoke.phase_lm_train(chip_smoke.LM_RECIPE, overrides, tmp_path, steps=steps),
+        tmp_path, watch,
+    )
+    (line,) = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    checked = line["checked"]
+    assert checked["loss_after_one_cycle"] < checked["loss_first"]
+    assert checked["moe_dropped"] == 0 and checked["skipped_steps"] == 0
+    assert 0.1 < checked["moe_held_share_min_max"][0] <= checked["moe_held_share_min_max"][1] < 0.5
+    assert checked["mfu_trainer_reported"] is None  # a CPU count is not a device rate
+    from jumbo_mae_tpu_tpu.obs.metrics import get_registry
+
+    published = get_registry().snapshot()["train_moe"]
+    assert {"imbalance", "held_share", "dropped", "rows_max_l1", "rows_min_mtp"} <= set(published)
 
 
 def test_train_then_resume_phases_rehearsal(tmp_path, compile_cache, watch, capsys):
@@ -246,7 +357,7 @@ def test_main_exit_code_and_last_line(
 
         return run
 
-    for name in ("kernels", "train", "resume", "serve"):
+    for name in ("kernels", "train", "resume", "serve", "lm_kernels", "lm_train"):
         monkeypatch.setattr(chip_smoke, f"phase_{name}", stub(name))
     rc = chip_smoke.main(["--out", str(tmp_path)])
     out = capsys.readouterr().out
@@ -263,6 +374,7 @@ def test_main_exit_code_and_last_line(
         assert not lines["train"]["passed"]
         assert "skipped" in lines["resume"]["error"]  # nothing to resume from
         assert lines["serve"]["passed"]  # later phases still report
+        assert lines["lm_train"]["passed"]
 
 
 def test_script_refuses_to_pass_on_cpu():
