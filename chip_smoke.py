@@ -386,7 +386,8 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     """``cli.train`` on the language-model recipe for ``steps`` steps of
     seeded tokens, every step's metrics logged: every loss finite; each of
     the cycled batches' loss lower the second time it is seen; nothing
-    dropped by an expert layer; no step skipped by the guard."""
+    dropped by an expert layer, whose held pairs fit one round of its chunk
+    at the recipe's routing; no step skipped by the guard."""
     from jumbo_mae_tpu_tpu.cli import train as cli_train
 
     before = _registry_snapshot()
@@ -401,6 +402,8 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     by_step = {int(r["step"]): r for r in records if "train/moe_dropped" in r}
     check(sorted(by_step) == list(range(1, steps + 1)), "missing expert counters")
     check(all(r["train/moe_dropped"] == 0 for r in by_step.values()), "an expert layer dropped pairs")
+    rounds = sorted({r["train/moe_rounds"] for r in by_step.values()})
+    check(rounds == [1], f"an expert layer's held pairs took other than one round: {rounds}")
     skipped = _delta(before, after, "train_steps_skipped_total", "")
     check(skipped == 0, f"{skipped} step(s) skipped by the divergence guard")
     retraces = _delta(before, after, "retrace_events_total", "train")
@@ -414,6 +417,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         "loss_after_one_cycle": round(losses[1 + cycle], 4),
         "loss_last": round(losses[steps], 4),
         "moe_dropped": 0,
+        "moe_rounds": 1,
         "moe_held_share_min_max": [round(min(share), 4), round(max(share), 4)],
         "moe_imbalance_max": round(max(r["train/moe_imbalance"] for r in by_step.values()), 3),
         "skipped_steps": 0,

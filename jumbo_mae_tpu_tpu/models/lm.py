@@ -28,9 +28,14 @@ exchange. ``vocab_rows = (v0, n)`` likewise: embedding and head hold rows
 logits and loss are over the slice.
 
 The expert layer sorts the (token, expert) pairs so that those on held
-experts come first, expert by expert, and runs grouped matrix products over
-them (``ops/grouped_matmul.py``). The buffer has a row for every pair, so no
-routing, however uneven, drops a token.
+experts come first, expert by expert, and walks the held ones in rounds of a
+fixed chunk of rows (``routed_experts``): a round gathers its rows, runs the
+grouped matrix products over them (``ops/grouped_matmul.py``) and adds their
+gate-weighted outputs to the tokens. The chunk is twice the share of the
+pairs that uniform routing sends to the experts held (``chunk_rows``) and the
+number of rounds follows the routing, so the buffers follow the rows this
+chip holds, and no routing, however uneven, drops a token: it takes more
+rounds.
 
 MTP: ``h'_i = W_eh [RMSNorm(Emb(t_{i+1})) ‖ RMSNorm(h_i)]`` -> one block of
 the expert kind -> the trunk's final norm and head, predicting ``t_{i+2}``.
@@ -40,6 +45,7 @@ Loss = CE(trunk) + ``mtp_loss_weight`` · CE(MTP), mean over tokens.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 
 import flax.linen as nn
@@ -63,10 +69,11 @@ from jumbo_mae_tpu_tpu.obs.trace import (
     SCOPE_SHARED_EXPERT,
 )
 from jumbo_mae_tpu_tpu.ops.flash_attention import causal_attention
-from jumbo_mae_tpu_tpu.ops.grouped_matmul import grouped_matmul
+from jumbo_mae_tpu_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, grouped_outer
 
 # the counters an expert layer reports, in the order of its stats vector
-MOE_COUNTERS = ("rows_min", "rows_mean", "rows_max", "imbalance", "held_share", "dropped")
+MOE_COUNTERS = ("rows_min", "rows_mean", "rows_max", "imbalance", "held_share", "dropped",
+                "rounds")
 
 
 @dataclass(frozen=True)
@@ -223,42 +230,112 @@ class GatedMlp(nn.Module):
         return Proj((self.hidden, d), "...h,hd->...d", self.cfg, name="down")(nn.silu(gate) * up)
 
 
-@jax.custom_vjp
-def _gather_pairs(x, token_of_row, row_of_pair):
-    """``x[token_of_row]``: every (token, slot) pair's copy of its token, in
-    sorted order. The transpose gathers too (no scatter-add): each token's
-    gradient is the sum over its slots' rows, found by ``row_of_pair``."""
-    return x[token_of_row]
+def chunk_rows(pairs: int, held: int, experts: int) -> int:
+    """Rows one round of the expert layer takes: twice the share of the
+    ``pairs`` that uniform routing sends to ``held`` of ``experts``, in whole
+    row tiles; all of them where that is fewer."""
+    return min(pairs, -(-2 * pairs * held // (experts * ROW_TILE)) * ROW_TILE)
 
 
-def _gather_pairs_fwd(x, token_of_row, row_of_pair):
-    return x[token_of_row], (row_of_pair, x.shape[0])
+def _round_rows(r, chunk, row_to_pair, pair_to_row, gate, group_sizes):
+    """Round ``r`` takes the sorted rows ``r chunk .. (r + 1) chunk``. From
+    the rows' side: each row's token and gate weight, and the experts' sizes
+    clipped to the round. From the pairs' side (tokens, slots): the pair's
+    row within the round, and whether it lies there at all."""
+    lo = r * chunk
+    pair = row_to_pair.at[lo + jnp.arange(chunk, dtype=jnp.int32)].get(mode="clip")
+    ends = jnp.cumsum(group_sizes)
+    sizes = jnp.clip(ends, lo, lo + chunk) - jnp.clip(ends - group_sizes, lo, lo + chunk)
+    local = pair_to_row - lo
+    mine = (local >= 0) & (local < chunk)
+    return (pair // gate.shape[1], gate.reshape(-1)[pair], sizes,
+            jnp.clip(local, 0, chunk - 1), mine)
 
 
-def _gather_pairs_bwd(res, g):
-    row_of_pair, tokens = res
-    return g[row_of_pair].reshape(tokens, -1, g.shape[-1]).sum(axis=1), None, None
+def _sum_slots(rows, row_of_pair, weight):
+    """Each token's sum over its slots of ``weight · rows[row_of_pair]``, in
+    float32: (tokens, width). One gather a slot, each of the tokens' height:
+    nothing a row wide is built for the pairs, and nothing is scattered."""
+    return sum(rows[row_of_pair[:, slot]].astype(jnp.float32) * weight[:, slot, None]
+               for slot in range(row_of_pair.shape[1]))
 
 
-_gather_pairs.defvjp(_gather_pairs_fwd, _gather_pairs_bwd)
+def _swiglu(gu):
+    hidden = gu.shape[1] // 2
+    return nn.silu(gu[:, :hidden]) * gu[:, hidden:]
 
 
-@jax.custom_vjp
-def _permute_rows(y, index, inverse):
-    """``y[index]`` for a permutation ``index`` whose inverse is known: the
-    transpose is ``g[inverse]``, a gather again."""
-    return y[index]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def routed_experts(x, w_gu, w_down, gate, row_to_pair, pair_to_row, group_sizes, rounds,
+                   chunk, impl="auto", interpret=False):
+    """``Σ_slots gate · E(x)`` over the pairs on held experts: ``x`` (tokens,
+    dim), the held experts' stacked matrices (gate ‖ up, and down), ``gate``
+    (tokens, slots) float32 and zero for a pair held elsewhere, the sort's
+    two permutations (sorted row -> pair; (tokens, slots) -> sorted row), the
+    held experts' ``group_sizes`` and ``rounds = ceil(sum(group_sizes) /
+    chunk)`` -> (tokens, dim) in ``x``'s dtype, the slots summed in float32.
+
+    Forward and backward walk the sorted rows ``chunk`` at a time, ``rounds``
+    times: a loop whose trip count follows the routing. The backward pass
+    keeps the arguments and nothing sized by the routing: it gathers each
+    round's rows again, recomputes their gate and up products, and sums the
+    matrices' gradients over the rounds in the matrices' dtype."""
+    return _routed_fwd(x, w_gu, w_down, gate, row_to_pair, pair_to_row, group_sizes, rounds,
+                       chunk, impl, interpret)[0]
 
 
-def _permute_rows_fwd(y, index, inverse):
-    return y[index], inverse
+def _routed_fwd(x, w_gu, w_down, gate, row_to_pair, pair_to_row, group_sizes, rounds,
+                chunk, impl, interpret):
+    product = functools.partial(grouped_matmul, impl=impl, interpret=interpret)
+
+    def one_round(r, y):
+        token, _, sizes, row_of_pair, mine = _round_rows(
+            r, chunk, row_to_pair, pair_to_row, gate, group_sizes)
+        rows = x[token]
+        with jax.named_scope(SCOPE_EXPERTS):
+            out = product(_swiglu(product(rows, w_gu, sizes)), w_down, sizes)
+        return y + _sum_slots(out, row_of_pair, jnp.where(mine, gate, 0.0))
+
+    with jax.named_scope(SCOPE_MOE_DISPATCH):
+        y = jax.lax.fori_loop(0, rounds, one_round, jnp.zeros(x.shape, jnp.float32))
+        y = y.astype(x.dtype)
+    return y, (x, w_gu, w_down, gate, row_to_pair, pair_to_row, group_sizes, rounds)
 
 
-def _permute_rows_bwd(inverse, g):
-    return g[inverse], None, None
+def _routed_bwd(chunk, impl, interpret, residuals, dy):
+    x, w_gu, w_down, gate, row_to_pair, pair_to_row, group_sizes, rounds = residuals
+    product = functools.partial(grouped_matmul, impl=impl, interpret=interpret)
+    outer = functools.partial(grouped_outer, impl=impl, interpret=interpret)
+
+    def one_round(r, carry):
+        d_x, d_gate, d_w_gu, d_w_down = carry
+        token, gate_of_row, sizes, row_of_pair, mine = _round_rows(
+            r, chunk, row_to_pair, pair_to_row, gate, group_sizes)
+        rows, d_out = x[token], dy[token]
+        with jax.named_scope(SCOPE_EXPERTS):
+            act, swiglu_vjp = jax.vjp(_swiglu, product(rows, w_gu, sizes))
+            # out = gate · (act W_down), so the gate's gradient is
+            # act · (d_out W_downᵀ) and the activation's is gate times it
+            d_act = product(d_out, w_down, sizes, transpose_rhs=True)
+            d_gate_of_row = (act.astype(jnp.float32) * d_act.astype(jnp.float32)).sum(axis=1)
+            scale = gate_of_row[:, None].astype(act.dtype)
+            d_w_down = outer(act * scale, d_out, sizes, d_w_down)
+            (d_gu,) = swiglu_vjp(d_act * scale)
+            d_rows = product(d_gu, w_gu, sizes, transpose_rhs=True)
+            d_w_gu = outer(rows, d_gu, sizes, d_w_gu)
+        d_x = d_x + _sum_slots(d_rows, row_of_pair, mine.astype(jnp.float32))
+        d_gate = d_gate + jnp.where(mine, d_gate_of_row[row_of_pair], 0.0)
+        return d_x, d_gate, d_w_gu, d_w_down
+
+    with jax.named_scope(SCOPE_MOE_DISPATCH):
+        d_x, d_gate, d_w_gu, d_w_down = jax.lax.fori_loop(
+            0, rounds, one_round,
+            (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(gate),
+             jnp.zeros_like(w_gu), jnp.zeros_like(w_down)))
+    return d_x.astype(x.dtype), d_w_gu, d_w_down, d_gate, None, None, None, None
 
 
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 
 class SparseExperts(nn.Module):
@@ -298,31 +375,30 @@ class SparseExperts(nn.Module):
             here = (local >= 0) & (local < held)
             key = jnp.where(here, local, held).reshape(n * k)  # pairs elsewhere sort last
             row_to_pair = jnp.argsort(key, stable=True).astype(jnp.int32)
-            pair_to_row = jnp.argsort(row_to_pair).astype(jnp.int32)
+            pair_to_row = jnp.argsort(row_to_pair).astype(jnp.int32).reshape(n, k)
             group_sizes = (key[:, None] == jnp.arange(held)).sum(axis=0).astype(jnp.int32)
-            rows = _gather_pairs(flat, row_to_pair // k, pair_to_row)  # (n k, d)
+            total = group_sizes.sum()
+            chunk = chunk_rows(n * k, held, e)
+            rounds = (total + chunk - 1) // chunk
+            gate = jnp.where(here, weights, 0.0)
         with jax.named_scope(SCOPE_EXPERTS):
             # one matrix per held expert, stacked
             stacked = lambda name, *shape: kernel(name, held, *shape).astype(cfg.compute_dtype)
             w_gu = jnp.concatenate([stacked("gate", d, cfg.expert_hidden),
                                     stacked("up", d, cfg.expert_hidden)], axis=-1)
-            gu = grouped_matmul(rows, w_gu, group_sizes)
-            act = nn.silu(gu[:, : cfg.expert_hidden]) * gu[:, cfg.expert_hidden:]
-            out = grouped_matmul(act, stacked("down", cfg.expert_hidden, d), group_sizes)
-        with jax.named_scope(SCOPE_MOE_DISPATCH):
-            out = _permute_rows(out, pair_to_row, row_to_pair).reshape(n, k, d)
-            gate = jnp.where(here, weights, 0.0)[..., None]
-            routed = (out.astype(jnp.float32) * gate).sum(axis=1).astype(x.dtype)
+            w_down = stacked("down", cfg.expert_hidden, d)
+        routed = routed_experts(flat, w_gu, w_down, gate, row_to_pair, pair_to_row,
+                                group_sizes, rounds, chunk)
         with jax.named_scope(SCOPE_ROUTER):
-            total = group_sizes.sum()
             per_expert = group_sizes.astype(jnp.float32)
             mean = per_expert.mean()
             stats = jnp.stack([
                 per_expert.min(), mean, per_expert.max(),
                 per_expert.max() / jnp.maximum(mean, 1.0),
                 total / (n * k),
-                # every held pair has a row of the buffer: what did not fit
-                (total - jnp.minimum(total, rows.shape[0])).astype(jnp.float32),
+                # the rounds take ``chunk`` rows each: what they did not reach
+                (total - jnp.minimum(total, rounds * chunk)).astype(jnp.float32),
+                rounds.astype(jnp.float32),
             ])
         with jax.named_scope(SCOPE_SHARED_EXPERT):
             shared = GatedMlp(cfg.n_shared_experts * cfg.expert_hidden, cfg, name="shared")(x)
@@ -435,5 +511,5 @@ class MlaMoeLM(nn.Module):
             out |= {f"moe_{c}_{name}": st[j] for j, c in enumerate(MOE_COUNTERS)}
         col = {c: table[:, j] for j, c in enumerate(MOE_COUNTERS)}
         out |= {"moe_imbalance": col["imbalance"].max(), "moe_held_share": col["held_share"].mean(),
-                "moe_dropped": col["dropped"].sum()}
+                "moe_dropped": col["dropped"].sum(), "moe_rounds": col["rounds"].max()}
         return out

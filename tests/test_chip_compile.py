@@ -118,7 +118,9 @@ def test_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):
     """The real cut of the shipped recipe (680 M parameters, 2 x 8192 tokens)
     through the trainer's own step factory, for a described v5e: the flash
     and grouped-product kernels are in it, the guard adds no ``conditional``,
-    and what the step holds fits the chip with room (15.5 GB)."""
+    the expert layers walk their held pairs in a loop and build nothing a row
+    wide for all 131 072 (token, expert) pairs, and what the step holds fits
+    the chip with room (under the 12.44 GB it held with such buffers)."""
     from jumbo_mae_tpu_tpu.cli.train import build_model
     from jumbo_mae_tpu_tpu.config import load_config
     from jumbo_mae_tpu_tpu.parallel import MeshConfig, create_mesh
@@ -155,10 +157,20 @@ def test_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):
     assert " conditional(" not in text and "/guard/" in text
     for kernel in ("causal_attention_fwd", "causal_attention_dq", "causal_attention_dkv", "gmm"):
         assert kernel in text, kernel
+    pairs = rows * cfg.data.seq_len * lm.experts_per_token
+    assert pairs == 131_072
+    for wide in (f"[{pairs},{lm.dim}]", f"[{pairs},{2 * lm.expert_hidden}]",
+                 f"[{pairs},{lm.expert_hidden}]",
+                 f"[{pairs // lm.experts_per_token},{lm.experts_per_token},{lm.dim}]"):
+        assert wide not in text, wide
+    loops = [line for line in text.splitlines()
+             if " while(" in line and '/moe/moe_dispatch/while"' in line]
+    assert len(loops) == 2 * 5, len(loops)  # forward and backward of five expert layers
+    assert re.search(r'op_name="[^"]*/moe_dispatch/while/body/experts/[^"]*pallas_call"', text)
     m = compiled.memory_analysis()
     held = (m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes)
-    assert 6.8e9 < held < 15.5e9, held
+    assert 6.8e9 < held < 12_444_211_712, held
 
 
 # -------------------------------------------- chip_smoke, rehearsed on CPU
@@ -237,12 +249,15 @@ def test_lm_train_phase_rehearsal(tmp_path, watch, capsys):
     checked = line["checked"]
     assert checked["loss_after_one_cycle"] < checked["loss_first"]
     assert checked["moe_dropped"] == 0 and checked["skipped_steps"] == 0
+    assert checked["moe_rounds"] == 1
     assert 0.1 < checked["moe_held_share_min_max"][0] <= checked["moe_held_share_min_max"][1] < 0.5
     assert checked["mfu_trainer_reported"] is None  # a CPU count is not a device rate
     from jumbo_mae_tpu_tpu.obs.metrics import get_registry
 
     published = get_registry().snapshot()["train_moe"]
-    assert {"imbalance", "held_share", "dropped", "rows_max_l1", "rows_min_mtp"} <= set(published)
+    assert {"imbalance", "held_share", "dropped", "rounds", "rows_max_l1", "rows_min_mtp",
+            "rounds_l1", "rounds_mtp"} <= set(published)
+    assert published["rounds"] == 1
 
 
 def test_train_then_resume_phases_rehearsal(tmp_path, compile_cache, watch, capsys):

@@ -14,8 +14,9 @@ import pytest
 from benchmarks import harness
 from benchmarks.reference import lm_model, lm_params
 from benchmarks.reference import params as ref_params
+from jumbo_mae_tpu_tpu.models import lm
 from jumbo_mae_tpu_tpu.models.lm import MOE_COUNTERS, MlaMoeConfig, MlaMoeLM, SparseExperts
-from jumbo_mae_tpu_tpu.ops.grouped_matmul import grouped_matmul
+from jumbo_mae_tpu_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, grouped_outer
 
 CELL = "joyai_flash_pretrain_2x8k"
 
@@ -126,6 +127,77 @@ def test_skewed_routing_drops_nothing():
     assert stats["dropped"] == 0 and stats["imbalance"] > 2
 
 
+@pytest.mark.parametrize("pairs,held,experts,want", [
+    (131_072, 16, 256, 16_384),   # the cell: 2 x 8192 tokens top-8, 16 of 256 held
+    (131_072, 256, 256, 131_072),  # every expert held: one round of every pair
+    (160, 4, 16, 160),             # the toy: a row tile is more than all its pairs
+    (1024, 2, 16, 256),
+    (3000, 2, 16, 768),            # 750 rows, in whole row tiles
+])
+def test_the_chunk_is_twice_the_uniform_share_in_whole_row_tiles(pairs, held, experts, want):
+    assert lm.chunk_rows(pairs, held, experts) == want
+
+
+# 16 experts top-2 of which 2 are held, 512 tokens: a round takes twice the
+# uniform share of the 1024 pairs, 2 · 1024 · 2 / 16 = 256 rows, one row tile
+ROUNDS = {
+    # every token picks expert 4 and some pick 5 too: 512 + a ragged rest
+    "three_rounds_ragged_last": dict(first=4, held=2, bias={4: 10.0, 5: 0.03}, rounds=3),
+    # every choice of every token is held: all 1024 pairs, n k / chunk rounds
+    "worst_case_every_pair_held": dict(first=4, held=2, bias={4: 10.0, 5: 10.0}, rounds=4),
+    # all experts held (experts_held=None): the chunk is every pair
+    "all_experts_held_one_round": dict(first=0, held=16, bias={}, rounds=1),
+}
+
+
+@pytest.mark.parametrize("impl,interpret", [("ragged_dot", False), ("pallas", True)])
+@pytest.mark.parametrize("routing", list(ROUNDS))
+def test_rounds_take_every_held_pair(routing, impl, interpret, monkeypatch):
+    """Routing that fills more than one chunk is computed in full: output,
+    every gradient leaf, the router's and the input's against the
+    reference, nothing dropped, and as many rounds as reckoned by hand."""
+    first, held = ROUNDS[routing]["first"], ROUNDS[routing]["held"]
+    config, cfg, *_ = _setup()
+    tokens, k = 512, 2
+    cfg = cfg.replace(experts_per_token=k)
+    whole, p, bias, x = _layer(config | {"num_experts_per_tok": k}, cfg, tokens=tokens)
+    for expert, value in ROUNDS[routing]["bias"].items():
+        bias = bias.at[expert].set(value)
+    chunk = tokens * k if held == 16 else ROW_TILE
+    assert lm.chunk_rows(tokens * k, held, 16) == chunk
+    _, _, counts = lm_model.route(lm_model.Ops(), x[0], p, bias, whole)
+    total = int(counts[first:first + held].sum())
+    rounds = -(-total // chunk)
+    assert rounds == ROUNDS[routing]["rounds"]
+    assert total % chunk if "ragged" in routing else total == tokens * k
+    monkeypatch.setattr(lm, "routed_experts", functools.partial(
+        lm.routed_experts, impl=impl, interpret=interpret))
+    layer = SparseExperts(cfg.replace(experts_held=None if held == 16 else (first, held)))
+    weight = jax.random.normal(jax.random.key(9), x.shape)
+    share = _share(p, first, held)
+
+    def program(params, x):
+        out, stats = layer.apply({"params": params, "batch_stats": {"router_bias": bias}}, x)
+        return (out * weight).sum(), (out, stats)
+
+    def reference(params, x):
+        out, _ = lm_model.expert_layer(lm_model.Ops(), x[0], params, bias, whole, first=first)
+        return (out * weight[0]).sum(), out
+
+    (_, (out, stats)), grads = jax.value_and_grad(program, argnums=(0, 1), has_aux=True)(share, x)
+    (_, want), want_grads = jax.value_and_grad(reference, argnums=(0, 1), has_aux=True)(share, x)
+    np.testing.assert_allclose(out[0], want, rtol=1e-4, atol=1e-6)
+    stats = dict(zip(MOE_COUNTERS, np.asarray(stats)))
+    assert stats["dropped"] == 0 and stats["rounds"] == rounds
+    assert stats["held_share"] == pytest.approx(total / (tokens * k))
+    got, ref = _flat(grads), _flat(want_grads)
+    assert got.keys() == ref.keys() and len(got) == 8  # router, 3 stacked, 3 shared, the input
+    for name, g in got.items():
+        assert np.abs(ref[name]).max() > 0, name
+        np.testing.assert_allclose(g, ref[name], rtol=2e-3,
+                                   atol=2e-4 * np.abs(ref[name]).max(), err_msg=name)
+
+
 def test_the_bias_rule():
     config, cfg, *_ = _setup()
     whole, p, bias, x = _layer(config, cfg)
@@ -162,6 +234,31 @@ def test_grouped_product_matches_a_loop_over_experts(impl, interpret):
     for g, r in zip(got, want):
         np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-5)
     assert not np.asarray(got[1])[1].any()  # the empty group's matrices get no gradient
+
+
+@pytest.mark.parametrize("impl,interpret", [("ragged_dot", False), ("pallas", True)])
+def test_the_gradients_own_products_match_a_loop_over_experts(impl, interpret):
+    """What the expert layer's backward pass calls directly: the product
+    against the transposed matrices, and the matrices' gradient added onto
+    what an earlier round left (an empty group keeps what it had)."""
+    sizes = [41, 0, 9, 6]
+    m, k, n = 64, 16, 24
+    keys = jax.random.split(jax.random.key(1), 4)
+    lhs, g = jax.random.normal(keys[0], (m, k)), jax.random.normal(keys[1], (m, n))
+    rhs, onto = jax.random.normal(keys[2], (4, k, n)), jax.random.normal(keys[3], (4, k, n))
+    back, outer, start = jnp.zeros((m, k)), [], 0
+    for e, size in enumerate(sizes):
+        rows = slice(start, start + size)
+        back = back.at[rows].set(g[rows] @ rhs[e].T)
+        outer.append(onto[e] + lhs[rows].T @ g[rows])
+        start += size
+    kw = dict(impl=impl, interpret=interpret)
+    got = grouped_matmul(g, rhs, jnp.asarray(sizes), transpose_rhs=True, **kw)
+    np.testing.assert_allclose(got, back, rtol=1e-5, atol=1e-5)
+    assert not np.asarray(got)[sum(sizes):].any()
+    got = grouped_outer(lhs, g, jnp.asarray(sizes), onto, **kw)
+    np.testing.assert_allclose(got, jnp.stack(outer), rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(got[1], onto[1])
 
 
 def test_token_flops_count_matches_the_issue_s_reckoning():
