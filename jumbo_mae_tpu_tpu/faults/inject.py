@@ -346,7 +346,7 @@ def _corrupt_bytes(data, nbytes: int, seed: int, salt: int):
     if isinstance(data, (bytes, bytearray)):
         if len(data) == 0:
             return data
-        rng = random.Random((seed, salt, len(data)))
+        rng = random.Random(f"{seed}:{salt}:{len(data)}")
         buf = bytearray(data)
         for _ in range(min(nbytes, len(buf))):
             i = rng.randrange(len(buf))
@@ -364,7 +364,7 @@ def _corrupt_bytes(data, nbytes: int, seed: int, salt: int):
         ]
         if not idx:
             return data
-        rng = random.Random((seed, salt, len(idx)))
+        rng = random.Random(f"{seed}:{salt}:{len(idx)}")
         chosen = rng.sample(idx, min(nbytes, len(idx)))
         out = list(leaves)
         for i in chosen:

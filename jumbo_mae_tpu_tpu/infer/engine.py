@@ -161,12 +161,9 @@ def _mae_decode(mdl, tokens, mask, ids_restore, deterministic: bool = True):
     throughout (per-token norms, within-sample attention, per-sample
     gather), so zero-padded rows stay provably inert — the same contract
     the fused executable has."""
-    enc_cfg = mdl.encoder_cfg
-    k = enc_cfg.num_cls_tokens
+    k = mdl.encoder_cfg.num_cls_tokens
     cls, visible = tokens[:, :k, :], tokens[:, k:, :]
-    full = unshuffle_with_mask_tokens(
-        visible, mdl.mask_token, ids_restore, impl=enc_cfg.gather_impl
-    )
+    full = unshuffle_with_mask_tokens(visible, mdl.mask_token, ids_restore)
     decoded = mdl.decoder(jnp.concatenate([cls, full], axis=1), deterministic)
     pred = mdl.pixel_proj(decoded[:, k:, :].astype(jnp.float32))
     return {"reconstruction": pred, "mask": mask}

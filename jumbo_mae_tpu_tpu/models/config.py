@@ -18,12 +18,10 @@ Posemb = Literal["learnable", "sincos2d"]
 Pooling = Literal["cls", "gap"]
 AttnImpl = Literal["einsum", "flash", "ring", "auto"]
 MaskModeT = Literal["shared", "per_sample"]
-GatherImplT = Literal["take", "onehot"]
 # rematerialization policy under grad_ckpt=True:
-#   "none"          — save nothing, recompute the whole block (max memory win)
-#   "dots"          — save every matmul output, recompute elementwise only
-#   "dots_no_batch" — save param matmuls but not attention score matmuls
-RematPolicy = Literal["none", "dots", "dots_no_batch"]
+#   "none" — save nothing, recompute the whole block (max memory win)
+#   "dots" — save every matmul output, recompute elementwise only
+RematPolicy = Literal["none", "dots"]
 
 
 def checkpoint_policy(name: str):
@@ -35,8 +33,6 @@ def checkpoint_policy(name: str):
         return None
     if name == "dots":
         return jax.checkpoint_policies.dots_saveable
-    if name == "dots_no_batch":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
     raise ValueError(f"unknown remat policy {name!r}")
 
 
@@ -97,16 +93,11 @@ class JumboViTConfig:
     # scores) or "flash" (Pallas kernels + differentiable lse merge,
     # O(S/n) score memory; falls back to einsum off-TPU)
     ring_inner: str = "einsum"
-    # masking shuffle/unshuffle lowering: "take" (XLA dynamic gather) or
-    # "onehot" (0/1 MXU matmul, concat-free unshuffle) — bit-identical
-    # numerics, pick by profile (ops/masking.py validates the value)
-    gather_impl: GatherImplT = "take"
 
     def __post_init__(self):
         if self.heads <= 0 or self.dim % self.heads:
             # head_dim floors silently otherwise: heads=7 at dim=768 would
-            # train a 763-wide attention with no warning (bench.py's
-            # _parse_dec_heads already rejects this; the recipe/--set
+            # train a 763-wide attention with no warning (the recipe/--set
             # surface lands here)
             raise ValueError(
                 f"dim ({self.dim}) must be divisible by heads ({self.heads})"

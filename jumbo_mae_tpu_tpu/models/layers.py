@@ -34,12 +34,8 @@ TRUNC_NORMAL = init.truncated_normal(0.02)
 
 # attn_impl="auto" switches einsum → Pallas flash at this sequence length.
 # The v5e-measured crossover sits between 199 (einsum 1.7× faster) and 787
-# (flash 1.7× faster); 512 splits it conservatively. Env-overridable so a
-# different TPU generation can re-pin it from tools/flash_microbench.py
-# without a code change.
-import os as _os
-
-AUTO_FLASH_MIN_SEQ = int(_os.environ.get("JUMBO_AUTO_FLASH_MIN_SEQ", "512"))
+# (flash 1.7× faster); 512 splits it conservatively.
+AUTO_FLASH_MIN_SEQ = 512
 
 
 def resolve_attn_impl(
@@ -52,7 +48,7 @@ def resolve_attn_impl(
 ) -> str:
     """Resolve ``attn_impl="auto"`` to a concrete backend per call shape.
 
-    Measured crossover on v5e (tools/flash_microbench.py, round 5,
+    Measured crossover on v5e (PERF_ARCHIVE.md, round 5,
     fwd+bwd ms): einsum wins at MAE-224 shapes (seq 199: 5.2 vs 8.7),
     the Pallas kernels win from long-context lengths up (seq 787: 9.0 vs
     15.3; seq 3139: 24.7 vs 45.8) now that they use bf16 MXU-rate

@@ -138,9 +138,7 @@ class MAEPretrainModel(nn.Module):
         tokens = self.decoder_proj(tokens)
         with jax.named_scope(SCOPE_MASK):
             cls, visible = tokens[:, :k, :], tokens[:, k:, :]
-        full = unshuffle_with_mask_tokens(
-            visible, self.mask_token, ids_restore, impl=enc_cfg.gather_impl
-        )
+        full = unshuffle_with_mask_tokens(visible, self.mask_token, ids_restore)
         with jax.named_scope(SCOPE_MASK):
             full = jnp.concatenate([cls, full], axis=1)
         decoded = self.decoder(

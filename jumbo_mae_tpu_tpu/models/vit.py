@@ -105,7 +105,6 @@ class JumboViT(nn.Module):
                 cfg.keep_len,
                 mode=cfg.mask_mode,
                 noise=mask_noise,
-                gather_impl=cfg.gather_impl,
             )
 
         cls = jnp.broadcast_to(

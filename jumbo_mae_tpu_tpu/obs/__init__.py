@@ -17,7 +17,8 @@
   / throughput / peak HBM; FSDP/DP comm terms) + the live
   predict-vs-measured drift gauge.
 - ``obs.perfledger`` — schema-versioned BENCH_HISTORY.jsonl writer/reader
-  the benches append to and ``tools/perf_doctor.py`` diagnoses.
+  ``tools/bench_infer.py`` and ``tools/loadgen.py`` append to and
+  ``tools/perf_doctor.py`` diagnoses.
 - ``obs.modelstats`` — per-layer-group grad/param/update statistics computed
   inside the jitted train step (``run.diag_every``).
 - ``obs.journal``  — append-only crash-safe JSONL run journal (per-host

@@ -1,8 +1,8 @@
 """Schema-versioned perf-regression ledger: BENCH_HISTORY.jsonl.
 
 The bench trajectory was empty because results never landed anywhere
-comparable: ``bench.py`` and ``tools/bench_infer.py`` each print one JSON
-line and exit, and nothing relates run N to run N−1. This module is the
+comparable: ``tools/bench_infer.py`` prints one JSON line and exits, and
+nothing relates run N to run N−1. This module is the
 landing strip — every bench appends one row here, and
 ``tools/perf_doctor.py`` reads the trail back to call regressions.
 

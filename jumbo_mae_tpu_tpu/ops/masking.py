@@ -7,17 +7,8 @@ per-device batch (upstream facebookresearch/mae permutes per sample). Shared
 mode is the parity default here; ``per_sample`` mode is also provided because
 it is strictly stronger as an augmentation and costs one batched argsort.
 
-TPU notes: the shuffle/unshuffle gathers have two selectable lowerings:
-
-- ``impl="take"`` (default) — ``jnp.take``(_along_axis); XLA lowers to a
-  dynamic gather, cheap at these sizes.
-- ``impl="onehot"`` — the gather becomes a 0/1 one-hot matmul on the MXU
-  (the north-star's "HBM-friendly gather/scatter", done the TPU way: the
-  systolic array IS the hardware gather engine, and the unshuffle variant
-  drops the concat so the full-sequence intermediate is written to HBM
-  once instead of twice). Numerically EXACT in any dtype — multiplying by
-  1.0 and summing zeros is lossless — so the two impls are
-  bit-interchangeable; pick by profile (``BENCH_GATHER_IMPL``).
+TPU notes: the shuffle/unshuffle gathers are ``jnp.take``(_along_axis); XLA
+lowers them to a dynamic gather, cheap at these sizes.
 """
 
 from __future__ import annotations
@@ -30,36 +21,14 @@ import jax.numpy as jnp
 from jumbo_mae_tpu_tpu.obs.trace import SCOPE_MASK
 
 MaskMode = Literal["shared", "per_sample"]
-GatherImpl = Literal["take", "onehot"]
 
 
-# HIGHEST keeps f32 operands in full-precision MXU passes: the default
-# precision would run bf16 passes and round f32 token values, breaking the
-# bit-identical-to-take guarantee the A/B rests on. (For bf16 inputs it
-# changes nothing — a 0/1 matmul has one nonzero product per output.)
-_EXACT = jax.lax.Precision.HIGHEST
-
-
-def _check_impl(impl: str) -> None:
-    if impl not in ("take", "onehot"):
-        raise ValueError(
-            f"unknown gather impl {impl!r}; choose 'take' or 'onehot'"
-        )
-
-
-def index_sequence(
-    x: jax.Array, ids: jax.Array, *, impl: GatherImpl = "take"
-) -> jax.Array:
+def index_sequence(x: jax.Array, ids: jax.Array) -> jax.Array:
     """Gather along the sequence (second) axis.
 
     ``ids`` may be 1-D (shared permutation, applied to every batch row) or 2-D
     ``(batch, n)`` (per-sample permutation).
     """
-    _check_impl(impl)
-    if impl == "onehot":
-        sel = jax.nn.one_hot(ids, x.shape[1], dtype=x.dtype)
-        eq = "nk,bk...->bn..." if ids.ndim == 1 else "bnk,bk...->bn..."
-        return jnp.einsum(eq, sel, x, precision=_EXACT)
     if ids.ndim == 1:
         return jnp.take(x, ids, axis=1)
     idx = ids.reshape(ids.shape + (1,) * (x.ndim - 2))
@@ -74,7 +43,6 @@ def random_masking(
     *,
     mode: MaskMode = "shared",
     noise: jax.Array | None = None,
-    gather_impl: GatherImpl = "take",
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Randomly drop all but ``keep_len`` tokens of ``x`` (batch, len, dim).
 
@@ -100,7 +68,7 @@ def random_masking(
             noise = jax.random.uniform(rng, (length,), dtype=jnp.float32)
         ids_shuffle = jnp.argsort(noise)
         ids_restore = jnp.argsort(ids_shuffle)
-        kept = index_sequence(x, ids_shuffle[:keep_len], impl=gather_impl)
+        kept = index_sequence(x, ids_shuffle[:keep_len])
         shuffled_mask = (jnp.arange(length) >= keep_len).astype(jnp.float32)
         mask = jnp.broadcast_to(shuffled_mask[ids_restore], (batch, length))
         return kept, mask, ids_restore
@@ -110,7 +78,7 @@ def random_masking(
             noise = jax.random.uniform(rng, (batch, length), dtype=jnp.float32)
         ids_shuffle = jnp.argsort(noise, axis=1)
         ids_restore = jnp.argsort(ids_shuffle, axis=1)
-        kept = index_sequence(x, ids_shuffle[:, :keep_len], impl=gather_impl)
+        kept = index_sequence(x, ids_shuffle[:, :keep_len])
         shuffled_mask = jnp.broadcast_to(
             (jnp.arange(length) >= keep_len).astype(jnp.float32), (batch, length)
         )
@@ -179,8 +147,6 @@ def unshuffle_with_mask_tokens(
     visible: jax.Array,
     mask_token: jax.Array,
     ids_restore: jax.Array,
-    *,
-    impl: GatherImpl = "take",
 ) -> jax.Array:
     """Restore the full sequence from visible tokens + a learned mask token.
 
@@ -190,25 +156,9 @@ def unshuffle_with_mask_tokens(
     derived as ``length - keep_len`` (the reference instead recomputes it as
     ``int(length * mask_ratio)``, which disagrees with ``keep_len`` for some
     ratios — ``/root/reference/src/pretraining.py:100-103``; fixed here).
-
-    ``impl="onehot"`` skips the concat entirely: output rows whose restore
-    index lands in the visible range come from a (length, keep_len) 0/1
-    matmul against ``visible`` on the MXU; the rest add the broadcast mask
-    token — the full-length intermediate is written once, not twice.
     """
     batch, keep_len, dim = visible.shape
     length = ids_restore.shape[-1]
-    _check_impl(impl)
-    if impl == "onehot":
-        # rows selecting a masked slot have an all-zero one-hot row (index
-        # >= keep_len matches nothing), so the matmul contributes 0 there
-        # and the mask-token term fills it in
-        sel = jax.nn.one_hot(ids_restore, keep_len, dtype=visible.dtype)
-        eq = "nk,bkd->bnd" if ids_restore.ndim == 1 else "bnk,bkd->bnd"
-        from_visible = jnp.einsum(eq, sel, visible, precision=_EXACT)
-        masked = (ids_restore >= keep_len).astype(visible.dtype)[..., :, None]
-        token = jnp.asarray(mask_token, visible.dtype).reshape(1, 1, dim)
-        return from_visible + masked * token
     mask_tokens = jnp.broadcast_to(mask_token, (batch, length - keep_len, dim))
     full = jnp.concatenate([visible, mask_tokens.astype(visible.dtype)], axis=1)
     return index_sequence(full, ids_restore)

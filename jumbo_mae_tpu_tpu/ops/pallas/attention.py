@@ -32,7 +32,6 @@ automatically, at some efficiency cost).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -46,11 +45,10 @@ NEG_INF = -1e30
 # the ViT-H bench shapes the 128-wide broadcast was ~840 MB of transient
 # per buffer; gradient parity at width 8 is verified on-device (v5e).
 # Mosaic's acceptance of sub-128 minor dims varies by TPU generation and
-# compiler version: if compilation fails on another device kind with a
-# Mosaic layout/lane error pointing at the lse/delta buffers, set
-# JUMBO_PALLAS_LANE=128 — full-lane residual buffers, identical numerics,
-# just fatter HBM transients.
-LANE = int(os.environ.get("JUMBO_PALLAS_LANE", "8"))
+# compiler version: a Mosaic layout/lane error pointing at the lse/delta
+# buffers on another device kind means this width (128 is always accepted:
+# identical numerics, fatter HBM transients).
+LANE = 8
 
 # Matmul operand dtype inside the kernels: the INPUT dtype (bf16 in
 # production) rather than an f32 upcast. bf16 operands feed the MXU at its
@@ -60,19 +58,7 @@ LANE = int(os.environ.get("JUMBO_PALLAS_LANE", "8"))
 # probs, so bf16 operands here are numerically comparable (scores still
 # accumulate f32 via preferred_element_type, softmax math stays f32, and
 # flash keeps its f32 online-softmax accumulation). f32 inputs (parity
-# oracles) are untouched. JUMBO_PALLAS_MM_F32=1 restores the f32 upcast.
-MM_F32 = os.environ.get("JUMBO_PALLAS_MM_F32") == "1"
-
-
-def _mm_dtype(ref) -> jnp.dtype:
-    return jnp.float32 if MM_F32 else ref.dtype
-
-# Block planning: by default the padded sequence rounds to the 128-lane tile
-# and the block shrinks to the largest divisor (at seq 787 → sk_pad 896 the
-# requested 256 collapses to 128, doubling streaming passes). With
-# JUMBO_PALLAS_PAD_TO_BLOCK=1 the sequence pads UP to a block multiple
-# instead (more masked rows, fewer/fatter passes) — measured per shape.
-PAD_TO_BLOCK = os.environ.get("JUMBO_PALLAS_PAD_TO_BLOCK") == "1"
+# oracles) are untouched.
 
 
 def _mask_cols(s, col0: int, valid_k: int):
@@ -83,7 +69,7 @@ def _mask_cols(s, col0: int, valid_k: int):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_k: int, valid_k: int):
-    mm = _mm_dtype(q_ref)
+    mm = q_ref.dtype
     q = q_ref[0].astype(mm)  # (block_q, d)
     block_q, d = q.shape
     seq_k = k_ref.shape[1]
@@ -121,7 +107,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_k: int, valid
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, *, block_k: int, valid_k: int
 ):
-    mm = _mm_dtype(q_ref)
+    mm = q_ref.dtype
     q = q_ref[0].astype(mm)  # (block_q, d)
     do = do_ref[0].astype(mm)
     lse = lse_ref[0][:, :1]  # (block_q, 1) — scalar replicated over lanes
@@ -156,7 +142,7 @@ def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref,
     *, block_q: int, valid_k: int, masked: bool,
 ):
-    mm = _mm_dtype(k_ref)
+    mm = k_ref.dtype
     k = k_ref[0].astype(mm)  # (block_k, d)
     v = v_ref[0].astype(mm)
     block_k, d = k.shape
@@ -231,18 +217,11 @@ def _unfold(x, b, h, s, d):
 def _plan(q, k, block_q, block_k):
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    # Default: pad ragged lengths only up to the 128-lane tile, then pick
-    # the largest block ≤ requested that divides the padded length (at seq
-    # 787 → 896 a requested 256 collapses to 128). PAD_TO_BLOCK instead
-    # pads up to a block multiple — more masked rows (787 → 1024, +14%),
-    # but fewer, fatter streaming passes; which wins is measured per shape
-    # (tools/flash_microbench.py).
-    if PAD_TO_BLOCK:
-        sq_pad = _round_up(sq, min(block_q, _round_up(sq, 128)))
-        sk_pad = _round_up(sk, min(block_k, _round_up(sk, 128)))
-    else:
-        sq_pad = _round_up(sq, 128)
-        sk_pad = _round_up(sk, 128)
+    # pad ragged lengths only up to the 128-lane tile, then pick the largest
+    # block ≤ requested that divides the padded length (at seq 787 → 896 a
+    # requested 256 collapses to 128)
+    sq_pad = _round_up(sq, 128)
+    sk_pad = _round_up(sk, 128)
     return (
         b, sq, h, d, sk, sq_pad, sk_pad,
         _largest_dividing_block(block_q, sq_pad),
@@ -371,7 +350,7 @@ def pallas_flash_attention(
     tests).
 
     Default blocks are 1024 (clamped per shape by ``_plan``): round-5
-    microbenches (tools/flash_microbench.py, v5e) showed the requested-256
+    microbenches (PERF_ARCHIVE.md, v5e) showed the requested-256
     default collapsing to 128 at seq 787 (896 tile-pad) and doubling the
     streaming passes — big requests resolve to full-row or near-full-row
     blocks (256@199, 896@787, 640@3139) and beat the einsum path at every
@@ -499,7 +478,7 @@ def _causal_fwd_kernel(qi_ref, kj_ref, qa_ref, qb_ref, ka_ref, kb_ref, v_ref,
                        o_ref, lse_ref, m_sc, l_sc, acc_sc):
     t = pl.program_id(2)
     i, j = qi_ref[t], kj_ref[t]
-    mm = _mm_dtype(qa_ref)
+    mm = qa_ref.dtype
 
     @pl.when(j == 0)
     def _():
@@ -536,7 +515,7 @@ def _causal_dq_kernel(qi_ref, kj_ref, qa_ref, qb_ref, ka_ref, kb_ref, v_ref,
                       do_ref, lse_ref, dd_ref, dqa_ref, dqb_ref, dqa_sc, dqb_sc):
     t = pl.program_id(2)
     i, j = qi_ref[t], kj_ref[t]
-    mm = _mm_dtype(qa_ref)
+    mm = qa_ref.dtype
 
     @pl.when(j == 0)
     def _():
@@ -571,7 +550,7 @@ def _causal_dkv_kernel(qi_ref, kj_ref, qa_ref, qb_ref, ka_ref, kb_ref, v_ref,
                        dka_sc, dkb_sc, dv_sc, *, last: int):
     t = pl.program_id(2)
     i, j = qi_ref[t], kj_ref[t]
-    mm = _mm_dtype(qa_ref)
+    mm = qa_ref.dtype
 
     def step(diagonal: bool):
         qa, qb = qa_ref[...].astype(mm), qb_ref[...].astype(mm)

@@ -30,7 +30,7 @@ def compile_cache_dir() -> str:
 def enable_compile_cache() -> str:
     """Point this process's persistent compilation cache at
     :func:`compile_cache_dir` — the one place the program sets that path.
-    Entry points (``cli.train``, ``cli.predict``, ``cli.batch``, ``bench.py``,
+    Entry points (``cli.train``, ``cli.predict``, ``cli.batch``,
     ``chip_smoke.py``) call it first thing. Entries other processes wrote
     there are never deleted: with the installed JAX an unreadable entry is a
     warning and a recompile, not a crash. Returns the directory."""

@@ -117,50 +117,66 @@ class TestPallasKernel:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-@pytest.mark.slow  # heavy compile; full suite covers it
-def test_lane_128_fallback_env_knob():
-    """JUMBO_PALLAS_LANE=128 (the documented escape hatch for TPU
-    generations where Mosaic rejects sub-128 minor dims) must produce the
-    same forward and gradients. LANE is bound at import, so run in a fresh
-    interpreter."""
+# every switch that once reached the kernels from outside the configuration
+_HOSTILE_ENV = {
+    "JUMBO_PALLAS_MM_F32": "1",
+    "JUMBO_PALLAS_PAD_TO_BLOCK": "1",
+    "JUMBO_PALLAS_LANE": "128",
+    "JUMBO_AUTO_FLASH_MIN_SEQ": "1",
+}
+
+# forward + gradient as a jaxpr (kernel bodies, block shapes and residual
+# buffers are in its text; nothing compiles), and what "auto" resolves to.
+# seq 300 at block 256: 128-lane padding gives 384, padding to the block 512
+_KERNEL_PROGRAM = {
+    "flash": """
+q = jnp.zeros((1, 300, 2, 32), jnp.bfloat16)
+f = lambda q, k, v: A.pallas_flash_attention(q, k, v, 256, 256, True).astype(jnp.float32).sum()
+print(jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1, 2)))(q, q, q))
+""",
+    "causal": """
+qa, qb = jnp.zeros((1, 2, 40, 16), jnp.bfloat16), jnp.zeros((1, 2, 40, 8), jnp.bfloat16)
+f = lambda qa, qb, ka, kb, v: A.pallas_causal_attention(
+    qa, qb, ka, kb, v, 16, True).astype(jnp.float32).sum()
+print(jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4)))(qa, qb, qa, qb[:, 0], qa))
+""",
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(_KERNEL_PROGRAM))
+def test_kernel_program_ignores_the_environment(kernel):
+    """The program is a function of the configuration and the shapes: a fresh
+    interpreter under a hostile environment traces the same kernels as one
+    under a clean environment."""
     import os
     import subprocess
     import sys
     from pathlib import Path
 
-    repo = Path(__file__).resolve().parent.parent
-    env = dict(os.environ)
-    env["JUMBO_PALLAS_LANE"] = "128"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = str(repo) + os.pathsep + env.get("PYTHONPATH", "")
-    code = """
-import jax, jax.numpy as jnp, numpy as np
-jax.config.update('jax_platforms', 'cpu')
-from jumbo_mae_tpu_tpu.ops.pallas import attention as A
-assert A.LANE == 128, A.LANE
-k0 = jax.random.key(0)
-q, k, v = (jax.random.normal(jax.random.fold_in(k0, i), (2, 199, 2, 32), jnp.float32) for i in range(3))
-def ref(q, k, v):
-    p = jax.nn.softmax(jnp.einsum('bqhd,bkhd->bhqk', q, k), -1)
-    return jnp.einsum('bhqk,bkhd->bqhd', p, v)
-def flash(q, k, v):
-    return A.pallas_flash_attention(q, k, v, 128, 128, True)
-np.testing.assert_allclose(np.asarray(flash(q, k, v)), np.asarray(ref(q, k, v)), atol=2e-5)
-g = jax.grad(lambda *a: (flash(*a) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
-gr = jax.grad(lambda *a: (ref(*a) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
-for a, b in zip(g, gr):
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=2e-4)
-print('LANE128-OK')
-"""
-    proc = subprocess.run(
-        [sys.executable, "-c", code],
-        env=env,
-        capture_output=True,
-        text=True,
-        timeout=600,
+    from jumbo_mae_tpu_tpu.utils.procenv import cpu_subprocess_env
+
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from jumbo_mae_tpu_tpu.models.layers import resolve_attn_impl\n"
+        "from jumbo_mae_tpu_tpu.ops.pallas import attention as A\n"
+        "print(resolve_attn_impl('auto', backend='tpu', seq_len=300, dropout=0.0,"
+        " deterministic=True))\n" + _KERNEL_PROGRAM[kernel]
     )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "LANE128-OK" in proc.stdout
+    clean = {k: v for k, v in os.environ.items() if not k.startswith("JUMBO_")}
+    texts = []
+    for extra in ({}, _HOSTILE_ENV):
+        proc = subprocess.run(
+            [sys.executable, "-c", code],
+            env=cpu_subprocess_env(base={**clean, **extra}),
+            cwd=Path(__file__).resolve().parent.parent,
+            capture_output=True,
+            text=True,
+            timeout=300,
+        )
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        texts.append(proc.stdout)
+    assert "pallas_call" in texts[0] and texts[0].startswith("einsum\n")
+    assert texts[0] == texts[1]
 
 
 def test_resolve_attn_impl_auto_policy():

@@ -114,8 +114,8 @@ class TestJumboViT:
         )
 
     def test_remat_policies_match_no_remat(self):
-        """Every remat policy must only change WHAT is recomputed, never the
-        gradient values (the dots policy is the ViT-H/14 bench default)."""
+        """A remat policy must only change WHAT is recomputed, never the
+        gradient values (``dots`` is the ViT-H/14 recipe's)."""
         imgs = jax.random.normal(jax.random.key(3), (2, 32, 32, 3))
         cfg = TINY.replace(labels=10)
         vars_ = JumboViT(cfg).init({"params": jax.random.key(0)}, imgs)
@@ -125,13 +125,12 @@ class TestJumboViT:
             return (out**2).mean()
 
         g1 = jax.grad(loss)(vars_["params"], cfg)
-        for policy in ("dots", "dots_no_batch"):
-            g2 = jax.grad(loss)(
-                vars_["params"], cfg.replace(grad_ckpt=True, remat_policy=policy)
-            )
-            jax.tree_util.tree_map(
-                lambda a, b: np.testing.assert_allclose(a, b, atol=1e-5), g1, g2
-            )
+        g2 = jax.grad(loss)(
+            vars_["params"], cfg.replace(grad_ckpt=True, remat_policy="dots")
+        )
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_allclose(a, b, atol=1e-5), g1, g2
+        )
 
 
 class TestMAEPretrainModel:

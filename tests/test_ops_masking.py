@@ -135,90 +135,3 @@ def test_mask_algebra():
     np.testing.assert_allclose(
         np.asarray(mask_not(jnp.array([[0.3, 0.0]]))), [[0.7, 1.0]], rtol=1e-6
     )
-
-
-# --------------------------------------------------------------------------
-# onehot (MXU-matmul) gather lowering — must be BIT-identical to take
-# --------------------------------------------------------------------------
-
-
-def test_onehot_index_sequence_bit_identical():
-    from jumbo_mae_tpu_tpu.ops.masking import index_sequence
-
-    x = jax.random.normal(jax.random.key(0), (4, 12, 8), jnp.float32)
-    ids1 = jnp.asarray([3, 0, 11, 7, 5])
-    np.testing.assert_array_equal(
-        np.asarray(index_sequence(x, ids1, impl="onehot")),
-        np.asarray(index_sequence(x, ids1, impl="take")),
-    )
-    ids2 = jnp.stack([jnp.roll(jnp.arange(12), s)[:6] for s in range(4)])
-    np.testing.assert_array_equal(
-        np.asarray(index_sequence(x, ids2, impl="onehot")),
-        np.asarray(index_sequence(x, ids2, impl="take")),
-    )
-    # bf16 too: 0/1 matmul is exact in any dtype
-    xb = x.astype(jnp.bfloat16)
-    np.testing.assert_array_equal(
-        np.asarray(index_sequence(xb, ids1, impl="onehot"), np.float32),
-        np.asarray(index_sequence(xb, ids1, impl="take"), np.float32),
-    )
-
-
-@pytest.mark.parametrize("mode", ["shared", "per_sample"])
-def test_onehot_unshuffle_bit_identical(mode):
-    from jumbo_mae_tpu_tpu.ops.masking import (
-        random_masking,
-        unshuffle_with_mask_tokens,
-    )
-
-    x = jax.random.normal(jax.random.key(1), (4, 16, 8), jnp.bfloat16)
-    kept, mask, ids_restore = random_masking(
-        x, jax.random.key(2), 6, mode=mode
-    )
-    token = jax.random.normal(jax.random.key(3), (1, 1, 8), jnp.bfloat16)
-    a = unshuffle_with_mask_tokens(kept, token, ids_restore, impl="take")
-    b = unshuffle_with_mask_tokens(kept, token, ids_restore, impl="onehot")
-    np.testing.assert_array_equal(
-        np.asarray(a, np.float32), np.asarray(b, np.float32)
-    )
-
-
-def test_gather_impl_end_to_end_same_loss():
-    """The model-level knob: identical loss under jit for both lowerings."""
-    from jumbo_mae_tpu_tpu.models import DecoderConfig, MAEPretrainModel, preset
-
-    imgs = np.random.RandomState(0).randint(0, 256, (2, 32, 32, 3), np.uint8)
-    rngs = {"params": jax.random.key(0), "noise": jax.random.key(1)}
-    losses = {}
-    for impl in ("take", "onehot"):
-        enc = preset(
-            "vit_t16",
-            image_size=32,
-            patch_size=8,
-            mask_ratio=0.75,
-            labels=None,
-            dtype="float32",
-            gather_impl=impl,
-        )
-        dec = DecoderConfig(layers=1, dim=32, heads=2, dtype="float32")
-        model = MAEPretrainModel(enc, dec)
-        variables = model.init(rngs, imgs)
-        out = jax.jit(
-            lambda v, m=model: m.apply(
-                v, imgs, rngs={"noise": jax.random.key(7)}
-            )
-        )(variables)
-        losses[impl] = float(out["loss"])
-    assert losses["take"] == losses["onehot"], losses
-
-
-def test_gather_impl_validated():
-    from jumbo_mae_tpu_tpu.ops.masking import index_sequence
-
-    x = jnp.zeros((2, 4, 3))
-    with pytest.raises(ValueError, match="gather impl"):
-        index_sequence(x, jnp.array([0, 1]), impl="one_hot")
-    with pytest.raises(ValueError, match="gather impl"):
-        unshuffle_with_mask_tokens(
-            x[:, :2], jnp.zeros((1, 1, 3)), jnp.array([0, 1, 2, 3]), impl="gather"
-        )

@@ -1,8 +1,7 @@
 #!/usr/bin/env python3
 """Two-leg inference benchmark: naive per-request jit vs the batched engine.
 
-Same discipline as the training bench (``bench.py``): both legs run the
-identical forward (the feature head by default) on the identical request
+Both legs run the identical forward (the feature head by default) on the identical request
 stream — N single-image requests — and the JSON line reports throughput,
 latency percentiles, and compile counts for each leg:
 

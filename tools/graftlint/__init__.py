@@ -57,7 +57,7 @@ __all__ = [
 ]
 
 # What a bare ``python -m tools.graftlint`` scans, relative to the root.
-DEFAULT_PATHS = ("jumbo_mae_tpu_tpu", "tools", "bench.py")
+DEFAULT_PATHS = ("jumbo_mae_tpu_tpu", "tools")
 
 
 @dataclass

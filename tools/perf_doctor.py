@@ -3,7 +3,7 @@
 compiled-cost story of one training run's journal.
 
 Ledger mode (the default — point it at a ``BENCH_HISTORY.jsonl`` written by
-``bench.py`` / ``tools/bench_infer.py``):
+``tools/bench_infer.py`` or ``tools/loadgen.py``):
 
 - groups rows by (bench, metric, env_key) — rows are only ever baselined
   against history from the *same* environment fingerprint subset;
