@@ -34,6 +34,7 @@ import contextlib
 import gc
 import json
 import logging
+import re
 import shutil
 import sys
 import time
@@ -363,18 +364,48 @@ def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, *,
     return {"mosaic_custom_call": not interpret, "max_rel_err_vs_xla": worst}
 
 
+def causal_kernel_calls(text: str) -> dict:
+    """How often a compiled program's text calls each of the causal core's
+    three kernels: ``{"fwd": n, "dq": n, "dkv": n}``."""
+    return {k: len(re.findall(rf'custom-call\([^\n]*/causal_attention_{k}/pallas_call"', text))
+            for k in ("fwd", "dq", "dkv")}
+
+
+def check_step_runs_each_causal_kernel_once_a_block(programs: dict, lm) -> dict:
+    """The step program among ``programs`` runs each of the causal core's
+    kernels once for each of ``lm``'s blocks: a rematted block keeps the
+    forward kernel's output and log-sum-exp, so a second forward run says the
+    remat policy lost the two names. Off the chip the core resolves to its
+    einsum form and the step holds no kernel at all."""
+    import jax
+
+    check("train_step" in programs, "cli.train noted no step program")
+    calls = causal_kernel_calls(programs["train_step"].as_text())
+    want = lm.layers + lm.mtp_layers if jax.default_backend() == "tpu" else 0
+    check(set(calls.values()) == {want},
+          f"the step calls the causal kernels {calls}, not {want} times each")
+    return calls
+
+
 def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: int) -> dict:
     """``cli.train`` on the language-model recipe for ``steps`` steps of
     seeded tokens, every step's metrics logged: every loss finite; each of
     the cycled batches' loss lower the second time it is seen; nothing
     dropped by an expert layer, whose held pairs fit one round of its chunk
-    at the recipe's routing; no step skipped by the guard."""
+    at the recipe's routing; no step skipped by the guard; the step program
+    runs each of the causal core's kernels once a block."""
     from jumbo_mae_tpu_tpu.cli import train as cli_train
+    from jumbo_mae_tpu_tpu.models.lm import MlaMoeConfig
+    from jumbo_mae_tpu_tpu.obs.trace import keeping_programs
 
     before = _registry_snapshot()
-    cli_train.main(_train_argv(recipe, overrides, out_dir))
+    with keeping_programs() as programs:  # the trainer's step dies with its loop
+        cli_train.main(_train_argv(recipe, overrides, out_dir))
     after = _registry_snapshot()
     cfg = _load(recipe, overrides)
+    calls = check_step_runs_each_causal_kernel_once_a_block(
+        programs, MlaMoeConfig(**cfg.model.lm))
+    programs.clear()  # or the step's executable outlives the phase
     records = _read_metrics(out_dir / cfg.run.name)
     losses = _logged_losses(records, 1, steps, "lm_train")
     cycle = 8  # data/synthetic.token_batches cycles 8 distinct batches
@@ -399,6 +430,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         "loss_last": round(losses[steps], 4),
         "moe_dropped": 0,
         "moe_rounds": 1,
+        "causal_kernel_calls": calls,
         "moe_held_share_min_max": [round(min(share), 4), round(max(share), 4)],
         "moe_imbalance_max": round(max(r["train/moe_imbalance"] for r in by_step.values()), 3),
         "skipped_steps": 0,

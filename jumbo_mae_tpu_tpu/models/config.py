@@ -19,20 +19,36 @@ Pooling = Literal["cls", "gap"]
 AttnImpl = Literal["einsum", "flash", "ring", "auto"]
 MaskModeT = Literal["shared", "per_sample"]
 # rematerialization policy under grad_ckpt=True:
-#   "none" — save nothing, recompute the whole block (max memory win)
-#   "dots" — save every matmul output, recompute elementwise only
+#   "none" — recompute the whole block; keep only the causal attention core's
+#            output and log-sum-exp, where a block has that kernel
+#   "dots" — also save every matmul output, recompute elementwise only
 RematPolicy = Literal["none", "dots"]
 
 
 def checkpoint_policy(name: str):
-    """Map a RematPolicy name to the jax.checkpoint policy callable (None =
-    nothing saveable, jax.checkpoint's default)."""
+    """Map a RematPolicy name to the jax.checkpoint policy callable.
+
+    Every policy keeps the arrays that carry the causal core's two checkpoint
+    names (``ops/pallas/attention.py``): the forward kernel's output and its
+    log-sum-exp, the only residuals of its backward kernels that recomputing
+    the block's projections does not rebuild. A block that keeps them runs the
+    forward kernel once. The cost is fixed by shapes: ``heads × v_head_dim``
+    values in the compute dtype plus one float32 a head, for each token and
+    layer (32 × 128 bf16 + 32 × 4 B = 8.3 KB against the 4 KB of block input a
+    remat keeps anyway, at the JoyAI share's widths). An array carries a name
+    only where that kernel's forward rule produced it: a block without it
+    (the ViT's, the MAE decoder's, the einsum form of the causal core) saves
+    under ``"none"`` nothing and under ``"dots"`` its matmul outputs."""
     import jax
 
+    from jumbo_mae_tpu_tpu.ops.pallas.attention import CAUSAL_LSE_NAME, CAUSAL_OUT_NAME
+
+    policies = jax.checkpoint_policies
+    named = policies.save_only_these_names(CAUSAL_OUT_NAME, CAUSAL_LSE_NAME)
     if name == "none":
-        return None
+        return named
     if name == "dots":
-        return jax.checkpoint_policies.dots_saveable
+        return policies.save_from_both_policies(policies.dots_saveable, named)
     raise ValueError(f"unknown remat policy {name!r}")
 
 

@@ -16,7 +16,8 @@ and the compiled step programs a trace is joined with.
 - :func:`note_program` / :func:`programs` — the compiled step programs of
   this process by name, recorded where they are built, so that whoever
   reduces a trace can ask the executable for its HLO text. Nothing is
-  serialised or parsed here.
+  serialised or parsed here. :func:`keeping_programs` holds the ones noted
+  inside a block past their builder's life.
 - :func:`trace` — capture a ``jax.profiler`` device trace into a directory.
 """
 
@@ -121,9 +122,15 @@ class span_timer:  # noqa: N801 - context-manager factory, used like span()
 _PROGRAMS: "weakref.WeakValueDictionary[str, object]" = weakref.WeakValueDictionary()
 
 
+# the dicts of the ``keeping_programs()`` blocks that are open
+_KEPT: list[dict] = []
+
+
 def note_program(name: str, compiled) -> None:
     """Record a compiled step program under ``name`` (the latest wins)."""
     _PROGRAMS[name] = compiled
+    for kept in _KEPT:
+        kept[name] = compiled
 
 
 def programs() -> dict:
@@ -131,6 +138,19 @@ def programs() -> dict:
     still alive; ``compiled.as_text()`` carries the ``op_name`` of every
     instruction a device trace shows."""
     return dict(_PROGRAMS)
+
+
+@contextmanager
+def keeping_programs():
+    """Yields a ``{name: compiled}`` that holds every program noted inside
+    the block: for a caller that reads a program's text after the loop that
+    built it has returned (``programs()`` forgets a step with its builder)."""
+    kept: dict = {}
+    _KEPT.append(kept)
+    try:
+        yield kept
+    finally:
+        _KEPT.remove(kept)
 
 
 @contextmanager

@@ -35,6 +35,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -437,6 +438,18 @@ pallas_flash_attention_with_lse.defvjp(_vjp_lse_fwd, _vjp_lse_bwd)
 # pad key lies after every real query, so causality already hides it, and a
 # pad query's output is sliced off, so its cotangent is zero and every
 # backward contribution from it vanishes.
+#
+# Of the seven residuals the backward kernels read, five are the caller's
+# arguments; the forward kernel itself makes two, the output ``o`` and the
+# log-sum-exp ``lse``, and the forward rule names both (``CAUSAL_OUT_NAME``,
+# ``CAUSAL_LSE_NAME``). A ``jax.checkpoint`` whose policy saves those names
+# (``models/config.checkpoint_policy`` does, under every policy) keeps the two
+# arrays across rematerialisation, so the recomputed forward kernel has no
+# consumer left and is dead code: one forward run a layer, not two. ``lse``
+# is kept compact, one float32 a (head, token), and widened to ``LANE`` only
+# at the backward kernels' entry: in HBM the ``LANE``-wide form is tiled to
+# 128 lanes, 128 float32 a (head, token). Outside a remat a name is an
+# identity.
 # ---------------------------------------------------------------------------
 
 
@@ -444,6 +457,9 @@ pallas_flash_attention_with_lse.defvjp(_vjp_lse_fwd, _vjp_lse_bwd)
 # probabilities and their bf16 copies pass the 16 MiB default
 CAUSAL_VMEM_BYTES = 64 * 1024 * 1024
 CAUSAL_BLOCK = 1024
+# checkpoint names of the two residuals the forward kernel makes
+CAUSAL_OUT_NAME = "causal_attention_out"
+CAUSAL_LSE_NAME = "causal_attention_lse"
 
 
 def _lower_triangle(n: int, *, by_key: bool):
@@ -650,7 +666,7 @@ def _causal_fwd(qa, qb, ka, kb, v, block, interpret):
         dtype=qa.dtype, b=b, h=h, s_pad=s_pad, block=block, by_key=False,
         interpret=interpret, name="causal_attention_fwd",
     )
-    return o[:, :, :s], lse
+    return o[:, :, :s], lse[..., 0]
 
 
 def _causal_bwd(qa, qb, ka, kb, v, o, lse, g, block, interpret):
@@ -661,6 +677,7 @@ def _causal_bwd(qa, qb, ka, kb, v, o, lse, g, block, interpret):
     # D = rowsum(dO ∘ O), as for the non-causal kernels: tiny, elementwise
     dd = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1, keepdims=True)
     dd = jnp.broadcast_to(dd, (b, h, s_pad, LANE))
+    lse = jnp.broadcast_to(lse[..., None], (b, h, s_pad, LANE))
     n = s_pad // block
     operands = [(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k"),
                 (g, "q"), (lse, "q"), (dd, "q")]
@@ -704,6 +721,9 @@ def pallas_causal_attention(
 
 def _causal_vjp_fwd(q_a, q_b, k_a, k_b, v, block, interpret):
     o, lse = _causal_fwd(q_a, q_b, k_a, k_b, v, block, interpret)
+    # the primal output and the residual are the one named array
+    o = checkpoint_name(o, CAUSAL_OUT_NAME)
+    lse = checkpoint_name(lse, CAUSAL_LSE_NAME)
     return o, (q_a, q_b, k_a, k_b, v, o, lse)
 
 

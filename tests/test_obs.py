@@ -304,6 +304,28 @@ def test_first_train_step_records_its_build_and_its_program(monkeypatch):
     assert "guard/jit(_where)/select_n" in compiled.as_text() and asked
 
 
+def test_keeping_programs_outlives_the_builder():
+    """``programs()`` forgets a program with the step that built it;
+    ``keeping_programs()`` holds what is noted inside its block, the latest
+    under a name, and nothing noted after it."""
+    import gc
+
+    from jumbo_mae_tpu_tpu.obs.trace import keeping_programs, note_program
+
+    Program = type("Program", (), {})
+    with keeping_programs() as kept:
+        note_program("probe_step", Program())
+        second = Program()
+        note_program("probe_step", second)
+        del second
+    note_program("probe_late", Program())
+    gc.collect()
+    assert list(kept) == ["probe_step"] and programs()["probe_step"] is kept["probe_step"]
+    kept.clear()
+    gc.collect()
+    assert "probe_step" not in programs() and "probe_late" not in programs()
+
+
 def test_prefetch_to_device_records_one_h2d_per_batch():
     import jax
     from jax.sharding import SingleDeviceSharding
