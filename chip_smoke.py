@@ -6,6 +6,8 @@ points, at the full width of a model the repo ships.
                                             #   resume, serve, lm_kernels,
                                             #   lm_train
     python3 chip_smoke.py --phases lm_kernels,lm_train   # only those
+    python3 chip_smoke.py --phases lm_train --lm-recipe recipes/pretrain_ling3_flash_ep64.yaml
+                                            # lm_train on the other language family
     python3 chip_smoke.py --chips 4         # four-chip host: sharded training
                                             #   against its one-chip control,
                                             #   and no other phase
@@ -373,7 +375,7 @@ def causal_kernel_calls(text: str) -> dict:
 
 def check_step_runs_each_causal_kernel_once_a_block(programs: dict, lm) -> dict:
     """The step program among ``programs`` runs each of the causal core's
-    kernels once for each of ``lm``'s blocks: a rematted block keeps the
+    kernels once for each of ``lm``'s latent-attention blocks: a rematted block keeps the
     forward kernel's output and log-sum-exp, so a second forward run says the
     remat policy lost the two names. Off the chip the core resolves to its
     einsum form and the step holds no kernel at all."""
@@ -381,7 +383,8 @@ def check_step_runs_each_causal_kernel_once_a_block(programs: dict, lm) -> dict:
 
     check("train_step" in programs, "cli.train noted no step program")
     calls = causal_kernel_calls(programs["train_step"].as_text())
-    want = lm.layers + lm.mtp_layers if jax.default_backend() == "tpu" else 0
+    # a linear-attention block has no causal core
+    want = lm.layers - lm.kda_layers + lm.mtp_layers if jax.default_backend() == "tpu" else 0
     check(set(calls.values()) == {want},
           f"the step calls the causal kernels {calls}, not {want} times each")
     return calls
@@ -393,7 +396,10 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     the cycled batches' loss lower the second time it is seen; nothing
     dropped by an expert layer, whose held pairs fit one round of its chunk
     at the recipe's routing; no step skipped by the guard; the step program
-    runs each of the causal core's kernels once a block."""
+    runs each of the causal core's kernels once a latent-attention block;
+    where the recipe has linear-attention layers, their counters are logged
+    on every step and their states stay bounded. ``recipe`` is either
+    language family's (``--lm-recipe``)."""
     from jumbo_mae_tpu_tpu.cli import train as cli_train
     from jumbo_mae_tpu_tpu.models.lm import MlaMoeConfig
     from jumbo_mae_tpu_tpu.obs.trace import keeping_programs
@@ -403,8 +409,8 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         cli_train.main(_train_argv(recipe, overrides, out_dir))
     after = _registry_snapshot()
     cfg = _load(recipe, overrides)
-    calls = check_step_runs_each_causal_kernel_once_a_block(
-        programs, MlaMoeConfig(**cfg.model.lm))
+    lm = MlaMoeConfig(**cfg.model.lm)
+    calls = check_step_runs_each_causal_kernel_once_a_block(programs, lm)
     programs.clear()  # or the step's executable outlives the phase
     records = _read_metrics(out_dir / cfg.run.name)
     losses = _logged_losses(records, 1, steps, "lm_train")
@@ -418,6 +424,14 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     check(rounds == [1], f"an expert layer's held pairs took other than one round: {rounds}")
     skipped = _delta(before, after, "train_steps_skipped_total", "")
     check(skipped == 0, f"{skipped} step(s) skipped by the divergence guard")
+    kda = {}
+    if lm.kda_layers:
+        states = [r.get("train/kda_state_absmax") for r in by_step.values()]
+        check(all(s is not None and 0 < s < 100 for s in states),
+              f"a linear-attention state is missing or unbounded: {states}")
+        decay = [r["train/kda_decay_mean"] for r in by_step.values()]
+        kda = {"kda_state_absmax_max": round(max(states), 4),
+               "kda_decay_mean_min_max": [round(min(decay), 4), round(max(decay), 4)]}
     retraces = _delta(before, after, "retrace_events_total", "train")
     check(retraces == 0, f"{retraces} unexpected recompile(s) after warmup")
     last = max((r for r in records if "perf/tokens_per_sec_per_chip" in r),
@@ -433,6 +447,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         "causal_kernel_calls": calls,
         "moe_held_share_min_max": [round(min(share), 4), round(max(share), 4)],
         "moe_imbalance_max": round(max(r["train/moe_imbalance"] for r in by_step.values()), 3),
+        **kda,
         "skipped_steps": 0,
         "tokens_per_sec_per_chip": round(last["perf/tokens_per_sec_per_chip"], 1),
         "mfu_trainer_reported": last.get("perf/mfu"),
@@ -805,6 +820,8 @@ def main(argv: list[str] | None = None) -> int:
                     help="4: only the sharded-training phase and its control")
     ap.add_argument("--phases", default="",
                     help="comma-separated names: run only these one-chip phases")
+    ap.add_argument("--lm-recipe", default=LM_RECIPE,
+                    help="the language-model recipe of the lm_train phase (either family's)")
     args = ap.parse_args(argv)
 
     from jumbo_mae_tpu_tpu.utils.procenv import enable_compile_cache
@@ -846,7 +863,7 @@ def main(argv: list[str] | None = None) -> int:
                 L16_RECIPE, resume, out, start=steps, steps=more, watch=watch)),
             ("serve", lambda: phase_serve(L16_RECIPE, [], out)),
             ("lm_kernels", phase_lm_kernels),
-            ("lm_train", lambda: phase_lm_train(LM_RECIPE, _lm_overrides(24), out, steps=24)),
+            ("lm_train", lambda: phase_lm_train(args.lm_recipe, _lm_overrides(24), out, steps=24)),
         ]
         if args.phases:
             only = args.phases.split(",")

@@ -1190,6 +1190,13 @@ def train(cfg: TrainConfig) -> dict:
         "held expert, imbalance, held share, dropped, per layer and overall",
         labels=("counter",),
     )
+    g_kda = reg.gauge(
+        "train_kda",
+        "linear-attention counters of the last fetched step (mode lm): "
+        "largest |state| at the end of the sequences and mean decay a step, "
+        "per layer and overall",
+        labels=("counter",),
+    )
     g_hfu = reg.gauge(
         "train_hardware_flops_utilization",
         "XLA-counted flops (remat recompute included) / peak (log-window)",
@@ -1372,6 +1379,8 @@ def train(cfg: TrainConfig) -> dict:
             for key, value in m.items():
                 if key.startswith("moe_"):
                     g_moe.labels(key[len("moe_"):]).set(float(value))
+                elif key.startswith("kda_"):
+                    g_kda.labels(key[len("kda_"):]).set(float(value))
             if flightrec is not None:
                 entry = {"loss": loss_v}
                 if gn is not None:

@@ -1,26 +1,59 @@
-"""Decoder-only language model with multi-head latent attention (MLA),
-sigmoid-routed sparse experts beside a shared expert, and one multi-token
-prediction (MTP) module: the DeepSeek-V3 family's block, as
-``JoyAI-LLM-Flash``'s ``config.json`` sizes it.
+"""Decoder-only sparse-expert language models, two families from one set of
+blocks. **All-MLA** (the DeepSeek-V3 family's block, as ``JoyAI-LLM-Flash``'s
+``config.json`` sizes it): multi-head latent attention in every block,
+sigmoid-routed experts beside a shared expert, one multi-token prediction
+(MTP) module. **Hybrid** (``Ling-3.0-flash``, ``model_type: bailing_hybrid``):
+with ``layer_group_size = p > 0`` block ``i`` is MLA when ``(i + 1) % p == 0``
+and Kimi delta attention (KDA, a linear attention) otherwise; MLA has no
+query latent, both kinds end in a head-wise gate, and the router's choice is
+limited to a token's best groups of experts. The defaults are the first
+family's; its parameter tree, scopes and program do not depend on the
+second's fields.
 
-Pre-norm residual blocks with RMSNorm. No bias anywhere.
+Pre-norm residual blocks with RMSNorm (eps ``rms_eps``): ``x += A_i(norm(x))``,
+``x += F_i(norm(x))``. No bias anywhere.
 
-MLA: ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` -> heads x (nope ‖ rope);
-``[c_kv ‖ k_pe] = x W_kva``, ``[k_nope ‖ v] = RMSNorm(c_kv) W_kvb`` -> heads x
-(nope ‖ v); ``k = [k_nope ‖ rope(k_pe)]`` with the one ``k_pe`` a token shared
-by every head; RoPE on adjacent pairs; causal
-``softmax(q kᵀ (nope + rope)^-½) v``; ``W_o`` over heads x v.
+MLA: ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` -> heads x (nope ‖ rope) — or
+``q = x W_q`` where ``q_lora_rank`` is None; ``[c_kv ‖ k_pe] = x W_kva``,
+``[k_nope ‖ v] = RMSNorm(c_kv) W_kvb`` -> heads x (nope ‖ v); ``k = [k_nope ‖
+rope(k_pe)]`` with the one ``k_pe`` a token shared by every head; RoPE on
+adjacent pairs; causal ``z = softmax(q kᵀ (nope + rope)^-½) v``; with
+``attn_gate``, ``z_h ← sigmoid(x W_γ)_h · z_h``; ``W_o`` over heads x v.
 
-MLP: ``W_d(silu(W_g x) ⊙ W_u x)``.
+KDA, per head ``h`` with ``d_k = d_v = kda_head_dim`` (as many key and value
+heads as query heads): ``q̃, k̃, ṽ = x W_q, x W_k, x W_v``; each through a
+causal depthwise convolution of ``kda_conv`` taps, one filter a channel, zero
+history before the sequence (one document a sequence), then SiLU:
+``u_t = silu(Σ_j c_j ⊙ ũ_{t−K+1+j})``; ``q̂ = q / ‖q‖₂ · d_k^-½``, ``k̂ = k /
+‖k‖₂`` (eps 1e-6; no norm of ``v``); the per-channel log-decay in its safe
+form, ``g_t = lower_bound · sigmoid(exp(A_log_h) · (x W_f + dt_bias))`` with
+one ``A_log`` a head and one ``dt_bias`` a channel, so ``g`` lies in
+``(lower_bound, 0)`` and ``α_t = exp(g_t)``; ``β_t = sigmoid(x W_b)``, one a
+head. The state ``S`` (d_k, d_v) starts at zero:
 
-Router, in float32: ``s = sigmoid(x W_r)``; the top ``k`` of ``s + b``;
-weights ``factor · s_i / Σ_chosen s``. ``b`` is no parameter: it lives in the
-``batch_stats`` collection and moves after each applied step by
-``b += rate · sign(mean(c) − c)``, ``c`` the step's counts over all outputs.
+    S_t = (I − β_t k̂_t k̂_tᵀ) Diag(α_t) S_{t−1} + β_t k̂_t v_tᵀ,   o_t = S_tᵀ q̂_t
+
+computed in chunks of ``kda_chunk`` positions (``ops/kda.py``: the
+triangular form inside a chunk, a scan that carries ``S`` between chunks,
+``g`` and ``S`` in float32). ``y = concat_h(sigmoid(x W_γ)_h ·
+RMSNorm(o_h)) W_o``, the norm over ``d_v`` with one learned scale shared by
+the heads. A layer reports the largest ``|S|`` at the end of the sequences
+and the mean ``α`` (``KDA_COUNTERS``).
+
+MLP: ``W_d(silu(W_g x) ⊙ W_u x)``. The clamped SwiGLU (a non-zero
+``*_swiglu_limit``) is not implemented and is refused.
+
+Router, in float32: ``s = sigmoid(x W_r)``, ``t = s + b``; with ``n_group >
+1`` the experts lie in ``n_group`` equal groups, a group's score is the sum
+of its two largest ``t``, and only the best ``topk_group`` groups' experts
+stay eligible; the top ``k`` eligible by ``t``; weights ``factor · s_i /
+Σ_chosen s``. ``b`` is no parameter: it lives in the ``batch_stats``
+collection and moves after each applied step by ``b += rate · sign(mean(c) −
+c)``, ``c`` the step's counts over all outputs.
 
 **The chip's share.** ``experts_held = (e0, n)`` says which routed experts
-this chip holds. The router keeps its full width and its ``k``; the weights
-are normalised over all ``k`` chosen; the layer's output is
+this chip holds. The router keeps its full width, its groups and its ``k``;
+the weights are normalised over all ``k`` chosen; the layer's output is
 ``shared(x) + Σ_{chosen i, e0 <= i < e0 + n} w_i E_i(x)`` — what the absent
 experts would add is left out, and nothing stands in for them or for their
 exchange. ``vocab_rows = (v0, n)`` likewise: embedding and head hold rows
@@ -38,8 +71,9 @@ chip holds, and no routing, however uneven, drops a token: it takes more
 rounds.
 
 MTP: ``h'_i = W_eh [RMSNorm(Emb(t_{i+1})) ‖ RMSNorm(h_i)]`` -> one block of
-the expert kind -> the trunk's final norm and head, predicting ``t_{i+2}``.
-Loss = CE(trunk) + ``mtp_loss_weight`` · CE(MTP), mean over tokens.
+the MLA kind with experts -> the trunk's final norm and head, predicting
+``t_{i+2}``. Loss = CE(trunk) + ``mtp_loss_weight`` · CE(MTP), mean over
+tokens.
 """
 
 from __future__ import annotations
@@ -60,6 +94,11 @@ from jumbo_mae_tpu_tpu.obs.trace import (
     SCOPE_DENSE_MLP,
     SCOPE_EMBED,
     SCOPE_EXPERTS,
+    SCOPE_KDA_CONV,
+    SCOPE_KDA_CORE,
+    SCOPE_KDA_GATE,
+    SCOPE_KDA_OUT,
+    SCOPE_KDA_PROJ,
     SCOPE_LM_HEAD,
     SCOPE_MLA_LATENT,
     SCOPE_MOE_DISPATCH,
@@ -70,16 +109,20 @@ from jumbo_mae_tpu_tpu.obs.trace import (
 )
 from jumbo_mae_tpu_tpu.ops.flash_attention import causal_attention
 from jumbo_mae_tpu_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, grouped_outer
+from jumbo_mae_tpu_tpu.ops.kda import causal_conv_silu, kda_chunked
 
 # the counters an expert layer reports, in the order of its stats vector
 MOE_COUNTERS = ("rows_min", "rows_mean", "rows_max", "imbalance", "held_share", "dropped",
                 "rounds")
+# the counters a linear-attention layer reports, in the order of its stats vector
+KDA_COUNTERS = ("state_absmax", "decay_mean")
 
 
 @dataclass(frozen=True)
 class MlaMoeConfig:
-    """Sizes as ``config.json`` names them (``JoyAI-LLM-Flash`` defaults),
-    plus what this chip holds of them."""
+    """Sizes as ``config.json`` names them (``JoyAI-LLM-Flash`` defaults: the
+    all-MLA family), plus what this chip holds of them; the hybrid family's
+    fields below ``init_std``."""
 
     vocab_size: int = 129280
     vocab_rows: tuple[int, int] | None = None  # (first row, rows held); None = all
@@ -87,7 +130,7 @@ class MlaMoeConfig:
     layers: int = 40  # num_hidden_layers: trunk blocks, the dense ones first
     first_k_dense: int = 1  # first_k_dense_replace
     heads: int = 32
-    q_lora_rank: int = 1536
+    q_lora_rank: int | None = 1536  # None: q = x W_q, no query latent
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -97,7 +140,10 @@ class MlaMoeConfig:
     n_routed_experts: int = 256
     experts_held: tuple[int, int] | None = None  # (first expert, experts held); None = all
     n_shared_experts: int = 1
+    shared_expert_hidden: int | None = None  # moe_shared_expert_intermediate_size; None = expert_hidden
     experts_per_token: int = 8
+    n_group: int = 1  # the experts lie in n_group groups, of which a token's
+    topk_group: int = 1  # best topk_group stay eligible (n_group 1: no limit)
     routed_scaling_factor: float = 2.5
     router_bias_rate: float = 0.001  # assumed: the noaux_tc rule's step
     mtp_layers: int = 1  # num_nextn_predict_layers (0 or 1)
@@ -105,6 +151,17 @@ class MlaMoeConfig:
     rope_theta: float = 32e6
     rms_eps: float = 1e-6
     init_std: float = 0.02  # assumed
+    # the hybrid family: block i is of the MLA kind when (i + 1) is a multiple
+    # of layer_group_size and of the KDA kind otherwise; 0 = every block MLA
+    layer_group_size: int = 0
+    kda_head_dim: int = 128  # head_dim: d_k = d_v of a linear-attention head
+    kda_conv: int = 4  # short_conv_kernel_size
+    kda_lower_bound: float = -5.0  # the safe gate's floor of the log-decay
+    kda_chunk: int = 64  # positions a chunk of the scan (ops/kda.py)
+    attn_gate: bool = False  # head-wise sigmoid gate on the attention output
+    # the clamped SwiGLU is not implemented: a non-zero limit is refused
+    expert_swiglu_limit: float = 0.0
+    shared_expert_swiglu_limit: float = 0.0
 
     grad_ckpt: bool = True
     remat_policy: RematPolicy = "none"
@@ -118,6 +175,14 @@ class MlaMoeConfig:
                 object.__setattr__(self, name, tuple(int(v) for v in value))
         if self.mtp_layers not in (0, 1):
             raise ValueError("mtp_layers must be 0 or 1")
+        if self.expert_swiglu_limit or self.shared_expert_swiglu_limit:
+            raise ValueError("a non-zero SwiGLU limit (the clamped SwiGLU) is not implemented")
+        if self.n_routed_experts % self.n_group or not 0 < self.topk_group <= self.n_group:
+            raise ValueError(f"n_group {self.n_group} / topk_group {self.topk_group} do not "
+                             f"divide the {self.n_routed_experts} routed experts")
+        if self.n_group > 1 and self.topk_group * (self.n_routed_experts // self.n_group) \
+                < self.experts_per_token:
+            raise ValueError("the groups kept hold fewer experts than a token picks")
         e0, n = self.held
         if not (0 <= e0 and n > 0 and e0 + n <= self.n_routed_experts):
             raise ValueError(f"experts_held {self.experts_held} outside the "
@@ -133,6 +198,18 @@ class MlaMoeConfig:
     @property
     def rows(self) -> tuple[int, int]:
         return self.vocab_rows or (0, self.vocab_size)
+
+    def is_kda(self, layer: int) -> bool:
+        """Whether trunk block ``layer`` is of the linear-attention kind."""
+        return self.layer_group_size > 0 and (layer + 1) % self.layer_group_size != 0
+
+    @property
+    def kda_layers(self) -> int:
+        return sum(self.is_kda(i) for i in range(self.layers))
+
+    @property
+    def shared_hidden(self) -> int:
+        return self.n_shared_experts * (self.shared_expert_hidden or self.expert_hidden)
 
     @property
     def qk_head_dim(self) -> int:
@@ -200,13 +277,18 @@ class LatentAttention(nn.Module):
         h, dn, dr, dv = cfg.heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         norm = lambda name: RMSNorm(cfg.rms_eps, cfg.compute_dtype, name=name)
         with jax.named_scope(SCOPE_MLA_LATENT):
-            c_q = norm("q_norm")(Proj((cfg.dim, cfg.q_lora_rank), "bsd,dr->bsr", cfg, name="q_a")(x))
-            q = Proj((cfg.q_lora_rank, h, dn + dr), "bsr,rhd->bhsd", cfg, name="q_b")(c_q)
+            if cfg.q_lora_rank is None:
+                q = Proj((cfg.dim, h, dn + dr), "bsd,dhe->bhse", cfg, name="q")(x)
+            else:
+                c_q = norm("q_norm")(Proj((cfg.dim, cfg.q_lora_rank), "bsd,dr->bsr", cfg, name="q_a")(x))
+                q = Proj((cfg.q_lora_rank, h, dn + dr), "bsr,rhd->bhsd", cfg, name="q_b")(c_q)
             q = q * cfg.qk_head_dim**-0.5
             kv = Proj((cfg.dim, cfg.kv_lora_rank + dr), "bsd,dr->bsr", cfg, name="kv_a")(x)
             c_kv, k_pe = kv[..., : cfg.kv_lora_rank], kv[..., cfg.kv_lora_rank:]
             kv = Proj((cfg.kv_lora_rank, h, dn + dv), "bsr,rhd->bhsd", cfg,
                       name="kv_b")(norm("kv_norm")(c_kv))
+            if cfg.attn_gate:
+                gate = Proj((cfg.dim, h), "bsd,dh->bhs", cfg, name="gate")(x)
         with jax.named_scope(SCOPE_ROPE):
             q_pe = rope_interleaved(q[..., dn:], cfg.rope_theta)
             k_pe = rope_interleaved(k_pe, cfg.rope_theta)
@@ -215,7 +297,76 @@ class LatentAttention(nn.Module):
         with jax.named_scope(SCOPE_ATTN_CORE):
             z = causal_attention(q[..., :dn], q_pe, kv[..., :dn], k_pe, kv[..., dn:], impl=impl)
         with jax.named_scope(SCOPE_ATTN_OUT):
+            if cfg.attn_gate:
+                z = _head_gate(z, gate)
             return Proj((h, dv, cfg.dim), "bhsd,hdm->bsm", cfg, name="out")(z)
+
+
+def _head_gate(z, gate):
+    """``sigmoid(gate) · z`` head by head: ``z`` (batch, heads, seq, width),
+    ``gate`` (batch, heads, seq) logits; the sigmoid in float32."""
+    return z * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None].astype(z.dtype)
+
+
+def _unit_norm(x, eps: float):
+    """``x / sqrt(Σ x² + eps)`` over the last axis, float32 inside."""
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.sum(jnp.square(xf), axis=-1, keepdims=True) + eps)
+
+
+def _decay_bias_init(key, shape, a_log, lower_bound):
+    """``dt_bias`` drawn so that the decay a step at a zero gate input,
+    ``exp(lower_bound · sigmoid(exp(A_log) · dt_bias))``, leaves ``1 − α``
+    log-uniform over 0.001 .. 0.1 (α over 0.9 .. 0.999): assumed."""
+    log_miss = jax.random.uniform(key, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1))
+    share = jnp.log1p(-jnp.exp(log_miss)) / lower_bound  # what the sigmoid has to give
+    return (jnp.log(share) - jnp.log1p(-share)) / jnp.exp(a_log)[:, None]
+
+
+class KdaAttention(nn.Module):
+    """Kimi Delta Attention (module docstring). Returns ``(y, stats)``,
+    ``stats`` in ``KDA_COUNTERS`` order."""
+
+    cfg: MlaMoeConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        h, dh, d, f32 = cfg.heads, cfg.kda_head_dim, cfg.dim, jnp.float32
+        dtype = cfg.compute_dtype
+        with jax.named_scope(SCOPE_KDA_PROJ):
+            wide = lambda name: Proj((d, h, dh), "bsd,dhe->bhse", cfg, name=name)(x)
+            thin = lambda name: Proj((d, h), "bsd,dh->bhs", cfg, name=name)(x)
+            q, k, v, a = wide("q"), wide("k"), wide("v"), wide("f")
+            b, gate = thin("b"), thin("gate")
+        with jax.named_scope(SCOPE_KDA_CONV):
+            def filt(name):  # a leaf <name>/kernel: one filter a channel
+                bound = cfg.kda_conv**-0.5
+                make = lambda key: {"kernel": jax.random.uniform(
+                    key, (cfg.kda_conv, h, dh), f32, -bound, bound)}
+                return self.param(name, make)["kernel"]
+
+            q, k, v = (causal_conv_silu(u, filt(f"{n}_conv")) for n, u in
+                       (("q", q), ("k", k), ("v", v)))
+        with jax.named_scope(SCOPE_KDA_GATE):
+            q = (_unit_norm(q, cfg.rms_eps) * dh**-0.5).astype(dtype)
+            k = _unit_norm(k, cfg.rms_eps).astype(dtype)
+            a_log = self.param("A_log", lambda key, shape: jnp.log(
+                jax.random.uniform(key, shape, f32, 0.25, 1.0)), (h,))
+            dt_bias = self.param("dt_bias", _decay_bias_init, (h, dh), a_log,
+                                 cfg.kda_lower_bound)
+            g = cfg.kda_lower_bound * jax.nn.sigmoid(
+                jnp.exp(a_log)[:, None, None] * (a.astype(f32) + dt_bias[:, None, :]))
+            beta = jax.nn.sigmoid(b.astype(f32))
+        with jax.named_scope(SCOPE_KDA_CORE):
+            o, state = kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk)
+        with jax.named_scope(SCOPE_KDA_OUT):
+            with jax.named_scope(SCOPE_KDA_GATE):
+                o = _head_gate(RMSNorm(cfg.rms_eps, dtype, name="o_norm")(o), gate)
+            y = Proj((h, dh, d), "bhse,hed->bsd", cfg, name="out")(o)
+        with jax.named_scope(SCOPE_KDA_GATE):
+            stats = jnp.stack([jnp.abs(state).max(), jnp.exp(g).mean()])
+        return y, jax.lax.stop_gradient(stats)
 
 
 class GatedMlp(nn.Module):
@@ -338,6 +489,18 @@ def _routed_bwd(chunk, impl, interpret, residuals, dy):
 routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 
+def _group_limited(biased, n_group: int, topk_group: int):
+    """``biased`` (tokens, experts) with the experts outside a token's best
+    ``topk_group`` of ``n_group`` equal groups set to ``-inf``; a group's
+    score is the sum of its two largest entries."""
+    n, e = biased.shape
+    grouped = biased.reshape(n, n_group, e // n_group)
+    group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)
+    _, keep = jax.lax.top_k(group_score, topk_group)  # (n, topk_group)
+    kept = (keep[..., None] == jnp.arange(n_group)).any(axis=1)  # (n, n_group)
+    return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(n, e)
+
+
 class SparseExperts(nn.Module):
     """Router over all experts, grouped products over those held here, and
     the shared expert. Returns ``(y, stats)``, ``stats`` in ``MOE_COUNTERS``
@@ -364,7 +527,10 @@ class SparseExperts(nn.Module):
             bias = self.variable(
                 "batch_stats", "router_bias",
                 lambda: 0.01 * jax.random.normal(self.make_rng("params"), (e,), jnp.float32))
-            _, chosen = jax.lax.top_k(scores + bias.value, k)  # (n, k)
+            biased = scores + bias.value
+            if cfg.n_group > 1:
+                biased = _group_limited(biased, cfg.n_group, cfg.topk_group)
+            _, chosen = jax.lax.top_k(biased, k)  # (n, k)
             picked = jnp.take_along_axis(scores, chosen, axis=1)
             weights = cfg.routed_scaling_factor * picked / picked.sum(axis=1, keepdims=True)
             counts = (chosen[..., None] == jnp.arange(e)).sum(axis=(0, 1)).astype(jnp.float32)
@@ -401,30 +567,38 @@ class SparseExperts(nn.Module):
                 rounds.astype(jnp.float32),
             ])
         with jax.named_scope(SCOPE_SHARED_EXPERT):
-            shared = GatedMlp(cfg.n_shared_experts * cfg.expert_hidden, cfg, name="shared")(x)
+            shared = GatedMlp(cfg.shared_hidden, cfg, name="shared")(x)
         return shared + routed.reshape(b, s, d), jax.lax.stop_gradient(stats)
 
 
 class Block(nn.Module):
-    """One pre-norm residual block: latent attention, then the dense MLP
-    (``sparse=False``) or the expert layer. Returns ``(x, stats)``."""
+    """One pre-norm residual block: latent attention, or linear attention
+    where ``kda``, then the dense MLP (``sparse=False``) or the expert layer.
+    Returns ``(x, stats, kda_stats)``: the expert layer's counters and the
+    linear-attention layer's (None where the block has none)."""
 
     cfg: MlaMoeConfig
     sparse: bool
+    kda: bool = False
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True):
         del deterministic  # no dropout; the argument keeps maybe_remat's signature
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.rms_eps, cfg.compute_dtype, name=name)
-        x = x + LatentAttention(cfg, name="attn")(norm("ln1")(x))
+        kda_stats = None
+        if self.kda:
+            y, kda_stats = KdaAttention(cfg, name="attn")(norm("ln1")(x))
+            x = x + y
+        else:
+            x = x + LatentAttention(cfg, name="attn")(norm("ln1")(x))
         if self.sparse:
             y, stats = SparseExperts(cfg, name="moe")(norm("ln2")(x))
         else:
             with jax.named_scope(SCOPE_DENSE_MLP):
                 y = GatedMlp(cfg.dense_hidden, cfg, name="mlp")(norm("ln2")(x))
             stats = jnp.zeros((len(MOE_COUNTERS),), jnp.float32)
-        return x + y, stats
+        return x + y, stats, kda_stats
 
 
 class MlaMoeLM(nn.Module):
@@ -439,8 +613,8 @@ class MlaMoeLM(nn.Module):
         block = maybe_remat(Block, cfg)
         self.embedding = self.param("embedding", _normal(cfg), (cfg.rows[1], cfg.dim),
                                     jnp.float32)
-        self.blocks = [block(cfg, sparse=i >= cfg.first_k_dense, name=f"block_{i}")
-                       for i in range(cfg.layers)]
+        self.blocks = [block(cfg, sparse=i >= cfg.first_k_dense, kda=cfg.is_kda(i),
+                             name=f"block_{i}") for i in range(cfg.layers)]
         self.ln = RMSNorm(cfg.rms_eps, cfg.compute_dtype, name="ln")
         self.head = Proj((cfg.dim, cfg.rows[1]), "bsd,dv->bsv", cfg, name="head")
         if cfg.mtp_layers:
@@ -454,25 +628,27 @@ class MlaMoeLM(nn.Module):
             return self.embedding[ids].astype(self.cfg.compute_dtype)
 
     def _hidden(self, tokens, deterministic: bool):
-        """Both heads' last hidden states ``[trunk, mtp?]`` and the expert
-        layers' stats ``{name: vector}``."""
+        """Both heads' last hidden states ``[trunk, mtp?]``, the expert
+        layers' stats ``{name: vector}`` and the linear-attention layers'."""
         cfg = self.cfg
         seq = tokens.shape[1] - 1 - cfg.mtp_layers
         ids = tokens - cfg.rows[0]
         x = self._embed(ids[:, :seq])
-        stats = {}
+        stats, kda = {}, {}
         for i, blk in enumerate(self.blocks):
-            x, st = blk(x, deterministic)
+            x, st, linear = blk(x, deterministic)
             if i >= cfg.first_k_dense:
                 stats[f"l{i}"] = st
+            if linear is not None:
+                kda[f"l{i}"] = linear
         hidden = [x]
         if cfg.mtp_layers:
             with jax.named_scope(SCOPE_MTP_MERGE):
                 nxt = self.mtp_embed_norm(self._embed(ids[:, 1 : seq + 1]))
                 merged = self.mtp_merge(jnp.concatenate([nxt, self.mtp_hidden_norm(x)], axis=-1))
-            y, stats["mtp"] = self.mtp_block(merged, deterministic)
+            y, stats["mtp"], _ = self.mtp_block(merged, deterministic)
             hidden.append(y)
-        return hidden, stats
+        return hidden, stats, kda
 
     def _logits(self, h):
         return self.head(self.ln(h)).astype(jnp.float32)
@@ -484,7 +660,7 @@ class MlaMoeLM(nn.Module):
     def __call__(self, tokens, deterministic: bool = True):
         cfg = self.cfg
         seq = tokens.shape[1] - 1 - cfg.mtp_layers
-        hidden, stats = self._hidden(tokens, deterministic)
+        hidden, stats, kda = self._hidden(tokens, deterministic)
         ids = tokens - cfg.rows[0]
 
         def cross_entropy(mdl, h, targets):
@@ -512,4 +688,9 @@ class MlaMoeLM(nn.Module):
         col = {c: table[:, j] for j, c in enumerate(MOE_COUNTERS)}
         out |= {"moe_imbalance": col["imbalance"].max(), "moe_held_share": col["held_share"].mean(),
                 "moe_dropped": col["dropped"].sum(), "moe_rounds": col["rounds"].max()}
+        if kda:
+            for name, st in kda.items():
+                out |= {f"kda_{c}_{name}": st[j] for j, c in enumerate(KDA_COUNTERS)}
+            table = jnp.stack(list(kda.values()))  # (linear-attention layers, counters)
+            out |= {"kda_state_absmax": table[:, 0].max(), "kda_decay_mean": table[:, 1].mean()}
         return out

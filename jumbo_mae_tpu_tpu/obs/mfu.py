@@ -115,35 +115,44 @@ def classify_flops_per_image(enc_cfg, *, training: bool = True) -> float:
 
 
 def lm_flops_per_token(cfg, seq: int, *, training: bool = True) -> float:
-    """Matmul FLOPs one token of the latent-attention sparse-expert language
-    model (``models/lm.MlaMoeConfig``) requires at sequence length ``seq``,
-    on this chip's share: the experts and vocabulary rows held. 2·m·n·k per
+    """Matmul FLOPs one token of the sparse-expert language model
+    (``models/lm.MlaMoeConfig``: latent attention, or latent and linear
+    attention layers in a pattern) requires at sequence length ``seq``, on
+    this chip's share: the experts and vocabulary rows held. 2·m·n·k per
     matmul; the causal core counts its lower triangle once (mean context
-    ``seq / 2``); a routed expert is counted for the share of (token, expert)
-    pairs expected here (``k · held / experts``); the embedding is a lookup;
+    ``seq / 2``); a linear-attention core counts the recurrence's three
+    products with its (d_k, d_v) state, ``6 · d_k · d_v`` a head, whatever
+    ``seq`` and the chunking; a routed expert is counted for the share of
+    (token, expert) pairs expected here (``k · held / experts``); the
+    embedding is a lookup and the short convolutions are elementwise;
     backward = 2 x forward, recomputation not counted."""
     d, h = cfg.dim, cfg.heads
     qk, dv = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim
+    query = d * h * qk if cfg.q_lora_rank is None else d * cfg.q_lora_rank + cfg.q_lora_rank * h * qk
     latent = 2 * (
-        d * cfg.q_lora_rank
-        + cfg.q_lora_rank * h * qk
+        query
         + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
         + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + dv)
         + h * dv * d
+        + (d * h if cfg.attn_gate else 0)
     )
     core = 2 * (seq / 2) * h * (qk + dv)
+    dh = cfg.kda_head_dim
+    # q, k, v and the decay gate, beta and the output gate, W_o; the recurrence
+    linear = 2 * (4 * d * h * dh + 2 * d * h + h * dh * d) + 6 * h * dh * dh
     gated = lambda hidden: 2 * 3 * d * hidden
     pairs_here = cfg.experts_per_token * cfg.held[1] / cfg.n_routed_experts
     sparse = (
         2 * d * cfg.n_routed_experts  # router
-        + gated(cfg.n_shared_experts * cfg.expert_hidden)
+        + gated(cfg.shared_hidden)
         + pairs_here * gated(cfg.expert_hidden)
     )
     dense_layers = min(cfg.first_k_dense, cfg.layers)
     sparse_layers = cfg.layers - dense_layers + cfg.mtp_layers
     head = 2 * d * cfg.rows[1]
     fwd = (
-        (cfg.layers + cfg.mtp_layers) * (latent + core)
+        (cfg.layers - cfg.kda_layers + cfg.mtp_layers) * (latent + core)
+        + cfg.kda_layers * linear
         + dense_layers * gated(cfg.dense_hidden)
         + sparse_layers * sparse
         + (1 + cfg.mtp_layers) * head

@@ -65,6 +65,14 @@ SCOPE_SHARED_EXPERT = "shared_expert"  # the expert every token passes
 SCOPE_DENSE_MLP = "dense_mlp"  # the SwiGLU MLP of a leading dense layer
 SCOPE_MTP_MERGE = "mtp_merge"  # multi-token prediction: norms, concatenation, W_eh
 SCOPE_LM_HEAD = "lm_head"  # final norm, logits over the rows held, cross-entropy
+# ... and in its linear-attention (Kimi delta attention) layers. A block of
+# that kind has no mla_latent / rope / attn_core / attn_out. An MLA block's
+# head-wise gate lies under attn_out, the expert groups' choice under router.
+SCOPE_KDA_PROJ = "kda_proj"  # the six projections of x: q, k, v, decay gate, beta, output gate
+SCOPE_KDA_CONV = "kda_conv"  # the causal depthwise convolutions of q, k, v and their SiLU
+SCOPE_KDA_GATE = "kda_gate"  # L2 norms, log-decay, beta; under kda_out: output norm and head gate
+SCOPE_KDA_CORE = "kda_core"  # (q, k, v, g, beta) -> o: the chunked gated delta rule
+SCOPE_KDA_OUT = "kda_out"  # output norm and head-wise gate (also under kda_gate), W_o
 
 
 def _span_hist(name: str, registry):

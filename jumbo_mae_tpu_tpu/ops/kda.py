@@ -1,0 +1,211 @@
+"""The gated delta rule with a per-channel decay (Kimi Delta Attention's
+core), in chunked form, and the causal depthwise convolution that feeds it.
+
+Per head, with a state ``S`` of shape (d_k, d_v) that starts at zero::
+
+    S_t = (I − β_t k_t k_tᵀ) Diag(α_t) S_{t−1} + β_t k_t v_tᵀ,   o_t = S_tᵀ q_t
+
+``α_t = exp(g_t)``, ``g_t <= 0`` one log-decay a key channel. Work and memory
+are linear in the sequence: nothing sized (tokens, d_k, d_v) is ever built.
+
+**Chunked form.** Inside a chunk of ``C`` positions that starts from ``S_0``,
+with ``G_t = Σ_{j<=t} g_j`` (float32) and ``Γ_t = exp(G_t)``, write
+``S_t = Diag(α_t) S_{t−1} + k_t u_tᵀ`` with the pseudo-value
+``u_t = β_t (v_t − S_{t−1}ᵀ (α_t ⊙ k_t))``. Unrolled over the chunk::
+
+    (I + A) U = Diag(β) (V − (Γ ⊙ K) S_0),  A_ti = β_t Σ_c k_tc k_ic e^{G_tc − G_ic}  (i < t)
+    O = (Γ ⊙ Q) S_0 + B U,                   B_ti =     Σ_c q_tc k_ic e^{G_tc − G_ic}  (i <= t)
+    S_C = Diag(Γ_C) S_0 + (K ⊙ e^{G_C − G})ᵀ U
+
+so with ``T = (I + A)⁻¹ Diag(β)`` (the UT transform: ``I + A`` is unit lower
+triangular), ``W = T (Γ ⊙ K)`` and ``U_0 = T V``, a chunk costs three products
+with the state, ``U = U_0 − W S_0``, ``O = (Γ ⊙ Q) S_0 + B U`` and the update.
+
+**Decay ratios without overflow.** ``e^{G_t − G_i}`` is at most 1, but its two
+factors are not: with ``g >= −5`` a chunk of 64 reaches ``e^{±320}``. ``A`` and
+``B`` are therefore built over sub-blocks of ``sub`` (16) positions. A pair of
+sub-blocks ``I > J`` takes its reference at the last position of ``J``: both
+``e^{G_t − G_ref}`` and ``e^{G_ref − G_i}`` are then at most 1. A diagonal pair
+takes it at its first position: the column factor is at most ``e^{5·15}``,
+inside float32. ``g``, ``G``, ``A``, ``B``, the inverse and ``S`` are float32
+(the products in three bfloat16 passes) whatever the compute dtype; ``W``, ``U``
+and the products with the state take operands in the compute dtype and
+accumulate in float32.
+
+**Schedule.** A ``lax.scan`` over the chunks carries ``S``; a step is one
+chunk of every head and batch row. The step is rematerialised in the backward
+pass (``jax.checkpoint``): what is kept across it is the chunk's inputs and
+the state at its start, (tokens / C, d_k, d_v) a head. (Steps that batch
+several chunks were measured and lost: 126.7 ms a layer forward and backward
+at 8 chunks a step, 93.8 at 4, 63.2 at one, at the Ling cell's shapes on a
+v5e — PERF.md, PR 31.)
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+# float32 products in three bfloat16 passes: 16 bits of mantissa, more than
+# the compute dtype keeps of their results; six passes (HIGHEST) cost 18% of
+# the layer's forward and backward time and moved its output by 1.2e-4 of its
+# norm (PERF.md, PR 31)
+PRECISE = jax.lax.Precision.HIGH
+
+
+def _causal_conv(x, w):
+    """``Σ_j w_j ⊙ x_{t−K+1+j}`` in float32, zero history before position 0."""
+    taps, seq = w.shape[0], x.shape[-2]
+    padded = jnp.pad(x.astype(jnp.float32), [(0, 0)] * (x.ndim - 2) + [(taps - 1, 0), (0, 0)])
+    w = w.astype(jnp.float32)
+    return sum(padded[..., j:j + seq, :] * w[j][..., None, :] for j in range(taps))
+
+
+@jax.custom_vjp
+def causal_conv_silu(x, w):
+    """``silu(Σ_j w_j ⊙ x_{t−K+1+j})`` along the axis before the last, zero
+    history before the first position: ``x`` (..., seq, width), one filter of
+    ``K`` taps a channel, ``w`` (K, ..., width) broadcast against ``x``
+    without its sequence axis. Float32 inside. The gradient is written out
+    (the same shifted sums run backwards, from ``x`` and ``w`` alone), so
+    that the backward pass is a few fused loops and keeps no activation."""
+    return jax.nn.silu(_causal_conv(x, w)).astype(x.dtype)
+
+
+def _conv_fwd(x, w):
+    return causal_conv_silu(x, w), (x, w)
+
+
+def _conv_bwd(residuals, dy):
+    x, w = residuals
+    taps, seq = w.shape[0], x.shape[-2]
+    z = _causal_conv(x, w)
+    s = jax.nn.sigmoid(z)
+    dz = dy.astype(jnp.float32) * s * (1.0 + z * (1.0 - s))
+    ahead = jnp.pad(dz, [(0, 0)] * (x.ndim - 2) + [(0, taps - 1), (0, 0)])
+    wf = w.astype(jnp.float32)
+    dx = sum(ahead[..., taps - 1 - j:taps - 1 - j + seq, :] * wf[j][..., None, :]
+             for j in range(taps))
+    behind = jnp.pad(x.astype(jnp.float32), [(0, 0)] * (x.ndim - 2) + [(taps - 1, 0), (0, 0)])
+    over = tuple(range(x.ndim - w.ndim)) + (x.ndim - 2,)  # batch axes and positions
+    dw = jnp.stack([(behind[..., j:j + seq, :] * dz).sum(axis=over) for j in range(taps)])
+    return dx.astype(x.dtype), dw.astype(w.dtype)
+
+
+causal_conv_silu.defvjp(_conv_fwd, _conv_bwd)
+
+
+def _unit_lower_inverse(lower, base: int = 16):
+    """Inverse of unit lower-triangular matrices (..., n, n), float32. Up to
+    ``base`` rows by the finite Neumann product of the nilpotent part,
+    ``(I + N)⁻¹ = (I − N)(I + N²)(I + N⁴) ...``; above, by halves:
+    ``[[X₁, 0], [−X₂ L₂₁ X₁, X₂]]``."""
+    n = lower.shape[-1]
+    mm = functools.partial(jnp.matmul, precision=PRECISE)
+    if n <= base or n % 2:
+        eye = jnp.eye(n, dtype=lower.dtype)
+        nil = lower - eye
+        inv, power, reach = eye - nil, mm(nil, nil), 2
+        while reach < n:
+            inv = inv + mm(inv, power)
+            reach *= 2
+            if reach < n:
+                power = mm(power, power)
+        return inv
+    h = n // 2
+    top = _unit_lower_inverse(lower[..., :h, :h], base)
+    bottom = _unit_lower_inverse(lower[..., h:, h:], base)
+    corner = -mm(mm(bottom, lower[..., h:, :h]), top)
+    return jnp.concatenate([
+        jnp.concatenate([top, jnp.zeros_like(lower[..., :h, h:])], axis=-1),
+        jnp.concatenate([corner, bottom], axis=-1)], axis=-2)
+
+
+def _decayed_products(rows, k, cum, sub: int):
+    """``out[..., r, t, i] = Σ_c rows[..., r, t, c] k[..., i, c]
+    e^{cum[..., t, c] − cum[..., i, c]}`` for ``i <= t`` and zero above the
+    diagonal, float32: ``rows`` (..., R, C, d) stacks the row operands (q and
+    k), ``k`` and the cumulative log-decays ``cum`` are (..., C, d). Built
+    one strip of ``sub`` columns at a time so that no exponent is positive
+    beyond ``sub − 1`` steps of decay (module docstring); a strip computes
+    nothing for the rows above it."""
+    c = k.shape[-2]
+    within = jnp.tril(jnp.ones((sub, sub), bool))
+    strips = []
+    for lo in range(0, c, sub):
+        hi = lo + sub
+        cum_j, k_j = cum[..., lo:hi, :], k[..., lo:hi, :]
+        first, last = cum_j[..., :1, :], cum_j[..., -1:, :]
+        # the sub-block against itself: the reference is its first position
+        diag = jnp.einsum("...rtc,...ic->...rti",
+                          rows[..., lo:hi, :] * jnp.exp(cum_j - first)[..., None, :, :],
+                          k_j * jnp.exp(first - cum_j), precision=PRECISE)
+        parts = [jnp.zeros((*diag.shape[:-2], lo, sub), diag.dtype), jnp.where(within, diag, 0.0)]
+        if hi < c:  # the rows after it: the reference is its last position
+            parts.append(jnp.einsum(
+                "...rtc,...ic->...rti",
+                rows[..., hi:, :] * jnp.exp(cum[..., hi:, :] - last)[..., None, :, :],
+                k_j * jnp.exp(last - cum_j), precision=PRECISE))
+        strips.append(jnp.concatenate(parts, axis=-2))
+    return jnp.concatenate(strips, axis=-1)
+
+
+def _chunk(state, xs, *, sub: int, dtype):
+    """One chunk: ``state`` (..., d_k, d_v) float32 at its start; ``xs`` = q,
+    k (..., C, d_k), v (..., C, d_v), g (..., C, d_k) float32, beta (..., C)
+    float32 -> (state at its end, o (..., C, d_v))."""
+    q, k, v, g, beta = xs
+    f32 = jnp.float32
+    cum = jnp.cumsum(g, axis=-2)
+    qf, kf = q.astype(f32), k.astype(f32)
+    both = _decayed_products(jnp.stack([qf, kf], axis=-3), kf, cum, sub)
+    c = k.shape[-2]
+    qk = both[..., 0, :, :]
+    kk = jnp.where(jnp.tril(jnp.ones((c, c), bool), -1), both[..., 1, :, :], 0.0)
+    unit = jnp.eye(c, dtype=f32) + beta[..., :, None] * kk
+    ut = _unit_lower_inverse(unit) * beta[..., None, :]  # T = (I + A)⁻¹ Diag(β)
+    decay = jnp.exp(cum)
+    mm = functools.partial(jnp.matmul, preferred_element_type=f32)
+    lo = lambda x: x.astype(dtype)
+    s = lo(state)
+    u = mm(lo(ut), v) - mm(lo(mm(lo(ut), lo(kf * decay))), s)  # U = U_0 − W S_0
+    o = mm(lo(qf * decay), s) + mm(lo(qk), lo(u))
+    k_out = lo(kf * jnp.exp(cum[..., -1:, :] - cum))
+    state = decay[..., -1, :, None] * state + mm(jnp.swapaxes(k_out, -1, -2), lo(u))
+    return state, o.astype(dtype)
+
+
+def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, sub: int | None = None):
+    """The gated delta rule over whole sequences from a zero state.
+
+    ``q``, ``k`` (batch, heads, seq, d_k) — ``k`` of unit norm, ``q`` already
+    scaled; ``v`` (batch, heads, seq, d_v); ``g`` (batch, heads, seq, d_k)
+    float32 log-decays, ``<= 0``; ``beta`` (batch, heads, seq) float32 in
+    (0, 1). Returns ``(o, state)``: ``o`` (batch, heads, seq, d_v) in ``v``'s
+    dtype and the float32 state after the last position (batch, heads, d_k,
+    d_v). A sequence that is no multiple of ``chunk`` is padded with
+    positions that leave the state as it is. ``sub`` is the sub-block of the
+    decay ratios: 16 positions (``16 · |g| <= 80`` stays inside float32 for
+    ``g >= −5``), or the whole of a chunk that is no multiple of 16."""
+    batch, heads, seq, d_k = k.shape
+    if sub is None:
+        sub = 16 if chunk % 16 == 0 else chunk
+    if chunk % sub:
+        raise ValueError(f"chunk {chunk} is no multiple of the sub-block {sub}")
+    pad = -seq % chunk
+    if pad:  # k = 0, beta = 0, g = 0: the state passes through
+        widen = lambda x: jnp.pad(x, [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 3))
+        q, k, v, g, beta = map(widen, (q, k, v, g, beta))
+
+    def split(x):  # (batch, heads, seq, ...) -> (chunks, batch, heads, chunk, ...)
+        return jnp.moveaxis(x.reshape(batch, heads, -1, chunk, *x.shape[3:]), 2, 0)
+
+    body = jax.checkpoint(functools.partial(_chunk, sub=sub, dtype=v.dtype))
+    xs = (split(q), split(k), split(v), split(g.astype(jnp.float32)),
+          split(beta.astype(jnp.float32)))
+    start = jnp.zeros((batch, heads, d_k, v.shape[-1]), jnp.float32)
+    state, o = jax.lax.scan(body, start, xs)
+    o = jnp.moveaxis(o, 0, 2).reshape(batch, heads, seq + pad, v.shape[-1])
+    return o[:, :, :seq], state

@@ -276,7 +276,7 @@ def test_lm_train_phase_counts_the_causal_kernels_in_the_step(monkeypatch, forwa
     call = ('  %k.{i} = bf16[2,4]{{1,0}} custom-call(%a), custom_call_target="tpu_custom_call", '
             'metadata={{op_name="jit(_train_step)/{phase}/block_{i}/attn/attn_core/'
             'causal_attention_{kernel}/pallas_call" stack_frame_id=1}}\n')
-    lm = SimpleNamespace(layers=2, mtp_layers=1)
+    lm = SimpleNamespace(layers=2, mtp_layers=1, kda_layers=0)
     text = "".join(
         call.format(i=i, phase=phase, kernel=kernel)
         for i in range(3)

@@ -1,0 +1,136 @@
+"""The chunked gated delta rule (``ops/kda.py``) against the recurrence it
+stands for, position by position, in float32: output, final state and every
+input's gradient, with the log-decays pinned at the safe gate's bound for
+whole chunks, near zero, and mixed; the causal convolution against a loop;
+the triangular inverse against ``numpy``."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from jumbo_mae_tpu_tpu.ops import kda
+from jumbo_mae_tpu_tpu.ops.kda import causal_conv_silu, kda_chunked
+
+
+def literal(q, k, v, g, beta):
+    """``S_t = (I − β k kᵀ) Diag(α) S_{t−1} + β k vᵀ``, ``o_t = S_tᵀ q_t``."""
+    def head(q, k, v, g, beta):
+        def position(state, at):
+            q_t, k_t, v_t, g_t, b_t = at
+            state = jnp.exp(g_t)[:, None] * state
+            state = state + b_t * jnp.outer(k_t, v_t - state.T @ k_t)
+            return state, state.T @ q_t
+
+        start = jnp.zeros((k.shape[-1], v.shape[-1]))
+        state, o = jax.lax.scan(position, start, (q, k, v, g, beta))
+        return o, state
+
+    return jax.vmap(jax.vmap(head))(q, k, v, g, beta)
+
+
+def inputs(decays: str, seq: int = 150, d_k: int = 8, d_v: int = 6, seed: int = 0):
+    keys = jax.random.split(jax.random.key(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(keys[0], (2, 2, seq, d_k))) * d_k**-0.5
+    k = unit(jax.random.normal(keys[1], (2, 2, seq, d_k)))
+    v = jax.random.normal(keys[2], (2, 2, seq, d_v))
+    u = jax.random.uniform(keys[3], (2, 2, seq, d_k))
+    g = {"at_the_bound": -5.0 + 1e-3 * u,  # every position of every chunk at the floor
+         "near_zero": -1e-3 * u,
+         # most channels hardly decay, a fifth nearly at the floor, and one
+         # whole chunk of 64 at the floor
+         "mixed": (-5.0 * jax.nn.sigmoid(8.0 * (u - 0.8))).at[:, :, 64:128].set(-4.999)}[decays]
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (2, 2, seq)))
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("chunk,sub", [(64, 16), (128, 16), (32, 16), (8, 8)])
+@pytest.mark.parametrize("decays", ["mixed", "at_the_bound", "near_zero"])
+def test_chunked_form_is_the_recurrence(decays, chunk, sub):
+    """150 positions: no multiple of any chunk, so the padded tail is in."""
+    args = inputs(decays)
+    weight = jax.random.normal(jax.random.key(9), args[2].shape)
+
+    def scalar(fn):
+        def f(*a):
+            o, state = fn(*a)
+            return (o * weight).sum() + jnp.square(state).sum()
+        return f
+
+    chunked = lambda *a: kda_chunked(*a, chunk=chunk, sub=sub)
+    o, state = chunked(*args)
+    want_o, want_state = literal(*args)
+    assert o.shape == want_o.shape and state.shape == want_state.shape
+    np.testing.assert_allclose(o, want_o, rtol=0, atol=2e-6 * float(jnp.abs(want_o).max()))
+    np.testing.assert_allclose(state, want_state, rtol=0,
+                               atol=2e-6 * float(jnp.abs(want_state).max()))
+    got = jax.grad(scalar(chunked), argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(scalar(literal), argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert bool(jnp.isfinite(a).all()), name
+        assert float(jnp.abs(b).max()) > 0, name
+        # at the floor a log-decay's gradient is e^-5 of the others': the
+        # rounding of the order-one terms it is summed from is its floor
+        np.testing.assert_allclose(a, b, rtol=0, atol=5e-6 * max(float(jnp.abs(b).max()), 0.1),
+                                   err_msg=name)
+
+
+def test_the_state_carries_across_chunks():
+    """With hardly any decay the first chunk's keys are still in the state
+    after 256 positions: zeroing what a chunk hands to the next is seen."""
+    args = inputs("near_zero", seq=256)
+    o, state = kda_chunked(*args, chunk=64)
+    first = tuple(x[:, :, :64] for x in args)
+    _, early = kda_chunked(*first, chunk=64)
+    assert float(jnp.abs(early).max()) > 0.1
+    alone = tuple(x[:, :, 128:] for x in args)
+    o_alone, _ = kda_chunked(*alone, chunk=64)
+    assert float(jnp.abs(o[:, :, 128:] - o_alone).max()) > 0.05 * float(jnp.abs(o).max())
+
+
+def test_compute_dtype_operands_keep_a_float32_state():
+    q, k, v, g, beta = inputs("mixed")
+    low = lambda x: x.astype(jnp.bfloat16)
+    o, state = kda_chunked(low(q), low(k), low(v), g, beta)
+    want, _ = literal(q, k, v, g, beta)
+    assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
+    gap = jnp.linalg.norm(o.astype(jnp.float32) - want) / jnp.linalg.norm(want)
+    assert float(gap) < 2e-2
+
+
+def test_a_chunk_that_is_no_multiple_of_the_sub_block_is_refused():
+    with pytest.raises(ValueError, match="sub-block"):
+        kda_chunked(*inputs("mixed"), chunk=24, sub=16)
+
+
+@pytest.mark.parametrize("n", [4, 16, 24, 64])
+def test_unit_lower_inverse(n):
+    lower = np.tril(np.random.default_rng(n).normal(size=(3, n, n)), -1) + np.eye(n)
+    got = kda._unit_lower_inverse(jnp.asarray(lower, jnp.float32))
+    want = np.linalg.inv(lower)
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-4 * np.abs(want).max())
+
+
+def test_causal_convolution_against_a_loop():
+    x = jax.random.normal(jax.random.key(0), (2, 3, 11, 5))
+    w = jax.random.normal(jax.random.key(1), (4, 3, 5))
+    got = causal_conv_silu(x, w)
+    want = np.zeros(x.shape, np.float32)
+    for t in range(11):
+        for j in range(4):
+            if t - 3 + j >= 0:
+                want[:, :, t] += np.asarray(w[j]) * np.asarray(x[:, :, t - 3 + j])
+    want = want / (1.0 + np.exp(-want))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    # its hand-written gradient is the plain form's
+    plain = lambda x, w: jax.nn.silu(sum(
+        jnp.pad(x, ((0, 0), (0, 0), (3 - j, 0), (0, 0)))[:, :, :11] * w[j][:, None, :]
+        for j in range(4)))
+    weight = jax.random.normal(jax.random.key(2), x.shape)
+    for got_g, want_g in zip(jax.grad(lambda *a: (causal_conv_silu(*a) * weight).sum(), (0, 1))(x, w),
+                             jax.grad(lambda *a: (plain(*a) * weight).sum(), (0, 1))(x, w)):
+        np.testing.assert_allclose(got_g, want_g, rtol=1e-5, atol=1e-5)
+    # nothing of a later position reaches an earlier one
+    moved = causal_conv_silu(x.at[:, :, 6:].add(1.0), w)
+    np.testing.assert_array_equal(np.asarray(moved[:, :, :6]), np.asarray(got[:, :, :6]))
