@@ -373,6 +373,31 @@ def causal_kernel_calls(text: str) -> dict:
             for k in ("fwd", "dq", "dkv")}
 
 
+def kda_kernel_calls(text: str) -> dict:
+    """How often a compiled program's text runs the linear-attention core:
+    ``{"fwd": calls of the forward chunk kernel, "bwd": of the backward one,
+    "loops": ``while`` loops under the ``kda_core`` scope}``."""
+    calls = {k: len(re.findall(rf'custom-call\([^\n]*/kda_chunk_{k}/pallas_call"', text))
+             for k in ("fwd", "bwd")}
+    return {**calls, "loops": len(re.findall(r' while\([^\n]*/attn/kda_core/[^"\n]*while"', text))}
+
+
+def check_step_runs_the_kda_kernels(programs: dict, lm) -> dict:
+    """The step program among ``programs`` runs, for each of ``lm``'s
+    linear-attention blocks, the forward chunk kernel twice (forward, and
+    the rematted block's second forward, which keeps every chunk's starting
+    state), the backward kernel once, and no loop. Off the chip the core is
+    the scan: no kernel, three loops a block."""
+    import jax
+
+    calls = kda_kernel_calls(programs["train_step"].as_text())
+    n = lm.kda_layers
+    want = ({"fwd": 2 * n, "bwd": n, "loops": 0} if jax.default_backend() == "tpu"
+            else {"fwd": 0, "bwd": 0, "loops": 3 * n})
+    check(calls == want, f"the step runs the linear-attention core {calls}, not {want}")
+    return calls
+
+
 def check_step_runs_each_causal_kernel_once_a_block(programs: dict, lm) -> dict:
     """The step program among ``programs`` runs each of the causal core's
     kernels once for each of ``lm``'s latent-attention blocks: a rematted block keeps the
@@ -396,10 +421,11 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     the cycled batches' loss lower the second time it is seen; nothing
     dropped by an expert layer, whose held pairs fit one round of its chunk
     at the recipe's routing; no step skipped by the guard; the step program
-    runs each of the causal core's kernels once a latent-attention block;
-    where the recipe has linear-attention layers, their counters are logged
-    on every step and their states stay bounded. ``recipe`` is either
-    language family's (``--lm-recipe``)."""
+    runs each of the causal core's kernels once a latent-attention block
+    and the chunk kernels (forward twice, backward once) a linear-attention
+    block; where the recipe has linear-attention layers, their counters are
+    logged on every step and their states stay bounded. ``recipe`` is
+    either language family's (``--lm-recipe``)."""
     from jumbo_mae_tpu_tpu.cli import train as cli_train
     from jumbo_mae_tpu_tpu.models.lm import MlaMoeConfig
     from jumbo_mae_tpu_tpu.obs.trace import keeping_programs
@@ -411,6 +437,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     cfg = _load(recipe, overrides)
     lm = MlaMoeConfig(**cfg.model.lm)
     calls = check_step_runs_each_causal_kernel_once_a_block(programs, lm)
+    kda_calls = check_step_runs_the_kda_kernels(programs, lm)
     programs.clear()  # or the step's executable outlives the phase
     records = _read_metrics(out_dir / cfg.run.name)
     losses = _logged_losses(records, 1, steps, "lm_train")
@@ -445,6 +472,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         "moe_dropped": 0,
         "moe_rounds": 1,
         "causal_kernel_calls": calls,
+        "kda_kernel_calls": kda_calls,
         "moe_held_share_min_max": [round(min(share), 4), round(max(share), 4)],
         "moe_imbalance_max": round(max(r["train/moe_imbalance"] for r in by_step.values()), 3),
         **kda,

@@ -32,13 +32,36 @@ inside float32. ``g``, ``G``, ``A``, ``B``, the inverse and ``S`` are float32
 and the products with the state take operands in the compute dtype and
 accumulate in float32.
 
-**Schedule.** A ``lax.scan`` over the chunks carries ``S``; a step is one
-chunk of every head and batch row. The step is rematerialised in the backward
-pass (``jax.checkpoint``): what is kept across it is the chunk's inputs and
-the state at its start, (tokens / C, d_k, d_v) a head. (Steps that batch
-several chunks were measured and lost: 126.7 ms a layer forward and backward
-at 8 chunks a step, 93.8 at 4, 63.2 at one, at the Ling cell's shapes on a
-v5e — PERF.md, PR 31.)
+**Schedule.** One algorithm, two schedules of it, chosen by what the code
+can observe (as ``resolve_attn_impl`` and ``grouped_matmul(impl="auto")``
+choose): on a TPU, where ``d_k`` and ``d_v`` are multiples of 128 and the
+sub-block one of 16, two Pallas kernels (``ops/pallas/kda.py``) under a VJP
+of their own; elsewhere (the CPU's tests, toy widths) a ``lax.scan`` over
+the chunks that JAX differentiates, a step one chunk of every head and
+batch row, rematerialised in the backward pass (``jax.checkpoint``).
+
+- Forward kernel: walks the chunks with ``S`` in VMEM, a few heads a grid
+  step, reading each chunk's operands straight from the (batch, heads, seq,
+  ·) layout; nothing a chunk builds leaves VMEM.
+- Kept across the backward pass: the five inputs and the state at every
+  chunk's start, (tokens / C, d_k, d_v) float32 a head and batch row — 537 MB
+  a layer at the Ling cell's shapes, live only during that layer's backward
+  pass; the scan keeps the same. A plain forward pass, and a rematted
+  block's first one (``optimize_remat``), runs the variant that keeps nothing.
+- Backward kernel: the chunks in reverse with ``dS`` in VMEM; each chunk is
+  rebuilt from its inputs and its kept start and transposed there
+  (``jax.vjp`` of the kernel's own chunk function, taken while the kernel is
+  traced), the five gradients written once.
+
+So on the TPU a rematted block runs the forward kernel twice and the
+backward kernel once, and no loop is left in the program.
+
+Why kernels (PERF.md, PR 31–32; one layer at the Ling cell's shapes on a
+v5e): as XLA the scan's step is ≈ 60 small fusions over (64 head·rows, 64,
+128) arrays, 176 µs a step, bound by their number and not their size —
+steps that batch several chunks lost (126.7 ms a layer forward and backward
+at 8 chunks a step, 93.8 at 4, 63.2 at one), and inside the real step the
+same scan cost half as much again as alone (22.5 against 14.9 ms).
 """
 
 from __future__ import annotations
@@ -177,7 +200,20 @@ def _chunk(state, xs, *, sub: int, dtype):
     return state, o.astype(dtype)
 
 
-def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, sub: int | None = None):
+def _scan(q, k, v, g, beta, chunk: int, sub: int):
+    """``kda_chunked`` as a ``lax.scan`` over the chunks, for JAX to
+    differentiate: a step is rematerialised in the backward pass."""
+    def split(x):  # (batch, heads, seq, ...) -> (chunks, batch, heads, chunk, ...)
+        return jnp.moveaxis(x.reshape(*x.shape[:2], -1, chunk, *x.shape[3:]), 2, 0)
+
+    body = jax.checkpoint(functools.partial(_chunk, sub=sub, dtype=v.dtype))
+    start = jnp.zeros((*k.shape[:2], k.shape[-1], v.shape[-1]), jnp.float32)
+    state, o = jax.lax.scan(body, start, tuple(split(x) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 2).reshape(v.shape), state
+
+
+def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, sub: int | None = None,
+                interpret: bool = False):
     """The gated delta rule over whole sequences from a zero state.
 
     ``q``, ``k`` (batch, heads, seq, d_k) — ``k`` of unit norm, ``q`` already
@@ -188,24 +224,29 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, sub: int | None = None):
     d_v). A sequence that is no multiple of ``chunk`` is padded with
     positions that leave the state as it is. ``sub`` is the sub-block of the
     decay ratios: 16 positions (``16 · |g| <= 80`` stays inside float32 for
-    ``g >= −5``), or the whole of a chunk that is no multiple of 16."""
-    batch, heads, seq, d_k = k.shape
+    ``g >= −5``), or the whole of a chunk that is no multiple of 16. The
+    schedule follows the backend and the shapes (module docstring);
+    ``interpret`` runs the kernels in the Pallas interpreter (tests)."""
+    from jumbo_mae_tpu_tpu.ops.pallas.kda import kda_kernels, suits
+
+    seq = k.shape[2]
     if sub is None:
         sub = 16 if chunk % 16 == 0 else chunk
     if chunk % sub:
         raise ValueError(f"chunk {chunk} is no multiple of the sub-block {sub}")
+    on_kernels = interpret or jax.default_backend() == "tpu"
+    if not suits(k.shape[-1], v.shape[-1], chunk, sub):
+        if interpret:
+            raise ValueError(f"the kernels do not take d_k {k.shape[-1]}, d_v {v.shape[-1]}, "
+                             f"chunk {chunk}, sub-block {sub}")
+        on_kernels = False
     pad = -seq % chunk
     if pad:  # k = 0, beta = 0, g = 0: the state passes through
         widen = lambda x: jnp.pad(x, [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 3))
         q, k, v, g, beta = map(widen, (q, k, v, g, beta))
-
-    def split(x):  # (batch, heads, seq, ...) -> (chunks, batch, heads, chunk, ...)
-        return jnp.moveaxis(x.reshape(batch, heads, -1, chunk, *x.shape[3:]), 2, 0)
-
-    body = jax.checkpoint(functools.partial(_chunk, sub=sub, dtype=v.dtype))
-    xs = (split(q), split(k), split(v), split(g.astype(jnp.float32)),
-          split(beta.astype(jnp.float32)))
-    start = jnp.zeros((batch, heads, d_k, v.shape[-1]), jnp.float32)
-    state, o = jax.lax.scan(body, start, xs)
-    o = jnp.moveaxis(o, 0, 2).reshape(batch, heads, seq + pad, v.shape[-1])
+    g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    if on_kernels:
+        o, state = kda_kernels(q, k, v, g, beta, chunk, sub, interpret)
+    else:
+        o, state = _scan(q, k, v, g, beta, chunk, sub)
     return o[:, :, :seq], state

@@ -8,7 +8,6 @@ from __future__ import annotations
 
 import json
 import os
-import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
@@ -20,17 +19,20 @@ import chip_smoke
 from test_chip_compile import v5e_chip, watch  # noqa: F401 - fixtures
 
 RECIPE = str(chip_smoke.REPO / "recipes" / "pretrain_ling3_flash_ep64.yaml")
-# what one AOT compile of this step read (PERF.md, PR 31), and the chip's
-# own line: 16 GiB less what the runtime keeps
-PROGRAM_BYTES, CHIP_BYTES = 15_875_868_160, 16.9e9
+# what one AOT compile of this step read (PERF.md, PR 32; 15 875 868 160 with
+# the chunk scan in place of the kernels, PR 31), and the chip's own line:
+# 16 GiB less what the runtime keeps
+PROGRAM_BYTES, CHIP_BYTES = 15_168_317_440, 16.9e9
 
 
 def test_hybrid_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):  # noqa: F811
     """822 M parameters, 2 x 8192 tokens, through the trainer's own step
     factory: the one MLA block runs each causal kernel once, the six
-    linear-attention blocks scan their chunks in a loop (forward, the
-    block's recompute and the backward pass of each) and build nothing
-    sized (tokens, d_k, d_v) or (tokens, chunk, d_k) for a whole sequence,
+    linear-attention blocks run the forward chunk kernel twice (forward and
+    the block's recompute, which keeps every chunk's starting state) and the
+    backward kernel once, with no loop left under ``kda_core``, and build
+    nothing sized (tokens, d_k, d_v) or (tokens, chunk, d_k) for a whole
+    sequence,
     the expert layers walk their held pairs in a loop, the guard adds no
     ``conditional``, and what the step holds fits the chip."""
     from jumbo_mae_tpu_tpu.cli.train import build_model
@@ -67,9 +69,9 @@ def test_hybrid_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypa
     assert " conditional(" not in text and "/guard/" in text
     assert chip_smoke.causal_kernel_calls(text) == {"fwd": 1, "dq": 1, "dkv": 1}
     assert "gmm" in text
-    scans = [line for line in text.splitlines()
-             if " while(" in line and re.search(r'/attn/kda_core/[^"]*while"', line)]
-    assert len(scans) == 3 * lm.kda_layers == 18, len(scans)
+    assert chip_smoke.kda_kernel_calls(text) == {"fwd": 2 * lm.kda_layers, "bwd": lm.kda_layers,
+                                                 "loops": 0}
+    assert lm.kda_layers == 6
     seq, h, e = cfg.data.seq_len, lm.heads, lm.kda_head_dim
     for wide in (f"[{rows},{h},{seq},{e},{e}]", f"[{rows},{h},{seq},{lm.kda_chunk},{e}]",
                  f"[{rows},{h},{seq // lm.kda_chunk},{lm.kda_chunk},{lm.kda_chunk},{e}]"):
@@ -112,6 +114,7 @@ def test_lm_train_phase_rehearsal_on_the_hybrid_recipe(tmp_path, watch, capsys):
     assert checked["loss_after_one_cycle"] < checked["loss_first"]
     assert checked["moe_dropped"] == 0 and checked["skipped_steps"] == 0
     assert checked["causal_kernel_calls"] == {"fwd": 0, "dq": 0, "dkv": 0}
+    assert checked["kda_kernel_calls"] == {"fwd": 0, "bwd": 0, "loops": 3 * 6}  # off the chip: the scan
     assert 0 < checked["kda_state_absmax_max"] < 10
     low, high = checked["kda_decay_mean_min_max"]
     assert 0.9 < low <= high < 1.0

@@ -1,8 +1,9 @@
 """The chunked gated delta rule (``ops/kda.py``) against the recurrence it
 stands for, position by position, in float32: output, final state and every
 input's gradient, with the log-decays pinned at the safe gate's bound for
-whole chunks, near zero, and mixed; the causal convolution against a loop;
-the triangular inverse against ``numpy``."""
+whole chunks, near zero, and mixed, on both schedules of it (the scan, and
+the Pallas kernel in the interpreter); the causal convolution against a
+loop; the triangular inverse against ``numpy``."""
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +98,119 @@ def test_compute_dtype_operands_keep_a_float32_state():
     assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
     gap = jnp.linalg.norm(o.astype(jnp.float32) - want) / jnp.linalg.norm(want)
     assert float(gap) < 2e-2
+
+
+# ---------------------------------------------------------- the kernel path
+# ``ops/pallas/kda.py`` in the Pallas interpreter at the narrowest widths it
+# takes. Its float32 products are three real bfloat16 passes here (XLA's HIGH
+# is plain float32 on the CPU), so it is held to 16 bits, not to 22.
+
+WIDE = dict(seq=150, d_k=128, d_v=128)  # three chunks of 64, the last padded
+
+
+def kernel_path(*a):
+    return kda_chunked(*a, chunk=64, interpret=True)
+
+
+@pytest.mark.parametrize("decays", ["mixed", "at_the_bound", "near_zero"])
+def test_kernel_path_is_the_recurrence_and_the_scan(decays):
+    """Output, final state and all five gradients through the custom VJP,
+    under a ``jax.checkpoint`` as the model's block runs it."""
+    args = inputs(decays, **WIDE)
+    weight = jax.random.normal(jax.random.key(9), args[2].shape)
+
+    def scalar(fn):
+        def f(*a):
+            o, state = fn(*a)
+            return (o * weight).sum() + jnp.square(state).sum()
+        return f
+
+    o, state = kernel_path(*args)
+    for want_o, want_state in (literal(*args), kda_chunked(*args, chunk=64)):
+        np.testing.assert_allclose(o, want_o, rtol=0, atol=3e-5 * float(jnp.abs(want_o).max()))
+        np.testing.assert_allclose(state, want_state, rtol=0,
+                                   atol=3e-5 * float(jnp.abs(want_state).max()))
+    five = (0, 1, 2, 3, 4)
+    got = jax.grad(scalar(jax.checkpoint(kernel_path)), argnums=five)(*args)
+    for other in (literal, lambda *a: kda_chunked(*a, chunk=64)):
+        for name, a, b in zip("q k v g beta".split(), got, jax.grad(scalar(other), five)(*args)):
+            assert bool(jnp.isfinite(a).all()), name
+            np.testing.assert_allclose(a, b, rtol=0, atol=1e-4 * max(float(jnp.abs(b).max()), 0.1),
+                                       err_msg=name)
+
+
+@pytest.mark.parametrize("decays", ["mixed", "at_the_bound", "near_zero"])
+def test_forward_kernel_keeps_the_state_at_every_chunks_start(decays):
+    """What the backward kernel rebuilds a chunk from: the state the
+    recurrence has after 0, 64 and 128 positions."""
+    from jumbo_mae_tpu_tpu.ops.pallas.kda import kda_forward
+
+    args = inputs(decays, seq=192, d_k=128, d_v=128)
+    o, state, starts = kda_forward(*args, chunk=64, sub=16, with_starts=True, interpret=True)
+    assert starts.shape == (3, 2, 2, 128, 128) and starts.dtype == jnp.float32
+    assert float(jnp.abs(starts[0]).max()) == 0.0
+    for n in (1, 2):
+        want = literal(*(x[:, :, :64 * n] for x in args))[1]
+        np.testing.assert_allclose(starts[n], want, rtol=0, atol=3e-5 * float(jnp.abs(want).max()))
+    # the plain forward pass is the same kernel without that output
+    o_plain, state_plain = kda_forward(*args, chunk=64, sub=16, with_starts=False, interpret=True)
+    np.testing.assert_array_equal(np.asarray(o_plain), np.asarray(o))
+    np.testing.assert_array_equal(np.asarray(state_plain), np.asarray(state))
+
+
+@pytest.mark.parametrize("decays", ["mixed", "at_the_bound", "near_zero"])
+def test_backward_kernel_is_the_scans_transpose(decays):
+    """From its own kept states and any cotangents (the state's too), the
+    kernel that transposes ``chunk_step`` in VMEM gives what JAX's transpose
+    of the scan gives."""
+    from jumbo_mae_tpu_tpu.ops.pallas.kda import kda_backward, kda_forward
+
+    args = inputs(decays, seq=192, d_k=128, d_v=128)
+    o, state, starts = kda_forward(*args, chunk=64, sub=16, with_starts=True, interpret=True)
+    d_o = jax.random.normal(jax.random.key(3), o.shape)
+    d_state = jax.random.normal(jax.random.key(4), state.shape)
+    got = kda_backward(*args, starts, d_o, d_state, chunk=64, sub=16, interpret=True)
+    want = jax.vjp(lambda *a: kda_chunked(*a, chunk=64), *args)[1]((d_o, d_state))
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-4 * max(float(jnp.abs(b).max()), 0.1),
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("decays", ["mixed", "at_the_bound", "near_zero"])
+def test_kernel_path_takes_compute_dtype_operands_and_keeps_a_float32_state(decays):
+    q, k, v, g, beta = inputs(decays, **WIDE)
+    low = lambda x: x.astype(jnp.bfloat16)
+    o, state = kernel_path(low(q), low(k), low(v), g, beta)
+    scan_o, scan_state = kda_chunked(low(q), low(k), low(v), g, beta)
+    want, want_state = literal(q, k, v, g, beta)
+    assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
+    gap = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
+    assert gap(o, want) < 2e-2 and gap(state, want_state) < 2e-2
+    # no further from the recurrence than the scan is, and beside it
+    assert gap(o, want) < 1.1 * gap(scan_o, want) + 1e-4
+    assert gap(o, scan_o.astype(jnp.float32)) < 5e-3 and gap(state, scan_state) < 5e-3
+    # gradients in the operands' dtypes, beside the scan's
+    weight = jax.random.normal(jax.random.key(9), v.shape)
+    loss = lambda fn: lambda *a: (fn(*a)[0].astype(jnp.float32) * weight).sum()
+    operands = (low(q), low(k), low(v), g, beta)
+    got = jax.grad(loss(kernel_path), (0, 1, 2, 3, 4))(*operands)
+    want = jax.grad(loss(kda_chunked), (0, 1, 2, 3, 4))(*operands)
+    for name, a, b, x in zip("q k v g beta".split(), got, want, operands):
+        assert a.dtype == b.dtype == x.dtype, name
+        assert gap(a, b.astype(jnp.float32)) < 2e-2, name
+
+
+def test_the_kernel_refuses_widths_it_does_not_take_and_the_scan_takes_them():
+    args = inputs("mixed")  # d_k 8, d_v 6
+    with pytest.raises(ValueError, match="kernels do not take"):
+        kda_chunked(*args, interpret=True)
+    from jumbo_mae_tpu_tpu.ops.pallas.kda import heads_per_step, suits
+
+    assert suits(128, 128, 64, 16) and suits(256, 128, 32, 16)
+    assert not suits(64, 128, 64, 16) and not suits(128, 128, 8, 8)
+    assert heads_per_step(32, "fwd") == 16 and heads_per_step(6, "bwd") == 6
+    assert heads_per_step(17, "fwd") == 1 and heads_per_step(24, "fwd") == 12
 
 
 def test_a_chunk_that_is_no_multiple_of_the_sub_block_is_refused():
