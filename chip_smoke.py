@@ -295,9 +295,11 @@ def phase_kernels(shapes=KERNEL_SHAPES, *, interpret: bool = False) -> dict:
 # ------------------------------------------------------- language model
 
 
-# (batch, heads, seq, nope, rope, v): the language model's published head at
-# a length that is no multiple of the kernel's block
-CAUSAL_SHAPES = ((1, 4, 2148, 128, 64, 128),)
+# (batch, heads, seq, qk width, shared rope width, v width[, key/value heads,
+# window]), at a length that is no multiple of the kernel's block: latent
+# attention's published head, and a grouped-query head of one score part
+# whose 512-token window is walked as a band of blocks
+CAUSAL_SHAPES = ((1, 4, 2148, 128, 64, 128), (1, 16, 2148, 128, 0, 128, 2, 512))
 # (rows, k, n, group sizes): one expert takes most rows, one takes none
 GROUPED_SHAPE = (4096, 2048, 1536, (2900, 0, 517, 200, 33, 8, 1, 300))
 
@@ -305,26 +307,29 @@ GROUPED_SHAPE = (4096, 2048, 1536, (2900, 0, 517, 200, 33, 8, 1, 300))
 def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, *,
                      interpret: bool = False) -> dict:
     """The causal flash kernels (unequal qk and v widths, the shared rope
-    key) forward+backward against the einsum form, and the grouped product
-    forward+backward against ``lax.ragged_dot``; worst relative errors."""
+    key; grouped key/value heads under a window) forward+backward against the
+    einsum form, and the grouped product forward+backward against
+    ``lax.ragged_dot``; worst relative errors."""
     import jax
     import jax.numpy as jnp
 
     from jumbo_mae_tpu_tpu.ops.flash_attention import xla_causal_attention
     from jumbo_mae_tpu_tpu.ops.grouped_matmul import grouped_matmul
-    from jumbo_mae_tpu_tpu.ops.pallas.attention import CAUSAL_BLOCK, pallas_causal_attention
+    from jumbo_mae_tpu_tpu.ops.pallas.attention import pallas_causal_attention
 
     worst: dict[str, float] = {}
 
     def compare(name, fn, ref_fn, args):
         _check_against_reference(name, name, fn, ref_fn, args, worst, interpret=interpret)
 
-    for b, h, s, dn, dr, dv in causal:
+    for b, h, s, dn, dr, dv, *grouped_window in causal:
+        g, window = grouped_window or (h, None)
         keys = jax.random.split(jax.random.key(s), 6)
         bf = lambda k, shape, scale=1.0: (jax.random.normal(k, shape) * scale).astype(jnp.bfloat16)
         scale = (dn + dr) ** -0.5
-        args = (bf(keys[0], (b, h, s, dn), scale), bf(keys[1], (b, h, s, dr), scale),
-                bf(keys[2], (b, h, s, dn)), bf(keys[3], (b, s, dr)), bf(keys[4], (b, h, s, dv)))
+        args = (bf(keys[0], (b, h, s, dn), scale), bf(keys[1], (b, h, s, dr), scale) if dr else None,
+                bf(keys[2], (b, g, s, dn)), bf(keys[3], (b, s, dr)) if dr else None,
+                bf(keys[4], (b, g, s, dv)))
         w = jax.random.normal(keys[5], (b, h, s, dv))
 
         def weigh(fn, w=w):
@@ -333,10 +338,12 @@ def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, *,
                 return (o.astype(jnp.float32) * w).sum(), o
             return weighed
 
-        block = CAUSAL_BLOCK if not interpret else 16
-        compare(f"causal@{s}x{dn}+{dr}/{dv}",
-                weigh(lambda *xs: pallas_causal_attention(*xs, block, interpret)),
-                weigh(xla_causal_attention), args)
+        block = None if not interpret else 16  # None: the shape's own (causal_block)
+        name = f"causal@{s}x{dn}+{dr}/{dv}" + (f"g{h // g}w{window}" if grouped_window else "")
+        compare(name,
+                weigh(lambda *xs, window=window: pallas_causal_attention(
+                    *xs, block, interpret, window)),
+                weigh(lambda *xs, window=window: xla_causal_attention(*xs, window)), args)
 
     m, k, n, sizes = grouped
     keys = jax.random.split(jax.random.key(m), 3)
@@ -473,6 +480,8 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         "moe_rounds": 1,
         "causal_kernel_calls": calls,
         "kda_kernel_calls": kda_calls,
+        "attn_pairs": {kind: {"visited": visited, "needed": needed} for kind, (visited, needed)
+                       in lm.attn_pairs(cfg.data.seq_len).items()},
         "moe_held_share_min_max": [round(min(share), 4), round(max(share), 4)],
         "moe_imbalance_max": round(max(r["train/moe_imbalance"] for r in by_step.values()), 3),
         **kda,

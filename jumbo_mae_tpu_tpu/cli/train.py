@@ -922,6 +922,19 @@ def train(cfg: TrainConfig) -> dict:
         wandb_tags=tuple(run.wandb_tags),
         wandb_id=run.wandb_id,
     )
+    if run.mode == "lm":
+        # static, from the causal kernels' block tables: the score entries a
+        # (head, sequence) of each attention kind visits, and those its mask
+        # keeps; logged once, beside the steps' train/moe_* counters
+        pairs = {
+            f"train/attn_pairs_{what}_{kind}": count
+            for kind, counts in enc_cfg.attn_pairs(cfg.data.seq_len).items()
+            for what, count in zip(("visited", "needed"), counts)
+        }
+        if pairs:
+            logger.log(pairs, step=start_step)
+            if is_main:
+                print(f"[train] attention pairs a head and sequence: {pairs}")
     valid_factory = make_valid_iterator(
         cfg, mesh, per_process_valid, num_labels=getattr(enc_cfg, "labels", None) or 1000
     )

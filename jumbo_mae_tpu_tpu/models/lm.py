@@ -1,4 +1,4 @@
-"""Decoder-only sparse-expert language models, two families from one set of
+"""Decoder-only sparse-expert language models, three families from one set of
 blocks. **All-MLA** (the DeepSeek-V3 family's block, as ``JoyAI-LLM-Flash``'s
 ``config.json`` sizes it): multi-head latent attention in every block,
 sigmoid-routed experts beside a shared expert, one multi-token prediction
@@ -6,9 +6,14 @@ sigmoid-routed experts beside a shared expert, one multi-token prediction
 with ``layer_group_size = p > 0`` block ``i`` is MLA when ``(i + 1) % p == 0``
 and Kimi delta attention (KDA, a linear attention) otherwise; MLA has no
 query latent, both kinds end in a head-wise gate, and the router's choice is
-limited to a token's best groups of experts. The defaults are the first
-family's; its parameter tree, scopes and program do not depend on the
-second's fields.
+limited to a token's best groups of experts. **Grouped-query**
+(``Laguna-XS.2``, ``model_type: laguna``): with ``layer_types`` given, block
+``i`` is grouped-query softmax attention of the kind ``layer_types[i]`` —
+``full_attention`` or ``sliding_attention`` — with
+``heads_per_layer[i]`` query heads over ``kv_heads`` key/value heads, its own
+rotary embedding a kind, and a head-wise gate; no latent, no MTP module. The
+defaults are the first family's; its parameter tree, scopes and program do
+not depend on the other two's fields.
 
 Pre-norm residual blocks with RMSNorm (eps ``rms_eps``): ``x += A_i(norm(x))``,
 ``x += F_i(norm(x))``. No bias anywhere.
@@ -19,6 +24,24 @@ MLA: ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` -> heads x (nope ‖ rope) — 
 rope(k_pe)]`` with the one ``k_pe`` a token shared by every head; RoPE on
 adjacent pairs; causal ``z = softmax(q kᵀ (nope + rope)^-½) v``; with
 ``attn_gate``, ``z_h ← sigmoid(x W_γ)_h · z_h``; ``W_o`` over heads x v.
+
+Grouped-query attention, block ``i`` with ``H = heads_per_layer[i]``, ``G =
+kv_heads``, ``d = head_dim``: ``q = x W_q`` -> (H, d), ``k = x W_k``, ``v = x
+W_v`` -> (G, d); query head ``h`` reads key/value head ``h // (H / G)``. No
+q/k norm. Rotary embedding with the **rotate-half pairing** (dimension ``j``
+with ``j + r/2``) on the first ``r = partial_rotary_factor · d`` dimensions
+of ``q`` and ``k``, the rest passed through, by the kind's
+``rope_parameters``: ``rope_type: default`` turns pair ``j`` by ``position ·
+θ^(−2j/r)``; ``yarn`` blends each frequency ``f_j = θ^(−2j/r)`` with ``f_j /
+factor`` by ``γ_j = clip((j − low) / (high − low), 0, 1)``, ``low = ⌊r
+ln(L₀ / (2π β_fast)) / (2 ln θ)⌋``, ``high = ⌈r ln(L₀ / (2π β_slow)) / (2 ln
+θ)⌉`` clipped to ``[0, r − 1]``, ``L₀ = original_max_position_embeddings``:
+``inv_freq_j = (1 − γ_j) f_j + γ_j f_j / factor``, and multiplies ``cos`` and
+``sin`` by ``attention_factor``. ``s = q kᵀ d^-½``; key ``j`` is visible to
+query ``i`` iff ``j <= i`` and, in a ``sliding_attention`` layer, ``i − j <
+sliding_window``; ``z = softmax(s) v``; ``z_h ← sigmoid(x W_γ)_h · z_h``;
+``W_o`` over heads x d. The core is ``ops/flash_attention.causal_attention``,
+the latent family's, with one score part, grouped heads and a window.
 
 KDA, per head ``h`` with ``d_k = d_v = kda_head_dim`` (as many key and value
 heads as query heads): ``q̃, k̃, ṽ = x W_q, x W_k, x W_v``; each through a
@@ -80,11 +103,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from dataclasses import dataclass
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jumbo_mae_tpu_tpu.models.config import AttnImpl, RematPolicy, maybe_remat
 from jumbo_mae_tpu_tpu.models.layers import resolve_attn_impl
@@ -94,6 +119,7 @@ from jumbo_mae_tpu_tpu.obs.trace import (
     SCOPE_DENSE_MLP,
     SCOPE_EMBED,
     SCOPE_EXPERTS,
+    SCOPE_GQA_PROJ,
     SCOPE_KDA_CONV,
     SCOPE_KDA_CORE,
     SCOPE_KDA_GATE,
@@ -106,6 +132,7 @@ from jumbo_mae_tpu_tpu.obs.trace import (
     SCOPE_ROPE,
     SCOPE_ROUTER,
     SCOPE_SHARED_EXPERT,
+    SCOPE_SWA_CORE,
 )
 from jumbo_mae_tpu_tpu.ops.flash_attention import causal_attention
 from jumbo_mae_tpu_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, grouped_outer
@@ -118,11 +145,49 @@ MOE_COUNTERS = ("rows_min", "rows_mean", "rows_max", "imbalance", "held_share", 
 KDA_COUNTERS = ("state_absmax", "decay_mean")
 
 
+GQA_KINDS = ("full_attention", "sliding_attention")
+
+
+@dataclass(frozen=True)
+class Rope:
+    """One attention kind's rotary embedding, under ``rope_parameters``'
+    own keys (module docstring)."""
+
+    rope_theta: float
+    rope_type: str = "default"  # or "yarn"
+    partial_rotary_factor: float = 1.0
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        if self.rope_type not in ("default", "yarn"):
+            raise ValueError(f"rope_type {self.rope_type!r}: only default and yarn are implemented")
+
+    def inv_freq(self, r: int) -> np.ndarray:
+        """The ``r / 2`` pair frequencies, float64: exact in the configuration's
+        numbers, so that every float32 program rounds the same constants."""
+        j = np.arange(r // 2, dtype=np.float64)
+        f = self.rope_theta ** (-2.0 * j / r)
+        if self.rope_type == "default":
+            return f
+        turn = lambda beta: r * math.log(self.original_max_position_embeddings
+                                         / (2 * math.pi * beta)) / (2 * math.log(self.rope_theta))
+        low = min(max(math.floor(turn(self.beta_fast)), 0), r - 1)
+        high = min(max(math.ceil(turn(self.beta_slow)), 0), r - 1)
+        blend = np.clip((j - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return (1.0 - blend) * f + blend * f / self.factor
+
+
 @dataclass(frozen=True)
 class MlaMoeConfig:
     """Sizes as ``config.json`` names them (``JoyAI-LLM-Flash`` defaults: the
     all-MLA family), plus what this chip holds of them; the hybrid family's
-    fields below ``init_std``."""
+    fields below ``init_std``, the grouped-query family's below those. The
+    name is the first family's: the class holds all three (a rename would
+    touch every recipe's reader and test for no behaviour)."""
 
     vocab_size: int = 129280
     vocab_rows: tuple[int, int] | None = None  # (first row, rows held); None = all
@@ -162,6 +227,15 @@ class MlaMoeConfig:
     # the clamped SwiGLU is not implemented: a non-zero limit is refused
     expert_swiglu_limit: float = 0.0
     shared_expert_swiglu_limit: float = 0.0
+    # the grouped-query family: with layer_types, block i is softmax attention
+    # of the kind layer_types[i] with heads_per_layer[i] query heads over
+    # kv_heads key/value heads of head_dim; None = no block of this family
+    layer_types: tuple[str, ...] | None = None
+    heads_per_layer: tuple[int, ...] | None = None  # num_attention_heads_per_layer
+    kv_heads: int = 8  # num_key_value_heads
+    head_dim: int = 128
+    sliding_window: int = 512  # keys a sliding_attention query sees, itself included
+    rope_parameters: tuple[tuple[str, Rope], ...] | None = None  # a Rope an attention kind
 
     grad_ckpt: bool = True
     remat_policy: RematPolicy = "none"
@@ -169,10 +243,12 @@ class MlaMoeConfig:
     attn_impl: AttnImpl = "auto"
 
     def __post_init__(self):
-        for name in ("vocab_rows", "experts_held"):  # a recipe gives lists
+        for name in ("vocab_rows", "experts_held", "heads_per_layer"):  # a recipe gives lists
             value = getattr(self, name)
             if value is not None:
                 object.__setattr__(self, name, tuple(int(v) for v in value))
+        if self.layer_types is not None:
+            self._check_grouped_query()
         if self.mtp_layers not in (0, 1):
             raise ValueError("mtp_layers must be 0 or 1")
         if self.expert_swiglu_limit or self.shared_expert_swiglu_limit:
@@ -191,6 +267,25 @@ class MlaMoeConfig:
         if not (0 <= v0 and rows > 0 and v0 + rows <= self.vocab_size):
             raise ValueError(f"vocab_rows {self.vocab_rows} outside the vocabulary")
 
+    def _check_grouped_query(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        ropes = self.rope_parameters or ()
+        if isinstance(ropes, dict):  # a recipe gives the published group
+            ropes = tuple((kind, Rope(**ropes[kind])) for kind in GQA_KINDS)
+        object.__setattr__(self, "rope_parameters", tuple(ropes))
+        heads = self.heads_per_layer or ()
+        if not (len(self.layer_types) == len(heads) == self.layers):
+            raise ValueError(f"layer_types and heads_per_layer must name each of the "
+                             f"{self.layers} layers")
+        if set(self.layer_types) - set(GQA_KINDS) or dict(ropes).keys() != set(GQA_KINDS):
+            raise ValueError(f"layer_types and rope_parameters name the kinds {GQA_KINDS}")
+        if any(h % self.kv_heads for h in heads):
+            raise ValueError(f"query heads {heads} are no multiple of {self.kv_heads} "
+                             "key/value heads")
+        if self.mtp_layers or self.layer_group_size:
+            raise ValueError("the grouped-query family has no MTP module and no "
+                             "linear-attention layer")
+
     @property
     def held(self) -> tuple[int, int]:
         return self.experts_held or (0, self.n_routed_experts)
@@ -206,6 +301,29 @@ class MlaMoeConfig:
     @property
     def kda_layers(self) -> int:
         return sum(self.is_kda(i) for i in range(self.layers))
+
+    def attention_kind(self, layer: int) -> str:
+        """Trunk block ``layer``'s attention: ``"kda"``, ``"mla"``, or a
+        grouped-query layer's ``layer_types`` entry."""
+        if self.layer_types is not None:
+            return self.layer_types[layer]
+        return "kda" if self.is_kda(layer) else "mla"
+
+    def rope(self, kind: str) -> Rope:
+        return dict(self.rope_parameters)[kind]
+
+    def attn_pairs(self, seq: int) -> dict:
+        """``{kind: (visited, needed)}`` for each kind of softmax attention
+        among the blocks: the score entries of one (head, sequence) of
+        ``seq`` tokens that the causal kernels' block tables walk, and those
+        the mask keeps (``ops/pallas/attention.causal_pairs``). Static."""
+        from jumbo_mae_tpu_tpu.ops.pallas.attention import causal_pairs
+
+        kinds = {self.attention_kind(i) for i in range(self.layers)} - {"kda"}
+        if self.mtp_layers:
+            kinds.add("mla")
+        window = lambda kind: self.sliding_window if kind == "sliding_attention" else None
+        return {kind: causal_pairs(seq, window(kind)) for kind in sorted(kinds)}
 
     @property
     def shared_hidden(self) -> int:
@@ -266,6 +384,56 @@ def rope_interleaved(x, theta: float):
     a, b = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rope_half(x, rope: Rope):
+    """Rotary embedding with the rotate-half pairing: of the last axis' first
+    ``r = partial_rotary_factor · d`` dimensions, ``j`` turns with ``j + r/2``
+    by ``position · inv_freq_j``, ``cos`` and ``sin`` times
+    ``attention_factor``; the other ``d − r`` pass through. Positions run
+    along the axis before the last. Float32 inside."""
+    seq, d = x.shape[-2:]
+    r = int(d * rope.partial_rotary_factor)
+    inv = jnp.asarray(rope.inv_freq(r), jnp.float32)
+    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos, sin = rope.attention_factor * jnp.cos(angle), rope.attention_factor * jnp.sin(angle)
+    turned = x[..., :r].astype(jnp.float32)
+    a, b = turned[..., : r // 2], turned[..., r // 2:]
+    out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+    return out if r == d else jnp.concatenate([out, x[..., r:]], axis=-1)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Grouped-query softmax attention of one kind (module docstring):
+    ``heads`` query heads over ``cfg.kv_heads`` key/value heads, full or,
+    where ``sliding``, windowed."""
+
+    cfg: MlaMoeConfig
+    heads: int
+    sliding: bool
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        h, g, d = self.heads, cfg.kv_heads, cfg.head_dim
+        rope = cfg.rope("sliding_attention" if self.sliding else "full_attention")
+        with jax.named_scope(SCOPE_GQA_PROJ):
+            q = Proj((cfg.dim, h, d), "bsd,dhe->bhse", cfg, name="q")(x) * d**-0.5
+            k = Proj((cfg.dim, g, d), "bsd,dhe->bhse", cfg, name="k")(x)
+            v = Proj((cfg.dim, g, d), "bsd,dhe->bhse", cfg, name="v")(x)
+            if cfg.attn_gate:
+                gate = Proj((cfg.dim, h), "bsd,dh->bhs", cfg, name="gate")(x)
+        with jax.named_scope(SCOPE_ROPE):
+            q, k = rope_half(q, rope), rope_half(k, rope)
+        impl = resolve_attn_impl(cfg.attn_impl, backend=jax.default_backend(),
+                                 seq_len=x.shape[1], dropout=0.0, deterministic=True)
+        with jax.named_scope(SCOPE_SWA_CORE if self.sliding else SCOPE_ATTN_CORE):
+            z = causal_attention(q, None, k, None, v, impl=impl,
+                                 window=cfg.sliding_window if self.sliding else None)
+        with jax.named_scope(SCOPE_ATTN_OUT):
+            if cfg.attn_gate:
+                z = _head_gate(z, gate)
+            return Proj((h, d, cfg.dim), "bhsd,hdm->bsm", cfg, name="out")(z)
 
 
 class LatentAttention(nn.Module):
@@ -572,14 +740,17 @@ class SparseExperts(nn.Module):
 
 
 class Block(nn.Module):
-    """One pre-norm residual block: latent attention, or linear attention
-    where ``kda``, then the dense MLP (``sparse=False``) or the expert layer.
-    Returns ``(x, stats, kda_stats)``: the expert layer's counters and the
-    linear-attention layer's (None where the block has none)."""
+    """One pre-norm residual block: attention of ``kind``
+    (``MlaMoeConfig.attention_kind``: latent, linear, or grouped-query of
+    ``heads`` query heads, full or sliding), then the dense MLP
+    (``sparse=False``) or the expert layer. Returns ``(x, stats,
+    kda_stats)``: the expert layer's counters and the linear-attention
+    layer's (None where the block has none)."""
 
     cfg: MlaMoeConfig
     sparse: bool
-    kda: bool = False
+    kind: str = "mla"
+    heads: int = 0  # a grouped-query block's query heads
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True):
@@ -587,11 +758,15 @@ class Block(nn.Module):
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.rms_eps, cfg.compute_dtype, name=name)
         kda_stats = None
-        if self.kda:
+        if self.kind == "kda":
             y, kda_stats = KdaAttention(cfg, name="attn")(norm("ln1")(x))
             x = x + y
-        else:
+        elif self.kind == "mla":
             x = x + LatentAttention(cfg, name="attn")(norm("ln1")(x))
+        else:
+            attn = GroupedQueryAttention(cfg, self.heads, self.kind == "sliding_attention",
+                                         name="attn")
+            x = x + attn(norm("ln1")(x))
         if self.sparse:
             y, stats = SparseExperts(cfg, name="moe")(norm("ln2")(x))
         else:
@@ -613,8 +788,9 @@ class MlaMoeLM(nn.Module):
         block = maybe_remat(Block, cfg)
         self.embedding = self.param("embedding", _normal(cfg), (cfg.rows[1], cfg.dim),
                                     jnp.float32)
-        self.blocks = [block(cfg, sparse=i >= cfg.first_k_dense, kda=cfg.is_kda(i),
-                             name=f"block_{i}") for i in range(cfg.layers)]
+        heads = cfg.heads_per_layer or (0,) * cfg.layers
+        self.blocks = [block(cfg, sparse=i >= cfg.first_k_dense, kind=cfg.attention_kind(i),
+                             heads=heads[i], name=f"block_{i}") for i in range(cfg.layers)]
         self.ln = RMSNorm(cfg.rms_eps, cfg.compute_dtype, name="ln")
         self.head = Proj((cfg.dim, cfg.rows[1]), "bsd,dv->bsv", cfg, name="head")
         if cfg.mtp_layers:

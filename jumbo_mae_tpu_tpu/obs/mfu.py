@@ -116,16 +116,18 @@ def classify_flops_per_image(enc_cfg, *, training: bool = True) -> float:
 
 def lm_flops_per_token(cfg, seq: int, *, training: bool = True) -> float:
     """Matmul FLOPs one token of the sparse-expert language model
-    (``models/lm.MlaMoeConfig``: latent attention, or latent and linear
-    attention layers in a pattern) requires at sequence length ``seq``, on
-    this chip's share: the experts and vocabulary rows held. 2·m·n·k per
-    matmul; the causal core counts its lower triangle once (mean context
-    ``seq / 2``); a linear-attention core counts the recurrence's three
-    products with its (d_k, d_v) state, ``6 · d_k · d_v`` a head, whatever
-    ``seq`` and the chunking; a routed expert is counted for the share of
-    (token, expert) pairs expected here (``k · held / experts``); the
-    embedding is a lookup and the short convolutions are elementwise;
-    backward = 2 x forward, recomputation not counted."""
+    (``models/lm.MlaMoeConfig``: latent attention, latent and linear
+    attention layers in a pattern, or grouped-query layers, full and
+    sliding) requires at sequence length ``seq``, on this chip's share: the
+    experts and vocabulary rows held. 2·m·n·k per matmul; the causal core
+    counts its lower triangle once (mean context ``seq / 2``), a sliding
+    layer's the keys its window shows (``min(i + 1, window)`` for query
+    ``i``, so it does not grow with ``seq``); a linear-attention core counts
+    the recurrence's three products with its (d_k, d_v) state, ``6 · d_k ·
+    d_v`` a head, whatever ``seq`` and the chunking; a routed expert is
+    counted for the share of (token, expert) pairs expected here (``k · held
+    / experts``); the embedding is a lookup and the short convolutions are
+    elementwise; backward = 2 x forward, recomputation not counted."""
     d, h = cfg.dim, cfg.heads
     qk, dv = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim
     query = d * h * qk if cfg.q_lora_rank is None else d * cfg.q_lora_rank + cfg.q_lora_rank * h * qk
@@ -150,8 +152,18 @@ def lm_flops_per_token(cfg, seq: int, *, training: bool = True) -> float:
     dense_layers = min(cfg.first_k_dense, cfg.layers)
     sparse_layers = cfg.layers - dense_layers + cfg.mtp_layers
     head = 2 * d * cfg.rows[1]
+
+    def grouped_query(layer: int) -> float:
+        hq, g, e = cfg.heads_per_layer[layer], cfg.kv_heads, cfg.head_dim
+        w = min(cfg.sliding_window, seq) if cfg.layer_types[layer] == "sliding_attention" else seq
+        keys = (w * (w + 1) / 2 + (seq - w) * w) / seq  # mean keys a query sees
+        gate = d * hq if cfg.attn_gate else 0
+        return 2 * (d * hq * e + 2 * d * g * e + gate + hq * e * d) + 2 * keys * hq * 2 * e
+
+    grouped = cfg.layers if cfg.layer_types is not None else 0
     fwd = (
-        (cfg.layers - cfg.kda_layers + cfg.mtp_layers) * (latent + core)
+        (cfg.layers - grouped - cfg.kda_layers + cfg.mtp_layers) * (latent + core)
+        + sum(grouped_query(i) for i in range(grouped))
         + cfg.kda_layers * linear
         + dense_layers * gated(cfg.dense_hidden)
         + sparse_layers * sparse

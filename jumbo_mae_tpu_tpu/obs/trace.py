@@ -73,6 +73,11 @@ SCOPE_KDA_CONV = "kda_conv"  # the causal depthwise convolutions of q, k, v and 
 SCOPE_KDA_GATE = "kda_gate"  # L2 norms, log-decay, beta; under kda_out: output norm and head gate
 SCOPE_KDA_CORE = "kda_core"  # (q, k, v, g, beta) -> o: the chunked gated delta rule
 SCOPE_KDA_OUT = "kda_out"  # output norm and head-wise gate (also under kda_gate), W_o
+# ... and in its grouped-query layers (full or sliding-window softmax attention
+# over shared key/value heads). A block of that kind has rope, attn_out and one
+# of the two cores: a full layer's is attn_core, as every causal core's.
+SCOPE_GQA_PROJ = "gqa_proj"  # the q, k, v and head-gate projections of x
+SCOPE_SWA_CORE = "swa_core"  # a sliding-window layer's causal kernels and what feeds them
 
 
 def _span_hist(name: str, registry):

@@ -1,9 +1,9 @@
-"""Memory-efficient attention for long sequences.
+"""Memory-efficient attention for long sequences, in two forms.
 
-The reference materializes full (B,H,N,N) score tensors
+**Non-causal**, ``flash_attention(q, k, v)`` over (B, N, H, D) tensors (the
+ViT's): the reference materializes full (B,H,N,N) score tensors
 (``/root/reference/src/modeling.py:136-137``) — fine at N=197, fatal for
-long-context. This module provides ``flash_attention(q, k, v)`` over
-(B, N, H, D) tensors:
+long-context.
 
 - on TPU, a Pallas blockwise-softmax kernel (``ops/pallas/attention.py``)
   that never materializes the N×N score matrix in HBM — any sequence length
@@ -11,8 +11,16 @@ long-context. This module provides ``flash_attention(q, k, v)`` over
 - elsewhere, an XLA fallback that is numerically identical to the naive
   path (blockwise-chunked above 2048 tokens).
 
+**Causal**, ``causal_attention(q_a, q_b, k_a, k_b, v, impl=, window=)`` over
+head-major (B, H, N, D) tensors (the language models'): a score of one part
+or of two (the second with a key all heads share: latent attention's rotary
+columns), key/value heads that a group of query heads shares, and an optional
+window of tokens a query looks back over. ``impl="flash"`` is the Pallas
+kernels over the visible block pairs (``ops/pallas/attention.py``'s causal
+section), ``"einsum"`` the form that builds the (N, N) scores: the CPU's.
+
 Inputs are expected pre-scaled (queries already multiplied by head_dim**-0.5,
-matching the callers in ``models/layers.py``).
+matching the callers in ``models/layers.py`` and ``models/lm.py``).
 """
 
 from __future__ import annotations
@@ -57,24 +65,34 @@ def flash_attention(
     return pallas_flash_attention(q, k, v, block_q, block_k)
 
 
-def xla_causal_attention(q_a, q_b, k_a, k_b, v) -> jax.Array:
+def xla_causal_attention(q_a, q_b, k_a, k_b, v, window: int | None = None) -> jax.Array:
     """The einsum form of :func:`causal_attention`: the (seq, seq) scores
     exist, so it is for the CPU's tests and short sequences only."""
+    group = q_a.shape[1] // k_a.shape[1]
+    if group > 1:  # each key/value head once a query head of its group
+        k_a, v = jnp.repeat(k_a, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q_a, k_a, preferred_element_type=jnp.float32)
-    s = s + jnp.einsum("bhqd,bkd->bhqk", q_b, k_b, preferred_element_type=jnp.float32)
+    if q_b is not None:
+        s = s + jnp.einsum("bhqd,bkd->bhqk", q_b, k_b, preferred_element_type=jnp.float32)
     keep = jnp.tril(jnp.ones(s.shape[-2:], bool))
+    if window is not None:
+        keep = keep & ~jnp.tril(keep, -window)  # row − col < window
     probs = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def causal_attention(q_a, q_b, k_a, k_b, v, *, impl: str) -> jax.Array:
-    """Causal softmax(q_a·k_aᵀ + q_b·k_bᵀ)·v, head-major: ``q_a``/``k_a``
-    (batch, heads, seq, d_a), ``q_b`` (batch, heads, seq, d_b), ``k_b``
-    (batch, seq, d_b) shared by all heads, ``v`` (batch, heads, seq, d_v);
-    queries pre-scaled. ``impl`` is ``"flash"`` (the Pallas kernels, scores
-    never materialised) or ``"einsum"``, as ``resolve_attn_impl`` resolved it."""
+def causal_attention(q_a, q_b, k_a, k_b, v, *, impl: str, window: int | None = None) -> jax.Array:
+    """Causal softmax(q_a·k_aᵀ + q_b·k_bᵀ)·v, head-major: ``q_a`` (batch,
+    heads, seq, d_a), ``k_a`` (batch, kv heads, seq, d_a) and ``v`` (batch, kv
+    heads, seq, d_v) with ``kv heads`` a divisor of ``heads`` (query head ``h``
+    reads key/value head ``h // (heads / kv heads)``); ``q_b`` (batch, heads,
+    seq, d_b) and ``k_b`` (batch, seq, d_b) shared by all heads, or both None
+    for a score of one part; queries pre-scaled. With ``window``, query ``i``
+    sees keys ``i − window + 1 .. i``. ``impl`` is ``"flash"`` (the Pallas
+    kernels, scores never materialised) or ``"einsum"``, as
+    ``resolve_attn_impl`` resolved it."""
     if impl == "flash":
         from jumbo_mae_tpu_tpu.ops.pallas.attention import pallas_causal_attention
 
-        return pallas_causal_attention(q_a, q_b, k_a, k_b, v)
-    return xla_causal_attention(q_a, q_b, k_a, k_b, v)
+        return pallas_causal_attention(q_a, q_b, k_a, k_b, v, window=window)
+    return xla_causal_attention(q_a, q_b, k_a, k_b, v, window)

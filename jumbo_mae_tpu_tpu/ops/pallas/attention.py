@@ -415,24 +415,34 @@ pallas_flash_attention_with_lse.defvjp(_vjp_lse_fwd, _vjp_lse_bwd)
 
 
 # ---------------------------------------------------------------------------
-# Causal form, with query/key and value head widths that differ.
+# Causal form, with query/key and value head widths that differ, key/value
+# heads that a group of query heads shares, and an optional window.
 #
-# The score of a (query, key) pair is the sum of two products: a per-head
-# part (``q_a·k_aᵀ``, width ``d_a``) and a part whose key is one vector a
-# token shared by every head (``q_b·k_bᵀ``, width ``d_b``; multi-head latent
-# attention's rotary columns). ``k_b`` is never replicated per head: its
-# BlockSpec ignores the head index. The value width ``d_v`` is independent.
+# The score of a (query, key) pair is ``q_a·k_aᵀ``, a per-head part of width
+# ``d_a``, plus — where ``q_b``/``k_b`` are given — a part whose key is one
+# vector a token shared by every head (``q_b·k_bᵀ``, width ``d_b``; multi-head
+# latent attention's rotary columns). ``k_b`` is never replicated per head:
+# its BlockSpec ignores the head index. The value width ``d_v`` is
+# independent. ``k_a`` and ``v`` may have fewer heads than the queries
+# (grouped-query attention): query head ``h`` reads key/value head ``h //
+# group``, again through the BlockSpec alone, and the key/value gradients of a
+# group are summed inside the dK/dV kernel, whose grid walks the group's
+# members innermost and writes a key block once.
 #
 # Layout is head-major, (batch, heads, seq, width): the projections that
 # feed the kernel write it directly, so there is no fold/transposition here.
 #
-# Causality is in the grid, not in a mask applied after the fact: the third
-# grid axis walks only the (query block, key block) pairs on or below the
-# diagonal, in an order two scalar-prefetched tables give, so a block above
-# the diagonal costs neither a DMA nor a grid step. Only the diagonal blocks
-# mask, with a local iota. Running maximum, denominator and the output
-# accumulator live in VMEM scratch across the key blocks of a query block;
-# scores never leave VMEM.
+# Causality and the window are in the grid, not in a mask applied after the
+# fact: the third grid axis walks only the (query block, key block) pairs
+# that hold a visible entry, in an order two scalar-prefetched tables give
+# (``_lower_triangle``: the whole triangle on or below the diagonal, or with a
+# window of ``w`` tokens the band of it that ``w`` reaches back into), so a
+# block outside the band costs neither a DMA nor a grid step: a windowed
+# layer is O(seq · window). Only the band's two edges mask, with a local
+# iota: the diagonal blocks (``row >= col``) and the trailing blocks that the
+# window's far edge cuts (``row − col < w`` in absolute positions). Running
+# maximum, denominator and the output accumulator live in VMEM scratch across
+# the key blocks of a query block; scores never leave VMEM.
 #
 # A sequence that is no multiple of the block is padded with zero rows: a
 # pad key lies after every real query, so causality already hides it, and a
@@ -462,49 +472,104 @@ CAUSAL_OUT_NAME = "causal_attention_out"
 CAUSAL_LSE_NAME = "causal_attention_lse"
 
 
-def _lower_triangle(n: int, *, by_key: bool):
-    """The block pairs on or below the diagonal as two int32 tables.
-    ``by_key=False``: query block outer, key blocks 0..i inner (the diagonal
-    comes last). ``by_key=True``: key block outer, query blocks j..n-1 inner
-    (the diagonal comes first)."""
+def _reach(window: int, block: int) -> int:
+    """How many key blocks before its own a query block's window reaches
+    into: query ``r`` sees keys ``r − window + 1 .. r``."""
+    return (window + block - 2) // block
+
+
+def _lower_triangle(n: int, *, by_key: bool, reach: int | None = None):
+    """The block pairs that hold a visible entry as two int32 tables: those
+    on or below the diagonal, and with ``reach`` only those at most ``reach``
+    blocks below it (the band). ``by_key=False``: query block outer, its key
+    blocks inner in rising order (the diagonal comes last). ``by_key=True``:
+    key block outer, its query blocks inner in rising order (the diagonal
+    comes first)."""
     import numpy as np
 
+    reach = n if reach is None else reach
     if by_key:
-        pairs = [(i, j) for j in range(n) for i in range(j, n)]
+        pairs = [(i, j) for j in range(n) for i in range(j, min(j + reach, n - 1) + 1)]
     else:
-        pairs = [(i, j) for i in range(n) for j in range(i + 1)]
+        pairs = [(i, j) for i in range(n) for j in range(max(i - reach, 0), i + 1)]
     qi, kj = zip(*pairs)
     return np.asarray(qi, np.int32), np.asarray(kj, np.int32)
 
 
-def _scores(qa, qb, ka, kb, diagonal: bool):
-    """(block, block) float32 scores of one block pair; on a diagonal block
-    the entries above the diagonal are −inf."""
+def _scores(qa, qb, ka, kb, diagonal: bool, edge=None):
+    """(block, block) float32 scores of one block pair. On a diagonal block
+    the entries above the diagonal are −inf; with ``edge`` so are those with
+    ``row − col >= edge`` (the window's far side, in the pair's own rows and
+    columns)."""
     dims = (((1,), (1,)), ((), ()))
     s = jax.lax.dot_general(qa, ka, dims, preferred_element_type=jnp.float32)
-    s = s + jax.lax.dot_general(qb, kb, dims, preferred_element_type=jnp.float32)
-    if diagonal:
+    if qb is not None:
+        s = s + jax.lax.dot_general(qb, kb, dims, preferred_element_type=jnp.float32)
+    if diagonal or edge is not None:
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(row >= col, s, NEG_INF)
+        keep = row >= col if diagonal else None
+        if edge is not None:
+            near = row - col < edge
+            keep = near if keep is None else keep & near
+        s = jnp.where(keep, s, NEG_INF)
     return s
 
 
-def _causal_fwd_kernel(qi_ref, kj_ref, qa_ref, qb_ref, ka_ref, kb_ref, v_ref,
-                       o_ref, lse_ref, m_sc, l_sc, acc_sc):
+def _parts(refs, two_part: bool):
+    """A kernel's refs as ``(qa, qb, ka, kb, v, *rest)``, ``qb`` and ``kb``
+    None where the score has one part."""
+    if two_part:
+        return refs
+    qa, ka, v = refs[:3]
+    return (qa, None, ka, None, v, *refs[3:])
+
+
+def _read(ref, dtype):
+    return None if ref is None else ref[...].astype(dtype)
+
+
+def _first_key_block(i, *, block: int, window: int | None):
+    """The first key block a query block's row of the tables visits."""
+    return 0 if window is None else jnp.maximum(i - _reach(window, block), 0)
+
+
+def _diagonal_edge(*, block: int, window: int | None):
+    """The window's edge on a diagonal block: there only where the window is
+    shorter than the block."""
+    return window if window is not None and window < block else None
+
+
+def _off_diagonal(i, j, step, *, block: int, window: int | None):
+    """Run ``step(edge)`` for a pair below the diagonal (``j < i``): with no
+    mask where the whole block is visible, with the window's edge where the
+    window ends inside it."""
+    if window is None:
+        pl.when(j < i)(lambda: step(None))
+        return
+    clear = window // block  # pairs fewer blocks apart than this lie wholly inside the window
+    if clear > 1:
+        pl.when((j < i) & (i - j < clear))(lambda: step(None))
+    pl.when((j < i) & (i - j >= clear))(lambda: step(window - (i - j) * block))
+
+
+def _causal_fwd_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int,
+                       window: int | None):
+    qa_ref, qb_ref, ka_ref, kb_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = _parts(
+        refs, two_part)
     t = pl.program_id(2)
     i, j = qi_ref[t], kj_ref[t]
     mm = qa_ref.dtype
 
-    @pl.when(j == 0)
+    @pl.when(j == _first_key_block(i, block=block, window=window))
     def _():
         m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    def step(diagonal: bool):
-        s = _scores(qa_ref[...].astype(mm), qb_ref[...].astype(mm),
-                    ka_ref[...].astype(mm), kb_ref[...].astype(mm), diagonal)
+    def step(diagonal: bool, edge=None):
+        s = _scores(_read(qa_ref, mm), _read(qb_ref, mm), _read(ka_ref, mm), _read(kb_ref, mm),
+                    diagonal, edge)
         m_prev = m_sc[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -515,32 +580,39 @@ def _causal_fwd_kernel(qi_ref, kj_ref, qa_ref, qb_ref, ka_ref, kb_ref, v_ref,
             preferred_element_type=jnp.float32)
         m_sc[...] = m_new
 
-    @pl.when(j < i)
-    def _():
-        step(False)
+    # a row that a trailing block hides whole leaves 1s in p at the running
+    # maximum −1e30; the diagonal block, always visited and never all hidden,
+    # scales them away by alpha = 0
+    _off_diagonal(i, j, lambda edge: step(False, edge), block=block, window=window)
 
     @pl.when(j == i)  # the diagonal is the last key block of a query block
     def _():
-        step(True)
+        step(True, _diagonal_edge(block=block, window=window))
         l = l_sc[...]
         o_ref[...] = (acc_sc[...] / l).astype(o_ref.dtype)
         lse_ref[...] = jnp.broadcast_to(m_sc[...] + jnp.log(l), lse_ref.shape)
 
 
-def _causal_dq_kernel(qi_ref, kj_ref, qa_ref, qb_ref, ka_ref, kb_ref, v_ref,
-                      do_ref, lse_ref, dd_ref, dqa_ref, dqb_ref, dqa_sc, dqb_sc):
+def _causal_dq_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int, window: int | None):
+    (qa_ref, qb_ref, ka_ref, kb_ref, v_ref, do_ref, lse_ref, dd_ref, *outs) = _parts(
+        refs, two_part)
+    if two_part:
+        dqa_ref, dqb_ref, dqa_sc, dqb_sc = outs
+    else:
+        (dqa_ref, dqa_sc), dqb_ref, dqb_sc = outs, None, None
     t = pl.program_id(2)
     i, j = qi_ref[t], kj_ref[t]
     mm = qa_ref.dtype
 
-    @pl.when(j == 0)
+    @pl.when(j == _first_key_block(i, block=block, window=window))
     def _():
         dqa_sc[...] = jnp.zeros(dqa_sc.shape, jnp.float32)
-        dqb_sc[...] = jnp.zeros(dqb_sc.shape, jnp.float32)
+        if two_part:
+            dqb_sc[...] = jnp.zeros(dqb_sc.shape, jnp.float32)
 
-    def step(diagonal: bool):
-        ka, kb = ka_ref[...].astype(mm), kb_ref[...].astype(mm)
-        s = _scores(qa_ref[...].astype(mm), qb_ref[...].astype(mm), ka, kb, diagonal)
+    def step(diagonal: bool, edge=None):
+        ka, kb = _read(ka_ref, mm), _read(kb_ref, mm)
+        s = _scores(_read(qa_ref, mm), _read(qb_ref, mm), ka, kb, diagonal, edge)
         p = jnp.exp(s - lse_ref[...][:, :1])
         dp = jax.lax.dot_general(
             do_ref[...].astype(mm), v_ref[...].astype(mm), (((1,), (1,)), ((), ())),
@@ -548,30 +620,35 @@ def _causal_dq_kernel(qi_ref, kj_ref, qa_ref, qb_ref, ka_ref, kb_ref, v_ref,
         ds = (p * (dp - dd_ref[...][:, :1])).astype(mm)
         dims = (((1,), (0,)), ((), ()))
         dqa_sc[...] += jax.lax.dot_general(ds, ka, dims, preferred_element_type=jnp.float32)
-        dqb_sc[...] += jax.lax.dot_general(ds, kb, dims, preferred_element_type=jnp.float32)
+        if two_part:
+            dqb_sc[...] += jax.lax.dot_general(ds, kb, dims, preferred_element_type=jnp.float32)
 
-    @pl.when(j < i)
-    def _():
-        step(False)
+    _off_diagonal(i, j, lambda edge: step(False, edge), block=block, window=window)
 
     @pl.when(j == i)
     def _():
-        step(True)
+        step(True, _diagonal_edge(block=block, window=window))
         dqa_ref[...] = dqa_sc[...].astype(dqa_ref.dtype)
-        dqb_ref[...] = dqb_sc[...].astype(dqb_ref.dtype)
+        if two_part:
+            dqb_ref[...] = dqb_sc[...].astype(dqb_ref.dtype)
 
 
-def _causal_dkv_kernel(qi_ref, kj_ref, qa_ref, qb_ref, ka_ref, kb_ref, v_ref,
-                       do_ref, lse_ref, dd_ref, dka_ref, dkb_ref, dv_ref,
-                       dka_sc, dkb_sc, dv_sc, *, last: int):
+def _causal_dkv_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int, window: int | None,
+                       last: int, group: int):
+    (qa_ref, qb_ref, ka_ref, kb_ref, v_ref, do_ref, lse_ref, dd_ref, *outs) = _parts(
+        refs, two_part)
+    if two_part:
+        dka_ref, dkb_ref, dv_ref, dka_sc, dkb_sc, dv_sc = outs
+    else:
+        (dka_ref, dv_ref, dka_sc, dv_sc), dkb_ref, dkb_sc = outs, None, None
     t = pl.program_id(2)
     i, j = qi_ref[t], kj_ref[t]
     mm = qa_ref.dtype
 
-    def step(diagonal: bool):
-        qa, qb = qa_ref[...].astype(mm), qb_ref[...].astype(mm)
+    def step(diagonal: bool, edge=None):
+        qa, qb = _read(qa_ref, mm), _read(qb_ref, mm)
         do = do_ref[...].astype(mm)
-        s = _scores(qa, qb, ka_ref[...].astype(mm), kb_ref[...].astype(mm), diagonal)
+        s = _scores(qa, qb, _read(ka_ref, mm), _read(kb_ref, mm), diagonal, edge)
         p = jnp.exp(s - lse_ref[...][:, :1])
         dp = jax.lax.dot_general(
             do, v_ref[...].astype(mm), (((1,), (1,)), ((), ())),
@@ -581,24 +658,50 @@ def _causal_dkv_kernel(qi_ref, kj_ref, qa_ref, qb_ref, ka_ref, kb_ref, v_ref,
         dv_sc[...] += jax.lax.dot_general(p.astype(mm), do, dims,
                                           preferred_element_type=jnp.float32)
         dka_sc[...] += jax.lax.dot_general(ds, qa, dims, preferred_element_type=jnp.float32)
-        dkb_sc[...] += jax.lax.dot_general(ds, qb, dims, preferred_element_type=jnp.float32)
+        if two_part:
+            dkb_sc[...] += jax.lax.dot_general(ds, qb, dims, preferred_element_type=jnp.float32)
 
-    @pl.when(i == j)  # the diagonal is the first query block of a key block
-    def _():
+    def zero():
         dka_sc[...] = jnp.zeros(dka_sc.shape, jnp.float32)
-        dkb_sc[...] = jnp.zeros(dkb_sc.shape, jnp.float32)
+        if two_part:
+            dkb_sc[...] = jnp.zeros(dkb_sc.shape, jnp.float32)
         dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
-        step(True)
 
-    @pl.when(i > j)
-    def _():
-        step(False)
-
-    @pl.when(i == last)
-    def _():
+    def write():
         dka_ref[...] = dka_sc[...].astype(dka_ref.dtype)
-        dkb_ref[...] = dkb_sc[...].astype(dkb_ref.dtype)
+        if two_part:
+            dkb_ref[...] = dkb_sc[...].astype(dkb_ref.dtype)
         dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
+
+    diagonal_edge = _diagonal_edge(block=block, window=window)
+    end = last if window is None else jnp.minimum(j + _reach(window, block), last)
+    if group == 1:
+        @pl.when(i == j)  # the diagonal is the first query block of a key block
+        def _():
+            zero()
+            step(True, diagonal_edge)
+    else:  # the group's members are the innermost grid axis: zero before the
+        member = pl.program_id(3)  # first of them, write after the last
+        pl.when((i == j) & (member == 0))(zero)
+        pl.when(i == j)(lambda: step(True, diagonal_edge))
+
+    _off_diagonal(i, j, lambda edge: step(False, edge), block=block, window=window)
+
+    if group == 1:
+        pl.when(i == end)(write)
+    else:
+        pl.when((i == end) & (member == group - 1))(write)
+
+
+def causal_block(seq: int, window: int | None) -> int:
+    """The kernels' block for a sequence and a window: ``CAUSAL_BLOCK`` where
+    the whole triangle is walked; under a window the block that costs a
+    windowed layer least on the chip (PERF.md §6, PR 33: at 1024 a 512-token
+    window computes 3.9 x the score entries it needs, at 512 2.0 x, at 256
+    1.5 x, against more and smaller grid steps)."""
+    if window is None or window >= seq:
+        return CAUSAL_BLOCK
+    return min(CAUSAL_BLOCK, max(128, window // 128 * 128))
 
 
 def _causal_plan(seq: int, block: int) -> tuple[int, int]:
@@ -609,6 +712,24 @@ def _causal_plan(seq: int, block: int) -> tuple[int, int]:
     return _round_up(seq, block), block
 
 
+def _causal_band(seq: int, window: int | None, block: int | None):
+    """``(padded seq, block, window, reach)`` of a call: a window the sequence
+    never reaches is none, and ``block`` None is ``causal_block``'s."""
+    window = None if window is None or window >= seq else window
+    s_pad, block = _causal_plan(seq, block or causal_block(seq, window))
+    return s_pad, block, window, None if window is None else _reach(window, block)
+
+
+def causal_pairs(seq: int, window: int | None = None, block: int | None = None) -> tuple[int, int]:
+    """(visited, needed) score entries of one (head, sequence): those in the
+    block pairs the kernels' tables walk, and those the mask keeps
+    (``min(i + 1, window)`` keys for query ``i``). Static, from the tables."""
+    s_pad, block, window, reach = _causal_band(seq, window, block)
+    visited = len(_lower_triangle(s_pad // block, by_key=False, reach=reach)[0]) * block * block
+    w = seq if window is None else window
+    return visited, w * (w + 1) // 2 + (seq - w) * w
+
+
 def _pad_rows(x, to: int):
     pad = to - x.shape[-2]
     if not pad:
@@ -616,35 +737,47 @@ def _pad_rows(x, to: int):
     return jnp.pad(x, ((0, 0),) * (x.ndim - 2) + ((0, pad), (0, 0)))
 
 
-def _causal_call(kernel, tables, operands, out_widths, scratch, *, dtype, b, h,
+def _causal_call(kernel, tables, operands, out_widths, scratch, *, dtype, b, h, group,
                  s_pad, block, by_key, interpret, name):
-    """One ``pallas_call`` over (batch, heads, lower-triangle block pairs).
+    """One ``pallas_call`` over (batch, heads, visible block pairs).
     ``operands`` are ``(array, kind)`` with kind ``"q"`` (blocked by the
-    query index), ``"k"`` (by the key index) or ``"k_shared"`` (by the key
-    index, no head axis); ``out_widths`` are ``(width, dtype)`` of outputs
-    blocked by the outer index."""
+    query index, a query head), ``"k"`` (by the key index, a key/value head)
+    or ``"k_shared"`` (by the key index, no head axis); ``out_widths`` are
+    ``(width, dtype)`` of outputs blocked by the outer index. By query
+    (``by_key=False``) the second grid axis walks the ``h`` query heads and
+    head ``hi`` reads key/value head ``hi // group``; by key it walks the
+    ``h // group`` key/value heads, and a fourth, innermost axis the group's
+    members (none where ``group`` is 1)."""
+    members = by_key and group > 1
+
     def spec(width, kind):
-        if kind == "k_shared":
-            return pl.BlockSpec((None, block, width), lambda bi, hi, t, qi, kj: (bi, kj[t], 0))
-        if kind == "k":
-            return pl.BlockSpec((None, None, block, width),
-                                lambda bi, hi, t, qi, kj: (bi, hi, kj[t], 0))
-        return pl.BlockSpec((None, None, block, width),
-                            lambda bi, hi, t, qi, kj: (bi, hi, qi[t], 0))
+        def index(bi, hi, t, *rest):
+            qi, kj = rest[-2:]
+            if kind == "k_shared":
+                return bi, kj[t], 0
+            if kind == "k":
+                return bi, (hi // group if group > 1 and not by_key else hi), kj[t], 0
+            return bi, (hi * group + rest[0] if members else hi), qi[t], 0
+
+        shape = (None, block, width) if kind == "k_shared" else (None, None, block, width)
+        return pl.BlockSpec(shape, index)
 
     out_kind = "k" if by_key else "q"
+    outer = h // group if by_key else h
+    grid = (b, outer, len(tables[0])) + ((group,) if members else ())
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, h, len(tables[0])),
+            grid=grid,
             in_specs=[spec(x.shape[-1], kind) for x, kind in operands],
             out_specs=[spec(w, out_kind) for w, _ in out_widths],
             scratch_shapes=scratch,
         ),
-        out_shape=[jax.ShapeDtypeStruct((b, h, s_pad, w), dt or dtype) for w, dt in out_widths],
+        out_shape=[jax.ShapeDtypeStruct((b, outer, s_pad, w), dt or dtype)
+                   for w, dt in out_widths],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel") + ("arbitrary",) * (len(grid) - 2),
             vmem_limit_bytes=CAUSAL_VMEM_BYTES,
         ),
         interpret=interpret,
@@ -652,83 +785,103 @@ def _causal_call(kernel, tables, operands, out_widths, scratch, *, dtype, b, h,
     )(*tables, *(x for x, _ in operands))
 
 
-def _causal_fwd(qa, qb, ka, kb, v, block, interpret):
+def _causal_shape(qa, ka, block, window):
+    """``(batch, query heads, group, seq, padded seq, block, window, reach)``
+    of a call."""
     b, h, s, _ = qa.shape
-    s_pad, block = _causal_plan(s, block)
-    qa, qb, ka, kb, v = (_pad_rows(x, s_pad) for x in (qa, qb, ka, kb, v))
+    return b, h, h // ka.shape[1], s, *_causal_band(s, window, block)
+
+
+def _causal_fwd(qa, qb, ka, kb, v, block, interpret, window=None):
+    b, h, group, s, s_pad, block, window, reach = _causal_shape(qa, ka, block, window)
+    two_part = qb is not None
+    operands = [(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k")]
+    operands = [(_pad_rows(x, s_pad), kind) for x, kind in operands if x is not None]
     d_v = v.shape[-1]
     o, lse = _causal_call(
-        _causal_fwd_kernel, _lower_triangle(s_pad // block, by_key=False),
-        [(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k")],
+        functools.partial(_causal_fwd_kernel, two_part=two_part, block=block, window=window),
+        _lower_triangle(s_pad // block, by_key=False, reach=reach), operands,
         [(d_v, None), (LANE, jnp.float32)],
         [pltpu.VMEM((block, 1), jnp.float32), pltpu.VMEM((block, 1), jnp.float32),
          pltpu.VMEM((block, d_v), jnp.float32)],
-        dtype=qa.dtype, b=b, h=h, s_pad=s_pad, block=block, by_key=False,
+        dtype=qa.dtype, b=b, h=h, group=group, s_pad=s_pad, block=block, by_key=False,
         interpret=interpret, name="causal_attention_fwd",
     )
     return o[:, :, :s], lse[..., 0]
 
 
-def _causal_bwd(qa, qb, ka, kb, v, o, lse, g, block, interpret):
-    b, h, s, d_a = qa.shape
-    d_b, d_v = qb.shape[-1], v.shape[-1]
-    s_pad, block = _causal_plan(s, block)
-    qa, qb, ka, kb, v, o, g = (_pad_rows(x, s_pad) for x in (qa, qb, ka, kb, v, o, g))
+def _causal_bwd(qa, qb, ka, kb, v, o, lse, g, block, interpret, window=None):
+    b, h, group, s, s_pad, block, window, reach = _causal_shape(qa, ka, block, window)
+    two_part = qb is not None
+    d_a, d_v = qa.shape[-1], v.shape[-1]
+    o, g = _pad_rows(o, s_pad), _pad_rows(g, s_pad)
     # D = rowsum(dO ∘ O), as for the non-causal kernels: tiny, elementwise
     dd = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1, keepdims=True)
     dd = jnp.broadcast_to(dd, (b, h, s_pad, LANE))
     lse = jnp.broadcast_to(lse[..., None], (b, h, s_pad, LANE))
     n = s_pad // block
-    operands = [(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k"),
-                (g, "q"), (lse, "q"), (dd, "q")]
-    common = dict(dtype=qa.dtype, b=b, h=h, s_pad=s_pad, block=block, interpret=interpret)
+    operands = [(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k")]
+    operands = [(_pad_rows(x, s_pad), kind) for x, kind in operands if x is not None]
+    operands += [(g, "q"), (lse, "q"), (dd, "q")]
+    common = dict(dtype=qa.dtype, b=b, h=h, group=group, s_pad=s_pad, block=block,
+                  interpret=interpret)
+    static = dict(two_part=two_part, block=block, window=window)
     f32 = lambda w: pltpu.VMEM((block, w), jnp.float32)
-    dqa, dqb = _causal_call(
-        _causal_dq_kernel, _lower_triangle(n, by_key=False), operands,
-        [(d_a, None), (d_b, None)], [f32(d_a), f32(d_b)],
+    second = [qb.shape[-1]] if two_part else []
+    dqa, *dqb = _causal_call(
+        functools.partial(_causal_dq_kernel, **static),
+        _lower_triangle(n, by_key=False, reach=reach), operands,
+        [(w, None) for w in [d_a, *second]], [f32(w) for w in [d_a, *second]],
         by_key=False, name="causal_attention_dq", **common,
     )
     # the shared key part's gradient comes out per head and is summed outside
-    dka, dkb, dv = _causal_call(
-        functools.partial(_causal_dkv_kernel, last=n - 1),
-        _lower_triangle(n, by_key=True), operands,
-        [(d_a, None), (d_b, jnp.float32), (d_v, None)], [f32(d_a), f32(d_b), f32(d_v)],
+    dka, *dkb, dv = _causal_call(
+        functools.partial(_causal_dkv_kernel, last=n - 1, group=group, **static),
+        _lower_triangle(n, by_key=True, reach=reach), operands,
+        [(d_a, None), *((w, jnp.float32) for w in second), (d_v, None)],
+        [f32(w) for w in [d_a, *second, d_v]],
         by_key=True, name="causal_attention_dkv", **common,
     )
-    dkb = dkb.sum(axis=1).astype(kb.dtype)
-    return tuple(x[..., :s, :] for x in (dqa, dqb, dka, dkb, dv))
+    dqb = dqb[0][..., :s, :] if two_part else None
+    dkb = dkb[0].sum(axis=1).astype(kb.dtype)[..., :s, :] if two_part else None
+    return dqa[..., :s, :], dqb, dka[..., :s, :], dkb, dv[..., :s, :]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def pallas_causal_attention(
     q_a: jax.Array,
-    q_b: jax.Array,
+    q_b: jax.Array | None,
     k_a: jax.Array,
-    k_b: jax.Array,
+    k_b: jax.Array | None,
     v: jax.Array,
-    block: int = CAUSAL_BLOCK,
+    block: int | None = None,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
-    """Causal softmax(q_a·k_aᵀ + q_b·k_bᵀ)·v; queries pre-scaled.
+    """Causal softmax(q_a·k_aᵀ + q_b·k_bᵀ)·v; queries pre-scaled. With
+    ``window``, query ``i`` sees keys ``i − window + 1 .. i`` only.
 
-    ``q_a``, ``k_a``: (batch, heads, seq, d_a); ``q_b``: (batch, heads, seq,
-    d_b); ``k_b``: (batch, seq, d_b), one vector a token for all heads;
-    ``v``: (batch, heads, seq, d_v). Returns (batch, heads, seq, d_v).
-    Forward and backward are Pallas kernels over the lower triangle of
-    block pairs (see the section comment above)."""
-    return _causal_fwd(q_a, q_b, k_a, k_b, v, block, interpret)[0]
+    ``q_a``: (batch, heads, seq, d_a); ``k_a``: (batch, kv heads, seq, d_a),
+    ``kv heads`` a divisor of ``heads`` (query head ``h`` reads ``h // (heads
+    / kv heads)``); ``q_b``: (batch, heads, seq, d_b) and ``k_b``: (batch,
+    seq, d_b), one vector a token for all heads, or both None; ``v``:
+    (batch, kv heads, seq, d_v). Returns (batch, heads, seq, d_v). ``block``
+    None takes ``causal_block``'s for the shape. Forward and backward are
+    Pallas kernels over the visible block pairs (see the section comment
+    above)."""
+    return _causal_fwd(q_a, q_b, k_a, k_b, v, block, interpret, window)[0]
 
 
-def _causal_vjp_fwd(q_a, q_b, k_a, k_b, v, block, interpret):
-    o, lse = _causal_fwd(q_a, q_b, k_a, k_b, v, block, interpret)
+def _causal_vjp_fwd(q_a, q_b, k_a, k_b, v, block=None, interpret=False, window=None):
+    o, lse = _causal_fwd(q_a, q_b, k_a, k_b, v, block, interpret, window)
     # the primal output and the residual are the one named array
     o = checkpoint_name(o, CAUSAL_OUT_NAME)
     lse = checkpoint_name(lse, CAUSAL_LSE_NAME)
     return o, (q_a, q_b, k_a, k_b, v, o, lse)
 
 
-def _causal_vjp_bwd(block, interpret, residuals, g):
-    return _causal_bwd(*residuals, g, block, interpret)
+def _causal_vjp_bwd(block, interpret, window, residuals, g):
+    return _causal_bwd(*residuals, g, block, interpret, window)
 
 
 pallas_causal_attention.defvjp(_causal_vjp_fwd, _causal_vjp_bwd)

@@ -1,0 +1,105 @@
+"""``chip_smoke``'s ``lm_train`` phase rehearsed on the CPU at toy widths,
+once for each language recipe: the recipe's own layer pattern through
+``cli.train``, every batch's loss lower on its second visit, nothing dropped,
+nothing skipped, the family's counters logged and published. One test, a case
+a recipe."""
+
+from __future__ import annotations
+
+import json
+
+import pytest
+
+import chip_smoke
+from test_chip_compile import watch  # noqa: F401 - fixture
+
+RECIPES = chip_smoke.REPO / "recipes"
+# every recipe is cut the same way: 8 sequences a step, a 64-row slice of a
+# 512-row vocabulary, width 32, 16 experts top-4 of which 4 are held
+_COMMON = dict(vocab_size=512, vocab_rows=[64, 64], dim=32, dense_hidden=64, expert_hidden=16,
+               n_routed_experts=16, experts_held=[4, 4], experts_per_token=4, dtype="float32")
+
+
+def _toy(seq: int, **lm) -> list[str]:
+    return [f"data.seq_len={seq}", "run.train_batch_size=8", "run.valid_batch_size=8",
+            "mesh.fsdp=1", "optim.learning_rate=3e-3", "optim.init_lr=3e-3",
+            "optim.warmup_steps=1", *(f"model.lm.{k}={v}" for k, v in (_COMMON | lm).items())]
+
+
+def _all_mla(checked, published, records):
+    """Two blocks + the MTP block of latent attention."""
+    assert checked["moe_rounds"] == 1
+    assert 0.1 < checked["moe_held_share_min_max"][0] <= checked["moe_held_share_min_max"][1] < 0.5
+    assert checked["mfu_trainer_reported"] is None  # a CPU count is not a device rate
+    assert checked["kda_kernel_calls"] == {"fwd": 0, "bwd": 0, "loops": 0}
+    assert checked["attn_pairs"] == {"mla": {"visited": 128 * 128, "needed": 16 * 17 // 2}}
+    assert {"imbalance", "held_share", "dropped", "rounds", "rows_max_l1", "rows_min_mtp",
+            "rounds_l1", "rounds_mtp"} <= set(published["train_moe"])
+    assert published["train_moe"]["rounds"] == 1
+
+
+def _hybrid(checked, published, records):
+    """7 blocks: dense KDA, KDA, KDA, KDA, KDA, MLA, KDA; the
+    linear-attention counters beside the experts'."""
+    assert checked["kda_kernel_calls"] == {"fwd": 0, "bwd": 0, "loops": 3 * 6}  # off the chip: the scan
+    assert 0 < checked["kda_state_absmax_max"] < 10
+    low, high = checked["kda_decay_mean_min_max"]
+    assert 0.9 < low <= high < 1.0
+    assert set(checked["attn_pairs"]) == {"mla"}
+    assert {"state_absmax", "decay_mean", "state_absmax_l0", "decay_mean_l6"} <= set(
+        published["train_kda"])
+    assert "state_absmax_l5" not in published["train_kda"]  # block 5 is the MLA block
+    assert {"imbalance", "rounds_l1", "rounds_l6"} <= set(published["train_moe"])
+
+
+def _grouped_query(checked, published, records):
+    """8 blocks: (full, sliding, sliding, sliding) twice, 6 and 8 query
+    heads over 2 key/value heads, a window of 11 of 24 tokens; the static
+    pair counts are in the phase's line and, once, in the trainer's log."""
+    assert checked["kda_kernel_calls"] == {"fwd": 0, "bwd": 0, "loops": 0}
+    pairs = checked["attn_pairs"]
+    assert set(pairs) == {"full_attention", "sliding_attention"}
+    assert pairs["full_attention"]["needed"] == 24 * 25 // 2
+    assert pairs["sliding_attention"]["needed"] == 11 * 12 // 2 + 13 * 11
+    assert all(p["visited"] >= p["needed"] for p in pairs.values())
+    (logged,) = [r for r in records if "train/attn_pairs_needed_sliding_attention" in r]
+    assert logged["train/attn_pairs_needed_sliding_attention"] == 11 * 12 // 2 + 13 * 11
+    assert logged["train/attn_pairs_visited_full_attention"] == pairs["full_attention"]["visited"]
+    assert {"imbalance", "rounds_l1", "rounds_l7"} <= set(published["train_moe"])
+    assert "rounds_l0" not in published["train_moe"]  # block 0 has the dense MLP
+
+
+CASES = [
+    pytest.param("pretrain_joyai_flash_ep16", _toy(
+        16, layers=2, heads=2, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16), _all_mla, id="joyai_flash"),
+    pytest.param("pretrain_ling3_flash_ep64", _toy(
+        24, heads=2, kda_head_dim=16, kda_chunk=8, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, shared_expert_hidden=16, n_group=4, topk_group=2),
+        _hybrid, id="ling3_flash"),
+    pytest.param("pretrain_laguna_xs2_share", _toy(
+        24, heads_per_layer=[6, 8, 8, 8, 6, 8, 8, 8], kv_heads=2, head_dim=16, sliding_window=11,
+        shared_expert_hidden=16), _grouped_query, id="laguna_xs2"),
+]
+
+
+@pytest.mark.parametrize("recipe,toy,family", CASES)
+def test_lm_train_phase_rehearsal(recipe, toy, family, tmp_path, watch, capsys):  # noqa: F811
+    steps = 10
+    path = str(RECIPES / f"{recipe}.yaml")
+    overrides = chip_smoke._lm_overrides(steps) + toy
+    assert chip_smoke.run_phase(
+        "lm_train",
+        lambda: chip_smoke.phase_lm_train(path, overrides, tmp_path, steps=steps),
+        tmp_path, watch,
+    )
+    (line,) = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    checked = line["checked"]
+    assert checked["loss_after_one_cycle"] < checked["loss_first"]
+    assert checked["moe_dropped"] == 0 and checked["skipped_steps"] == 0
+    # on the CPU the core resolves to its einsum form: no kernel in the step
+    assert checked["causal_kernel_calls"] == {"fwd": 0, "dq": 0, "dkv": 0}
+    from jumbo_mae_tpu_tpu.obs.metrics import get_registry
+
+    run_name = chip_smoke._load(path, overrides).run.name
+    family(checked, get_registry().snapshot(), chip_smoke._read_metrics(tmp_path / run_name))

@@ -40,15 +40,19 @@ def _flat(tree):
 
 def test_both_heads_logits_match_the_reference():
     config, cfg, params, biases, tokens = _setup()
-    got = MlaMoeLM(cfg).apply({"params": params, "batch_stats": biases}, tokens,
-                              method="logits")
+    # jitted, both: op by op every layer compiles a program an operation
+    got = jax.jit(lambda p: MlaMoeLM(cfg).apply({"params": p, "batch_stats": biases}, tokens,
+                                                method="logits"))(params)
     assert len(got) == 2 and got[0].shape == (3, 20, config["vocab_size"])
     ops = lm_model.Ops()
+
+    @jax.jit
+    def reference(ids):
+        hidden = lm_model.hidden_states(ops, params, biases, ids, config)[0]
+        return [lm_model.head_logits(ops, params, h, config) for h in hidden]
+
     for row in range(tokens.shape[0]):
-        ids = tokens[row] - config["vocab_rows"][0]
-        hidden, _ = lm_model.hidden_states(ops, params, biases, ids, config)
-        for head, h in enumerate(hidden):
-            want = lm_model.head_logits(ops, params, h, config)
+        for head, want in enumerate(reference(tokens[row] - config["vocab_rows"][0])):
             np.testing.assert_allclose(got[head][row], want, rtol=2e-4, atol=2e-5)
 
 
@@ -60,9 +64,9 @@ def test_loss_and_every_gradient_leaf_match_the_reference():
         out = model.apply({"params": p, "batch_stats": biases}, tokens)
         return out["loss"], out
 
-    (loss, out), grads = jax.value_and_grad(program, has_aux=True)(params)
-    (want, _), want_grads = jax.value_and_grad(
-        lambda p: lm_model.batch_loss(p, biases, tokens, config), has_aux=True)(params)
+    (loss, out), grads = jax.jit(jax.value_and_grad(program, has_aux=True))(params)
+    (want, _), want_grads = jax.jit(jax.value_and_grad(
+        lambda p: lm_model.batch_loss(p, biases, tokens, config), has_aux=True))(params)
     np.testing.assert_allclose(loss, want, rtol=1e-5)
     assert float(out["moe_dropped"]) == 0.0
     got, ref = _flat(grads), _flat(want_grads)
