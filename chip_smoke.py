@@ -389,6 +389,26 @@ def kda_kernel_calls(text: str) -> dict:
     return {**calls, "loops": len(re.findall(r' while\([^\n]*/attn/kda_core/[^"\n]*while"', text))}
 
 
+def rope_kernel_calls(text: str) -> int:
+    """How often a compiled program's text calls the rotate-half rope kernel
+    (``ops/pallas/rope.py``)."""
+    return len(re.findall(r'custom-call\([^\n]*[/(]rope_half\)*/pallas_call"', text))
+
+
+def check_step_runs_the_rope_kernel(programs: dict, lm) -> int:
+    """The step program among ``programs`` turns each grouped-query block's
+    ``q`` and ``k`` through the rope kernel three times: forward, under the
+    block's rematerialisation, and transposed. Off the chip, and in a family
+    whose rope is on adjacent pairs, the step holds no such call."""
+    import jax
+
+    calls = rope_kernel_calls(programs["train_step"].as_text())
+    blocks = lm.layers if lm.layer_types is not None else 0
+    want = 3 * 2 * blocks if jax.default_backend() == "tpu" else 0
+    check(calls == want, f"the step calls the rope kernel {calls} times, not {want}")
+    return calls
+
+
 def check_step_runs_the_kda_kernels(programs: dict, lm) -> dict:
     """The step program among ``programs`` runs, for each of ``lm``'s
     linear-attention blocks, the forward chunk kernel twice (forward, and
@@ -428,11 +448,12 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     the cycled batches' loss lower the second time it is seen; nothing
     dropped by an expert layer, whose held pairs fit one round of its chunk
     at the recipe's routing; no step skipped by the guard; the step program
-    runs each of the causal core's kernels once a latent-attention block
-    and the chunk kernels (forward twice, backward once) a linear-attention
-    block; where the recipe has linear-attention layers, their counters are
-    logged on every step and their states stay bounded. ``recipe`` is
-    either language family's (``--lm-recipe``)."""
+    runs each of the causal core's kernels once a latent-attention block,
+    the chunk kernels (forward twice, backward once) a linear-attention
+    block and the rope kernel six times a grouped-query block; where the
+    recipe has linear-attention layers, their counters are logged on every
+    step and their states stay bounded. ``recipe`` is any language family's
+    (``--lm-recipe``)."""
     from jumbo_mae_tpu_tpu.cli import train as cli_train
     from jumbo_mae_tpu_tpu.models.lm import MlaMoeConfig
     from jumbo_mae_tpu_tpu.obs.trace import keeping_programs
@@ -445,6 +466,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     lm = MlaMoeConfig(**cfg.model.lm)
     calls = check_step_runs_each_causal_kernel_once_a_block(programs, lm)
     kda_calls = check_step_runs_the_kda_kernels(programs, lm)
+    rope_calls = check_step_runs_the_rope_kernel(programs, lm)
     programs.clear()  # or the step's executable outlives the phase
     records = _read_metrics(out_dir / cfg.run.name)
     losses = _logged_losses(records, 1, steps, "lm_train")
@@ -480,6 +502,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         "moe_rounds": 1,
         "causal_kernel_calls": calls,
         "kda_kernel_calls": kda_calls,
+        "rope_kernel_calls": rope_calls,
         "attn_pairs": {kind: {"visited": visited, "needed": needed} for kind, (visited, needed)
                        in lm.attn_pairs(cfg.data.seq_len).items()},
         "moe_held_share_min_max": [round(min(share), 4), round(max(share), 4)],

@@ -37,7 +37,13 @@ factor`` by ``γ_j = clip((j − low) / (high − low), 0, 1)``, ``low = ⌊r
 ln(L₀ / (2π β_fast)) / (2 ln θ)⌋``, ``high = ⌈r ln(L₀ / (2π β_slow)) / (2 ln
 θ)⌉`` clipped to ``[0, r − 1]``, ``L₀ = original_max_position_embeddings``:
 ``inv_freq_j = (1 − γ_j) f_j + γ_j f_j / factor``, and multiplies ``cos`` and
-``sin`` by ``attention_factor``. ``s = q kᵀ d^-½``; key ``j`` is visible to
+``sin`` by ``attention_factor``. In float32, rounded once to the compute
+dtype (``rope_half``): on the TPU, where ``q`` and ``k`` are head-major with
+``d`` a whole number of 128-lane tiles and a sequence that cuts into blocks
+of 16 rows, one Pallas pass that reads a block, turns it in VMEM and writes
+it, and is its own transpose with the sines negated
+(``ops/pallas/rope.py``); anywhere else the same formula in ``jax.numpy``.
+``s = q kᵀ d^-½``; key ``j`` is visible to
 query ``i`` iff ``j <= i`` and, in a ``sliding_attention`` layer, ``i − j <
 sliding_window``; ``z = softmax(s) v``; ``z_h ← sigmoid(x W_γ)_h · z_h``;
 ``W_o`` over heads x d. The core is ``ops/flash_attention.causal_attention``,
@@ -386,17 +392,56 @@ def rope_interleaved(x, theta: float):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def rope_half(x, rope: Rope):
+def _rope_angles(rope: Rope, seq: int, r: int):
+    """``attention_factor`` times the cosine and the sine of ``position ·
+    inv_freq_j``: two (seq, r / 2) float32 tables."""
+    inv = jnp.asarray(rope.inv_freq(r), jnp.float32)
+    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv[None, :]
+    return rope.attention_factor * jnp.cos(angle), rope.attention_factor * jnp.sin(angle)
+
+
+def _rope_half_pass(x, rope: Rope, sign: float, interpret: bool):
+    """``rope_half`` as the one-pass kernel (``sign`` 1), or its transpose
+    (−1): a rotation scaled by a factor transposes to the rotation back
+    scaled by the same factor, the same map with the sines negated."""
+    from jumbo_mae_tpu_tpu.ops.pallas.rope import rotate_half
+
+    seq, d = x.shape[-2:]
+    r = int(d * rope.partial_rotary_factor)
+    cos, sin = _rope_angles(rope, seq, r)
+    c = jnp.concatenate([cos, cos, jnp.ones((seq, d - r), jnp.float32)], axis=-1)
+    s = jnp.concatenate([-sign * sin, sign * sin, jnp.zeros((seq, d - r), jnp.float32)], axis=-1)
+    return rotate_half(x, c, s, r, interpret=interpret)
+
+
+# no residual: the transpose makes its tables again from the static Rope
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _rope_half_kernel(x, rope: Rope, interpret: bool):
+    return _rope_half_pass(x, rope, 1.0, interpret)
+
+
+_rope_half_kernel.defvjp(
+    lambda x, rope, interpret: (_rope_half_pass(x, rope, 1.0, interpret), None),
+    lambda rope, interpret, _, ct: (_rope_half_pass(ct, rope, -1.0, interpret),))
+
+
+def rope_half(x, rope: Rope, *, interpret: bool = False):
     """Rotary embedding with the rotate-half pairing: of the last axis' first
     ``r = partial_rotary_factor · d`` dimensions, ``j`` turns with ``j + r/2``
     by ``position · inv_freq_j``, ``cos`` and ``sin`` times
     ``attention_factor``; the other ``d − r`` pass through. Positions run
-    along the axis before the last. Float32 inside."""
+    along the axis before the last. Float32 inside. On the TPU a head-major
+    (batch, heads, seq, d) operand whose shape the kernel takes
+    (``ops/pallas/rope.rope_blocks``) goes through it in one pass;
+    ``interpret`` runs the kernel in the Pallas interpreter (tests)."""
+    from jumbo_mae_tpu_tpu.ops.pallas.rope import rope_blocks
+
     seq, d = x.shape[-2:]
     r = int(d * rope.partial_rotary_factor)
-    inv = jnp.asarray(rope.inv_freq(r), jnp.float32)
-    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos, sin = rope.attention_factor * jnp.cos(angle), rope.attention_factor * jnp.sin(angle)
+    if ((interpret or jax.default_backend() == "tpu") and x.ndim == 4
+            and rope_blocks(x.shape[1], seq, d)):
+        return _rope_half_kernel(x, rope, interpret)
+    cos, sin = _rope_angles(rope, seq, r)
     turned = x[..., :r].astype(jnp.float32)
     a, b = turned[..., : r // 2], turned[..., r // 2:]
     out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)

@@ -177,6 +177,7 @@ def test_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):
     text = compiled.as_text()
     assert " conditional(" not in text and "/guard/" in text
     assert chip_smoke.causal_kernel_calls(text) == {"fwd": 6, "dq": 6, "dkv": 6}
+    assert chip_smoke.rope_kernel_calls(text) == 0  # rope on adjacent pairs: not the kernel's
     assert "gmm" in text
     pairs = rows * cfg.data.seq_len * lm.experts_per_token
     assert pairs == 131_072

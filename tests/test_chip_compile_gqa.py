@@ -15,9 +15,10 @@ import chip_smoke
 from test_chip_compile import compile_lm_step, program_bytes, v5e_chip  # noqa: F401 - fixture
 
 RECIPE = str(chip_smoke.REPO / "recipes" / "pretrain_laguna_xs2_share.yaml")
-# what one AOT compile of this step read (PERF.md, PR 33), and the chip's own
-# line: 16 GiB less what the runtime keeps
-PROGRAM_BYTES, CHIP_BYTES = 13_096_821_760, 16.9e9
+# what one AOT compile of this step read (PERF.md, PR 35; 13 096 821 760 before
+# the rope kernel, PR 33), and the chip's own line: 16 GiB less what the
+# runtime keeps
+PROGRAM_BYTES, CHIP_BYTES = 13_059_776_000, 16.9e9
 
 
 def test_grouped_query_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):  # noqa: F811
@@ -27,8 +28,12 @@ def test_grouped_query_language_model_step_compiles_for_v5e_and_fits(v5e_chip, m
     two full layers' under ``attn_core`` and the six window layers' under
     ``swa_core``; nothing sized (seq, seq) a head exists; the window layers'
     tables walk the band (2.0 x the entries their mask keeps, not the
-    triangle's 8.3); the expert layers walk their held pairs in a loop, the
-    guard adds no ``conditional``, and what the step holds fits the chip."""
+    triangle's 8.3); each block turns its ``q`` and its ``k`` through the
+    rope kernel three times (forward, under the block's rematerialisation,
+    transposed: 8 x 2 x 3 calls) and no float32 array of their shapes is
+    left under the ``rope`` scope; the expert layers walk their held pairs
+    in a loop, the guard adds no ``conditional``, and what the step holds
+    fits the chip."""
     cfg, lm, parameters, compiled = compile_lm_step(RECIPE, v5e_chip, monkeypatch)
     assert parameters == 765_954_048
     rows, seq = cfg.run.train_batch_size, cfg.data.seq_len
@@ -41,6 +46,11 @@ def test_grouped_query_language_model_step_compiles_for_v5e_and_fits(v5e_chip, m
     assert by_kind == {"attn_core": 3 * 2, "swa_core": 3 * 6}
     assert (lm.layer_types.count("full_attention"), lm.layer_types.count("sliding_attention")) \
         == (2, 6)
+    assert chip_smoke.rope_kernel_calls(text) == 8 * 2 * 3
+    under_rope = [line for line in text.splitlines() if re.search(r'op_name="[^"]*/rope/', line)]
+    assert len(under_rope) >= 8 * 2 * 3
+    for h in sorted({*lm.heads_per_layer, lm.kv_heads}):
+        assert not [line for line in under_rope if f"f32[{rows},{h},{seq}," in line], h
     for h in sorted({*lm.heads_per_layer, lm.kv_heads, lm.heads_per_layer[1] // lm.kv_heads}):
         for wide in (f"[{rows},{h},{seq},{seq}]", f"[{h},{seq},{seq}]"):
             assert wide not in text, wide

@@ -36,6 +36,7 @@ def test_hybrid_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypa
     text = compiled.as_text()
     assert " conditional(" not in text and "/guard/" in text
     assert chip_smoke.causal_kernel_calls(text) == {"fwd": 1, "dq": 1, "dkv": 1}
+    assert chip_smoke.rope_kernel_calls(text) == 0  # rope on adjacent pairs: not the kernel's
     assert "gmm" in text
     assert chip_smoke.kda_kernel_calls(text) == {"fwd": 2 * lm.kda_layers, "bwd": lm.kda_layers,
                                                  "loops": 0}

@@ -151,6 +151,65 @@ def test_rope_half_against_complex_rotation():
     np.testing.assert_allclose(plain_rope.inv_freq(128), 1e4 ** (-np.arange(64) / 64), rtol=1e-12)
 
 
+# a bfloat16 output may differ by one rounding; a float32 one by the order of
+# a multiply and an add
+ROPE_TOL = {"bfloat16": dict(rtol=2**-7, atol=2**-9), "float32": dict(rtol=1e-5, atol=1e-5)}
+
+
+@pytest.mark.parametrize("case,kind,dtype,shape", [
+    *[(case, kind, dtype, (2, 4, 128, 128)) for case in ("forward", "vjp")
+      for kind in lm.GQA_KINDS for dtype in ROPE_TOL],
+    ("pass_through", "full_attention", "bfloat16", (2, 4, 128, 128)),
+    ("pass_through", "full_attention", "float32", (2, 4, 128, 128)),
+    ("falls_back", "full_attention", "bfloat16", (2, 4, 40, 128)),  # no block of whole sublane tiles
+    ("falls_back", "sliding_attention", "float32", (2, 4, 64, 96)),  # no whole 128-lane tile
+    ("falls_back", "full_attention", "float32", (2, 40, 128)),  # not head-major
+])
+def test_rope_kernel_against_the_jax_numpy_form(case, kind, dtype, shape, monkeypatch):
+    """The one-pass kernel (``ops/pallas/rope.py``, in the Pallas
+    interpreter, at blocks cut so that every grid axis has two steps: a
+    block's positions come from its block of the tables), for the cell's own
+    two kinds of ``Rope`` (θ 1e4 on all 128 dimensions; θ 5e5, YaRN factor 64
+    on the first 64, attention factor 1.4159), is ``rope_half``'s
+    ``jax.numpy`` form, which is what runs here without ``interpret``: the
+    output and, with no residual kept, the transpose; the dimensions past the
+    rotary part to the bit; a shape the kernel does not take goes to the
+    ``jax.numpy`` form and raises nothing."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from jumbo_mae_tpu_tpu.ops.pallas import rope as kernel
+
+    monkeypatch.setattr(kernel, "SEQ_BLOCK", 64)
+    monkeypatch.setattr(kernel, "BLOCK_ELEMENTS", 2 * 64 * 128)
+    rope = MlaMoeConfig(**DRIVER.lm_fields(harness.load_cell(CELL)["config"])).rope(kind)
+    assert (rope.partial_rotary_factor, rope.attention_factor > 1) == (
+        (0.5, True) if kind == "full_attention" else (1.0, False))
+    x, w = (jax.random.normal(jax.random.key(i), shape, jnp.float32).astype(dtype)
+            for i in (0, 1))
+    f32 = lambda a: np.asarray(a, np.float32)
+    if case == "falls_back":
+        assert len(shape) != 4 or kernel.rope_blocks(*shape[1:]) is None
+        np.testing.assert_array_equal(f32(lm.rope_half(x, rope, interpret=True)),
+                                      f32(lm.rope_half(x, rope)))
+        return
+    assert kernel.rope_blocks(*shape[1:]) == (2, 64)
+    got = lm.rope_half(x, rope, interpret=True)
+    assert got.dtype == x.dtype
+    if case == "forward":
+        np.testing.assert_allclose(f32(got), f32(lm.rope_half(x, rope)), **ROPE_TOL[dtype])
+    elif case == "pass_through":
+        r = int(shape[-1] * rope.partial_rotary_factor)
+        np.testing.assert_array_equal(f32(got[..., r:]), f32(x[..., r:]))
+        assert not np.array_equal(f32(got[..., :r]), f32(x[..., :r]))
+    else:
+        ct, = jax.vjp(lambda x: lm.rope_half(x, rope, interpret=True), x)[1](w)
+        want, = jax.vjp(lambda x: lm.rope_half(x, rope), x)[1](w)
+        assert ct.dtype == x.dtype
+        np.testing.assert_allclose(f32(ct), f32(want), **ROPE_TOL[dtype])
+        # nothing is kept for the transpose, which makes its tables again
+        assert saved_residuals(lambda x: lm.rope_half(x, rope, interpret=True), x) == []
+
+
 def _layer(config, cfg, seed=5, tokens=40):
     """One expert layer's full weights (all 16 experts), biases and input."""
     whole = config | {"num_experts": config["published"]["num_experts"],
