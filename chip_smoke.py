@@ -456,12 +456,14 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     (``--lm-recipe``)."""
     from jumbo_mae_tpu_tpu.cli import train as cli_train
     from jumbo_mae_tpu_tpu.models.lm import MlaMoeConfig
-    from jumbo_mae_tpu_tpu.obs.trace import keeping_programs
+    from jumbo_mae_tpu_tpu.obs.trace import format_setup_report, keeping_programs, setup_report
 
-    before = _registry_snapshot()
+    before, t0 = _registry_snapshot(), time.perf_counter()
     with keeping_programs() as programs:  # the trainer's step dies with its loop
         cli_train.main(_train_argv(recipe, overrides, out_dir))
     after = _registry_snapshot()
+    for line in format_setup_report(setup_report(t0), min_s=0.25):
+        print(f"[lm_train] {line}", flush=True)  # this phase's records alone, set-up and steps
     cfg = _load(recipe, overrides)
     lm = MlaMoeConfig(**cfg.model.lm)
     calls = check_step_runs_each_causal_kernel_once_a_block(programs, lm)

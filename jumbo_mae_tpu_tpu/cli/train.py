@@ -96,6 +96,12 @@ from jumbo_mae_tpu_tpu.obs import (
     stats_dict,
     trace,
 )
+from jumbo_mae_tpu_tpu.obs.trace import (
+    SPAN_MODEL_BUILD,
+    format_setup_report,
+    setup_report,
+    spanned,
+)
 from jumbo_mae_tpu_tpu.obs.costmodel import (
     cost_asdict,
     extract_cost,
@@ -122,6 +128,7 @@ from jumbo_mae_tpu_tpu.train.modes import MODES, STEP_MODE
 from jumbo_mae_tpu_tpu.utils.procenv import enable_compile_cache
 
 
+@spanned(SPAN_MODEL_BUILD)
 def build_model(cfg: TrainConfig):
     """Construct the mode's flax module and its per-sample train FLOPs (a
     sample is an image, or in mode ``lm`` a sequence of ``data.seq_len``
@@ -1425,6 +1432,10 @@ def train(cfg: TrainConfig) -> dict:
             diag_pending.clear()
         summary = meter.summary("train/")
         if step_cost is None:
+            # the first losses are on the host: set-up is over, and the span
+            # log says where it went (obs/trace.py; README "Reading a trace")
+            for line in format_setup_report(setup_report(), min_s=0.25):
+                print(f"[setup] {line}")
             execs = getattr(train_step, "executables", None)
             if execs:
                 cost = extract_cost(

@@ -5,8 +5,9 @@
   telemetry-off A/B runs); hosts ``AverageMeter``.
 - ``obs.exporter`` — stdlib HTTP server for ``/metrics`` and ``/healthz``.
 - ``obs.trace``    — host-side spans (registry histogram + profiler
-  annotation), the step program's scope vocabulary, the record of compiled
-  step programs, and the XLA device-trace capture helper.
+  annotation + the always-on span log, with JAX's trace / lower / compile
+  events as child records), the step program's scope vocabulary, the record
+  of compiled step programs, and the XLA device-trace capture helper.
 - ``obs.mfu``      — analytic FLOPs + MFU reporting (fed into the registry
   by the train loop), with one device_kind normalizer for the peak-TFLOPS
   tables.

@@ -24,42 +24,22 @@ and never in a compile-free steady state). Protocol:
 Metrics: ``retrace_compiles_total`` (every compile seen while active),
 ``retrace_events_total`` (violations), ``retrace_armed`` gauge.
 
-JAX has no per-listener unregister, so one module-level listener is
-installed on first use and dispatches to live sentinels via a WeakSet —
-creating/dropping sentinels (tests do this a lot) never accumulates
-listeners.
+JAX has no per-listener unregister, so the program has one listener for the
+compile event: ``obs.trace``'s, registered when that module is imported, which
+also writes the event into the span log. It calls the live sentinels through
+a WeakSet (``obs.trace.compile_watchers``) — creating/dropping sentinels
+(tests do this a lot) never accumulates listeners.
 """
 
 from __future__ import annotations
 
 import threading
 import warnings
-import weakref
 from contextlib import contextmanager
 
+from jumbo_mae_tpu_tpu.obs.trace import COMPILE_EVENT, compile_watchers
+
 __all__ = ["RetraceSentinel", "COMPILE_EVENT"]
-
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-_sentinels: "weakref.WeakSet[RetraceSentinel]" = weakref.WeakSet()
-_listener_installed = False
-
-
-def _dispatch(event: str, duration: float, **_kw) -> None:
-    if event != COMPILE_EVENT:
-        return
-    for sentinel in list(_sentinels):
-        sentinel._on_compile(duration)
-
-
-def _ensure_listener() -> None:
-    global _listener_installed
-    if _listener_installed:
-        return
-    import jax.monitoring
-
-    jax.monitoring.register_event_duration_secs_listener(_dispatch)
-    _listener_installed = True
 
 
 def _signature(tree) -> tuple:
@@ -127,8 +107,7 @@ class RetraceSentinel:
             labels=("loop",),
         )
         self._m_armed.labels(loop=name).set(0)
-        _ensure_listener()
-        _sentinels.add(self)
+        compile_watchers.add(self)
 
     # -- protocol --------------------------------------------------------
 
@@ -239,4 +218,4 @@ class RetraceSentinel:
 
     def close(self) -> None:
         self.disarm()
-        _sentinels.discard(self)
+        compile_watchers.discard(self)

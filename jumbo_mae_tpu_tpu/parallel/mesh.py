@@ -24,6 +24,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from jumbo_mae_tpu_tpu.obs.trace import SPAN_MESH_BUILD, spanned
+
 AXES = ("data", "fsdp", "tensor", "seq")
 
 
@@ -119,6 +121,7 @@ def mesh_strategy(slice_ids: list[int], sizes: tuple[int, int, int, int]) -> str
     return "hybrid"
 
 
+@spanned(SPAN_MESH_BUILD)
 def create_mesh(
     config: MeshConfig | None = None, devices: list | None = None
 ) -> Mesh:

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 import optax
 from jax.tree_util import tree_map_with_path
 
+from jumbo_mae_tpu_tpu.obs.trace import SPAN_OPTIMIZER_BUILD, spanned
+
 OptimizerName = Literal["adamw", "lamb", "lars", "sgd"]
 LrScaling = Literal["batch", "none"]
 
@@ -226,6 +228,7 @@ def modified_lamb(
     )
 
 
+@spanned(SPAN_OPTIMIZER_BUILD)
 def make_optimizer(
     cfg: OptimConfig,
     global_batch_size: int,

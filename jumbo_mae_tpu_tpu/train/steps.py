@@ -46,6 +46,7 @@ from jumbo_mae_tpu_tpu.obs.trace import (
     SCOPE_RNG,
     SPAN_PROGRAM_BUILD,
     SPAN_STATE_INIT,
+    SPAN_STATE_SHAPES,
     note_program,
     span,
 )
@@ -131,8 +132,9 @@ def create_sharded_state(
         # so a resumed run would recompile what the first run had cached.
         return state.replace(step=jnp.zeros((), jnp.int32))
 
-    shapes = jax.eval_shape(init_fn)
-    sharding = infer_state_sharding(shapes, mesh, min_shard_size=min_shard_size)
+    with span(SPAN_STATE_SHAPES):
+        shapes = jax.eval_shape(init_fn)
+        sharding = infer_state_sharding(shapes, mesh, min_shard_size=min_shard_size)
     with span(SPAN_STATE_INIT):
         state = jax.block_until_ready(jax.jit(init_fn, out_shardings=sharding)())
     return state, sharding

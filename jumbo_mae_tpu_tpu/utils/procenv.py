@@ -36,8 +36,11 @@ def enable_compile_cache() -> str:
     warning and a recompile, not a crash. Returns the directory."""
     import jax
 
-    path = compile_cache_dir()
-    jax.config.update("jax_compilation_cache_dir", path)
+    from jumbo_mae_tpu_tpu.obs.trace import SPAN_COMPILE_CACHE_SETUP, span
+
+    with span(SPAN_COMPILE_CACHE_SETUP):
+        path = compile_cache_dir()
+        jax.config.update("jax_compilation_cache_dir", path)
     return path
 
 

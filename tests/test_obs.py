@@ -206,10 +206,11 @@ def test_span_timer_reuse_and_last_s():
     with st:
         time.sleep(0.01)
     assert st.last_s >= 0.01
-    st.observe(0.5)
+    with st:
+        pass
     snap = reg.snapshot()["span_seconds"]["loop"]
     assert snap["count"] == 2
-    assert snap["sum"] >= 0.51
+    assert snap["sum"] >= 0.01 and st.last_s < 0.01
 
 
 def _host_event_names(trace_dir):
@@ -252,6 +253,294 @@ def test_spans_sit_on_the_profilers_clock(tmp_path):
     with span("probe_span", registry=reg):
         pass
     assert reg.snapshot()["span_seconds"]["probe_span"]["count"] == 2
+
+
+# ---------------------------------------------------------- the span log
+
+
+def _trace_mod():
+    import importlib
+
+    return importlib.import_module("jumbo_mae_tpu_tpu.obs.trace")  # obs.trace is also a function
+
+
+def _mine(since_id):
+    return [r for r in _trace_mod().spans() if r["id"] > since_id]
+
+
+def _last_id():
+    log = _trace_mod().spans()
+    return max((r["id"] for r in log), default=0)
+
+
+def test_span_log_records_parents_per_thread():
+    """A nested span's parent is the enclosing span's id; one opened on
+    another thread while it is open has none, and says which thread."""
+    reg, since = MetricsRegistry(), _last_id()
+    timer = span_timer("log_inner_timer", registry=reg)
+
+    def elsewhere():
+        with span("log_other_thread", registry=reg):
+            pass
+
+    with span("log_outer", registry=reg):
+        with span("log_inner", registry=reg):
+            with timer:
+                pass
+        t = threading.Thread(target=elsewhere)
+        t.start()
+        t.join(timeout=10)
+    by = {r["name"]: r for r in _mine(since)}
+    assert by["log_outer"]["parent"] is None
+    assert by["log_inner"]["parent"] == by["log_outer"]["id"]
+    assert by["log_inner_timer"]["parent"] == by["log_inner"]["id"]
+    assert by["log_other_thread"]["parent"] is None
+    assert by["log_other_thread"]["thread"] != by["log_outer"]["thread"] == threading.get_ident()
+    for child, parent in (("log_inner", "log_outer"), ("log_inner_timer", "log_inner")):
+        assert by[parent]["start"] <= by[child]["start"] <= by[child]["end"] <= by[parent]["end"]
+
+
+def test_self_seconds_is_duration_minus_the_union_of_children():
+    tr = _trace_mod()
+    rec = lambda i, parent, s, e: {"id": i, "parent": parent, "name": f"r{i}", "start": s,
+                                   "end": e, "thread": 1}
+    # two children that overlap by one second, and a grandchild that is not the parent's
+    records = [rec(1, None, 0.0, 10.0), rec(2, 1, 1.0, 4.0), rec(3, 1, 3.0, 6.0),
+               rec(4, 2, 1.5, 2.0)]
+    own = tr.self_seconds(records)
+    assert own == {1: 10.0 - 5.0, 2: 3.0 - 0.5, 3: 3.0, 4: 0.5}
+    assert tr.union_seconds([(1.0, 4.0), (3.0, 6.0), (8.0, 8.5)]) == 5.5
+
+
+def test_jax_events_enter_the_log_under_the_open_span():
+    """A jit compiled inside a span leaves its trace, its lowering and its
+    compile as that span's children, with JAX's own start and end; a jit
+    traced inside another's trace lies inside it, so the union is not the
+    sum; and one traced there in under a millisecond leaves no record."""
+    import jax
+
+    tr, since = _trace_mod(), _last_id()
+
+    @jax.jit
+    def log_probe_inner(x):
+        time.sleep(2 * tr.NESTED_RECORD_MIN_S)  # while it is traced
+        return x * 2
+
+    @jax.jit
+    def log_probe_outer(x):
+        return log_probe_inner(x) + 1
+
+    with span("log_jit_home", registry=MetricsRegistry()):
+        jax.block_until_ready(log_probe_outer(np.ones(3, np.float32)))
+    mine = _mine(since)
+    by = {r["name"]: r for r in mine}
+    home = by["log_jit_home"]
+    for kind in ("jit_trace", "jit_lower", "backend_compile"):
+        r = by[f"{kind}:log_probe_outer"]
+        assert r["parent"] == home["id"]
+        assert home["start"] <= r["start"] < r["end"] <= home["end"]
+    outer, inner = by["jit_trace:log_probe_outer"], by["jit_trace:log_probe_inner"]
+    assert inner["parent"] == home["id"]  # JAX's events open no span of their own
+    assert outer["start"] <= inner["start"] and inner["end"] <= outer["end"]
+    traces = [(r["start"], r["end"]) for r in mine if r["name"].startswith("jit_trace:")]
+    assert tr.union_seconds(traces) == pytest.approx(outer["end"] - outer["start"])
+    assert sum(e - s for s, e in traces) > outer["end"] - outer["start"]
+    nested = [r for r in mine if r["name"].startswith("jit_trace:") and r is not outer
+              and outer["start"] <= r["start"] and r["end"] <= outer["end"]]
+    assert inner in nested  # the addition and the multiplication were traced there too
+    assert all(r["end"] - r["start"] >= tr.NESTED_RECORD_MIN_S for r in nested)
+    assert by["jit_trace:log_probe_outer"]["end"] <= by["jit_lower:log_probe_outer"]["start"]
+    own = tr.self_seconds(mine)[home["id"]]
+    assert 0 <= own < home["end"] - home["start"]
+
+
+def test_setup_report_is_the_tree_with_self_times():
+    import jax
+
+    tr = _trace_mod()
+    reg = MetricsRegistry()
+    t0 = time.perf_counter()
+    for _ in range(2):
+        with span("log_report_root", registry=reg):
+            jax.block_until_ready(jax.jit(lambda x: x - 7)(np.ones(2, np.float32)))
+            with span("log_report_leaf", registry=reg):
+                time.sleep(0.002)
+    report = tr.setup_report(t0, time.perf_counter())
+    (root,) = [n for n in report["roots"] if n["name"] == "log_report_root"]
+    assert root["count"] == 2 and root["main"]
+    assert {"jit_trace", "jit_lower", "backend_compile", "log_report_leaf"} <= set(root["kinds"])
+    assert root["self_s"] == pytest.approx(
+        root["seconds"] - tr.union_seconds(
+            (r["start"], r["end"]) for r in tr.spans()
+            if r["start"] >= t0 and r["parent"] is not None), abs=1e-6)
+    assert report["spanned_s"] == pytest.approx(root["seconds"])
+    assert report["spanned_s"] <= report["seconds"]
+    lines = tr.format_setup_report(report, min_s=0.0)
+    (line,) = [ln for ln in lines if ln.strip().startswith("log_report_root")]
+    assert " x2 " in line and " = self " in line and "backend_compile" in line
+    assert any(ln.strip().startswith("log_report_leaf x2") for ln in lines)
+    # by default: from the process's start (or the oldest record) to now
+    whole = tr.setup_report()
+    assert whole["records"] >= report["records"] and whole["end"] >= report["end"]
+    start = tr.process_start()
+    assert start is None or start < min(r["start"] for r in tr.spans())
+
+
+def test_cache_load_is_a_record_inside_its_compile(tmp_path):
+    """A program read back from the persistent cache leaves ``cache_load``
+    inside the ``backend_compile`` record that asked for it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    cc.reset_cache()
+    try:
+        def probe():  # a new function each call: the same program, new to JAX's own memory
+            def log_cached_probe(x):
+                return x * 5 - 2
+
+            return jax.jit(log_cached_probe)
+
+        x = np.ones(6, np.float32)
+        jax.block_until_ready(probe()(x))  # compiles and writes
+        since = _last_id()
+        with span("log_cache_home", registry=MetricsRegistry()):
+            jax.block_until_ready(probe()(x))  # reads
+    finally:
+        jax.config.update("jax_enable_compilation_cache", False)
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    by = {r["name"]: r for r in _mine(since)}
+    load, compiled = by["cache_load"], by["backend_compile:log_cached_probe"]
+    assert load["parent"] == compiled["parent"] == by["log_cache_home"]["id"]
+    assert compiled["start"] - 1e-3 <= load["start"] < load["end"] <= compiled["end"] + 1e-3
+
+
+def test_log_and_trace_share_a_clock(tmp_path):
+    """A span entered under jax.profiler starts at the same moment in the log
+    and on the written trace's host line (to 1 ms), through the log's own
+    conversion; and the conversion to time.time() holds."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr, since = _trace_mod(), _last_id()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        time.sleep(0.02)
+        with span("log_clock_probe", registry=MetricsRegistry()):
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    planes = list(ProfileData.from_file(path).planes)
+    (began,) = [v for p in planes for k, v in p.stats if k == "profile_start_time"]
+    (event,) = [e for p in planes if p.name.startswith("/host:") for line in p.lines
+                for e in line.events if e.name == "log_clock_probe"]
+    (record,) = [r for r in _mine(since) if r["name"] == "log_clock_probe"]
+    assert abs(tr.to_trace_ns(record["start"], began) - event.start_ns) < 1e6
+    assert abs((record["end"] - record["start"]) * 1e9 - event.duration_ns) < 1e6
+    now_perf, now_wall = time.perf_counter(), time.time()
+    assert abs(tr.to_wall(now_perf) - now_wall) < 1e-3
+    assert tr.from_wall(tr.to_wall(now_perf)) == pytest.approx(now_perf, abs=1e-6)
+
+
+def test_span_log_stays_bounded():
+    tr = _trace_mod()
+    timer = span_timer("log_flood", registry=MetricsRegistry())
+    for _ in range(tr.LOG_RECORDS + 10):
+        with timer:
+            pass
+    log = tr.spans()
+    assert len(log) == tr.LOG_RECORDS
+    assert log[0]["name"] == log[-1]["name"] == "log_flood"  # the oldest went first
+
+
+def test_one_jax_listener_of_each_kind_for_the_program():
+    """Importing the modules again and arming further sentinels registers
+    nothing further, and every sentinel still sees every compile."""
+    import importlib
+
+    import jax
+    from jax._src import monitoring  # the public module has no getters
+
+    from jumbo_mae_tpu_tpu.obs.retrace import RetraceSentinel
+
+    def ours():
+        return [[f for f in listeners
+                 if getattr(f, "__module__", "").startswith("jumbo_mae_tpu_tpu")]
+                for listeners in (monitoring.get_event_duration_listeners(),
+                                  monitoring.get_event_time_span_listeners(),
+                                  monitoring.get_event_listeners(),
+                                  monitoring.get_scalar_listeners())]
+
+    assert [len(kind) for kind in ours()] == [1, 1, 0, 1]
+    importlib.import_module("jumbo_mae_tpu_tpu.obs.trace")
+    importlib.import_module("jumbo_mae_tpu_tpu.obs.retrace")
+    sentinels = [RetraceSentinel(f"log_listener_{i}", registry=MetricsRegistry())
+                 for i in range(3)]
+    try:
+        for s in sentinels:
+            s.arm()
+        assert [len(kind) for kind in ours()] == [1, 1, 0, 1]
+        with sentinels[0].expected("a probe"), sentinels[1].expected("a probe"), \
+                sentinels[2].expected("a probe"):
+            for k in (2, 3):
+                jax.block_until_ready(jax.jit(lambda x: x + k)(np.ones(k, np.float32)))
+        assert [s.summary()["compiles"] for s in sentinels] == [2, 2, 2]
+        assert [s.summary()["violations"] for s in sentinels] == [0, 0, 0]
+    finally:
+        for s in sentinels:
+            s.close()
+
+
+def test_setup_spans_sit_where_the_work_happens(tmp_path, monkeypatch):
+    """``state_shapes`` holds the first trace of the model's init (eval_shape
+    and the sharding rules) and ends before ``state_init`` begins; the mesh,
+    the optimizer, the model and the compile cache's path each leave a span."""
+    import jax
+
+    from jumbo_mae_tpu_tpu.cli.train import build_model
+    from jumbo_mae_tpu_tpu.config import MeshConfig, OptimConfig, config_from_dict
+    from jumbo_mae_tpu_tpu.parallel import create_mesh
+    from jumbo_mae_tpu_tpu.train import create_sharded_state, make_optimizer
+    from jumbo_mae_tpu_tpu.utils.procenv import enable_compile_cache
+
+    since = _last_id()
+    old_dir = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        enable_compile_cache()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old_dir)
+    cfg = config_from_dict({
+        "run": {"mode": "pretrain", "synthetic_data": True, "train_batch_size": 8},
+        "model": {"preset": "vit_t16", "dec_layers": 1, "dec_dim": 32, "dec_heads": 2,
+                  "overrides": {"image_size": 32, "dtype": "float32"}},
+        "data": {"image_size": 32}})
+    model, enc, _ = build_model(cfg)
+    mesh = create_mesh(MeshConfig(data=1, fsdp=1), devices=jax.devices()[:1])
+    tx = make_optimizer(OptimConfig(warmup_steps=2, training_steps=10), global_batch_size=8)
+    create_sharded_state(model, tx, {"images": np.zeros((8, 32, 32, 3), np.uint8)}, mesh,
+                         mode="pretrain")
+    mine = _mine(since)
+    by = {r["name"]: r for r in mine}
+    for name in ("compile_cache_setup", "model_build", "mesh_build", "optimizer_build",
+                 "state_shapes", "state_init"):
+        assert by[name]["parent"] is None, name
+    assert by["state_shapes"]["end"] <= by["state_init"]["start"]
+    homes = {r["parent"] for r in mine if r["name"] == "jit_trace:init_fn"}
+    assert homes == {by["state_shapes"]["id"], by["state_init"]["id"]}
 
 
 def _span_count(name):
