@@ -375,9 +375,9 @@ def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, *,
 
 def causal_kernel_calls(text: str) -> dict:
     """How often a compiled program's text calls each of the causal core's
-    three kernels: ``{"fwd": n, "dq": n, "dkv": n}``."""
+    two kernels: ``{"fwd": n, "bwd": n}``."""
     return {k: len(re.findall(rf'custom-call\([^\n]*/causal_attention_{k}/pallas_call"', text))
-            for k in ("fwd", "dq", "dkv")}
+            for k in ("fwd", "bwd")}
 
 
 def kda_kernel_calls(text: str) -> dict:

@@ -35,6 +35,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -426,8 +427,8 @@ pallas_flash_attention_with_lse.defvjp(_vjp_lse_fwd, _vjp_lse_bwd)
 # independent. ``k_a`` and ``v`` may have fewer heads than the queries
 # (grouped-query attention): query head ``h`` reads key/value head ``h //
 # group``, again through the BlockSpec alone, and the key/value gradients of a
-# group are summed inside the dK/dV kernel, whose grid walks the group's
-# members innermost and writes a key block once.
+# group are summed inside the backward kernel, whose accumulators outlive a
+# member (below).
 #
 # Layout is head-major, (batch, heads, seq, width): the projections that
 # feed the kernel write it directly, so there is no fold/transposition here.
@@ -449,7 +450,29 @@ pallas_flash_attention_with_lse.defvjp(_vjp_lse_fwd, _vjp_lse_bwd)
 # pad query's output is sliced off, so its cotangent is zero and every
 # backward contribution from it vanishes.
 #
-# Of the seven residuals the backward kernels read, five are the caller's
+# The backward pass is one kernel (``causal_attention_bwd``): a visited block
+# pair's scores, probabilities, ``dO·Vᵀ`` and ``ds`` are formed once and all of
+# dQ, dK (both parts) and dV accumulated from them. Its grid is (batch,
+# key/value heads, steps), and four scalar-prefetched tables give a step its
+# query block, key block, member of the group and what it opens and closes
+# (``_backward_walk``): for each member in turn the pairs in the forward
+# kernel's order, a query block's key blocks rising. dQ of the current
+# (member, query block) lives in block-sized float32 scratch, zeroed at the
+# row's first key block and written at its last. dK_a and dV live in float32
+# scratch as long as the (padded) sequence, a key block's rows addressed by a
+# dynamic slice, zeroed at a (batch, key/value head)'s first step and written
+# at its last into output blocks that are the whole sequence, so the group's
+# sum costs nothing and a key block is written once. dK_b, a query head's own,
+# accumulates in its float32 output block (the whole sequence too) and is
+# summed over the heads outside. What decides whether the sequence-long
+# accumulators fit is their bytes, from the operands' shapes
+# (``_causal_span``): where they outgrow VMEM beside a block pair's tiles
+# (past 16 384 tokens at latent attention's widths) the key blocks are walked
+# in the fewest spans that fit, one after the other in the same grid axis of
+# the same kernel, each span's accumulators as long as the span and its share
+# of dQ a slab of its own, summed outside with one add a span.
+#
+# Of the seven residuals the backward kernel reads, five are the caller's
 # arguments; the forward kernel itself makes two, the output ``o`` and the
 # log-sum-exp ``lse``, and the forward rule names both (``CAUSAL_OUT_NAME``,
 # ``CAUSAL_LSE_NAME``). A ``jax.checkpoint`` whose policy saves those names
@@ -457,14 +480,15 @@ pallas_flash_attention_with_lse.defvjp(_vjp_lse_fwd, _vjp_lse_bwd)
 # arrays across rematerialisation, so the recomputed forward kernel has no
 # consumer left and is dead code: one forward run a layer, not two. ``lse``
 # is kept compact, one float32 a (head, token), and widened to ``LANE`` only
-# at the backward kernels' entry: in HBM the ``LANE``-wide form is tiled to
+# at the backward kernel's entry: in HBM the ``LANE``-wide form is tiled to
 # 128 lanes, 128 float32 a (head, token). Outside a remat a name is an
 # identity.
 # ---------------------------------------------------------------------------
 
 
-# what one causal kernel may hold in VMEM: a 1024-block's float32 scores,
-# probabilities and their bf16 copies pass the 16 MiB default
+# what one causal kernel may hold in VMEM (half of a v5e's): a 1024-block's
+# float32 scores, probabilities and their bf16 copies pass the 16 MiB default,
+# and the backward kernel's sequence-long accumulators are sized against it
 CAUSAL_VMEM_BYTES = 64 * 1024 * 1024
 CAUSAL_BLOCK = 1024
 # checkpoint names of the two residuals the forward kernel makes
@@ -478,20 +502,13 @@ def _reach(window: int, block: int) -> int:
     return (window + block - 2) // block
 
 
-def _lower_triangle(n: int, *, by_key: bool, reach: int | None = None):
+def _lower_triangle(n: int, *, reach: int | None = None):
     """The block pairs that hold a visible entry as two int32 tables: those
     on or below the diagonal, and with ``reach`` only those at most ``reach``
-    blocks below it (the band). ``by_key=False``: query block outer, its key
-    blocks inner in rising order (the diagonal comes last). ``by_key=True``:
-    key block outer, its query blocks inner in rising order (the diagonal
-    comes first)."""
-    import numpy as np
-
+    blocks below it (the band). Query block outer, its key blocks inner in
+    rising order (the diagonal comes last)."""
     reach = n if reach is None else reach
-    if by_key:
-        pairs = [(i, j) for j in range(n) for i in range(j, min(j + reach, n - 1) + 1)]
-    else:
-        pairs = [(i, j) for i in range(n) for j in range(max(i - reach, 0), i + 1)]
+    pairs = [(i, j) for i in range(n) for j in range(max(i - reach, 0), i + 1)]
     qi, kj = zip(*pairs)
     return np.asarray(qi, np.int32), np.asarray(kj, np.int32)
 
@@ -593,112 +610,106 @@ def _causal_fwd_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int,
         lse_ref[...] = jnp.broadcast_to(m_sc[...] + jnp.log(l), lse_ref.shape)
 
 
-def _causal_dq_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int, window: int | None):
+# what a step of the backward kernel's walk opens and closes: bits of its
+# fourth table
+ROW_FIRST, ROW_LAST, HEAD_FIRST, SPAN_FIRST, SPAN_LAST = 1, 2, 4, 8, 16
+
+
+def _backward_walk(n: int, *, reach: int | None, group: int, span: int):
+    """The backward kernel's grid steps as four int32 tables: query block,
+    key block, the group's member, and the step's bits. The ``n`` key blocks
+    lie in spans of ``span``; a span's pairs are walked once a member in
+    ``_lower_triangle``'s order (a query block's key blocks rising), so the
+    steps of a span are consecutive, within it a query head's, and within
+    those a query block's."""
+    visible = list(zip(*(table.tolist() for table in _lower_triangle(n, reach=reach))))
+    steps = []
+    for at_span in range(-(-n // span)):
+        pairs = [(i, j) for i, j in visible if j // span == at_span]
+        last = len(pairs) - 1
+        for member in range(group):
+            for t, (i, j) in enumerate(pairs):
+                at = (ROW_FIRST * (t == 0 or pairs[t - 1][0] != i)
+                      | ROW_LAST * (t == last or pairs[t + 1][0] != i)
+                      | HEAD_FIRST * (t == 0)
+                      | SPAN_FIRST * (t == 0 and member == 0)
+                      | SPAN_LAST * (t == last and member == group - 1))
+                steps.append((i, j, member, at))
+    return tuple(np.asarray(column, np.int32) for column in zip(*steps))
+
+
+def _causal_bwd_kernel(qi_ref, kj_ref, _member_ref, at_ref, *refs, two_part: bool, block: int,
+                       window: int | None, span: int):  # the member is the index maps' alone
     (qa_ref, qb_ref, ka_ref, kb_ref, v_ref, do_ref, lse_ref, dd_ref, *outs) = _parts(
         refs, two_part)
     if two_part:
-        dqa_ref, dqb_ref, dqa_sc, dqb_sc = outs
+        dqa_ref, dqb_ref, dka_ref, dkb_ref, dv_ref, dqa_sc, dqb_sc, dka_sc, dv_sc = outs
     else:
-        (dqa_ref, dqa_sc), dqb_ref, dqb_sc = outs, None, None
+        dqa_ref, dka_ref, dv_ref, dqa_sc, dka_sc, dv_sc = outs
     t = pl.program_id(2)
-    i, j = qi_ref[t], kj_ref[t]
+    i, j, at = qi_ref[t], kj_ref[t], at_ref[t]
     mm = qa_ref.dtype
+    # the key block's rows of the span-long accumulators
+    keys = pl.ds(pl.multiple_of(j % span * block, block), block)
 
-    @pl.when(j == _first_key_block(i, block=block, window=window))
+    @pl.when(at & ROW_FIRST != 0)
     def _():
         dqa_sc[...] = jnp.zeros(dqa_sc.shape, jnp.float32)
         if two_part:
             dqb_sc[...] = jnp.zeros(dqb_sc.shape, jnp.float32)
 
-    def step(diagonal: bool, edge=None):
-        ka, kb = _read(ka_ref, mm), _read(kb_ref, mm)
-        s = _scores(_read(qa_ref, mm), _read(qb_ref, mm), ka, kb, diagonal, edge)
-        p = jnp.exp(s - lse_ref[...][:, :1])
-        dp = jax.lax.dot_general(
-            do_ref[...].astype(mm), v_ref[...].astype(mm), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - dd_ref[...][:, :1])).astype(mm)
-        dims = (((1,), (0,)), ((), ()))
-        dqa_sc[...] += jax.lax.dot_general(ds, ka, dims, preferred_element_type=jnp.float32)
-        if two_part:
-            dqb_sc[...] += jax.lax.dot_general(ds, kb, dims, preferred_element_type=jnp.float32)
+    if two_part:  # a query head's own: float32 on its way out, summed in place
+        @pl.when(at & HEAD_FIRST != 0)
+        def _():
+            dkb_ref[...] = jnp.zeros(dkb_ref.shape, jnp.float32)
 
-    _off_diagonal(i, j, lambda edge: step(False, edge), block=block, window=window)
-
-    @pl.when(j == i)
+    @pl.when(at & SPAN_FIRST != 0)
     def _():
-        step(True, _diagonal_edge(block=block, window=window))
-        dqa_ref[...] = dqa_sc[...].astype(dqa_ref.dtype)
-        if two_part:
-            dqb_ref[...] = dqb_sc[...].astype(dqb_ref.dtype)
-
-
-def _causal_dkv_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int, window: int | None,
-                       last: int, group: int):
-    (qa_ref, qb_ref, ka_ref, kb_ref, v_ref, do_ref, lse_ref, dd_ref, *outs) = _parts(
-        refs, two_part)
-    if two_part:
-        dka_ref, dkb_ref, dv_ref, dka_sc, dkb_sc, dv_sc = outs
-    else:
-        (dka_ref, dv_ref, dka_sc, dv_sc), dkb_ref, dkb_sc = outs, None, None
-    t = pl.program_id(2)
-    i, j = qi_ref[t], kj_ref[t]
-    mm = qa_ref.dtype
+        dka_sc[...] = jnp.zeros(dka_sc.shape, jnp.float32)
+        dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
 
     def step(diagonal: bool, edge=None):
-        qa, qb = _read(qa_ref, mm), _read(qb_ref, mm)
+        qa, qb, ka, kb = (_read(ref, mm) for ref in (qa_ref, qb_ref, ka_ref, kb_ref))
         do = do_ref[...].astype(mm)
-        s = _scores(qa, qb, _read(ka_ref, mm), _read(kb_ref, mm), diagonal, edge)
+        s = _scores(qa, qb, ka, kb, diagonal, edge)
         p = jnp.exp(s - lse_ref[...][:, :1])
         dp = jax.lax.dot_general(
             do, v_ref[...].astype(mm), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = (p * (dp - dd_ref[...][:, :1])).astype(mm)
-        dims = (((0,), (0,)), ((), ()))
-        dv_sc[...] += jax.lax.dot_general(p.astype(mm), do, dims,
-                                          preferred_element_type=jnp.float32)
-        dka_sc[...] += jax.lax.dot_general(ds, qa, dims, preferred_element_type=jnp.float32)
+        to_keys, to_queries = (((0,), (0,)), ((), ())), (((1,), (0,)), ((), ()))
+        dot = functools.partial(jax.lax.dot_general, preferred_element_type=jnp.float32)
+        dv_sc[keys, :] += dot(p.astype(mm), do, to_keys)
+        dka_sc[keys, :] += dot(ds, qa, to_keys)
+        dqa_sc[...] += dot(ds, ka, to_queries)
         if two_part:
-            dkb_sc[...] += jax.lax.dot_general(ds, qb, dims, preferred_element_type=jnp.float32)
-
-    def zero():
-        dka_sc[...] = jnp.zeros(dka_sc.shape, jnp.float32)
-        if two_part:
-            dkb_sc[...] = jnp.zeros(dkb_sc.shape, jnp.float32)
-        dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
-
-    def write():
-        dka_ref[...] = dka_sc[...].astype(dka_ref.dtype)
-        if two_part:
-            dkb_ref[...] = dkb_sc[...].astype(dkb_ref.dtype)
-        dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
-
-    diagonal_edge = _diagonal_edge(block=block, window=window)
-    end = last if window is None else jnp.minimum(j + _reach(window, block), last)
-    if group == 1:
-        @pl.when(i == j)  # the diagonal is the first query block of a key block
-        def _():
-            zero()
-            step(True, diagonal_edge)
-    else:  # the group's members are the innermost grid axis: zero before the
-        member = pl.program_id(3)  # first of them, write after the last
-        pl.when((i == j) & (member == 0))(zero)
-        pl.when(i == j)(lambda: step(True, diagonal_edge))
+            dkb_ref[keys, :] += dot(ds, qb, to_keys)
+            dqb_sc[...] += dot(ds, kb, to_queries)
 
     _off_diagonal(i, j, lambda edge: step(False, edge), block=block, window=window)
+    pl.when(j == i)(lambda: step(True, _diagonal_edge(block=block, window=window)))
 
-    if group == 1:
-        pl.when(i == end)(write)
-    else:
-        pl.when((i == end) & (member == group - 1))(write)
+    @pl.when(at & ROW_LAST != 0)
+    def _():
+        dqa_ref[...] = dqa_sc[...].astype(dqa_ref.dtype)
+        if two_part:
+            dqb_ref[...] = dqb_sc[...].astype(dqb_ref.dtype)
+
+    @pl.when(at & SPAN_LAST != 0)
+    def _():
+        dka_ref[...] = dka_sc[...].astype(dka_ref.dtype)
+        dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
 
 
 def causal_block(seq: int, window: int | None) -> int:
     """The kernels' block for a sequence and a window: ``CAUSAL_BLOCK`` where
     the whole triangle is walked; under a window the block that costs a
-    windowed layer least on the chip (PERF.md §6, PR 33: at 1024 a 512-token
-    window computes 3.9 x the score entries it needs, at 512 2.0 x, at 256
-    1.5 x, against more and smaller grid steps)."""
+    windowed layer least on the chip (at 1024 a 512-token window computes
+    3.9 x the score entries it needs, at 512 2.0 x, at 256 1.5 x, against
+    more and smaller grid steps; PERF.md §6, PR 37, one layer's forward +
+    backward at 64 heads over 8, 2 x 8192 tokens: 1024 → 25.36 ms, 512 →
+    21.15, 256 → 30.78; with PR 33's two backward kernels 31.05, 25.12,
+    37.65)."""
     if window is None or window >= seq:
         return CAUSAL_BLOCK
     return min(CAUSAL_BLOCK, max(128, window // 128 * 128))
@@ -725,7 +736,7 @@ def causal_pairs(seq: int, window: int | None = None, block: int | None = None) 
     block pairs the kernels' tables walk, and those the mask keeps
     (``min(i + 1, window)`` keys for query ``i``). Static, from the tables."""
     s_pad, block, window, reach = _causal_band(seq, window, block)
-    visited = len(_lower_triangle(s_pad // block, by_key=False, reach=reach)[0]) * block * block
+    visited = len(_lower_triangle(s_pad // block, reach=reach)[0]) * block * block
     w = seq if window is None else window
     return visited, w * (w + 1) // 2 + (seq - w) * w
 
@@ -737,47 +748,50 @@ def _pad_rows(x, to: int):
     return jnp.pad(x, ((0, 0),) * (x.ndim - 2) + ((0, pad), (0, 0)))
 
 
-def _causal_call(kernel, tables, operands, out_widths, scratch, *, dtype, b, h, group,
-                 s_pad, block, by_key, interpret, name):
-    """One ``pallas_call`` over (batch, heads, visible block pairs).
-    ``operands`` are ``(array, kind)`` with kind ``"q"`` (blocked by the
-    query index, a query head), ``"k"`` (by the key index, a key/value head)
-    or ``"k_shared"`` (by the key index, no head axis); ``out_widths`` are
-    ``(width, dtype)`` of outputs blocked by the outer index. By query
-    (``by_key=False``) the second grid axis walks the ``h`` query heads and
-    head ``hi`` reads key/value head ``hi // group``; by key it walks the
-    ``h // group`` key/value heads, and a fourth, innermost axis the group's
-    members (none where ``group`` is 1)."""
-    members = by_key and group > 1
+def _pair_spec(width: int, kind: str, *, block: int, group: int, members: bool,
+               span: int | None = None):
+    """The BlockSpec of an operand or output of a walk over block pairs, whose
+    first two tables are the step's query and key block. The second grid axis
+    walks the query heads, and head ``hi`` reads key/value head ``hi //
+    group``; with ``members`` it walks the key/value heads and the third table
+    gives the group's member. ``kind``: ``"q"`` (blocked by the query index, a
+    query head), ``"k"`` (by the key index, a key/value head), ``"k_shared"``
+    (by the key index, no head axis); and of the backward kernel's outputs,
+    whose keys lie in spans of ``span`` blocks, ``"dq"`` (a ``"q"`` that leads
+    with the key's span), ``"dk"`` (a key/value head's whole span) and
+    ``"dk_head"`` (a query head's whole span)."""
+    def index(bi, hi, t, qi, kj, *more):
+        q_head = hi * group + more[0][t] if members else hi
+        k_head = hi if members else hi // group
+        return {"q": lambda: (bi, q_head, qi[t], 0),
+                "k": lambda: (bi, k_head, kj[t], 0),
+                "k_shared": lambda: (bi, kj[t], 0),
+                "dq": lambda: (kj[t] // span, bi, q_head, qi[t], 0),
+                "dk": lambda: (bi, k_head, kj[t] // span, 0),
+                "dk_head": lambda: (bi, q_head, kj[t] // span, 0)}[kind]()
 
-    def spec(width, kind):
-        def index(bi, hi, t, *rest):
-            qi, kj = rest[-2:]
-            if kind == "k_shared":
-                return bi, kj[t], 0
-            if kind == "k":
-                return bi, (hi // group if group > 1 and not by_key else hi), kj[t], 0
-            return bi, (hi * group + rest[0] if members else hi), qi[t], 0
+    rows = span * block if kind in ("dk", "dk_head") else block
+    lead = {"k_shared": 1, "dq": 3}.get(kind, 2)
+    return pl.BlockSpec((*(None,) * lead, rows, width), index)
 
-        shape = (None, block, width) if kind == "k_shared" else (None, None, block, width)
-        return pl.BlockSpec(shape, index)
 
-    out_kind = "k" if by_key else "q"
-    outer = h // group if by_key else h
-    grid = (b, outer, len(tables[0])) + ((group,) if members else ())
+def _causal_call(kernel, tables, grid, operands, outputs, scratch, *, interpret, name):
+    """One ``pallas_call`` over ``grid`` = (batch, heads, steps of a walk over
+    the visible block pairs), the walk's ``tables`` scalar-prefetched.
+    ``operands`` are ``(array, BlockSpec)``, ``outputs`` ``(ShapeDtypeStruct,
+    BlockSpec)``."""
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(tables),
             grid=grid,
-            in_specs=[spec(x.shape[-1], kind) for x, kind in operands],
-            out_specs=[spec(w, out_kind) for w, _ in out_widths],
+            in_specs=[spec for _, spec in operands],
+            out_specs=[spec for _, spec in outputs],
             scratch_shapes=scratch,
         ),
-        out_shape=[jax.ShapeDtypeStruct((b, outer, s_pad, w), dt or dtype)
-                   for w, dt in out_widths],
+        out_shape=[shape for shape, _ in outputs],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel") + ("arbitrary",) * (len(grid) - 2),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=CAUSAL_VMEM_BYTES,
         ),
         interpret=interpret,
@@ -792,59 +806,111 @@ def _causal_shape(qa, ka, block, window):
     return b, h, h // ka.shape[1], s, *_causal_band(s, window, block)
 
 
+def _causal_operands(named, s_pad, spec):
+    """``(padded array, BlockSpec)`` of the ``(array, kind)`` in ``named``
+    that are there."""
+    return [(_pad_rows(x, s_pad), spec(x.shape[-1], kind)) for x, kind in named if x is not None]
+
+
 def _causal_fwd(qa, qb, ka, kb, v, block, interpret, window=None):
     b, h, group, s, s_pad, block, window, reach = _causal_shape(qa, ka, block, window)
-    two_part = qb is not None
-    operands = [(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k")]
-    operands = [(_pad_rows(x, s_pad), kind) for x, kind in operands if x is not None]
+    spec = functools.partial(_pair_spec, block=block, group=group, members=False)
+    tables = _lower_triangle(s_pad // block, reach=reach)
     d_v = v.shape[-1]
+    out = lambda w, dtype: (jax.ShapeDtypeStruct((b, h, s_pad, w), dtype), spec(w, "q"))
     o, lse = _causal_call(
-        functools.partial(_causal_fwd_kernel, two_part=two_part, block=block, window=window),
-        _lower_triangle(s_pad // block, by_key=False, reach=reach), operands,
-        [(d_v, None), (LANE, jnp.float32)],
+        functools.partial(_causal_fwd_kernel, two_part=qb is not None, block=block,
+                          window=window),
+        tables, (b, h, len(tables[0])),
+        _causal_operands([(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k")],
+                         s_pad, spec),
+        [out(d_v, qa.dtype), out(LANE, jnp.float32)],
         [pltpu.VMEM((block, 1), jnp.float32), pltpu.VMEM((block, 1), jnp.float32),
          pltpu.VMEM((block, d_v), jnp.float32)],
-        dtype=qa.dtype, b=b, h=h, group=group, s_pad=s_pad, block=block, by_key=False,
         interpret=interpret, name="causal_attention_fwd",
     )
     return o[:, :, :s], lse[..., 0]
+
+
+def _causal_span(n: int, block: int, widths, itemsize: int,
+                 budget: int = CAUSAL_VMEM_BYTES) -> int:
+    """How many of its ``n`` key blocks a span of the backward kernel holds:
+    all of them where their accumulators fit ``budget`` bytes of VMEM beside
+    what a block pair takes, else the fewest spans that do, as equal as ``n``
+    allows. ``widths`` are ``(d_a, d_v)`` or ``(d_a, d_v, d_b)``, each a whole
+    number of 128-lane tiles there. A key row of a span holds dK_a's and dV's
+    float32 accumulators, two copies of their output blocks (Pallas
+    double-buffers a block) and two of dK_b's float32 one; a row of a block q,
+    k, v and dO twice, ``lse`` and ``D`` twice, dQ's accumulators and two
+    copies of its (at most float32) blocks; and the compiler's own scratch
+    for a pair's scores and their products is under two ``block²`` float32
+    tiles (for a 1024-block of bf16 at widths 128 + 64 and 128 the TPU
+    compiler counts 13.4 MiB beside the accumulators, this rule 16: 16 384
+    tokens fit one span, 32 768 two)."""
+    d_a, d_v, *d_b = (_round_up(w, 128) for w in widths)
+    d_b = sum(d_b)
+    key_row = (d_a + d_v) * (4 + 2 * itemsize) + d_b * 2 * 4
+    block_row = 2 * itemsize * 2 * (d_a + d_b + d_v) + 2 * 2 * 4 * 128 + (d_a + d_b) * 3 * 4
+    beside = block * block_row + 2 * block * block * 4
+    fits = max((budget - beside) // (block * key_row), 1)
+    return -(-n // -(-n // fits))
 
 
 def _causal_bwd(qa, qb, ka, kb, v, o, lse, g, block, interpret, window=None):
     b, h, group, s, s_pad, block, window, reach = _causal_shape(qa, ka, block, window)
     two_part = qb is not None
     d_a, d_v = qa.shape[-1], v.shape[-1]
+    second = [qb.shape[-1]] if two_part else []
+    n = s_pad // block
+    span = _causal_span(n, block, (d_a, d_v, *second), qa.dtype.itemsize)
+    spans = -(-n // span)
     o, g = _pad_rows(o, s_pad), _pad_rows(g, s_pad)
     # D = rowsum(dO ∘ O), as for the non-causal kernels: tiny, elementwise
     dd = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1, keepdims=True)
     dd = jnp.broadcast_to(dd, (b, h, s_pad, LANE))
     lse = jnp.broadcast_to(lse[..., None], (b, h, s_pad, LANE))
-    n = s_pad // block
-    operands = [(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k")]
-    operands = [(_pad_rows(x, s_pad), kind) for x, kind in operands if x is not None]
-    operands += [(g, "q"), (lse, "q"), (dd, "q")]
-    common = dict(dtype=qa.dtype, b=b, h=h, group=group, s_pad=s_pad, block=block,
-                  interpret=interpret)
-    static = dict(two_part=two_part, block=block, window=window)
-    f32 = lambda w: pltpu.VMEM((block, w), jnp.float32)
-    second = [qb.shape[-1]] if two_part else []
-    dqa, *dqb = _causal_call(
-        functools.partial(_causal_dq_kernel, **static),
-        _lower_triangle(n, by_key=False, reach=reach), operands,
-        [(w, None) for w in [d_a, *second]], [f32(w) for w in [d_a, *second]],
-        by_key=False, name="causal_attention_dq", **common,
+    spec = functools.partial(_pair_spec, block=block, group=group, members=True, span=span)
+    tables = _backward_walk(n, reach=reach, group=group, span=span)
+
+    def out(kind, w, dtype=qa.dtype):
+        shape = {"dq": (spans, b, h), "dk": (b, h // group), "dk_head": (b, h)}[kind]
+        rows = s_pad if kind == "dq" else spans * span * block  # whole spans
+        return jax.ShapeDtypeStruct((*shape, rows, w), dtype), spec(w, kind)
+
+    # a span's share of dQ leaves in float32 where there is more than one to
+    # sum; the shared key part's gradient leaves per query head, in float32
+    partial = jnp.float32 if spans > 1 else qa.dtype
+    f32 = lambda rows, w: pltpu.VMEM((rows, w), jnp.float32)
+    outs = _causal_call(
+        functools.partial(_causal_bwd_kernel, two_part=two_part, block=block, window=window,
+                          span=span),
+        tables, (b, h // group, len(tables[0])),
+        _causal_operands([(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k"),
+                          (g, "q"), (lse, "q"), (dd, "q")], s_pad, spec),
+        [*(out("dq", w, partial) for w in [d_a, *second]), out("dk", d_a),
+         *(out("dk_head", w, jnp.float32) for w in second), out("dk", d_v)],
+        [*(f32(block, w) for w in [d_a, *second]), f32(span * block, d_a), f32(span * block, d_v)],
+        interpret=interpret, name="causal_attention_bwd",
     )
-    # the shared key part's gradient comes out per head and is summed outside
-    dka, *dkb, dv = _causal_call(
-        functools.partial(_causal_dkv_kernel, last=n - 1, group=group, **static),
-        _lower_triangle(n, by_key=True, reach=reach), operands,
-        [(d_a, None), *((w, jnp.float32) for w in second), (d_v, None)],
-        [f32(w) for w in [d_a, *second, d_v]],
-        by_key=True, name="causal_attention_dkv", **common,
-    )
-    dqb = dqb[0][..., :s, :] if two_part else None
-    dkb = dkb[0].sum(axis=1).astype(kb.dtype)[..., :s, :] if two_part else None
-    return dqa[..., :s, :], dqb, dka[..., :s, :], dkb, dv[..., :s, :]
+    if two_part:
+        dqa, dqb, dka, dkb, dv = outs
+    else:
+        (dqa, dka, dv), dqb, dkb = outs, None, None
+
+    def whole(dq):
+        """dQ of its spans' shares: a span never meets the query blocks before
+        its own (nor, under a window, those past its reach), and their blocks
+        of its share were never written."""
+        if spans == 1:
+            return dq[0, ..., :s, :]
+        met = np.zeros((spans, n), bool)
+        met[tables[1] // span, tables[0]] = True
+        met = jnp.asarray(np.repeat(met, block, axis=1))[:, None, None, :, None]
+        return jnp.where(met, dq, 0.0).sum(0).astype(qa.dtype)[..., :s, :]
+
+    if two_part:
+        dqb, dkb = whole(dqb), dkb.sum(axis=1).astype(kb.dtype)[..., :s, :]
+    return whole(dqa), dqb, dka[..., :s, :], dkb, dv[..., :s, :]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))

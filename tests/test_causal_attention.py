@@ -17,6 +17,7 @@ from jumbo_mae_tpu_tpu.ops.flash_attention import xla_causal_attention
 from jumbo_mae_tpu_tpu.ops.pallas.attention import (
     CAUSAL_LSE_NAME,
     CAUSAL_OUT_NAME,
+    _backward_walk,
     _lower_triangle,
     pallas_causal_attention,
 )
@@ -31,13 +32,17 @@ def _inputs(seed, b, h, s, d_a, d_b, d_v, dtype=jnp.float32):
             n(ks[5], (b, h, s, d_v)))
 
 
-@pytest.mark.parametrize("by_key", [False, True])
-def test_lower_triangle_visits_each_pair_once(by_key):
-    qi, kj = _lower_triangle(5, by_key=by_key)
+@pytest.mark.parametrize("backward", [False, True])
+def test_lower_triangle_visits_each_pair_once(backward):
+    """The forward kernel's tables, and the backward kernel's where one span
+    holds every key block and a group has one member: the same walk."""
+    qi, kj, *more = _backward_walk(5, reach=None, group=1, span=5) if backward else \
+        _lower_triangle(5)
     pairs = list(zip(qi.tolist(), kj.tolist()))
     assert sorted(pairs) == [(i, j) for i in range(5) for j in range(i + 1)]
-    outer = kj if by_key else qi
-    assert list(outer) == sorted(outer)  # one visit of each output block
+    assert list(qi) == sorted(qi)  # one visit of each output block
+    if backward:
+        assert set(more[0].tolist()) == {0}
 
 
 # seq 40 at block 16 pads to 48: a sequence that is no multiple of the block
@@ -125,14 +130,14 @@ def _kept(capsys, loss, *args):
 @pytest.mark.parametrize("policy", ["none", "dots"])
 def test_a_rematted_block_keeps_the_kernels_output_and_runs_the_forward_once(policy, capsys):
     """Under every remat policy the block keeps the forward kernel's output
-    and log-sum-exp, so the gradient holds one forward kernel a block (three
-    kernels a block, not four) and equals the un-rematted gradient to the
+    and log-sum-exp, so the gradient holds one forward kernel a block (two
+    kernels a block, not three) and equals the un-rematted gradient to the
     bit. A remat that keeps nothing runs the forward kernel twice."""
     cfg = SimpleNamespace(grad_ckpt=True, remat_policy=policy)
     loss, params, x = _two_blocks(maybe_remat(_Block, cfg))
     grad = jax.grad(loss, argnums=(0, 1))
     assert _kernel_calls(jax.make_jaxpr(grad)(params, x).jaxpr) == {
-        "causal_attention_fwd": 2, "causal_attention_dq": 2, "causal_attention_dkv": 2}
+        "causal_attention_fwd": 2, "causal_attention_bwd": 2}
     # the same remat without the two names: jax's own policy object
     unnamed = {"none": None, "dots": jax.checkpoint_policies.dots_saveable}[policy]
     base = _two_blocks(nn.remat(_Block, static_argnums=(2,), policy=unnamed))[0]

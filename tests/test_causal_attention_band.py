@@ -1,7 +1,10 @@
 """The causal flash kernels' generalisations — key/value heads that a group
 of query heads shares, a window walked as a band of block pairs, a score of
-one part — in interpret mode against the einsum form, forward and backward;
-and the static count of what the band's tables visit."""
+one part, a backward kernel whose key/value accumulators span the sequence or
+a share of it — in interpret mode against the einsum form, forward and
+backward; and the static count of what the band's tables visit."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -9,8 +12,16 @@ import numpy as np
 import pytest
 
 from jumbo_mae_tpu_tpu.ops.flash_attention import causal_attention, xla_causal_attention
+from jumbo_mae_tpu_tpu.ops.pallas import attention as pallas_attention
 from jumbo_mae_tpu_tpu.ops.pallas.attention import (
     CAUSAL_BLOCK,
+    HEAD_FIRST,
+    ROW_FIRST,
+    ROW_LAST,
+    SPAN_FIRST,
+    SPAN_LAST,
+    _backward_walk,
+    _causal_span,
     _lower_triangle,
     _reach,
     causal_block,
@@ -51,14 +62,19 @@ def _dense(q_a, q_b, k_a, k_b, v, window):
 # window that is no multiple of the block, one smaller than the block (and
 # than a block that is clamped to the sequence), one of whole blocks, one the
 # sequence never reaches, one of a single token; sequences that are no
-# multiple of the block (40 and 50 at 16)
+# multiple of the block (40, 50 and 70 at 16); and for the backward kernel's
+# sequence-long accumulators a key block that several query blocks of each of
+# several members add to, with a padded tail (the whole triangle of five
+# blocks under groups of 3, and a window that reaches three blocks back)
 CASES = [(3, 3, 40, 16, None), (6, 2, 40, 16, 13), (8, 2, 24, 32, 5), (6, 2, 40, 16, 64),
-         (3, 3, 40, 16, 1), (8, 2, 48, 16, 32), (6, 2, 50, 16, 33)]
+         (3, 3, 40, 16, 1), (8, 2, 48, 16, 32), (6, 2, 50, 16, 33), (6, 2, 70, 16, None),
+         (8, 2, 70, 16, 40)]
 
 # the score of two parts (latent attention's) under each generalisation once:
-# a window inside a block, inside a clamped block, of whole blocks (with none of
-# them it is ``tests/test_causal_attention.py``'s)
-TWO_PART = [(6, 2, 40, 16, 13), (8, 2, 24, 32, 5), (8, 2, 48, 16, 32)]
+# a window inside a block, inside a clamped block, of whole blocks, and one
+# that reaches three blocks back over a padded tail (with none of them it is
+# ``tests/test_causal_attention.py``'s)
+TWO_PART = [(6, 2, 40, 16, 13), (8, 2, 24, 32, 5), (8, 2, 48, 16, 32), (6, 2, 70, 16, 40)]
 
 
 @pytest.mark.parametrize("h,g,seq,block,window,two_part",
@@ -84,6 +100,58 @@ def test_grouped_windowed_kernels_match_every_pair_forward_and_backward(h, g, se
         np.testing.assert_allclose(e, r, rtol=2e-4, atol=2e-5)
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+# key blocks a span of five: one, two (three spans, the last ragged), three (two spans)
+@pytest.mark.parametrize("span", [1, 2, 3])
+@pytest.mark.parametrize("h,g,window,two_part", [(6, 2, None, True), (6, 2, 40, False),
+                                                 (2, 2, 24, True)])
+def test_accumulators_that_outgrow_the_budget_split_the_keys_into_spans(monkeypatch, h, g, window,
+                                                                        two_part, span):
+    """The shape rule's other path: with a budget the sequence-long
+    accumulators do not fit, the same kernel walks the keys a span at a time
+    and a query block's gradient is the sum of its spans' shares; every
+    gradient equals the unsplit call's to float32 rounding."""
+    seq, block = 70, 16
+    args, w = _inputs(seq + h, 2, h, g, seq, two_part)
+    given = tuple(i for i, a in enumerate(args) if a is not None)
+    widths = (16, 12, 8) if two_part else (16, 12)
+    assert _causal_span(5, block, widths, 4) == 5  # the rule's own budget: one span
+    budget = next(b for b in range(0, 1 << 20, 4096) if _causal_span(5, block, widths, 4, b) == span)
+    grad = jax.jit(jax.grad(lambda *xs: (pallas_causal_attention(*xs, block, True, window) * w).sum(),
+                            argnums=given))
+    whole = grad(*args)
+    monkeypatch.setattr(pallas_attention, "_causal_span",
+                        functools.partial(_causal_span, budget=budget))
+    jax.clear_caches()  # the rule is read when the call is traced
+    split = grad(*args)
+    for a, b in zip(split, whole, strict=True):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-6)
+    # and it was the other walk: the kernel's first output leads with the spans
+    shares = [eqn.outvars[0].aval.shape[0] for eqn in _eqns(jax.make_jaxpr(grad)(*args).jaxpr)
+              if eqn.primitive.name == "pallas_call" and eqn.params["name"] == "causal_attention_bwd"]
+    assert shares == [-(-5 // span)]
+
+
+def test_the_span_rule_at_the_shapes_the_repo_trains():
+    """8192 tokens at block 1024 (and the window layers' 512) hold their
+    accumulators in one span at every recipe's widths; the two-part widths
+    take two at 32 768 tokens, and a number of blocks no span length divides
+    is cut into spans as equal as it allows."""
+    mla, gqa = (128, 128, 64), (128, 128)
+    assert _causal_span(8, 1024, mla, 2) == _causal_span(8, 1024, gqa, 2) == 8
+    assert _causal_span(16, 512, gqa, 2) == 16
+    assert (_causal_span(16, 1024, mla, 2), _causal_span(32, 1024, mla, 2)) == (16, 16)
+    assert (_causal_span(17, 1024, mla, 2), _causal_span(24, 1024, gqa, 2)) == (9, 24)
+    assert _causal_span(5, 1024, mla, 2, budget=1) == 1  # never under a block
+
+
 def test_a_windowed_kernel_sees_neither_the_future_nor_beyond_its_window():
     (q, _, k, _, v), _ = _inputs(4, 1, 4, 2, 48, False)
     run = lambda k, v: pallas_causal_attention(q, None, k, None, v, 16, True, 20)
@@ -96,12 +164,16 @@ def test_a_windowed_kernel_sees_neither_the_future_nor_beyond_its_window():
     np.testing.assert_array_equal(base[:, :, :30], later[:, :, :30])
 
 
-@pytest.mark.parametrize("by_key", [False, True])
+@pytest.mark.parametrize("backward", [False, True])
 @pytest.mark.parametrize("n,block,window", [(8, 16, 16), (8, 16, 17), (8, 16, 40), (5, 32, 5),
                                             (6, 8, 1), (4, 16, 1000)])
-def test_the_band_holds_each_pair_with_a_visible_entry_once(n, block, window, by_key):
+def test_the_band_holds_each_pair_with_a_visible_entry_once(n, block, window, backward):
+    """The forward kernel's tables; and the backward kernel's walk of the
+    same pairs, once a member of a group of two a span of three key blocks,
+    with the bits that open and close a query block's, a query head's and a
+    span's accumulators."""
     reach = _reach(window, block)
-    qi, kj = _lower_triangle(n, by_key=by_key, reach=reach)
+    qi, kj = _lower_triangle(n, reach=reach)
     pairs = list(zip(qi.tolist(), kj.tolist()))
     # block pair (i, j) holds a visible entry iff some row r of i and column
     # c of j have 0 <= r - c < window
@@ -110,10 +182,31 @@ def test_the_band_holds_each_pair_with_a_visible_entry_once(n, block, window, by
                    for c in (j * block, (j + 1) * block - 1))
             or (i == j)]
     assert sorted(pairs) == sorted(seen) and len(set(pairs)) == len(pairs)
-    outer = kj if by_key else qi
-    assert list(outer) == sorted(outer)  # one visit of each output block
-    inner = qi if by_key else kj
-    assert all(inner[t] < inner[t + 1] for t in range(len(pairs) - 1) if outer[t] == outer[t + 1])
+    assert list(qi) == sorted(qi)  # one visit of each output block
+    assert all(kj[t] < kj[t + 1] for t in range(len(pairs) - 1) if qi[t] == qi[t + 1])
+    if not backward:
+        return
+    steps = list(zip(*(column.tolist() for column in _backward_walk(n, reach=reach, group=2,
+                                                                    span=3))))
+    for member in (0, 1):
+        assert sorted((i, j) for i, j, m, _ in steps if m == member) == sorted(seen)
+    # the steps of a span are consecutive, within it a query head's, within
+    # those a query block's (its key blocks rising), and each bit marks the
+    # first or the last step of its run and no other
+    of_span, of_head, of_row = (lambda s: s[1] // 3, lambda s: (s[1] // 3, s[2]),
+                                lambda s: (s[1] // 3, s[2], s[0]))
+    for key, first, last in [(of_row, ROW_FIRST, ROW_LAST), (of_head, HEAD_FIRST, 0),
+                             (of_span, SPAN_FIRST, SPAN_LAST)]:
+        keys = [key(s) for s in steps]
+        starts = [t for t, k in enumerate(keys) if t == 0 or keys[t - 1] != k]
+        ends = [t for t, k in enumerate(keys) if t == len(keys) - 1 or keys[t + 1] != k]
+        assert len(starts) == len(set(keys))  # one run each
+        assert [t for t, s in enumerate(steps) if s[3] & first] == starts
+        assert not last or [t for t, s in enumerate(steps) if s[3] & last] == ends
+    assert all(a[1] < b[1] for a, b in zip(steps, steps[1:]) if of_row(a) == of_row(b))
+    # the key/value accumulators outlive a member: opened by the first, closed by the last
+    assert not any(s[3] & SPAN_FIRST for s in steps if s[2] == 1)
+    assert not any(s[3] & SPAN_LAST for s in steps if s[2] == 0)
 
 
 def test_the_pairs_the_tables_visit_against_those_the_mask_keeps():
@@ -142,8 +235,6 @@ def test_the_dispatcher_hands_the_window_and_the_groups_to_either_form(monkeypat
     want = _dense(q, None, k, None, v, 7)
     np.testing.assert_allclose(causal_attention(q, None, k, None, v, impl="einsum", window=7),
                                want, rtol=2e-5, atol=2e-6)
-    from jumbo_mae_tpu_tpu.ops.pallas import attention as pallas_attention
-
     real = pallas_attention.pallas_causal_attention
     monkeypatch.setattr(pallas_attention, "pallas_causal_attention",
                         lambda *xs, window=None: real(*xs, 8, True, window))

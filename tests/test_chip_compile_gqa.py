@@ -15,16 +15,17 @@ import chip_smoke
 from test_chip_compile import compile_lm_step, program_bytes, v5e_chip  # noqa: F401 - fixture
 
 RECIPE = str(chip_smoke.REPO / "recipes" / "pretrain_laguna_xs2_share.yaml")
-# what one AOT compile of this step read (PERF.md, PR 35; 13 096 821 760 before
-# the rope kernel, PR 33), and the chip's own line: 16 GiB less what the
-# runtime keeps
-PROGRAM_BYTES, CHIP_BYTES = 13_059_776_000, 16.9e9
+# what one AOT compile of this step read (PERF.md, PR 37: one backward causal
+# kernel; 13 059 776 000 with two, PR 35; 13 096 821 760 before the rope
+# kernel, PR 33), and the chip's own line: 16 GiB less what the runtime keeps
+PROGRAM_BYTES, CHIP_BYTES = 13_058_227_712, 16.9e9
 
 
 def test_grouped_query_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):  # noqa: F811
     """766 M parameters, 2 x 8192 tokens, through the trainer's own step
-    factory: each of the eight blocks runs each causal kernel once (a
-    rematted block keeps the forward kernel's output and log-sum-exp), the
+    factory: each of the eight blocks runs the forward and the one backward
+    causal kernel once (a rematted block keeps the forward kernel's output
+    and log-sum-exp; no ``causal_attention_dq`` / ``_dkv`` is left), the
     two full layers' under ``attn_core`` and the six window layers' under
     ``swa_core``; nothing sized (seq, seq) a head exists; the window layers'
     tables walk the band (2.0 x the entries their mask keeps, not the
@@ -39,11 +40,12 @@ def test_grouped_query_language_model_step_compiles_for_v5e_and_fits(v5e_chip, m
     rows, seq = cfg.run.train_batch_size, cfg.data.seq_len
     text = compiled.as_text()
     assert " conditional(" not in text and "/guard/" in text
-    assert chip_smoke.causal_kernel_calls(text) == {"fwd": 8, "dq": 8, "dkv": 8}
+    assert chip_smoke.causal_kernel_calls(text) == {"fwd": 8, "bwd": 8}
     by_kind = {scope: len(re.findall(
         rf'custom-call\([^\n]*/{scope}/causal_attention_\w+/pallas_call"', text))
         for scope in ("attn_core", "swa_core")}
-    assert by_kind == {"attn_core": 3 * 2, "swa_core": 3 * 6}
+    assert by_kind == {"attn_core": 2 * 2, "swa_core": 2 * 6}
+    assert "causal_attention_dq" not in text and "causal_attention_dkv" not in text
     assert (lm.layer_types.count("full_attention"), lm.layer_types.count("sliding_attention")) \
         == (2, 6)
     assert chip_smoke.rope_kernel_calls(text) == 8 * 2 * 3

@@ -14,15 +14,16 @@ import chip_smoke
 from test_chip_compile import compile_lm_step, program_bytes, v5e_chip  # noqa: F401 - fixture
 
 RECIPE = str(chip_smoke.REPO / "recipes" / "pretrain_ling3_flash_ep64.yaml")
-# what one AOT compile of this step read (PERF.md, PR 32; 15 875 868 160 with
-# the chunk scan in place of the kernels, PR 31), and the chip's own line:
-# 16 GiB less what the runtime keeps
-PROGRAM_BYTES, CHIP_BYTES = 15_168_317_440, 16.9e9
+# what one AOT compile of this step read (PERF.md, PR 37: one backward causal
+# kernel whose key/value outputs are sequence-long blocks; 15 168 317 440 with
+# two, PR 32; 15 875 868 160 with the chunk scan in place of the kernels, PR
+# 31), and the chip's own line: 16 GiB less what the runtime keeps
+PROGRAM_BYTES, CHIP_BYTES = 15_168_285_184, 16.9e9
 
 
 def test_hybrid_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):  # noqa: F811
     """822 M parameters, 2 x 8192 tokens, through the trainer's own step
-    factory: the one MLA block runs each causal kernel once, the six
+    factory: the one MLA block runs each of the two causal kernels once, the six
     linear-attention blocks run the forward chunk kernel twice (forward and
     the block's recompute, which keeps every chunk's starting state) and the
     backward kernel once, with no loop left under ``kda_core``, and build
@@ -35,7 +36,7 @@ def test_hybrid_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypa
     rows = cfg.run.train_batch_size
     text = compiled.as_text()
     assert " conditional(" not in text and "/guard/" in text
-    assert chip_smoke.causal_kernel_calls(text) == {"fwd": 1, "dq": 1, "dkv": 1}
+    assert chip_smoke.causal_kernel_calls(text) == {"fwd": 1, "bwd": 1}
     assert chip_smoke.rope_kernel_calls(text) == 0  # rope on adjacent pairs: not the kernel's
     assert "gmm" in text
     assert chip_smoke.kda_kernel_calls(text) == {"fwd": 2 * lm.kda_layers, "bwd": lm.kda_layers,

@@ -98,7 +98,7 @@ def test_lm_train_phase_rehearsal(recipe, toy, family, tmp_path, watch, capsys):
     assert checked["loss_after_one_cycle"] < checked["loss_first"]
     assert checked["moe_dropped"] == 0 and checked["skipped_steps"] == 0
     # on the CPU the core resolves to its einsum form: no kernel in the step
-    assert checked["causal_kernel_calls"] == {"fwd": 0, "dq": 0, "dkv": 0}
+    assert checked["causal_kernel_calls"] == {"fwd": 0, "bwd": 0}
     assert checked["rope_kernel_calls"] == 0  # and rope to its jax.numpy form
     from jumbo_mae_tpu_tpu.obs.metrics import get_registry
 
