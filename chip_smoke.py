@@ -403,7 +403,9 @@ def check_step_runs_the_rope_kernel(programs: dict, lm) -> int:
     import jax
 
     calls = rope_kernel_calls(programs["train_step"].as_text())
-    blocks = lm.layers if lm.layer_types is not None else 0
+    # a grouped-query block whose kind has a rotary embedding (a kind may have none)
+    ropes = dict(lm.rope_parameters or ())
+    blocks = sum(ropes.get(kind) is not None for kind in lm.kinds)
     want = 3 * 2 * blocks if jax.default_backend() == "tpu" else 0
     check(calls == want, f"the step calls the rope kernel {calls} times, not {want}")
     return calls
@@ -488,8 +490,15 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         check(all(s is not None and 0 < s < 100 for s in states),
               f"a linear-attention state is missing or unbounded: {states}")
         decay = [r["train/kda_decay_mean"] for r in by_step.values()]
+        beta = [r["train/kda_beta_max"] for r in by_step.values()]
+        negative = [r["train/kda_neg_eig_share"] for r in by_step.values()]
+        check(all(0 < x < lm.kda_beta_scale for x in beta)
+              and all((x > 0) == (lm.kda_beta_scale > 1) for x in negative),
+              f"beta outside (0, {lm.kda_beta_scale}) or its share past 1 off: {beta} {negative}")
         kda = {"kda_state_absmax_max": round(max(states), 4),
-               "kda_decay_mean_min_max": [round(min(decay), 4), round(max(decay), 4)]}
+               "kda_decay_mean_min_max": [round(min(decay), 4), round(max(decay), 4)],
+               "kda_beta_max": round(max(beta), 4),
+               "kda_neg_eig_share_min_max": [round(min(negative), 4), round(max(negative), 4)]}
     retraces = _delta(before, after, "retrace_events_total", "train")
     check(retraces == 0, f"{retraces} unexpected recompile(s) after warmup")
     last = max((r for r in records if "perf/tokens_per_sec_per_chip" in r),
@@ -507,6 +516,8 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         "rope_kernel_calls": rope_calls,
         "attn_pairs": {kind: {"visited": visited, "needed": needed} for kind, (visited, needed)
                        in lm.attn_pairs(cfg.data.seq_len).items()},
+        "attn_heads": {kind: {"held": held, "published": published}
+                       for kind, (held, published) in lm.attn_heads().items()},
         "moe_held_share_min_max": [round(min(share), 4), round(max(share), 4)],
         "moe_imbalance_max": round(max(r["train/moe_imbalance"] for r in by_step.values()), 3),
         **kda,

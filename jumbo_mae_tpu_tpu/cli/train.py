@@ -942,6 +942,16 @@ def train(cfg: TrainConfig) -> dict:
             logger.log(pairs, step=start_step)
             if is_main:
                 print(f"[train] attention pairs a head and sequence: {pairs}")
+        # static too: the heads this chip holds of each attention kind, and the
+        # model's own count (they differ where a layer's heads are divided)
+        heads = {
+            f"train/attn_heads_{what}_{kind}": count
+            for kind, counts in enc_cfg.attn_heads().items()
+            for what, count in zip(("held", "published"), counts)
+        }
+        logger.log(heads, step=start_step)
+        if is_main:
+            print(f"[train] attention heads a kind: {heads}")
     valid_factory = make_valid_iterator(
         cfg, mesh, per_process_valid, num_labels=getattr(enc_cfg, "labels", None) or 1000
     )

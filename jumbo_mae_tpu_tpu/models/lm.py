@@ -1,5 +1,7 @@
-"""Decoder-only sparse-expert language models, three families from one set of
-blocks. **All-MLA** (the DeepSeek-V3 family's block, as ``JoyAI-LLM-Flash``'s
+"""Decoder-only sparse-expert language models, four families from one set of
+blocks; each trunk block's attention is one of four kinds, and
+``MlaMoeConfig.kinds`` is the one list that says which (a family is a way to
+fill it). **All-MLA** (the DeepSeek-V3 family's block, as ``JoyAI-LLM-Flash``'s
 ``config.json`` sizes it): multi-head latent attention in every block,
 sigmoid-routed experts beside a shared expert, one multi-token prediction
 (MTP) module. **Hybrid** (``Ling-3.0-flash``, ``model_type: bailing_hybrid``):
@@ -11,9 +13,13 @@ limited to a token's best groups of experts. **Grouped-query**
 ``i`` is grouped-query softmax attention of the kind ``layer_types[i]`` —
 ``full_attention`` or ``sliding_attention`` — with
 ``heads_per_layer[i]`` query heads over ``kv_heads`` key/value heads, its own
-rotary embedding a kind, and a head-wise gate; no latent, no MTP module. The
+rotary embedding a kind, and a head-wise gate; no latent, no MTP module.
+**Linear beside grouped-query** (``Solar-Open2-250B``, ``model_type:
+solar_open2``): ``layer_types`` may name ``"kda"`` (and ``"mla"``) beside the
+grouped-query kinds — here one rope-free ``full_attention`` block to three
+KDA blocks whose gates are the other variants below. The
 defaults are the first family's; its parameter tree, scopes and program do
-not depend on the other two's fields.
+not depend on the others' fields.
 
 Pre-norm residual blocks with RMSNorm (eps ``rms_eps``): ``x += A_i(norm(x))``,
 ``x += F_i(norm(x))``. No bias anywhere.
@@ -28,7 +34,9 @@ adjacent pairs; causal ``z = softmax(q kᵀ (nope + rope)^-½) v``; with
 Grouped-query attention, block ``i`` with ``H = heads_per_layer[i]``, ``G =
 kv_heads``, ``d = head_dim``: ``q = x W_q`` -> (H, d), ``k = x W_k``, ``v = x
 W_v`` -> (G, d); query head ``h`` reads key/value head ``h // (H / G)``. No
-q/k norm. Rotary embedding with the **rotate-half pairing** (dimension ``j``
+q/k norm. A kind whose ``rope_parameters`` entry is None has no rotary
+embedding: ``q`` and ``k`` go to the core as projected. Otherwise, rotary
+embedding with the **rotate-half pairing** (dimension ``j``
 with ``j + r/2``) on the first ``r = partial_rotary_factor · d`` dimensions
 of ``q`` and ``k``, the rest passed through, by the kind's
 ``rope_parameters``: ``rope_type: default`` turns pair ``j`` by ``position ·
@@ -49,25 +57,36 @@ sliding_window``; ``z = softmax(s) v``; ``z_h ← sigmoid(x W_γ)_h · z_h``;
 ``W_o`` over heads x d. The core is ``ops/flash_attention.causal_attention``,
 the latent family's, with one score part, grouped heads and a window.
 
-KDA, per head ``h`` with ``d_k = d_v = kda_head_dim`` (as many key and value
-heads as query heads): ``q̃, k̃, ṽ = x W_q, x W_k, x W_v``; each through a
+KDA, per head ``h`` of ``kda_heads`` (``heads`` where that is None; the field
+stands for the source's own ``linear_attn_config.num_heads`` key, and no
+configuration or recipe here sets the two apart: only the test cuts do) with
+``d_k = d_v = kda_head_dim`` (as many key and value heads as query heads): ``q̃, k̃, ṽ = x W_q, x W_k, x W_v``; each through a
 causal depthwise convolution of ``kda_conv`` taps, one filter a channel, zero
 history before the sequence (one document a sequence), then SiLU:
 ``u_t = silu(Σ_j c_j ⊙ ũ_{t−K+1+j})``; ``q̂ = q / ‖q‖₂ · d_k^-½``, ``k̂ = k /
 ‖k‖₂`` (eps 1e-6; no norm of ``v``); the per-channel log-decay in its safe
 form, ``g_t = lower_bound · sigmoid(exp(A_log_h) · (x W_f + dt_bias))`` with
 one ``A_log`` a head and one ``dt_bias`` a channel, so ``g`` lies in
-``(lower_bound, 0)`` and ``α_t = exp(g_t)``; ``β_t = sigmoid(x W_b)``, one a
-head. The state ``S`` (d_k, d_v) starts at zero:
+``(lower_bound, 0)`` (``kda_gate: "safe"``), or with no floor ``g_t =
+−exp(A_log_h) · softplus(x W_f + dt_bias)`` (``"softplus"``);
+``α_t = exp(g_t)``; ``β_t = kda_beta_scale · sigmoid(x W_b)``, one a head: at
+scale 2 the transition's eigenvalue along ``k̂_t``, ``1 − β_t``, lies in (−1,
+1). With ``kda_gate_rank = r`` the decay gate's ``W_f`` and the output
+gate's ``W_γ`` are two factors each, ``d → r → heads · d_k`` (the first is
+what every chip that shares the layer's heads computes alike). The state
+``S`` (d_k, d_v) starts at zero:
 
     S_t = (I − β_t k̂_t k̂_tᵀ) Diag(α_t) S_{t−1} + β_t k̂_t v_tᵀ,   o_t = S_tᵀ q̂_t
 
 computed in chunks of ``kda_chunk`` positions (``ops/kda.py``: the
 triangular form inside a chunk, a scan that carries ``S`` between chunks,
-``g`` and ``S`` in float32). ``y = concat_h(sigmoid(x W_γ)_h ·
-RMSNorm(o_h)) W_o``, the norm over ``d_v`` with one learned scale shared by
-the heads. A layer reports the largest ``|S|`` at the end of the sequences
-and the mean ``α`` (``KDA_COUNTERS``).
+``g`` and ``S`` in float32; the scan is told the gate's floor, or that it has
+none, and builds its decay ratios accordingly). ``y = concat_h(sigmoid(x
+W_γ)_h · RMSNorm(o_h)) W_o``, the norm over ``d_v`` with one learned scale
+shared by the heads, the gate one logit a head (``kda_out_gate: "head"``) or
+one a value channel (``"element"``). A layer reports the largest ``|S|`` at
+the end of the sequences, the mean ``α``, the largest ``β`` and the share of
+(token, head) with ``β > 1`` (``KDA_COUNTERS``).
 
 MLP: ``W_d(silu(W_g x) ⊙ W_u x)``. The clamped SwiGLU (a non-zero
 ``*_swiglu_limit``) is not implemented and is refused.
@@ -87,7 +106,12 @@ the weights are normalised over all ``k`` chosen; the layer's output is
 experts would add is left out, and nothing stands in for them or for their
 exchange. ``vocab_rows = (v0, n)`` likewise: embedding and head hold rows
 ``v0 .. v0 + n`` of the vocabulary, token ids come from that range, and
-logits and loss are over the slice.
+logits and loss are over the slice. **Heads** likewise: ``heads``,
+``kda_heads``, ``heads_per_layer`` and ``kv_heads`` count what this chip
+holds of a layer whose heads are divided, ``heads_published`` states the
+model's own counts a kind (``attn_heads``), and ``W_o`` holds the held
+heads' rows: the block adds the partial attention output, and nothing stands
+in for the other chips' parts or their all-reduce.
 
 The expert layer sorts the (token, expert) pairs so that those on held
 experts come first, expert by expert, and walks the held ones in rounds of a
@@ -148,10 +172,12 @@ from jumbo_mae_tpu_tpu.ops.kda import causal_conv_silu, kda_chunked
 MOE_COUNTERS = ("rows_min", "rows_mean", "rows_max", "imbalance", "held_share", "dropped",
                 "rounds")
 # the counters a linear-attention layer reports, in the order of its stats vector
-KDA_COUNTERS = ("state_absmax", "decay_mean")
+KDA_COUNTERS = ("state_absmax", "decay_mean", "beta_max", "neg_eig_share")
 
 
+KDA_UNIT_EPS = 1e-6  # under the root of q's and k's L2 norm; not the RMSNorms' rms_eps
 GQA_KINDS = ("full_attention", "sliding_attention")
+ATTENTION_KINDS = ("kda", "mla", *GQA_KINDS)
 
 
 @dataclass(frozen=True)
@@ -192,7 +218,7 @@ class MlaMoeConfig:
     """Sizes as ``config.json`` names them (``JoyAI-LLM-Flash`` defaults: the
     all-MLA family), plus what this chip holds of them; the hybrid family's
     fields below ``init_std``, the grouped-query family's below those. The
-    name is the first family's: the class holds all three (a rename would
+    name is the first family's: the class holds all four (a rename would
     touch every recipe's reader and test for no behaviour)."""
 
     vocab_size: int = 129280
@@ -229,19 +255,27 @@ class MlaMoeConfig:
     kda_conv: int = 4  # short_conv_kernel_size
     kda_lower_bound: float = -5.0  # the safe gate's floor of the log-decay
     kda_chunk: int = 64  # positions a chunk of the scan (ops/kda.py)
+    kda_heads: int | None = None  # linear-attention heads held; None = heads
+    kda_gate: str = "safe"  # or "softplus": g = −exp(A_log) · softplus(·), no floor
+    kda_beta_scale: float = 1.0  # β = scale · sigmoid(x W_b); 2 = kda_allow_neg_eigval
+    kda_gate_rank: int | None = None  # W_f and W_γ through this rank; None = one matrix each
+    kda_out_gate: str = "head"  # the output gate: one logit a "head" or an "element"
     attn_gate: bool = False  # head-wise sigmoid gate on the attention output
     # the clamped SwiGLU is not implemented: a non-zero limit is refused
     expert_swiglu_limit: float = 0.0
     shared_expert_swiglu_limit: float = 0.0
-    # the grouped-query family: with layer_types, block i is softmax attention
-    # of the kind layer_types[i] with heads_per_layer[i] query heads over
-    # kv_heads key/value heads of head_dim; None = no block of this family
+    # each block's attention kind, of ATTENTION_KINDS; None = by layer_group_size
+    # (``kinds``). A grouped-query block i has heads_per_layer[i] query heads
+    # (``heads`` where the list is None) over kv_heads key/value heads of
+    # head_dim; a kind's rope_parameters entry may be None: no rotary embedding
     layer_types: tuple[str, ...] | None = None
     heads_per_layer: tuple[int, ...] | None = None  # num_attention_heads_per_layer
     kv_heads: int = 8  # num_key_value_heads
     head_dim: int = 128
     sliding_window: int = 512  # keys a sliding_attention query sees, itself included
-    rope_parameters: tuple[tuple[str, Rope], ...] | None = None  # a Rope an attention kind
+    rope_parameters: tuple[tuple[str, Rope | None], ...] | None = None  # a Rope a kind
+    # the model's own head count a kind, where this chip holds a share of them
+    heads_published: tuple[tuple[str, int], ...] | None = None
 
     grad_ckpt: bool = True
     remat_policy: RematPolicy = "none"
@@ -254,7 +288,13 @@ class MlaMoeConfig:
             if value is not None:
                 object.__setattr__(self, name, tuple(int(v) for v in value))
         if self.layer_types is not None:
-            self._check_grouped_query()
+            self._check_layer_types()
+        if isinstance(self.heads_published, dict):  # a recipe gives a mapping
+            object.__setattr__(self, "heads_published", tuple(self.heads_published.items()))
+        if self.kda_gate not in ("safe", "softplus") or self.kda_out_gate not in (
+                "head", "element"):
+            raise ValueError(f"kda_gate {self.kda_gate!r} / kda_out_gate {self.kda_out_gate!r}: "
+                             "safe or softplus, head or element")
         if self.mtp_layers not in (0, 1):
             raise ValueError("mtp_layers must be 0 or 1")
         if self.expert_swiglu_limit or self.shared_expert_swiglu_limit:
@@ -273,24 +313,33 @@ class MlaMoeConfig:
         if not (0 <= v0 and rows > 0 and v0 + rows <= self.vocab_size):
             raise ValueError(f"vocab_rows {self.vocab_rows} outside the vocabulary")
 
-    def _check_grouped_query(self):
+    def _check_layer_types(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         ropes = self.rope_parameters or ()
-        if isinstance(ropes, dict):  # a recipe gives the published group
-            ropes = tuple((kind, Rope(**ropes[kind])) for kind in GQA_KINDS)
+        if isinstance(ropes, dict):  # a recipe gives the published group: a kind -> keys or null
+            order = lambda item: GQA_KINDS.index(item[0]) if item[0] in GQA_KINDS else 2
+            ropes = tuple((kind, None if keys is None else Rope(**keys))
+                          for kind, keys in sorted(ropes.items(), key=order))
         object.__setattr__(self, "rope_parameters", tuple(ropes))
-        heads = self.heads_per_layer or ()
+        grouped = [i for i, kind in enumerate(self.layer_types) if kind in GQA_KINDS]
+        heads = self.heads_per_layer or (self.heads,) * self.layers
         if not (len(self.layer_types) == len(heads) == self.layers):
             raise ValueError(f"layer_types and heads_per_layer must name each of the "
                              f"{self.layers} layers")
-        if set(self.layer_types) - set(GQA_KINDS) or dict(ropes).keys() != set(GQA_KINDS):
-            raise ValueError(f"layer_types and rope_parameters name the kinds {GQA_KINDS}")
-        if any(h % self.kv_heads for h in heads):
+        if (set(self.layer_types) - set(ATTENTION_KINDS)
+                or not {self.layer_types[i] for i in grouped} <= dict(ropes).keys() <= set(
+                    GQA_KINDS)):
+            raise ValueError(f"layer_types name the kinds {ATTENTION_KINDS}, and rope_parameters "
+                             f"each of {GQA_KINDS} among them (a Rope, or None for no rotation)")
+        if any(heads[i] % self.kv_heads for i in grouped):
             raise ValueError(f"query heads {heads} are no multiple of {self.kv_heads} "
                              "key/value heads")
-        if self.mtp_layers or self.layer_group_size:
-            raise ValueError("the grouped-query family has no MTP module and no "
-                             "linear-attention layer")
+        if self.layer_group_size:
+            raise ValueError("layer_types and layer_group_size both say which block is of "
+                             "which kind: give one")
+        if self.mtp_layers and grouped:
+            raise ValueError("a grouped-query block has no MTP module beside it: the module's "
+                             "block is of the MLA kind, built for the all-MLA and hybrid trunks")
 
     @property
     def held(self) -> tuple[int, int]:
@@ -300,23 +349,52 @@ class MlaMoeConfig:
     def rows(self) -> tuple[int, int]:
         return self.vocab_rows or (0, self.vocab_size)
 
+    @property
+    def kinds(self) -> tuple[str, ...]:
+        """Each trunk block's attention kind, of ``ATTENTION_KINDS``: the one
+        list every reader goes by. ``layer_types`` where given; else filled by
+        the hybrid family's rule (block ``i`` is MLA when ``i + 1`` is a
+        multiple of ``layer_group_size``, KDA otherwise; 0: every block MLA)."""
+        if self.layer_types is not None:
+            return self.layer_types
+        p = self.layer_group_size
+        return tuple("kda" if p and (i + 1) % p else "mla" for i in range(self.layers))
+
     def is_kda(self, layer: int) -> bool:
         """Whether trunk block ``layer`` is of the linear-attention kind."""
-        return self.layer_group_size > 0 and (layer + 1) % self.layer_group_size != 0
+        return self.kinds[layer] == "kda"
 
     @property
     def kda_layers(self) -> int:
-        return sum(self.is_kda(i) for i in range(self.layers))
+        return self.kinds.count("kda")
 
     def attention_kind(self, layer: int) -> str:
-        """Trunk block ``layer``'s attention: ``"kda"``, ``"mla"``, or a
-        grouped-query layer's ``layer_types`` entry."""
-        if self.layer_types is not None:
-            return self.layer_types[layer]
-        return "kda" if self.is_kda(layer) else "mla"
+        """Trunk block ``layer``'s attention: its entry of ``kinds``."""
+        return self.kinds[layer]
 
-    def rope(self, kind: str) -> Rope:
+    def query_heads(self, layer: int) -> int:
+        """The query heads a grouped-query block ``layer`` holds."""
+        return self.heads_per_layer[layer] if self.heads_per_layer else self.heads
+
+    def rope(self, kind: str) -> Rope | None:
+        """A grouped-query kind's rotary embedding; None: it has none."""
         return dict(self.rope_parameters)[kind]
+
+    def attn_heads(self) -> dict:
+        """``{kind: (held, published)}``: the heads this chip holds of each
+        attention kind among the blocks (a grouped-query kind's query heads,
+        the first such block's) and the model's own count, which is the held
+        one where ``heads_published`` names none. Static."""
+        def of_block(i, kind):
+            if kind in GQA_KINDS:
+                return self.query_heads(i)
+            return (self.kda_heads or self.heads) if kind == "kda" else self.heads
+
+        held = {}
+        for i, kind in enumerate(self.kinds + ("mla",) * self.mtp_layers):
+            held.setdefault(kind, of_block(i, kind))
+        published = dict(self.heads_published or ())
+        return {kind: (n, published.get(kind, n)) for kind, n in sorted(held.items())}
 
     def attn_pairs(self, seq: int) -> dict:
         """``{kind: (visited, needed)}`` for each kind of softmax attention
@@ -325,7 +403,7 @@ class MlaMoeConfig:
         the mask keeps (``ops/pallas/attention.causal_pairs``). Static."""
         from jumbo_mae_tpu_tpu.ops.pallas.attention import causal_pairs
 
-        kinds = {self.attention_kind(i) for i in range(self.layers)} - {"kda"}
+        kinds = set(self.kinds) - {"kda"}
         if self.mtp_layers:
             kinds.add("mla")
         window = lambda kind: self.sliding_window if kind == "sliding_attention" else None
@@ -468,8 +546,9 @@ class GroupedQueryAttention(nn.Module):
             v = Proj((cfg.dim, g, d), "bsd,dhe->bhse", cfg, name="v")(x)
             if cfg.attn_gate:
                 gate = Proj((cfg.dim, h), "bsd,dh->bhs", cfg, name="gate")(x)
-        with jax.named_scope(SCOPE_ROPE):
-            q, k = rope_half(q, rope), rope_half(k, rope)
+        if rope is not None:  # a kind without rotary embedding opens no rope scope
+            with jax.named_scope(SCOPE_ROPE):
+                q, k = rope_half(q, rope), rope_half(k, rope)
         impl = resolve_attn_impl(cfg.attn_impl, backend=jax.default_backend(),
                                  seq_len=x.shape[1], dropout=0.0, deterministic=True)
         with jax.named_scope(SCOPE_SWA_CORE if self.sliding else SCOPE_ATTN_CORE):
@@ -536,22 +615,44 @@ def _decay_bias_init(key, shape, a_log, lower_bound):
     return (jnp.log(share) - jnp.log1p(-share)) / jnp.exp(a_log)[:, None]
 
 
+def _softplus_bias_init(key, shape):
+    """``dt_bias`` of the softplus gate: ``softplus(dt_bias) = dt`` with ``dt``
+    log-uniform over 0.001 .. 0.1, so that ``g = −exp(A_log) · dt`` at a zero
+    gate input (flash-linear-attention's ``KimiDeltaAttention``: assumed)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
 class KdaAttention(nn.Module):
-    """Kimi Delta Attention (module docstring). Returns ``(y, stats)``,
-    ``stats`` in ``KDA_COUNTERS`` order."""
+    """Kimi Delta Attention (module docstring), its gates by what the
+    configuration says: ``kda_gate``, ``kda_beta_scale``, ``kda_gate_rank``,
+    ``kda_out_gate``. Returns ``(y, stats)``, ``stats`` in ``KDA_COUNTERS``
+    order."""
 
     cfg: MlaMoeConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        h, dh, d, f32 = cfg.heads, cfg.kda_head_dim, cfg.dim, jnp.float32
+        h, dh, d, f32 = cfg.kda_heads or cfg.heads, cfg.kda_head_dim, cfg.dim, jnp.float32
         dtype = cfg.compute_dtype
+        safe, rank = cfg.kda_gate == "safe", cfg.kda_gate_rank
         with jax.named_scope(SCOPE_KDA_PROJ):
             wide = lambda name: Proj((d, h, dh), "bsd,dhe->bhse", cfg, name=name)(x)
             thin = lambda name: Proj((d, h), "bsd,dh->bhs", cfg, name=name)(x)
-            q, k, v, a = wide("q"), wide("k"), wide("v"), wide("f")
-            b, gate = thin("b"), thin("gate")
+
+            def gate_proj(name, head_wise=False):
+                # one matrix, or through ``rank``: the first factor is the same
+                # on every chip that holds a share of the layer's heads
+                if rank is None:
+                    return thin(name) if head_wise else wide(name)
+                low = Proj((d, rank), "bsd,dr->bsr", cfg, name=f"{name}_a")(x)
+                if head_wise:
+                    return Proj((rank, h), "bsr,rh->bhs", cfg, name=f"{name}_b")(low)
+                return Proj((rank, h, dh), "bsr,rhe->bhse", cfg, name=f"{name}_b")(low)
+
+            q, k, v, a = wide("q"), wide("k"), wide("v"), gate_proj("f")
+            b, gate = thin("b"), gate_proj("gate", cfg.kda_out_gate == "head")
         with jax.named_scope(SCOPE_KDA_CONV):
             def filt(name):  # a leaf <name>/kernel: one filter a channel
                 bound = cfg.kda_conv**-0.5
@@ -562,23 +663,38 @@ class KdaAttention(nn.Module):
             q, k, v = (causal_conv_silu(u, filt(f"{n}_conv")) for n, u in
                        (("q", q), ("k", k), ("v", v)))
         with jax.named_scope(SCOPE_KDA_GATE):
-            q = (_unit_norm(q, cfg.rms_eps) * dh**-0.5).astype(dtype)
-            k = _unit_norm(k, cfg.rms_eps).astype(dtype)
+            q = (_unit_norm(q, KDA_UNIT_EPS) * dh**-0.5).astype(dtype)
+            k = _unit_norm(k, KDA_UNIT_EPS).astype(dtype)
+            rates = (0.25, 1.0) if safe else (1.0, 16.0)  # exp(A_log)'s range: assumed
             a_log = self.param("A_log", lambda key, shape: jnp.log(
-                jax.random.uniform(key, shape, f32, 0.25, 1.0)), (h,))
-            dt_bias = self.param("dt_bias", _decay_bias_init, (h, dh), a_log,
-                                 cfg.kda_lower_bound)
-            g = cfg.kda_lower_bound * jax.nn.sigmoid(
-                jnp.exp(a_log)[:, None, None] * (a.astype(f32) + dt_bias[:, None, :]))
+                jax.random.uniform(key, shape, f32, *rates)), (h,))
+            if safe:
+                dt_bias = self.param("dt_bias", _decay_bias_init, (h, dh), a_log,
+                                     cfg.kda_lower_bound)
+                g = cfg.kda_lower_bound * jax.nn.sigmoid(
+                    jnp.exp(a_log)[:, None, None] * (a.astype(f32) + dt_bias[:, None, :]))
+            else:
+                dt_bias = self.param("dt_bias", _softplus_bias_init, (h, dh))
+                g = -jnp.exp(a_log)[:, None, None] * jax.nn.softplus(
+                    a.astype(f32) + dt_bias[:, None, :])
             beta = jax.nn.sigmoid(b.astype(f32))
+            if cfg.kda_beta_scale != 1.0:
+                beta = cfg.kda_beta_scale * beta
         with jax.named_scope(SCOPE_KDA_CORE):
-            o, state = kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk)
+            # the scan is told the floor under g, or that there is none
+            o, state = kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk,
+                                   floor=cfg.kda_lower_bound if safe else None)
         with jax.named_scope(SCOPE_KDA_OUT):
             with jax.named_scope(SCOPE_KDA_GATE):
-                o = _head_gate(RMSNorm(cfg.rms_eps, dtype, name="o_norm")(o), gate)
+                o = RMSNorm(cfg.rms_eps, dtype, name="o_norm")(o)
+                if cfg.kda_out_gate == "head":
+                    o = _head_gate(o, gate)
+                else:
+                    o = o * jax.nn.sigmoid(gate.astype(f32)).astype(o.dtype)
             y = Proj((h, dh, d), "bhse,hed->bsd", cfg, name="out")(o)
         with jax.named_scope(SCOPE_KDA_GATE):
-            stats = jnp.stack([jnp.abs(state).max(), jnp.exp(g).mean()])
+            stats = jnp.stack([jnp.abs(state).max(), jnp.exp(g).mean(), beta.max(),
+                               (beta > 1.0).mean(dtype=f32)])
         return y, jax.lax.stop_gradient(stats)
 
 
@@ -833,8 +949,9 @@ class MlaMoeLM(nn.Module):
         block = maybe_remat(Block, cfg)
         self.embedding = self.param("embedding", _normal(cfg), (cfg.rows[1], cfg.dim),
                                     jnp.float32)
-        heads = cfg.heads_per_layer or (0,) * cfg.layers
-        self.blocks = [block(cfg, sparse=i >= cfg.first_k_dense, kind=cfg.attention_kind(i),
+        heads = [cfg.query_heads(i) if kind in GQA_KINDS else 0
+                 for i, kind in enumerate(cfg.kinds)]
+        self.blocks = [block(cfg, sparse=i >= cfg.first_k_dense, kind=cfg.kinds[i],
                              heads=heads[i], name=f"block_{i}") for i in range(cfg.layers)]
         self.ln = RMSNorm(cfg.rms_eps, cfg.compute_dtype, name="ln")
         self.head = Proj((cfg.dim, cfg.rows[1]), "bsd,dv->bsv", cfg, name="head")
@@ -913,5 +1030,6 @@ class MlaMoeLM(nn.Module):
             for name, st in kda.items():
                 out |= {f"kda_{c}_{name}": st[j] for j, c in enumerate(KDA_COUNTERS)}
             table = jnp.stack(list(kda.values()))  # (linear-attention layers, counters)
-            out |= {"kda_state_absmax": table[:, 0].max(), "kda_decay_mean": table[:, 1].mean()}
+            out |= {"kda_state_absmax": table[:, 0].max(), "kda_decay_mean": table[:, 1].mean(),
+                    "kda_beta_max": table[:, 2].max(), "kda_neg_eig_share": table[:, 3].mean()}
         return out

@@ -116,10 +116,10 @@ def classify_flops_per_image(enc_cfg, *, training: bool = True) -> float:
 
 def lm_flops_per_token(cfg, seq: int, *, training: bool = True) -> float:
     """Matmul FLOPs one token of the sparse-expert language model
-    (``models/lm.MlaMoeConfig``: latent attention, latent and linear
-    attention layers in a pattern, or grouped-query layers, full and
-    sliding) requires at sequence length ``seq``, on this chip's share: the
-    experts and vocabulary rows held. 2·m·n·k per matmul; the causal core
+    (``models/lm.MlaMoeConfig``: each block's attention by its entry of
+    ``cfg.kinds`` — latent, linear, or grouped-query, full and sliding)
+    requires at sequence length ``seq``, on this chip's share: the experts,
+    heads and vocabulary rows held. 2·m·n·k per matmul; the causal core
     counts its lower triangle once (mean context ``seq / 2``), a sliding
     layer's the keys its window shows (``min(i + 1, window)`` for query
     ``i``, so it does not grow with ``seq``); a linear-attention core counts
@@ -139,9 +139,13 @@ def lm_flops_per_token(cfg, seq: int, *, training: bool = True) -> float:
         + (d * h if cfg.attn_gate else 0)
     )
     core = 2 * (seq / 2) * h * (qk + dv)
-    dh = cfg.kda_head_dim
-    # q, k, v and the decay gate, beta and the output gate, W_o; the recurrence
-    linear = 2 * (4 * d * h * dh + 2 * d * h + h * dh * d) + 6 * h * dh * dh
+    hk, dh, rank = cfg.kda_heads or h, cfg.kda_head_dim, cfg.kda_gate_rank
+    # a gate's projection to ``out`` columns: one matrix, or two through ``rank``
+    gate_proj = lambda out: d * out if rank is None else d * rank + rank * out
+    # q, k, v, W_o and beta; the decay gate and the output gate; the recurrence
+    linear = (2 * (4 * d * hk * dh + d * hk + gate_proj(hk * dh)
+                   + gate_proj(hk if cfg.kda_out_gate == "head" else hk * dh))
+              + 6 * hk * dh * dh)
     gated = lambda hidden: 2 * 3 * d * hidden
     pairs_here = cfg.experts_per_token * cfg.held[1] / cfg.n_routed_experts
     sparse = (
@@ -154,16 +158,16 @@ def lm_flops_per_token(cfg, seq: int, *, training: bool = True) -> float:
     head = 2 * d * cfg.rows[1]
 
     def grouped_query(layer: int) -> float:
-        hq, g, e = cfg.heads_per_layer[layer], cfg.kv_heads, cfg.head_dim
-        w = min(cfg.sliding_window, seq) if cfg.layer_types[layer] == "sliding_attention" else seq
+        hq, g, e = cfg.query_heads(layer), cfg.kv_heads, cfg.head_dim
+        w = min(cfg.sliding_window, seq) if cfg.kinds[layer] == "sliding_attention" else seq
         keys = (w * (w + 1) / 2 + (seq - w) * w) / seq  # mean keys a query sees
         gate = d * hq if cfg.attn_gate else 0
         return 2 * (d * hq * e + 2 * d * g * e + gate + hq * e * d) + 2 * keys * hq * 2 * e
 
-    grouped = cfg.layers if cfg.layer_types is not None else 0
+    kinds = cfg.kinds
     fwd = (
-        (cfg.layers - grouped - cfg.kda_layers + cfg.mtp_layers) * (latent + core)
-        + sum(grouped_query(i) for i in range(grouped))
+        (kinds.count("mla") + cfg.mtp_layers) * (latent + core)
+        + sum(grouped_query(i) for i, kind in enumerate(kinds) if kind not in ("mla", "kda"))
         + cfg.kda_layers * linear
         + dense_layers * gated(cfg.dense_hidden)
         + sparse_layers * sparse

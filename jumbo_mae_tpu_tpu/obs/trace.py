@@ -104,14 +104,15 @@ SCOPE_LM_HEAD = "lm_head"  # final norm, logits over the rows held, cross-entrop
 # ... and in its linear-attention (Kimi delta attention) layers. A block of
 # that kind has no mla_latent / rope / attn_core / attn_out. An MLA block's
 # head-wise gate lies under attn_out, the expert groups' choice under router.
-SCOPE_KDA_PROJ = "kda_proj"  # the six projections of x: q, k, v, decay gate, beta, output gate
+SCOPE_KDA_PROJ = "kda_proj"  # the projections of x: q, k, v, decay gate, beta, output gate (a gate's two factors where it goes through a rank)
 SCOPE_KDA_CONV = "kda_conv"  # the causal depthwise convolutions of q, k, v and their SiLU
-SCOPE_KDA_GATE = "kda_gate"  # L2 norms, log-decay, beta; under kda_out: output norm and head gate
+SCOPE_KDA_GATE = "kda_gate"  # L2 norms, log-decay, beta; under kda_out: output norm and output gate
 SCOPE_KDA_CORE = "kda_core"  # (q, k, v, g, beta) -> o: the chunked gated delta rule
 SCOPE_KDA_OUT = "kda_out"  # output norm and head-wise gate (also under kda_gate), W_o
 # ... and in its grouped-query layers (full or sliding-window softmax attention
-# over shared key/value heads). A block of that kind has rope, attn_out and one
-# of the two cores: a full layer's is attn_core, as every causal core's.
+# over shared key/value heads). A block of that kind has rope (unless its kind
+# has no rotary embedding: then no rope scope opens), attn_out and one of the
+# two cores: a full layer's is attn_core, as every causal core's.
 SCOPE_GQA_PROJ = "gqa_proj"  # the q, k, v and head-gate projections of x
 SCOPE_SWA_CORE = "swa_core"  # a sliding-window layer's causal kernels and what feeds them
 

@@ -27,7 +27,14 @@ factors are not: with ``g >= −5`` a chunk of 64 reaches ``e^{±320}``. ``A`` a
 sub-blocks ``I > J`` takes its reference at the last position of ``J``: both
 ``e^{G_t − G_ref}`` and ``e^{G_ref − G_i}`` are then at most 1. A diagonal pair
 takes it at its first position: the column factor is at most ``e^{5·15}``,
-inside float32. ``g``, ``G``, ``A``, ``B``, the inverse and ``S`` are float32
+inside float32. That rests on a floor under ``g`` (the safe gate's
+``lower_bound``): where the caller names none (``floor=None``: a softplus
+gate), or one too low for a sub-block (``−floor · sub > 80``), a diagonal
+pair is built **pair by pair** instead, ``Σ_c r_tc k_ic e^{G_tc − G_ic}`` over
+(sub, sub, d_k) with the exponent taken after the subtraction, so that every
+exponent is ``<= 0`` whatever one step's decay: a single ``g = −100`` inside a
+sub-block would make the reference form's column factor infinite beside a
+row factor of zero. ``g``, ``G``, ``A``, ``B``, the inverse and ``S`` are float32
 (the products in three bfloat16 passes) whatever the compute dtype; ``W``, ``U``
 and the products with the state take operands in the compute dtype and
 accumulate in float32.
@@ -146,14 +153,15 @@ def _unit_lower_inverse(lower, base: int = 16):
         jnp.concatenate([corner, bottom], axis=-1)], axis=-2)
 
 
-def _decayed_products(rows, k, cum, sub: int):
+def _decayed_products(rows, k, cum, sub: int, bounded: bool = True):
     """``out[..., r, t, i] = Σ_c rows[..., r, t, c] k[..., i, c]
     e^{cum[..., t, c] − cum[..., i, c]}`` for ``i <= t`` and zero above the
     diagonal, float32: ``rows`` (..., R, C, d) stacks the row operands (q and
     k), ``k`` and the cumulative log-decays ``cum`` are (..., C, d). Built
     one strip of ``sub`` columns at a time so that no exponent is positive
-    beyond ``sub − 1`` steps of decay (module docstring); a strip computes
-    nothing for the rows above it."""
+    beyond ``sub − 1`` steps of decay (module docstring) — and, where the
+    decays are not ``bounded``, none at all: the strip's diagonal sub-block
+    pair by pair; a strip computes nothing for the rows above it."""
     c = k.shape[-2]
     within = jnp.tril(jnp.ones((sub, sub), bool))
     strips = []
@@ -161,10 +169,14 @@ def _decayed_products(rows, k, cum, sub: int):
         hi = lo + sub
         cum_j, k_j = cum[..., lo:hi, :], k[..., lo:hi, :]
         first, last = cum_j[..., :1, :], cum_j[..., -1:, :]
-        # the sub-block against itself: the reference is its first position
-        diag = jnp.einsum("...rtc,...ic->...rti",
-                          rows[..., lo:hi, :] * jnp.exp(cum_j - first)[..., None, :, :],
-                          k_j * jnp.exp(first - cum_j), precision=PRECISE)
+        if bounded:  # the sub-block against itself: the reference is its first position
+            diag = jnp.einsum("...rtc,...ic->...rti",
+                              rows[..., lo:hi, :] * jnp.exp(cum_j - first)[..., None, :, :],
+                              k_j * jnp.exp(first - cum_j), precision=PRECISE)
+        else:  # pair by pair; a pair above the diagonal (masked below) is held to e^0
+            ratio = jnp.exp(jnp.minimum(cum_j[..., :, None, :] - cum_j[..., None, :, :], 0.0))
+            diag = jnp.einsum("...rtc,...tic,...ic->...rti", rows[..., lo:hi, :], ratio, k_j,
+                              precision=PRECISE)
         parts = [jnp.zeros((*diag.shape[:-2], lo, sub), diag.dtype), jnp.where(within, diag, 0.0)]
         if hi < c:  # the rows after it: the reference is its last position
             parts.append(jnp.einsum(
@@ -175,7 +187,7 @@ def _decayed_products(rows, k, cum, sub: int):
     return jnp.concatenate(strips, axis=-1)
 
 
-def _chunk(state, xs, *, sub: int, dtype):
+def _chunk(state, xs, *, sub: int, dtype, bounded: bool = True):
     """One chunk: ``state`` (..., d_k, d_v) float32 at its start; ``xs`` = q,
     k (..., C, d_k), v (..., C, d_v), g (..., C, d_k) float32, beta (..., C)
     float32 -> (state at its end, o (..., C, d_v))."""
@@ -183,7 +195,7 @@ def _chunk(state, xs, *, sub: int, dtype):
     f32 = jnp.float32
     cum = jnp.cumsum(g, axis=-2)
     qf, kf = q.astype(f32), k.astype(f32)
-    both = _decayed_products(jnp.stack([qf, kf], axis=-3), kf, cum, sub)
+    both = _decayed_products(jnp.stack([qf, kf], axis=-3), kf, cum, sub, bounded)
     c = k.shape[-2]
     qk = both[..., 0, :, :]
     kk = jnp.where(jnp.tril(jnp.ones((c, c), bool), -1), both[..., 1, :, :], 0.0)
@@ -200,31 +212,42 @@ def _chunk(state, xs, *, sub: int, dtype):
     return state, o.astype(dtype)
 
 
-def _scan(q, k, v, g, beta, chunk: int, sub: int):
+def _scan(q, k, v, g, beta, chunk: int, sub: int, bounded: bool = True):
     """``kda_chunked`` as a ``lax.scan`` over the chunks, for JAX to
     differentiate: a step is rematerialised in the backward pass."""
     def split(x):  # (batch, heads, seq, ...) -> (chunks, batch, heads, chunk, ...)
         return jnp.moveaxis(x.reshape(*x.shape[:2], -1, chunk, *x.shape[3:]), 2, 0)
 
-    body = jax.checkpoint(functools.partial(_chunk, sub=sub, dtype=v.dtype))
+    body = jax.checkpoint(functools.partial(_chunk, sub=sub, dtype=v.dtype, bounded=bounded))
     start = jnp.zeros((*k.shape[:2], k.shape[-1], v.shape[-1]), jnp.float32)
     state, o = jax.lax.scan(body, start, tuple(split(x) for x in (q, k, v, g, beta)))
     return jnp.moveaxis(o, 0, 2).reshape(v.shape), state
 
 
+# the largest exponent the reference form of a diagonal sub-block may reach
+# (``−floor · sub``): e^80 is inside float32 with room for a row's 128 terms
+MAX_SUB_BLOCK_DECAY = 80.0
+
+
 def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, sub: int | None = None,
-                interpret: bool = False):
+                floor: float | None = None, interpret: bool = False):
     """The gated delta rule over whole sequences from a zero state.
 
     ``q``, ``k`` (batch, heads, seq, d_k) — ``k`` of unit norm, ``q`` already
     scaled; ``v`` (batch, heads, seq, d_v); ``g`` (batch, heads, seq, d_k)
     float32 log-decays, ``<= 0``; ``beta`` (batch, heads, seq) float32 in
-    (0, 1). Returns ``(o, state)``: ``o`` (batch, heads, seq, d_v) in ``v``'s
+    (0, 2): ``I − β k kᵀ`` has the eigenvalue ``1 − β`` along ``k``, negative
+    past 1. Returns ``(o, state)``: ``o`` (batch, heads, seq, d_v) in ``v``'s
     dtype and the float32 state after the last position (batch, heads, d_k,
     d_v). A sequence that is no multiple of ``chunk`` is padded with
     positions that leave the state as it is. ``sub`` is the sub-block of the
-    decay ratios: 16 positions (``16 · |g| <= 80`` stays inside float32 for
-    ``g >= −5``), or the whole of a chunk that is no multiple of 16. The
+    decay ratios: 16 positions, or the whole of a chunk that is no multiple
+    of 16. ``floor`` is a bound the caller knows under every ``g`` (a safe
+    gate's ``lower_bound``), static: with ``−floor · sub <= 80`` a diagonal
+    sub-block's ratios are two factors about a reference position
+    (``16 · |g| <= 80`` stays inside float32 for ``g >= −5``); with no floor
+    (None), or a deeper one, they are built pair by pair with every exponent
+    ``<= 0`` (module docstring). The
     schedule follows the backend and the shapes (module docstring);
     ``interpret`` runs the kernels in the Pallas interpreter (tests)."""
     from jumbo_mae_tpu_tpu.ops.pallas.kda import kda_kernels, suits
@@ -245,8 +268,9 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, sub: int | None = None,
         widen = lambda x: jnp.pad(x, [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 3))
         q, k, v, g, beta = map(widen, (q, k, v, g, beta))
     g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    bounded = floor is not None and -floor * sub <= MAX_SUB_BLOCK_DECAY
     if on_kernels:
-        o, state = kda_kernels(q, k, v, g, beta, chunk, sub, interpret)
+        o, state = kda_kernels(q, k, v, g, beta, chunk, sub, interpret, bounded)
     else:
-        o, state = _scan(q, k, v, g, beta, chunk, sub)
+        o, state = _scan(q, k, v, g, beta, chunk, sub, bounded)
     return o[:, :, :seq], state

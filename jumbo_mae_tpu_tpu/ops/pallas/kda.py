@@ -46,6 +46,20 @@ chosen for the (8, 128) tiling:
   after ``I`` are masked before the exponential;
 - the inverse is the Neumann product over the whole chunk, ``[P; N^k]``
   stacked so that one product a stage gives both ``P N^k`` and ``N^{2k}``.
+
+**Decays with no floor** (``bounded=False``: ``ops/kda.kda_chunked`` knows no
+bound under ``g``, or one too deep for a sub-block). A reference in the
+middle of ``I`` would raise ``e`` to ``sub / 2`` steps of decay either way,
+and one step of ``g = −100`` makes that infinite beside a zero. Rows ``I``
+then take their reference at ``I``'s **first** position against the columns
+before ``I`` (both factors at most 1), and the (sub, sub) block of ``I``
+against itself is built pair by pair, a column at a time: column ``j`` is
+``Σ_c r_tc k_jc e^{min(G_tc − G_jc, 0)}``, a lane reduction of a (sub, d_k)
+product, placed by a select on the column index; the rows above ``j`` (whose
+true exponent would be positive) are held to ``e^0`` and masked with the
+rest of the upper triangle. Elementwise float32, no product in halves. It
+costs 64 such columns a chunk where the bounded form has four products, so
+a configuration that states its floor keeps the form above.
 """
 
 from __future__ import annotations
@@ -157,11 +171,34 @@ _cumsum.defvjp(lambda x: (_cumsum(x), None),
                lambda _, ct: (_triangle_sums(ct, after=True),))
 
 
-def chunk_step(state, q, k, v, g, beta, *, sub: int):
+def _pair_by_pair(qf, kf, cum, lo: int, sub: int, before):
+    """Rows ``lo .. lo + sub`` of the decay-ratio products of ``q`` and of
+    ``k`` against ``k``, (..., sub, C) each, with no exponent above 0: the
+    columns before ``lo`` from ``before`` (..., 2 sub, C), the rows' product
+    about the reference ``G_lo`` (None where ``lo`` is 0); the sub-block
+    against itself a column at a time (module docstring); columns after it
+    are left for the caller's triangle mask."""
+    c = cum.shape[-2]
+    q_i, k_i, g_i = (x[..., lo:lo + sub, :] for x in (qf, kf, cum))
+    column = _iota((*cum.shape[:-2], sub, c), -1)
+    zero = jnp.zeros((*cum.shape[:-2], sub, c), F32)
+    qk, kk = (zero, zero) if before is None else (before[..., :sub, :], before[..., sub:, :])
+    for j in range(sub):
+        ratio = jnp.exp(jnp.minimum(g_i - g_i[..., j:j + 1, :], 0.0))
+        k_j = kf[..., lo + j:lo + j + 1, :] * ratio
+        here = column == lo + j
+        qk = jnp.where(here, jnp.sum(q_i * k_j, axis=-1, keepdims=True), qk)
+        kk = jnp.where(here, jnp.sum(k_i * k_j, axis=-1, keepdims=True), kk)
+    return qk, kk
+
+
+def chunk_step(state, q, k, v, g, beta, *, sub: int, bounded: bool = True):
     """One chunk of ``ops/kda._chunk`` in operations Mosaic lowers: ``state``
     (..., d_k, d_v) float32; ``q``, ``k`` (..., C, d_k), ``v`` (..., C, d_v)
     in the compute dtype; ``g`` (..., C, d_k) float32; ``beta`` (..., 1, C)
-    float32, a row. Returns ``(state at the end, o (..., C, d_v) float32)``."""
+    float32, a row. Returns ``(state at the end, o (..., C, d_v) float32)``.
+    ``bounded``: whether a sub-block's decays stay inside float32 about a
+    reference position (module docstring)."""
     dtype = v.dtype
     c, d_k = k.shape[-2:]
     lead = k.shape[:-2]
@@ -178,6 +215,20 @@ def chunk_step(state, q, k, v, g, beta, *, sub: int):
     qk_rows, kk_rows = [], []
     for lo in range(0, c, sub):
         hi = lo + sub
+        if not bounded:
+            before = None
+            if lo:  # against the columns before I, about G_lo: no factor above 1
+                ref = cum[..., lo:lo + 1, :]
+                away = jnp.exp(cum[..., lo:hi, :] - ref)
+                rows = jnp.concatenate([qf[..., lo:hi, :] * away, kf[..., lo:hi, :] * away],
+                                       axis=-2)
+                early = position < lo
+                cols = jnp.where(early, kf, 0.0) * jnp.exp(jnp.where(early, ref - cum, 0.0))
+                before = _dot3(rows, cols, 1, 1)
+            qk_i, kk_i = _pair_by_pair(qf, kf, cum, lo, sub, before)
+            qk_rows.append(qk_i)
+            kk_rows.append(kk_i)
+            continue
         ref = cum[..., lo + sub // 2:lo + sub // 2 + 1, :]
         away = jnp.exp(cum[..., lo:hi, :] - ref)
         rows = jnp.concatenate([qf[..., lo:hi, :] * away, kf[..., lo:hi, :] * away], axis=-2)
@@ -214,7 +265,8 @@ def chunk_step(state, q, k, v, g, beta, *, sub: int):
     return state, o
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state_ref, *rest, sub: int):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state_ref, *rest, sub: int,
+                bounded: bool = True):
     *starts_ref, s_sc = rest
     c = pl.program_id(2)
 
@@ -226,7 +278,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state_ref, *rest, s
     if starts_ref:
         starts_ref[0][...] = state
     state, o = chunk_step(state, q_ref[...], k_ref[...], v_ref[...], g_ref[...],
-                          beta_ref[:, pl.ds(c, 1), :], sub=sub)
+                          beta_ref[:, pl.ds(c, 1), :], sub=sub, bounded=bounded)
     o_ref[...] = o.astype(o_ref.dtype)
     s_sc[...] = state
 
@@ -247,7 +299,7 @@ def heads_per_step(heads: int, kernel: str) -> int:
 
 
 def kda_forward(q, k, v, g, beta, *, chunk: int, sub: int, with_starts: bool,
-                interpret: bool = False):
+                interpret: bool = False, bounded: bool = True):
     """The chunked gated delta rule from a zero state, ``ops/kda.kda_chunked``'s
     arguments with ``seq`` a multiple of ``chunk``. Returns ``(o, state)``,
     and with ``with_starts`` also the state at every chunk's start,
@@ -267,7 +319,7 @@ def kda_forward(q, k, v, g, beta, *, chunk: int, sub: int, with_starts: bool,
                                       lambda b, h, c: (c, b, h, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((n, batch, heads, d_k, d_v), F32))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, sub=sub),
+        functools.partial(_fwd_kernel, sub=sub, bounded=bounded),
         grid=(batch, heads // hb, n),
         in_specs=[at_chunk(d_k), at_chunk(d_k), at_chunk(d_v), at_chunk(d_k),
                   pl.BlockSpec((None, hb, n, chunk), lambda b, h, c: (b, h, 0, 0))],
@@ -284,7 +336,8 @@ def kda_forward(q, k, v, g, beta, *, chunk: int, sub: int, with_starts: bool,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref, dstate_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_sc, *, sub: int):
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_sc, *, sub: int,
+                bounded: bool = True):
     step = pl.program_id(2)
     c = pl.num_programs(2) - 1 - step
 
@@ -293,7 +346,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref, dstate
         ds_sc[...] = dstate_ref[...]
 
     _, transpose = jax.vjp(
-        functools.partial(chunk_step, sub=sub), starts_ref[...], q_ref[...], k_ref[...],
+        functools.partial(chunk_step, sub=sub, bounded=bounded), starts_ref[...], q_ref[...],
+        k_ref[...],
         v_ref[...], g_ref[...], beta_ref[:, pl.ds(c, 1), :])
     d_start, dq, dk, dv, dg, dbeta = transpose((ds_sc[...], do_ref[...].astype(F32)))
     ds_sc[...] = d_start
@@ -302,7 +356,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref, do_ref, dstate
 
 
 def kda_backward(q, k, v, g, beta, starts, d_o, d_state, *, chunk: int, sub: int,
-                 interpret: bool = False):
+                 interpret: bool = False, bounded: bool = True):
     """The five input gradients of ``kda_forward`` from its kept chunk-start
     states: the chunks in reverse, the state's cotangent in VMEM, each chunk
     rebuilt from its inputs and its start and transposed in VMEM
@@ -315,7 +369,7 @@ def kda_backward(q, k, v, g, beta, starts, d_o, d_state, *, chunk: int, sub: int
     a_group = pl.BlockSpec((None, hb, n, chunk), lambda b, h, c: (b, h, 0, 0))
     shape = lambda x, dtype=None: jax.ShapeDtypeStruct(x.shape, dtype or x.dtype)
     dq, dk, dv, dg, dbeta = pl.pallas_call(
-        functools.partial(_bwd_kernel, sub=sub),
+        functools.partial(_bwd_kernel, sub=sub, bounded=bounded),
         grid=(batch, heads // hb, n),
         in_specs=[at_chunk(d_k), at_chunk(d_k), at_chunk(d_v), at_chunk(d_k), a_group,
                   pl.BlockSpec((None, None, hb, d_k, d_v), lambda b, h, c: (n - 1 - c, b, h, 0, 0)),
@@ -336,23 +390,25 @@ def kda_backward(q, k, v, g, beta, starts, d_o, d_state, *, chunk: int, sub: int
     return dq, dk, dv, dg, dbeta.reshape(batch, heads, seq)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def kda_kernels(q, k, v, g, beta, chunk: int, sub: int, interpret: bool = False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def kda_kernels(q, k, v, g, beta, chunk: int, sub: int, interpret: bool = False,
+                bounded: bool = True):
     """``ops/kda.kda_chunked`` (``seq`` a multiple of ``chunk``, ``g`` and
     ``beta`` float32) as the two kernels: ``(o, state)``, differentiable in
     all five inputs."""
     return kda_forward(q, k, v, g, beta, chunk=chunk, sub=sub, with_starts=False,
-                       interpret=interpret)
+                       interpret=interpret, bounded=bounded)
 
 
-def _kernels_fwd(q, k, v, g, beta, chunk, sub, interpret):
+def _kernels_fwd(q, k, v, g, beta, chunk, sub, interpret, bounded):
     o, state, starts = kda_forward(q, k, v, g, beta, chunk=chunk, sub=sub, with_starts=True,
-                                   interpret=interpret)
+                                   interpret=interpret, bounded=bounded)
     return (o, state), (q, k, v, g, beta, starts)
 
 
-def _kernels_bwd(chunk, sub, interpret, residuals, cotangents):
-    return kda_backward(*residuals, *cotangents, chunk=chunk, sub=sub, interpret=interpret)
+def _kernels_bwd(chunk, sub, interpret, bounded, residuals, cotangents):
+    return kda_backward(*residuals, *cotangents, chunk=chunk, sub=sub, interpret=interpret,
+                        bounded=bounded)
 
 
 # under a ``jax.checkpoint`` the block's first forward pass needs no residual:

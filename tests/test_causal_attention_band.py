@@ -68,7 +68,11 @@ def _dense(q_a, q_b, k_a, k_b, v, window):
 # blocks under groups of 3, and a window that reaches three blocks back)
 CASES = [(3, 3, 40, 16, None), (6, 2, 40, 16, 13), (8, 2, 24, 32, 5), (6, 2, 40, 16, 64),
          (3, 3, 40, 16, 1), (8, 2, 48, 16, 32), (6, 2, 50, 16, 33), (6, 2, 70, 16, None),
-         (8, 2, 70, 16, 40)]
+         (8, 2, 70, 16, 40),
+         # one group of 8 over a single key/value head, no window, one score
+         # part, operands as projected (no rotation before them): the
+         # rope-free grouped-query layer's call at a head slice of 8
+         (8, 1, 70, 16, None)]
 
 # the score of two parts (latent attention's) under each generalisation once:
 # a window inside a block, inside a clamped block, of whole blocks, and one

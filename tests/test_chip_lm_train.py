@@ -69,6 +69,27 @@ def _grouped_query(checked, published, records):
     assert "rounds_l0" not in published["train_moe"]  # block 0 has the dense MLP
 
 
+def _linear_and_grouped_query(checked, published, records):
+    """4 blocks: rope-free grouped-query, KDA, KDA, KDA; 4 query heads over 2
+    key/value heads and 2 KDA heads held of 16 and 8 published; beta runs to
+    2 and the counters say how often it passes 1."""
+    assert checked["kda_kernel_calls"] == {"fwd": 0, "bwd": 0, "loops": 3 * 3}  # the scan
+    assert set(checked["attn_pairs"]) == {"full_attention"}
+    assert checked["attn_heads"] == {"full_attention": {"held": 4, "published": 16},
+                                     "kda": {"held": 2, "published": 8}}
+    assert 1.0 < checked["kda_beta_max"] < 2.0
+    low, high = checked["kda_neg_eig_share_min_max"]
+    assert 0.2 < low <= high < 0.8
+    (logged,) = [r for r in records if "train/attn_heads_held_kda" in r]
+    assert (logged["train/attn_heads_held_kda"], logged["train/attn_heads_published_kda"]) == (2, 8)
+    assert {"state_absmax", "decay_mean", "beta_max", "neg_eig_share", "neg_eig_share_l1",
+            "beta_max_l3"} <= set(published["train_kda"])
+    # block 0 is the grouped-query block (the registry outlives a run, the log does not)
+    assert not [r for r in records if "train/kda_beta_max_l0" in r]
+    assert [r for r in records if "train/kda_beta_max_l1" in r]
+    assert {"imbalance", "rounds_l0", "rounds_l3"} <= set(published["train_moe"])
+
+
 CASES = [
     pytest.param("pretrain_joyai_flash_ep16", _toy(
         16, layers=2, heads=2, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
@@ -80,6 +101,10 @@ CASES = [
     pytest.param("pretrain_laguna_xs2_share", _toy(
         24, heads_per_layer=[6, 8, 8, 8, 6, 8, 8, 8], kv_heads=2, head_dim=16, sliding_window=11,
         shared_expert_hidden=16), _grouped_query, id="laguna_xs2"),
+    pytest.param("pretrain_solar_open2_share", _toy(
+        24, heads=4, kv_heads=2, head_dim=16, kda_heads=2, kda_head_dim=16, kda_gate_rank=16,
+        kda_chunk=8, heads_published="{full_attention: 16, kda: 8}"),
+        _linear_and_grouped_query, id="solar_open2"),
 ]
 
 

@@ -2,8 +2,9 @@
 stands for, position by position, in float32: output, final state and every
 input's gradient, with the log-decays pinned at the safe gate's bound for
 whole chunks, near zero, and mixed, on both schedules of it (the scan, and
-the Pallas kernel in the interpreter); the causal convolution against a
-loop; the triangular inverse against ``numpy``."""
+the Pallas kernel in the interpreter); with no floor under them and ``beta`` up to 2 (one step of
+−100 in the middle of a sub-block); the causal convolution against a loop;
+the triangular inverse against ``numpy``."""
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +29,9 @@ def literal(q, k, v, g, beta):
         return o, state
 
     return jax.vmap(jax.vmap(head))(q, k, v, g, beta)
+
+
+FLOOR = -5.0  # the safe gate's bound, which these inputs keep: the reference-position form
 
 
 def inputs(decays: str, seq: int = 150, d_k: int = 8, d_v: int = 6, seed: int = 0):
@@ -59,7 +63,7 @@ def test_chunked_form_is_the_recurrence(decays, chunk, sub):
             return (o * weight).sum() + jnp.square(state).sum()
         return f
 
-    chunked = lambda *a: kda_chunked(*a, chunk=chunk, sub=sub)
+    chunked = lambda *a: kda_chunked(*a, chunk=chunk, sub=sub, floor=FLOOR)
     o, state = chunked(*args)
     want_o, want_state = literal(*args)
     assert o.shape == want_o.shape and state.shape == want_state.shape
@@ -93,7 +97,7 @@ def test_the_state_carries_across_chunks():
 def test_compute_dtype_operands_keep_a_float32_state():
     q, k, v, g, beta = inputs("mixed")
     low = lambda x: x.astype(jnp.bfloat16)
-    o, state = kda_chunked(low(q), low(k), low(v), g, beta)
+    o, state = kda_chunked(low(q), low(k), low(v), g, beta, floor=FLOOR)
     want, _ = literal(q, k, v, g, beta)
     assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
     gap = jnp.linalg.norm(o.astype(jnp.float32) - want) / jnp.linalg.norm(want)
@@ -109,7 +113,7 @@ WIDE = dict(seq=150, d_k=128, d_v=128)  # three chunks of 64, the last padded
 
 
 def kernel_path(*a):
-    return kda_chunked(*a, chunk=64, interpret=True)
+    return kda_chunked(*a, chunk=64, floor=FLOOR, interpret=True)
 
 
 @pytest.mark.parametrize("decays", ["mixed", "at_the_bound", "near_zero"])
@@ -126,13 +130,13 @@ def test_kernel_path_is_the_recurrence_and_the_scan(decays):
         return f
 
     o, state = kernel_path(*args)
-    for want_o, want_state in (literal(*args), kda_chunked(*args, chunk=64)):
+    for want_o, want_state in (literal(*args), kda_chunked(*args, chunk=64, floor=FLOOR)):
         np.testing.assert_allclose(o, want_o, rtol=0, atol=3e-5 * float(jnp.abs(want_o).max()))
         np.testing.assert_allclose(state, want_state, rtol=0,
                                    atol=3e-5 * float(jnp.abs(want_state).max()))
     five = (0, 1, 2, 3, 4)
     got = jax.grad(scalar(jax.checkpoint(kernel_path)), argnums=five)(*args)
-    for other in (literal, lambda *a: kda_chunked(*a, chunk=64)):
+    for other in (literal, lambda *a: kda_chunked(*a, chunk=64, floor=FLOOR)):
         for name, a, b in zip("q k v g beta".split(), got, jax.grad(scalar(other), five)(*args)):
             assert bool(jnp.isfinite(a).all()), name
             np.testing.assert_allclose(a, b, rtol=0, atol=1e-4 * max(float(jnp.abs(b).max()), 0.1),
@@ -170,7 +174,7 @@ def test_backward_kernel_is_the_scans_transpose(decays):
     d_o = jax.random.normal(jax.random.key(3), o.shape)
     d_state = jax.random.normal(jax.random.key(4), state.shape)
     got = kda_backward(*args, starts, d_o, d_state, chunk=64, sub=16, interpret=True)
-    want = jax.vjp(lambda *a: kda_chunked(*a, chunk=64), *args)[1]((d_o, d_state))
+    want = jax.vjp(lambda *a: kda_chunked(*a, chunk=64, floor=FLOOR), *args)[1]((d_o, d_state))
     for name, a, b in zip("q k v g beta".split(), got, want):
         assert a.shape == b.shape and a.dtype == b.dtype, name
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-4 * max(float(jnp.abs(b).max()), 0.1),
@@ -182,7 +186,7 @@ def test_kernel_path_takes_compute_dtype_operands_and_keeps_a_float32_state(deca
     q, k, v, g, beta = inputs(decays, **WIDE)
     low = lambda x: x.astype(jnp.bfloat16)
     o, state = kernel_path(low(q), low(k), low(v), g, beta)
-    scan_o, scan_state = kda_chunked(low(q), low(k), low(v), g, beta)
+    scan_o, scan_state = kda_chunked(low(q), low(k), low(v), g, beta, floor=FLOOR)
     want, want_state = literal(q, k, v, g, beta)
     assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
     gap = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
@@ -195,16 +199,72 @@ def test_kernel_path_takes_compute_dtype_operands_and_keeps_a_float32_state(deca
     loss = lambda fn: lambda *a: (fn(*a)[0].astype(jnp.float32) * weight).sum()
     operands = (low(q), low(k), low(v), g, beta)
     got = jax.grad(loss(kernel_path), (0, 1, 2, 3, 4))(*operands)
-    want = jax.grad(loss(kda_chunked), (0, 1, 2, 3, 4))(*operands)
+    want = jax.grad(loss(lambda *a: kda_chunked(*a, floor=FLOOR)), (0, 1, 2, 3, 4))(*operands)
     for name, a, b, x in zip("q k v g beta".split(), got, want, operands):
         assert a.dtype == b.dtype == x.dtype, name
         assert gap(a, b.astype(jnp.float32)) < 2e-2, name
 
 
+# ------------------------------------------------- decays with no floor
+# A softplus gate bounds nothing: one step may decay by e^-100 in the middle
+# of a sub-block, where the reference-position form raises e to +-8 steps of
+# it. With no floor stated every diagonal pair is built pair by pair; beta
+# runs to 2 (negative eigenvalues).
+
+def unbounded_inputs(d: int):
+    q, k, v, _, beta = inputs("near_zero", seq=100, d_k=d, d_v=d)
+    g = -0.3 * jax.random.uniform(jax.random.key(5), k.shape)
+    g = g.at[:, :, 71].set(-100.0)  # position 7 of the sub-block 64 .. 80, every channel
+    g = g.at[:, 0, 7, : d // 2].set(-100.0)  # and in a first chunk, half the channels
+    return q, k, v, g, 2.0 * beta
+
+
+@pytest.mark.parametrize("schedule", ["scan", "kernels"])
+def test_a_step_of_minus_100_inside_a_sub_block_is_finite_and_the_recurrence(schedule):
+    """Output, state and the five gradients (the kernel pair through its VJP,
+    under a ``jax.checkpoint``), with beta up to 2; the form that rests on a
+    floor, given the same inputs, is not finite: the fault this form is for."""
+    args = unbounded_inputs(8 if schedule == "scan" else 128)
+    assert float(args[4].max()) > 1.9
+    weight = jax.random.normal(jax.random.key(9), args[2].shape)
+    scalar = lambda fn: lambda *a: (lambda o, s: (o * weight).sum() + jnp.square(s).sum())(*fn(*a))
+    how = dict(chunk=64, interpret=schedule == "kernels")
+    free = jax.checkpoint(lambda *a: kda_chunked(*a, **how))  # floor=None: none is known
+    o, state = free(*args)
+    want_o, want_state = literal(*args)
+    tol = 3e-5 if schedule == "kernels" else 5e-6
+    np.testing.assert_allclose(o, want_o, rtol=0, atol=tol * float(jnp.abs(want_o).max()))
+    np.testing.assert_allclose(state, want_state, rtol=0,
+                               atol=tol * float(jnp.abs(want_state).max()))
+    five = (0, 1, 2, 3, 4)
+    for name, a, b in zip("q k v g beta".split(), jax.grad(scalar(free), five)(*args),
+                          jax.grad(scalar(literal), five)(*args)):
+        assert bool(jnp.isfinite(a).all()), name
+        np.testing.assert_allclose(a, b, rtol=0, atol=4 * tol * max(float(jnp.abs(b).max()), 0.1),
+                                   err_msg=name)
+    bounded, _ = kda_chunked(*args, floor=FLOOR, **how)
+    assert not bool(jnp.isfinite(bounded).all())
+
+
+def test_a_floor_too_deep_for_a_sub_block_takes_the_pairwise_form_too():
+    """``−floor · sub <= 80`` decides, from what the caller states: −5 at
+    sub-blocks of 16 keeps the reference form, −6 does not, and no floor
+    never does. At inputs both forms take they agree."""
+    seen = []
+    real = kda._scan
+    args = inputs("mixed")
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kda, "_scan", lambda *a: seen.append(a[-1]) or real(*a))
+        outs = [kda_chunked(*args, floor=floor)[0] for floor in (-5.0, -6.0, None)]
+    assert seen == [True, False, False]
+    np.testing.assert_allclose(outs[1], outs[0], rtol=0, atol=2e-6 * float(jnp.abs(outs[0]).max()))
+    np.testing.assert_array_equal(np.asarray(outs[1]), np.asarray(outs[2]))
+
+
 def test_the_kernel_refuses_widths_it_does_not_take_and_the_scan_takes_them():
     args = inputs("mixed")  # d_k 8, d_v 6
     with pytest.raises(ValueError, match="kernels do not take"):
-        kda_chunked(*args, interpret=True)
+        kda_chunked(*args, floor=FLOOR, interpret=True)
     from jumbo_mae_tpu_tpu.ops.pallas.kda import heads_per_step, suits
 
     assert suits(128, 128, 64, 16) and suits(256, 128, 32, 16)

@@ -241,7 +241,8 @@ def test_restart_backoff_recovery_and_generation():
         rs.submit(_img()).result(timeout=5)
     deadline = time.monotonic() + 10.0
     while time.monotonic() < deadline:
-        if rs.stats()["replicas"]["r0"]["state"] == "up":
+        # "up" alone also holds before the crash is booked (the future fails first)
+        if rs.generation(0) == 1 and rs.stats()["replicas"]["r0"]["state"] == "up":
             break
         time.sleep(0.02)
     assert rs.generation(0) == 1  # new incarnation
