@@ -25,9 +25,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from flax.linen import initializers as init
+from flax.linen.dtypes import promote_dtype
 
 from jumbo_mae_tpu_tpu.models.config import DecoderConfig, JumboViTConfig
 from jumbo_mae_tpu_tpu.obs.trace import SCOPE_ATTN_CORE
+from jumbo_mae_tpu_tpu.ops import shared_grad
 from jumbo_mae_tpu_tpu.ops.posemb import sincos2d_positional_embedding
 
 TRUNC_NORMAL = init.truncated_normal(0.02)
@@ -215,7 +217,7 @@ class Mlp(nn.Module):
     """Dense(hidden) → GELU → Dense(out) with dropout after each dense.
 
     Parity: ``FeedForward``, ``/root/reference/src/modeling.py:141-148``.
-    Also instantiated as the shared "jumbo MLP" with dim = k·encoder_dim.
+    The shared "jumbo MLP" (dim = k·encoder_dim) is :class:`JumboMlp`.
     """
 
     dim: int
@@ -235,12 +237,87 @@ class Mlp(nn.Module):
         return nn.Dropout(self.dropout)(x, deterministic)
 
 
-def make_jumbo_mlp(cfg: JumboViTConfig, name: str | None = "jumbo_mlp") -> Mlp:
+class SharedDense(nn.Module):
+    """``nn.Dense``'s parameters (``kernel``, ``bias``; same inits and dtype
+    rules) and arithmetic, for a kernel that every layer applies. Declared in
+    ``setup`` so that the kernel can be reached before the first call
+    (:meth:`open_slots`). Called with a slot, the kernel takes no gradient
+    here: ``ops.shared_grad.record`` leaves ``(x, dy)`` in the slot and the
+    one product over all layers' rows is formed where the slots were opened."""
+
+    in_features: int
+    features: int
+    dtype: Any
+
+    def setup(self):
+        self.kernel = self.param(
+            "kernel", TRUNC_NORMAL, (self.in_features, self.features)
+        )
+        self.bias = self.param("bias", init.zeros, (self.features,))
+
+    @nn.nowrap
+    def open_slots(self, layers: int, rows: int) -> tuple:
+        # under the module's own scope, as a call is: the trace books the
+        # product to the part its path names
+        with jax.named_scope(self.name):
+            return shared_grad.open_slots(self.kernel, layers, rows, self.dtype)
+
+    def __call__(self, x: jax.Array, slot: tuple | None = None) -> jax.Array:
+        x, kernel, bias = promote_dtype(x, self.kernel, self.bias, dtype=self.dtype)
+        if slot is None:
+            return x @ kernel + bias
+        return shared_grad.record(slot, x, x @ jax.lax.stop_gradient(kernel)) + bias
+
+
+class JumboMlp(nn.Module):
+    """:class:`Mlp`'s arithmetic and parameter tree (``fc1``, ``fc2``) for the
+    one MLP all ``JumboBlock``s share. Each layer sees only ``rows`` = one
+    concatenated CLS vector an image, so per-layer kernel gradients are L
+    thin products whose kernel-sized partials are summed through HBM;
+    :meth:`open_slots` before the block loop and a layer's pair of slots at
+    each call form them as one product a kernel after the last block's
+    backward pass instead. Without slots (the pipeline runtime, forward-only
+    programs) a call is a plain dense and autodiff's per-call gradient."""
+
+    dim: int
+    hidden_dim: int
+    dropout: float
+    dtype: Any
+
+    def setup(self):
+        self.fc1 = SharedDense(self.dim, self.hidden_dim, self.dtype)
+        self.fc2 = SharedDense(self.hidden_dim, self.dim, self.dtype)
+        # the names nn.compact gives Mlp's two dropouts: flax folds a
+        # module's path into its dropout key, so a seed keeps drawing the
+        # masks it drew when this MLP was an Mlp
+        self.Dropout_0 = nn.Dropout(self.dropout)
+        self.Dropout_1 = nn.Dropout(self.dropout)
+
+    @nn.nowrap
+    def open_slots(self, layers: int, rows: int) -> tuple:
+        """One ``(fc1 slot, fc2 slot)`` pair a layer, for ``rows`` rows."""
+        with jax.named_scope(self.name):
+            return tuple(
+                zip(self.fc1.open_slots(layers, rows), self.fc2.open_slots(layers, rows))
+            )
+
+    def __call__(
+        self, x: jax.Array, deterministic: bool = True, slots: tuple | None = None
+    ) -> jax.Array:
+        slot1, slot2 = slots if slots is not None else (None, None)
+        x = self.fc1(x, slot1)
+        x = self.Dropout_0(nn.gelu(x), deterministic)
+        x = self.fc2(x, slot2)
+        return self.Dropout_1(x, deterministic)
+
+
+def make_jumbo_mlp(cfg: JumboViTConfig, name: str | None = "jumbo_mlp") -> JumboMlp:
     """The shared jumbo CLS MLP's one architectural definition — used by
     :class:`~jumbo_mae_tpu_tpu.models.vit.JumboViT` (owner of the shared
-    params) and by the pipeline-parallel runtime, so the two can never
-    diverge."""
-    return Mlp(
+    params, and of the slots through which the two kernels' gradient is
+    formed once a step) and by the pipeline-parallel runtime (no slots:
+    per-call gradients), so the two can never diverge."""
+    return JumboMlp(
         dim=cfg.num_cls_tokens * cfg.dim,
         hidden_dim=4 * cfg.num_cls_tokens * cfg.dim,
         dropout=cfg.dropout,
@@ -310,6 +387,13 @@ class JumboBlock(nn.Module):
     gather the k CLS tokens, same ln3/jumbo_mlp/residual, scatter back —
     so a packed segment computes exactly what its unpacked batch row
     would (the parity tests' contract).
+
+    ``slots`` (positional and traced, like ``packed``) is this layer's pair
+    of slots from ``jumbo_mlp.open_slots``: with it the shared kernels take
+    no gradient in this block's backward pass, which leaves its rows and
+    their cotangents in the slots for the one product formed where they were
+    opened (:class:`JumboMlp`). Without it the call differentiates as any
+    dense does: a standalone block (the pipeline runtime) passes none.
     """
 
     cfg: JumboViTConfig
@@ -321,6 +405,7 @@ class JumboBlock(nn.Module):
         x: jax.Array,
         deterministic: bool = True,
         packed: dict | None = None,
+        slots: tuple | None = None,
     ) -> jax.Array:
         cfg = self.cfg
         k = cfg.num_cls_tokens
@@ -346,9 +431,11 @@ class JumboBlock(nn.Module):
             cc = nn.LayerNorm(dtype=cfg.compute_dtype, name="ln3")(
                 cls.reshape(bs, k * cfg.dim)
             )
+            # slots go only to a shared MLP that was handed some: a block
+            # built over a plain Mlp has none to take
+            shared = (cc, deterministic) if slots is None else (cc, deterministic, slots)
             cc = cc + DropPath(cfg.droppath, name="dp3")(
-                ls("ls3", k * cfg.dim) * self.jumbo_mlp(cc, deterministic),
-                deterministic,
+                ls("ls3", k * cfg.dim) * self.jumbo_mlp(*shared), deterministic
             )
 
             h = Mlp(

@@ -15,6 +15,12 @@ serves three modes:
 
 The shared ``jumbo_mlp`` (width k·dim) is built once here and passed to every
 block — the weight sharing is the defining property of the architecture.
+Its two kernels' gradient is formed here too, once a step: ``__call__`` opens
+one pair of slots a layer before the block loop (``JumboMlp.open_slots``),
+each block's backward pass leaves its CLS rows and their cotangents in its
+pair, and after ``block_0``'s the two products over all layers' rows run
+(``ops/shared_grad.py``). A program that never differentiates drops the slots
+as dead code.
 Gradient checkpointing wraps each block with ``nn.remat`` (deterministic flag
 static). The reference's ``pooling`` flag was parsed but ignored
 (defect ledger #3); here ``pooling="gap"`` is actually implemented.
@@ -116,8 +122,11 @@ class JumboViT(nn.Module):
         if blocks_override is not None:
             x = blocks_override(x)
         else:
-            for block in self.blocks:
-                x = block(x, deterministic)
+            # each block gets only its own layer's slots, so that what a
+            # rematted block keeps of its inputs is one layer's zeros
+            slots = self.jumbo_mlp.open_slots(cfg.layers, bs)
+            for block, layer_slots in zip(self.blocks, slots):
+                x = block(x, deterministic, None, layer_slots)
         x = self.norm(x)
 
         if self.mae_mode:

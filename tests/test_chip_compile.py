@@ -529,8 +529,9 @@ def test_guarded_step_is_branch_free_and_in_place_on_v5e(v5e_chip, tiny_train_st
     """The divergence guard's gate, as the chip's compiler sees it: the
     guarded step compiled for a described v5e holds no ``conditional`` (the
     ``lax.cond`` it replaced cost the L/16 step 9.2 ms of 235 in copies and
-    waits inside its branches — PERF.md, PR 25), and the donated state is
-    updated in place: the aliased bytes cover every array of it."""
+    waits inside its branches — PERF.md, PR 25), the donated state is
+    updated in place (the aliased bytes cover every array of it), and the
+    shared jumbo MLP's kernel gradients are one product each (PR 39)."""
     from jumbo_mae_tpu_tpu.parallel import MeshConfig, create_mesh
     from jumbo_mae_tpu_tpu.parallel.sharding import batch_sharding, infer_state_sharding
     from jumbo_mae_tpu_tpu.train import make_train_step
@@ -551,6 +552,13 @@ def test_guarded_step_is_branch_free_and_in_place_on_v5e(v5e_chip, tiny_train_st
     compiled = step.lower(described, {"images": images}).compile()
     text = compiled.as_text()
     assert "/guard/" in text and " conditional(" not in text
+    # the shared jumbo MLP's two kernel gradients are the deferred products,
+    # under the part's own scope; no block forms a kernel-shaped partial
+    products = [re.search(r'op_name="([^"]*)"', line).group(1) for line in text.splitlines()
+                if re.search(r"= f32\[(192,768|768,192)\]\S* (convolution|dot)\(", line)]
+    assert len(products) == 2, products
+    assert all("transpose(" in p and "/encoder/jumbo_mlp/fc" in p and "/block_" not in p
+               for p in products), products
     state_bytes = sum(
         int(np.prod(leaf.shape)) * leaf.dtype.itemsize
         for leaf in jax.tree_util.tree_leaves(shapes)
