@@ -484,7 +484,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     check(rounds == [1], f"an expert layer's held pairs took other than one round: {rounds}")
     skipped = _delta(before, after, "train_steps_skipped_total", "")
     check(skipped == 0, f"{skipped} step(s) skipped by the divergence guard")
-    kda = {}
+    family = {}  # the family's own counters, where it has any
     if lm.kda_layers:
         states = [r.get("train/kda_state_absmax") for r in by_step.values()]
         check(all(s is not None and 0 < s < 100 for s in states),
@@ -495,10 +495,16 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         check(all(0 < x < lm.kda_beta_scale for x in beta)
               and all((x > 0) == (lm.kda_beta_scale > 1) for x in negative),
               f"beta outside (0, {lm.kda_beta_scale}) or its share past 1 off: {beta} {negative}")
-        kda = {"kda_state_absmax_max": round(max(states), 4),
+        family = {"kda_state_absmax_max": round(max(states), 4),
                "kda_decay_mean_min_max": [round(min(decay), 4), round(max(decay), 4)],
                "kda_beta_max": round(max(beta), 4),
                "kda_neg_eig_share_min_max": [round(min(negative), 4), round(max(negative), 4)]}
+    if lm.expert_act == "relu":  # a ReLU gate's zeros are counted, step by step
+        zeros = [r.get("train/moe_act_zero_share") for r in by_step.values()]
+        check(all(z is not None and 0 < z < 1 for z in zeros),
+              f"the experts' share of zero activations is missing or not a share: {zeros}")
+        family |= {"moe_act_zero_share_min_max": [round(min(zeros), 4), round(max(zeros), 4)],
+                "router_input": lm.router_input}
     retraces = _delta(before, after, "retrace_events_total", "train")
     check(retraces == 0, f"{retraces} unexpected recompile(s) after warmup")
     last = max((r for r in records if "perf/tokens_per_sec_per_chip" in r),
@@ -520,7 +526,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
                        for kind, (held, published) in lm.attn_heads().items()},
         "moe_held_share_min_max": [round(min(share), 4), round(max(share), 4)],
         "moe_imbalance_max": round(max(r["train/moe_imbalance"] for r in by_step.values()), 3),
-        **kda,
+        **family,
         "skipped_steps": 0,
         "tokens_per_sec_per_chip": round(last["perf/tokens_per_sec_per_chip"], 1),
         "mfu_trainer_reported": last.get("perf/mfu"),

@@ -952,6 +952,9 @@ def train(cfg: TrainConfig) -> dict:
         logger.log(heads, step=start_step)
         if is_main:
             print(f"[train] attention heads a kind: {heads}")
+            # static too: the expert layers' variants
+            print(f"[train] expert layers: the router reads {enc_cfg.router_input} and scores "
+                  f"{enc_cfg.router_scoring}; an expert's gate is {enc_cfg.expert_act}")
     valid_factory = make_valid_iterator(
         cfg, mesh, per_process_valid, num_labels=getattr(enc_cfg, "labels", None) or 1000
     )

@@ -1,4 +1,4 @@
-"""Decoder-only sparse-expert language models, four families from one set of
+"""Decoder-only sparse-expert language models, five families from one set of
 blocks; each trunk block's attention is one of four kinds, and
 ``MlaMoeConfig.kinds`` is the one list that says which (a family is a way to
 fill it). **All-MLA** (the DeepSeek-V3 family's block, as ``JoyAI-LLM-Flash``'s
@@ -17,7 +17,14 @@ rotary embedding a kind, and a head-wise gate; no latent, no MTP module.
 **Linear beside grouped-query** (``Solar-Open2-250B``, ``model_type:
 solar_open2``): ``layer_types`` may name ``"kda"`` (and ``"mla"``) beside the
 grouped-query kinds — here one rope-free ``full_attention`` block to three
-KDA blocks whose gates are the other variants below. The
+KDA blocks whose gates are the other variants below. **Window beside
+rope-free full, routed from the block's input**
+(``SmallThinker-21BA3B-Instruct``, ``model_name:
+smallthinker_21b_instruct``): grouped-query blocks again, one rope-free
+``full_attention`` block to three ``sliding_attention`` blocks with rope, no
+gate on the attention output, and an expert layer of the other variants
+below (``router_input``, ``router_scoring``, ``expert_act``, no shared
+expert, no dense layer). The
 defaults are the first family's; its parameter tree, scopes and program do
 not depend on the others' fields.
 
@@ -88,11 +95,21 @@ one a value channel (``"element"``). A layer reports the largest ``|S|`` at
 the end of the sequences, the mean ``α``, the largest ``β`` and the share of
 (token, head) with ``β > 1`` (``KDA_COUNTERS``).
 
-MLP: ``W_d(silu(W_g x) ⊙ W_u x)``. The clamped SwiGLU (a non-zero
-``*_swiglu_limit``) is not implemented and is refused.
+MLP: ``W_d(silu(W_g x) ⊙ W_u x)``; a routed expert's gate is a ReLU where
+``expert_act`` is ``"relu"`` (ReGLU: ``W_d(relu(W_g x) ⊙ W_u x)``), and the
+layer then reports the share of its held rows' ``relu(W_g x) ⊙ W_u x``
+entries that are exactly zero (``ACT_ZERO_COUNTER``). The clamped SwiGLU (a
+non-zero ``*_swiglu_limit``) is not implemented and is refused.
 
-Router, in float32: ``s = sigmoid(x W_r)``, ``t = s + b``; with ``n_group >
-1`` the experts lie in ``n_group`` equal groups, a group's score is the sum
+Router, in float32, of the expert layer's input ``x`` — the block's
+post-attention norm — or, with ``router_input: "block_input"``, of the
+block's input as it arrives, before the input norm and before attention
+(the experts still read the post-attention norm: the layer has two inputs,
+and the routing does not wait for the attention sublayer). With
+``router_scoring: "softmax_topk"``: ``ℓ = x W_r``, the top ``k`` by ``ℓ``,
+weights ``factor · softmax(ℓ over the k chosen)``, no bias and no
+``batch_stats`` variable. Else (``"sigmoid_bias"``): ``s = sigmoid(x W_r)``,
+``t = s + b``; with ``n_group > 1`` the experts lie in ``n_group`` equal groups, a group's score is the sum
 of its two largest ``t``, and only the best ``topk_group`` groups' experts
 stay eligible; the top ``k`` eligible by ``t``; weights ``factor · s_i /
 Σ_chosen s``. ``b`` is no parameter: it lives in the ``batch_stats``
@@ -102,7 +119,8 @@ c)``, ``c`` the step's counts over all outputs.
 **The chip's share.** ``experts_held = (e0, n)`` says which routed experts
 this chip holds. The router keeps its full width, its groups and its ``k``;
 the weights are normalised over all ``k`` chosen; the layer's output is
-``shared(x) + Σ_{chosen i, e0 <= i < e0 + n} w_i E_i(x)`` — what the absent
+``shared(x) + Σ_{chosen i, e0 <= i < e0 + n} w_i E_i(x)`` (no ``shared`` term
+and no such module where ``n_shared_experts`` is 0) — what the absent
 experts would add is left out, and nothing stands in for them or for their
 exchange. ``vocab_rows = (v0, n)`` likewise: embedding and head hold rows
 ``v0 .. v0 + n`` of the vocabulary, token ids come from that range, and
@@ -171,6 +189,9 @@ from jumbo_mae_tpu_tpu.ops.kda import causal_conv_silu, kda_chunked
 # the counters an expert layer reports, in the order of its stats vector
 MOE_COUNTERS = ("rows_min", "rows_mean", "rows_max", "imbalance", "held_share", "dropped",
                 "rounds")
+# one more where the experts' gate is a ReLU (``MlaMoeConfig.moe_counters``): the
+# share of the held rows' ``relu(W_g x) ⊙ W_u x`` entries that are exactly zero
+ACT_ZERO_COUNTER = "act_zero_share"
 # the counters a linear-attention layer reports, in the order of its stats vector
 KDA_COUNTERS = ("state_absmax", "decay_mean", "beta_max", "neg_eig_share")
 
@@ -248,6 +269,7 @@ class MlaMoeConfig:
     rope_theta: float = 32e6
     rms_eps: float = 1e-6
     init_std: float = 0.02  # assumed
+    embed_init_std: float | None = None  # the token embedding's; None = init_std
     # the hybrid family: block i is of the MLA kind when (i + 1) is a multiple
     # of layer_group_size and of the KDA kind otherwise; 0 = every block MLA
     layer_group_size: int = 0
@@ -276,6 +298,11 @@ class MlaMoeConfig:
     rope_parameters: tuple[tuple[str, Rope | None], ...] | None = None  # a Rope a kind
     # the model's own head count a kind, where this chip holds a share of them
     heads_published: tuple[tuple[str, int], ...] | None = None
+    # the expert layer's variants (module docstring): what the router reads,
+    # how it scores, and a routed expert's gate activation
+    router_input: str = "ffn_norm"  # or "block_input": before the input norm and attention
+    router_scoring: str = "sigmoid_bias"  # or "softmax_topk": softmax over the chosen logits
+    expert_act: str = "silu"  # or "relu"
 
     grad_ckpt: bool = True
     remat_policy: RematPolicy = "none"
@@ -295,6 +322,13 @@ class MlaMoeConfig:
                 "head", "element"):
             raise ValueError(f"kda_gate {self.kda_gate!r} / kda_out_gate {self.kda_out_gate!r}: "
                              "safe or softplus, head or element")
+        for name, known in (("router_input", ("ffn_norm", "block_input")),
+                            ("router_scoring", ("sigmoid_bias", "softmax_topk")),
+                            ("expert_act", ("silu", "relu"))):
+            if getattr(self, name) not in known:
+                raise ValueError(f"{name} {getattr(self, name)!r}: one of {known}")
+        if self.router_scoring == "softmax_topk" and self.n_group > 1:
+            raise ValueError("softmax_topk routing has no group limit: n_group must be 1")
         if self.mtp_layers not in (0, 1):
             raise ValueError("mtp_layers must be 0 or 1")
         if self.expert_swiglu_limit or self.shared_expert_swiglu_limit:
@@ -408,6 +442,12 @@ class MlaMoeConfig:
             kinds.add("mla")
         window = lambda kind: self.sliding_window if kind == "sliding_attention" else None
         return {kind: causal_pairs(seq, window(kind)) for kind in sorted(kinds)}
+
+    @property
+    def moe_counters(self) -> tuple[str, ...]:
+        """The counters an expert layer of this configuration reports, in
+        the order of its stats vector."""
+        return MOE_COUNTERS + ((ACT_ZERO_COUNTER,) if self.expert_act == "relu" else ())
 
     @property
     def shared_hidden(self) -> int:
@@ -740,15 +780,17 @@ def _sum_slots(rows, row_of_pair, weight):
                for slot in range(row_of_pair.shape[1]))
 
 
-def _swiglu(gu):
+def _glu(gu, act: str):
+    """``act(gate) ⊙ up`` of the stacked product (rows, gate ‖ up)."""
     hidden = gu.shape[1] // 2
-    return nn.silu(gu[:, :hidden]) * gu[:, hidden:]
+    return (nn.silu if act == "silu" else nn.relu)(gu[:, :hidden]) * gu[:, hidden:]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
 def routed_experts(x, w_gu, w_down, gate, row_to_pair, pair_to_row, group_sizes, rounds,
-                   chunk, impl="auto", interpret=False):
-    """``Σ_slots gate · E(x)`` over the pairs on held experts: ``x`` (tokens,
+                   chunk, impl="auto", interpret=False, act="silu"):
+    """``(Σ_slots gate · E(x), zeros)`` over the pairs on held experts, an
+    expert's gate activation ``act`` (``"silu"`` or ``"relu"``): ``x`` (tokens,
     dim), the held experts' stacked matrices (gate ‖ up, and down), ``gate``
     (tokens, slots) float32 and zero for a pair held elsewhere, the sort's
     two permutations (sorted row -> pair; (tokens, slots) -> sorted row), the
@@ -761,28 +803,36 @@ def routed_experts(x, w_gu, w_down, gate, row_to_pair, pair_to_row, group_sizes,
     round's rows again, recomputes their gate and up products, and sums the
     matrices' gradients over the rounds in the matrices' dtype."""
     return _routed_fwd(x, w_gu, w_down, gate, row_to_pair, pair_to_row, group_sizes, rounds,
-                       chunk, impl, interpret)[0]
+                       chunk, impl, interpret, act)[0]
 
 
 def _routed_fwd(x, w_gu, w_down, gate, row_to_pair, pair_to_row, group_sizes, rounds,
-                chunk, impl, interpret):
+                chunk, impl, interpret, act):
     product = functools.partial(grouped_matmul, impl=impl, interpret=interpret)
 
-    def one_round(r, y):
+    def one_round(r, carry):
+        y, zeros = carry
         token, _, sizes, row_of_pair, mine = _round_rows(
             r, chunk, row_to_pair, pair_to_row, gate, group_sizes)
         rows = x[token]
         with jax.named_scope(SCOPE_EXPERTS):
-            out = product(_swiglu(product(rows, w_gu, sizes)), w_down, sizes)
-        return y + _sum_slots(out, row_of_pair, jnp.where(mine, gate, 0.0))
+            hidden = _glu(product(rows, w_gu, sizes), act)
+            out = product(hidden, w_down, sizes)
+            if zeros is not None:  # the round's rows past its experts' sizes are padding
+                held = jnp.arange(chunk)[:, None] < sizes.sum()
+                zeros = zeros + ((hidden == 0) & held).sum(dtype=jnp.float32)
+        return y + _sum_slots(out, row_of_pair, jnp.where(mine, gate, 0.0)), zeros
 
     with jax.named_scope(SCOPE_MOE_DISPATCH):
-        y = jax.lax.fori_loop(0, rounds, one_round, jnp.zeros(x.shape, jnp.float32))
+        y, zeros = jax.lax.fori_loop(
+            0, rounds, one_round, (jnp.zeros(x.shape, jnp.float32),
+                                   jnp.zeros((), jnp.float32) if act == "relu" else None))
         y = y.astype(x.dtype)
-    return y, (x, w_gu, w_down, gate, row_to_pair, pair_to_row, group_sizes, rounds)
+    return (y, zeros), (x, w_gu, w_down, gate, row_to_pair, pair_to_row, group_sizes, rounds)
 
 
-def _routed_bwd(chunk, impl, interpret, residuals, dy):
+def _routed_bwd(chunk, impl, interpret, act, residuals, cotangents):
+    dy, _ = cotangents  # the count of zeros takes no gradient
     x, w_gu, w_down, gate, row_to_pair, pair_to_row, group_sizes, rounds = residuals
     product = functools.partial(grouped_matmul, impl=impl, interpret=interpret)
     outer = functools.partial(grouped_outer, impl=impl, interpret=interpret)
@@ -793,14 +843,15 @@ def _routed_bwd(chunk, impl, interpret, residuals, dy):
             r, chunk, row_to_pair, pair_to_row, gate, group_sizes)
         rows, d_out = x[token], dy[token]
         with jax.named_scope(SCOPE_EXPERTS):
-            act, swiglu_vjp = jax.vjp(_swiglu, product(rows, w_gu, sizes))
-            # out = gate · (act W_down), so the gate's gradient is
-            # act · (d_out W_downᵀ) and the activation's is gate times it
+            hidden, glu_vjp = jax.vjp(functools.partial(_glu, act=act),
+                                      product(rows, w_gu, sizes))
+            # out = gate · (hidden W_down), so the gate's gradient is
+            # hidden · (d_out W_downᵀ) and the activation's is gate times it
             d_act = product(d_out, w_down, sizes, transpose_rhs=True)
-            d_gate_of_row = (act.astype(jnp.float32) * d_act.astype(jnp.float32)).sum(axis=1)
-            scale = gate_of_row[:, None].astype(act.dtype)
-            d_w_down = outer(act * scale, d_out, sizes, d_w_down)
-            (d_gu,) = swiglu_vjp(d_act * scale)
+            d_gate_of_row = (hidden.astype(jnp.float32) * d_act.astype(jnp.float32)).sum(axis=1)
+            scale = gate_of_row[:, None].astype(hidden.dtype)
+            d_w_down = outer(hidden * scale, d_out, sizes, d_w_down)
+            (d_gu,) = glu_vjp(d_act * scale)
             d_rows = product(d_gu, w_gu, sizes, transpose_rhs=True)
             d_w_gu = outer(rows, d_gu, sizes, d_w_gu)
         d_x = d_x + _sum_slots(d_rows, row_of_pair, mine.astype(jnp.float32))
@@ -832,13 +883,15 @@ def _group_limited(biased, n_group: int, topk_group: int):
 
 class SparseExperts(nn.Module):
     """Router over all experts, grouped products over those held here, and
-    the shared expert. Returns ``(y, stats)``, ``stats`` in ``MOE_COUNTERS``
-    order."""
+    the shared expert where the configuration has one. ``x`` is what the
+    experts read; the router reads ``router_x`` where one is given (the
+    block's input: ``router_input: "block_input"``), else ``x``. Returns
+    ``(y, stats)``, ``stats`` in ``cfg.moe_counters`` order."""
 
     cfg: MlaMoeConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_x=None):
         cfg = self.cfg
         b, s, d = x.shape
         n, k, e = b * s, cfg.experts_per_token, cfg.n_routed_experts
@@ -851,20 +904,26 @@ class SparseExperts(nn.Module):
 
         with jax.named_scope(SCOPE_ROUTER):
             w_r = kernel("router", d, e)
-            scores = jax.nn.sigmoid(jnp.dot(flat.astype(jnp.float32), w_r,
-                                            precision=jax.lax.Precision.HIGHEST))
-            bias = self.variable(
-                "batch_stats", "router_bias",
-                lambda: 0.01 * jax.random.normal(self.make_rng("params"), (e,), jnp.float32))
-            biased = scores + bias.value
-            if cfg.n_group > 1:
-                biased = _group_limited(biased, cfg.n_group, cfg.topk_group)
-            _, chosen = jax.lax.top_k(biased, k)  # (n, k)
-            picked = jnp.take_along_axis(scores, chosen, axis=1)
-            weights = cfg.routed_scaling_factor * picked / picked.sum(axis=1, keepdims=True)
-            counts = (chosen[..., None] == jnp.arange(e)).sum(axis=(0, 1)).astype(jnp.float32)
-            if not self.is_initializing() and self.is_mutable_collection("batch_stats"):
-                bias.value = bias.value + cfg.router_bias_rate * jnp.sign(counts.mean() - counts)
+            read = flat if router_x is None else router_x.reshape(n, d)
+            logits = jnp.dot(read.astype(jnp.float32), w_r, precision=jax.lax.Precision.HIGHEST)
+            if cfg.router_scoring == "softmax_topk":
+                picked, chosen = jax.lax.top_k(logits, k)  # (n, k)
+                weights = cfg.routed_scaling_factor * jax.nn.softmax(picked, axis=1)
+            else:
+                scores = jax.nn.sigmoid(logits)
+                bias = self.variable(
+                    "batch_stats", "router_bias",
+                    lambda: 0.01 * jax.random.normal(self.make_rng("params"), (e,), jnp.float32))
+                biased = scores + bias.value
+                if cfg.n_group > 1:
+                    biased = _group_limited(biased, cfg.n_group, cfg.topk_group)
+                _, chosen = jax.lax.top_k(biased, k)  # (n, k)
+                picked = jnp.take_along_axis(scores, chosen, axis=1)
+                weights = cfg.routed_scaling_factor * picked / picked.sum(axis=1, keepdims=True)
+                counts = (chosen[..., None] == jnp.arange(e)).sum(axis=(0, 1)).astype(jnp.float32)
+                if not self.is_initializing() and self.is_mutable_collection("batch_stats"):
+                    bias.value = bias.value + cfg.router_bias_rate * jnp.sign(
+                        counts.mean() - counts)
         with jax.named_scope(SCOPE_MOE_DISPATCH):
             local = chosen - e0
             here = (local >= 0) & (local < held)
@@ -882,19 +941,24 @@ class SparseExperts(nn.Module):
             w_gu = jnp.concatenate([stacked("gate", d, cfg.expert_hidden),
                                     stacked("up", d, cfg.expert_hidden)], axis=-1)
             w_down = stacked("down", cfg.expert_hidden, d)
-        routed = routed_experts(flat, w_gu, w_down, gate, row_to_pair, pair_to_row,
-                                group_sizes, rounds, chunk)
+        routed, zeros = routed_experts(flat, w_gu, w_down, gate, row_to_pair, pair_to_row,
+                                       group_sizes, rounds, chunk, act=cfg.expert_act)
         with jax.named_scope(SCOPE_ROUTER):
             per_expert = group_sizes.astype(jnp.float32)
             mean = per_expert.mean()
-            stats = jnp.stack([
+            stats = [
                 per_expert.min(), mean, per_expert.max(),
                 per_expert.max() / jnp.maximum(mean, 1.0),
                 total / (n * k),
                 # the rounds take ``chunk`` rows each: what they did not reach
                 (total - jnp.minimum(total, rounds * chunk)).astype(jnp.float32),
                 rounds.astype(jnp.float32),
-            ])
+            ]
+            if zeros is not None:  # ACT_ZERO_COUNTER: of the held rows' entries
+                stats.append(zeros / jnp.maximum(total * cfg.expert_hidden, 1))
+            stats = jnp.stack(stats)
+        if not cfg.shared_hidden:  # no shared expert: no module, no zero-width leaf
+            return routed.reshape(b, s, d), jax.lax.stop_gradient(stats)
         with jax.named_scope(SCOPE_SHARED_EXPERT):
             shared = GatedMlp(cfg.shared_hidden, cfg, name="shared")(x)
         return shared + routed.reshape(b, s, d), jax.lax.stop_gradient(stats)
@@ -904,7 +968,8 @@ class Block(nn.Module):
     """One pre-norm residual block: attention of ``kind``
     (``MlaMoeConfig.attention_kind``: latent, linear, or grouped-query of
     ``heads`` query heads, full or sliding), then the dense MLP
-    (``sparse=False``) or the expert layer. Returns ``(x, stats,
+    (``sparse=False``) or the expert layer, whose router reads the block's
+    input where ``router_input`` says so. Returns ``(x, stats,
     kda_stats)``: the expert layer's counters and the linear-attention
     layer's (None where the block has none)."""
 
@@ -919,6 +984,7 @@ class Block(nn.Module):
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.rms_eps, cfg.compute_dtype, name=name)
         kda_stats = None
+        router_x = (x,) if cfg.router_input == "block_input" else ()
         if self.kind == "kda":
             y, kda_stats = KdaAttention(cfg, name="attn")(norm("ln1")(x))
             x = x + y
@@ -929,11 +995,11 @@ class Block(nn.Module):
                                          name="attn")
             x = x + attn(norm("ln1")(x))
         if self.sparse:
-            y, stats = SparseExperts(cfg, name="moe")(norm("ln2")(x))
+            y, stats = SparseExperts(cfg, name="moe")(norm("ln2")(x), *router_x)
         else:
             with jax.named_scope(SCOPE_DENSE_MLP):
                 y = GatedMlp(cfg.dense_hidden, cfg, name="mlp")(norm("ln2")(x))
-            stats = jnp.zeros((len(MOE_COUNTERS),), jnp.float32)
+            stats = jnp.zeros((len(cfg.moe_counters),), jnp.float32)
         return x + y, stats, kda_stats
 
 
@@ -947,8 +1013,8 @@ class MlaMoeLM(nn.Module):
     def setup(self):
         cfg = self.cfg
         block = maybe_remat(Block, cfg)
-        self.embedding = self.param("embedding", _normal(cfg), (cfg.rows[1], cfg.dim),
-                                    jnp.float32)
+        embed_init = nn.initializers.normal(cfg.embed_init_std or cfg.init_std)
+        self.embedding = self.param("embedding", embed_init, (cfg.rows[1], cfg.dim), jnp.float32)
         heads = [cfg.query_heads(i) if kind in GQA_KINDS else 0
                  for i, kind in enumerate(cfg.kinds)]
         self.blocks = [block(cfg, sparse=i >= cfg.first_k_dense, kind=cfg.kinds[i],
@@ -1022,10 +1088,12 @@ class MlaMoeLM(nn.Module):
         out |= {"loss": per_sample.mean(), "loss_per_sample": per_sample}
         table = jnp.stack(list(stats.values()))  # (expert layers, counters)
         for name, st in stats.items():
-            out |= {f"moe_{c}_{name}": st[j] for j, c in enumerate(MOE_COUNTERS)}
-        col = {c: table[:, j] for j, c in enumerate(MOE_COUNTERS)}
+            out |= {f"moe_{c}_{name}": st[j] for j, c in enumerate(cfg.moe_counters)}
+        col = {c: table[:, j] for j, c in enumerate(cfg.moe_counters)}
         out |= {"moe_imbalance": col["imbalance"].max(), "moe_held_share": col["held_share"].mean(),
                 "moe_dropped": col["dropped"].sum(), "moe_rounds": col["rounds"].max()}
+        if ACT_ZERO_COUNTER in col:
+            out[f"moe_{ACT_ZERO_COUNTER}"] = col[ACT_ZERO_COUNTER].mean()
         if kda:
             for name, st in kda.items():
                 out |= {f"kda_{c}_{name}": st[j] for j, c in enumerate(KDA_COUNTERS)}

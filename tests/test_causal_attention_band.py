@@ -72,7 +72,11 @@ CASES = [(3, 3, 40, 16, None), (6, 2, 40, 16, 13), (8, 2, 24, 32, 5), (6, 2, 40,
          # one group of 8 over a single key/value head, no window, one score
          # part, operands as projected (no rotation before them): the
          # rope-free grouped-query layer's call at a head slice of 8
-         (8, 1, 70, 16, None)]
+         (8, 1, 70, 16, None),
+         # a group of 7 over one key/value head under a window of four whole
+         # blocks (reach 4, pairs one to three blocks apart wholly inside it,
+         # the fourth cut by its edge: 28 over 4 at 4096 of block 1024)
+         (7, 1, 100, 16, 64)]
 
 # the score of two parts (latent attention's) under each generalisation once:
 # a window inside a block, inside a clamped block, of whole blocks, and one
@@ -170,7 +174,7 @@ def test_a_windowed_kernel_sees_neither_the_future_nor_beyond_its_window():
 
 @pytest.mark.parametrize("backward", [False, True])
 @pytest.mark.parametrize("n,block,window", [(8, 16, 16), (8, 16, 17), (8, 16, 40), (5, 32, 5),
-                                            (6, 8, 1), (4, 16, 1000)])
+                                            (6, 8, 1), (4, 16, 1000), (7, 16, 64)])
 def test_the_band_holds_each_pair_with_a_visible_entry_once(n, block, window, backward):
     """The forward kernel's tables; and the backward kernel's walk of the
     same pairs, once a member of a group of two a span of three key blocks,
@@ -232,6 +236,23 @@ def test_the_pairs_the_tables_visit_against_those_the_mask_keeps():
     assert causal_pairs(400, 512) == causal_pairs(400)
     brute = sum(min(i + 1, 37) for i in range(300))
     assert causal_pairs(300, 37, 16)[1] == brute
+
+
+def test_a_window_of_four_blocks_at_the_longest_row_one_span_holds():
+    """16 384 tokens under a 4096-token window (``smallthinker_pretrain_1x16k``):
+    the block stays 1024, a query block reaches four key blocks back, three of
+    them wholly inside the window, and the band visits 70 block pairs of the
+    triangle's 136 for 58.7 M needed entries of 134.2 M (1.25 x what the mask
+    keeps: at 8192 tokens the same window keeps 75% of a full layer's pairs,
+    here 44%); the backward kernel's accumulators fit one span."""
+    assert causal_block(16384, 4096) == CAUSAL_BLOCK and _reach(4096, CAUSAL_BLOCK) == 4
+    needed = 4096 * 4097 // 2 + (16384 - 4096) * 4096
+    assert causal_pairs(16384, 4096) == (70 * 1024 * 1024, needed) == (73_400_320, 58_722_304)
+    assert causal_pairs(16384) == (136 * 1024 * 1024, 16384 * 16385 // 2)
+    assert needed / (16384 * 16385 // 2) == pytest.approx(0.4375, abs=1e-4)
+    qi, kj = _lower_triangle(16, reach=4)
+    assert len(qi) == 70 and max(qi - kj) == 4
+    assert _causal_span(16, CAUSAL_BLOCK, (128, 128), 2) == 16
 
 
 def test_the_dispatcher_hands_the_window_and_the_groups_to_either_form(monkeypatch):

@@ -138,7 +138,7 @@ def compile_lm_step(recipe: str, chip, monkeypatch):
     def init():
         v = model.init(jax.random.key(0), jnp.zeros((rows, length), jnp.int32))
         state = TrainState.create(apply_fn=model.apply, params=v["params"], tx=tx,
-                                  batch_stats=v["batch_stats"], rng=make_base_rng(0))
+                                  batch_stats=v.get("batch_stats"), rng=make_base_rng(0))
         return state.replace(step=jnp.zeros((), jnp.int32))
 
     shapes = jax.eval_shape(init)  # shapes only: nothing can be put on the chip

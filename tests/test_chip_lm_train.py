@@ -90,6 +90,23 @@ def _linear_and_grouped_query(checked, published, records):
     assert {"imbalance", "rounds_l0", "rounds_l3"} <= set(published["train_moe"])
 
 
+def _window_and_routed_from_the_input(checked, published, records):
+    """4 blocks: rope-free full, window x3 (11 of 24 tokens), 7 query heads
+    over 1 key/value head, every block sparse, no shared expert; the router
+    reads the block's input and the ReLU gate's zeros are logged."""
+    assert checked["kda_kernel_calls"] == {"fwd": 0, "bwd": 0, "loops": 0}
+    pairs = checked["attn_pairs"]
+    assert set(pairs) == {"full_attention", "sliding_attention"}
+    assert pairs["sliding_attention"]["needed"] == 11 * 12 // 2 + 13 * 11
+    assert checked["attn_heads"] == {kind: {"held": 7, "published": 7} for kind in pairs}
+    assert checked["router_input"] == "block_input"
+    low, high = checked["moe_act_zero_share_min_max"]
+    assert 0.3 < low <= high < 0.7
+    assert {"imbalance", "act_zero_share", "act_zero_share_l0", "act_zero_share_l3",
+            "rounds_l0"} <= set(published["train_moe"])  # block 0 is sparse too
+    assert [r for r in records if "train/moe_act_zero_share_l2" in r]
+
+
 CASES = [
     pytest.param("pretrain_joyai_flash_ep16", _toy(
         16, layers=2, heads=2, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
@@ -105,6 +122,9 @@ CASES = [
         24, heads=4, kv_heads=2, head_dim=16, kda_heads=2, kda_head_dim=16, kda_gate_rank=16,
         kda_chunk=8, heads_published="{full_attention: 16, kda: 8}"),
         _linear_and_grouped_query, id="solar_open2"),
+    pytest.param("pretrain_smallthinker_21b_share", _toy(
+        24, heads=7, kv_heads=1, head_dim=16, sliding_window=11),
+        _window_and_routed_from_the_input, id="smallthinker_21b"),
 ]
 
 
