@@ -36,6 +36,7 @@ import contextlib
 import gc
 import json
 import logging
+import math
 import re
 import shutil
 import sys
@@ -395,6 +396,63 @@ def rope_kernel_calls(text: str) -> int:
     return len(re.findall(r'custom-call\([^\n]*[/(]rope_half\)*/pallas_call"', text))
 
 
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "f32": 4,
+                "s64": 8, "u64": 8, "f64": 8}
+
+
+def head_product_calls(text: str, rows: int) -> dict:
+    """How often a compiled program's text runs the head's product, by phase,
+    and the widest array it holds over the rows held: ``{"fwd": n,
+    "recompute": n, "bwd": n, "widest_bytes": b}``. A head's product is a
+    ``dot`` / ``convolution`` under the ``lm_head`` scope with ``rows`` among
+    its operands' or its result's dimensions; the phase is its ``op_name``'s
+    (a rematted computation, else a transpose, else the forward pass, as
+    ``benchmarks/scope_reduce.py`` reads it). The widest array is over every
+    shape with a ``rows`` axis: a tile's float32 logits or the float32 kernel
+    where the loss walks the tokens in tiles (``ops/head_loss.py``), all
+    tokens' logits where it does not."""
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+\[[\d,]*\])", text, re.M))
+    dims = lambda shape: [int(d) for d in re.findall(r"\d+", shape.split("[", 1)[1])]
+    calls = {"fwd": 0, "recompute": 0, "bwd": 0}
+    product = re.compile(r"= (\w+\[[\d,]*\])\S* (?:dot|convolution)\(([^)]*)\)"
+                         r'[^\n]*op_name="([^"]*/lm_head/[^"]*)"')
+    for result, operands, op_name in product.findall(text):
+        inline = re.findall(r"\w+\[[\d,]*\]", operands)  # some backends print operands' shapes
+        named = [shape_of.get(name, "x[]") for name in re.findall(r"%([\w.\-]+)", operands)]
+        if any(rows in dims(shape) for shape in [result, *inline, *named]):
+            phase = ("recompute" if "rematted_computation" in op_name
+                     else "bwd" if "transpose(" in op_name else "fwd")
+            calls[phase] += 1
+    arrays = {(dtype, tuple(map(int, inner.split(","))))
+              for dtype, inner in re.findall(r"\b(\w+)\[([\d,]+)\]", text)}
+    widest = max((_DTYPE_BYTES.get(dtype, 0) * math.prod(shape) for dtype, shape in arrays
+                  if rows in shape), default=0)
+    return {**calls, "widest_bytes": widest}
+
+
+def check_step_runs_the_head_three_times(programs: dict, lm, tokens: int) -> dict:
+    """The step program among ``programs`` runs each head's product three
+    times, all in the forward pass (logits, dX and dW a tile of tokens at a
+    time: ``ops/head_loss.py``), never again under a remat or in the backward
+    pass, on every backend: the tiles are a loop XLA compiles. On the chip,
+    at the recipe's own sizes, it holds nothing over the rows held that is
+    larger than one tile's float32 logits or the float32 kernel (at the
+    rehearsal's toy widths other axes share the rows' length)."""
+    import jax
+
+    from jumbo_mae_tpu_tpu.ops.head_loss import head_tile
+
+    rows = lm.rows[1]
+    calls = head_product_calls(programs["train_step"].as_text(), rows)
+    want = {"fwd": 3 * (1 + lm.mtp_layers), "recompute": 0, "bwd": 0}
+    check({k: calls[k] for k in want} == want,
+          f"the step runs the head's product {calls}, not {want}")
+    most = 4 * rows * max(head_tile(tokens, rows), lm.dim)
+    check(jax.default_backend() != "tpu" or 0 < calls["widest_bytes"] <= most,
+          f"the step holds {calls['widest_bytes']} B over the rows held, over {most}")
+    return calls
+
+
 def check_step_runs_the_rope_kernel(programs: dict, lm) -> int:
     """The step program among ``programs`` turns each grouped-query block's
     ``q`` and ``k`` through the rope kernel three times: forward, under the
@@ -452,7 +510,8 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     at the recipe's routing; no step skipped by the guard; the step program
     runs each of the causal core's kernels once a latent-attention block,
     the chunk kernels (forward twice, backward once) a linear-attention
-    block and the rope kernel six times a grouped-query block; where the
+    block, the rope kernel six times a grouped-query block and each head's
+    product three times, all in the forward pass; where the
     recipe has linear-attention layers, their counters are logged on every
     step and their states stay bounded. ``recipe`` is any language family's
     (``--lm-recipe``)."""
@@ -471,6 +530,8 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     calls = check_step_runs_each_causal_kernel_once_a_block(programs, lm)
     kda_calls = check_step_runs_the_kda_kernels(programs, lm)
     rope_calls = check_step_runs_the_rope_kernel(programs, lm)
+    head_calls = check_step_runs_the_head_three_times(
+        programs, lm, cfg.run.train_batch_size * cfg.data.seq_len)
     programs.clear()  # or the step's executable outlives the phase
     records = _read_metrics(out_dir / cfg.run.name)
     losses = _logged_losses(records, 1, steps, "lm_train")
@@ -520,6 +581,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         "causal_kernel_calls": calls,
         "kda_kernel_calls": kda_calls,
         "rope_kernel_calls": rope_calls,
+        "head_product_calls": head_calls,
         "attn_pairs": {kind: {"visited": visited, "needed": needed} for kind, (visited, needed)
                        in lm.attn_pairs(cfg.data.seq_len).items()},
         "attn_heads": {kind: {"held": held, "published": published}

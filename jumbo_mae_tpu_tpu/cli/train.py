@@ -64,6 +64,7 @@ from jumbo_mae_tpu_tpu.models import (
 )
 from jumbo_mae_tpu_tpu.models.lm import MlaMoeConfig, MlaMoeLM
 from jumbo_mae_tpu_tpu.obs.mfu import lm_flops_per_token
+from jumbo_mae_tpu_tpu.ops.head_loss import head_tile
 from jumbo_mae_tpu_tpu.parallel import batch_sharding, create_mesh
 from jumbo_mae_tpu_tpu.train import (
     EXIT_FATAL,
@@ -955,6 +956,11 @@ def train(cfg: TrainConfig) -> dict:
             # static too: the expert layers' variants
             print(f"[train] expert layers: the router reads {enc_cfg.router_input} and scores "
                   f"{enc_cfg.router_scoring}; an expert's gate is {enc_cfg.expert_act}")
+            # static too: the tile of tokens the head's loss walks (ops/head_loss.py)
+            tokens = run.train_batch_size // run.grad_accum * cfg.data.seq_len
+            tile = head_tile(tokens, enc_cfg.rows[1])
+            print(f"[train] head loss: {tokens} tokens a program over {enc_cfg.rows[1]} rows "
+                  f"in {-(-tokens // tile)} tile(s) of {tile}")
     valid_factory = make_valid_iterator(
         cfg, mesh, per_process_valid, num_labels=getattr(enc_cfg, "labels", None) or 1000
     )

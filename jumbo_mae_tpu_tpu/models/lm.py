@@ -184,6 +184,7 @@ from jumbo_mae_tpu_tpu.obs.trace import (
 )
 from jumbo_mae_tpu_tpu.ops.flash_attention import causal_attention
 from jumbo_mae_tpu_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, grouped_outer
+from jumbo_mae_tpu_tpu.ops.head_loss import head_loss
 from jumbo_mae_tpu_tpu.ops.kda import causal_conv_silu, kda_chunked
 
 # the counters an expert layer reports, in the order of its stats vector
@@ -490,11 +491,12 @@ class Proj(nn.Module):
     spec: str
     cfg: MlaMoeConfig
 
-    @nn.compact
+    def setup(self):
+        self.kernel = self.param("kernel", _normal(self.cfg), self.shape, jnp.float32)
+
     def __call__(self, x):
-        kernel = self.param("kernel", _normal(self.cfg), self.shape, jnp.float32)
         dtype = self.cfg.compute_dtype
-        return jnp.einsum(self.spec, x.astype(dtype), kernel.astype(dtype))
+        return jnp.einsum(self.spec, x.astype(dtype), self.kernel.astype(dtype))
 
 
 def rope_interleaved(x, theta: float):
@@ -1006,7 +1008,17 @@ class Block(nn.Module):
 class MlaMoeLM(nn.Module):
     """``__call__(tokens)`` with ``tokens`` (batch, seq + 1 + mtp_layers)
     int32 ids from the vocabulary rows held: the training loss and the
-    step's counters. ``logits(tokens)`` returns both heads' logits."""
+    step's counters. ``logits(tokens)`` returns both heads' logits.
+
+    Under the ``lm_head`` scope the training path runs the final norm and,
+    a head, ``ops/head_loss.head_loss``: a tile of tokens at a time the
+    logits over the rows held, each token's loss, and the gradients of the
+    normed hidden state and of the head kernel, all in the forward pass (the
+    backward pass only scales them), whatever ``grad_ckpt`` says — nothing
+    of the head is rematerialised. ``loss`` is the scalar that carries the
+    gradient (each head's ``Σ weight · nll``); ``loss_per_sample``,
+    ``loss_trunk`` and ``loss_mtp`` are values only. ``logits()`` is the
+    plain product for every token (evaluation, the references' comparisons)."""
 
     cfg: MlaMoeConfig
 
@@ -1063,29 +1075,31 @@ class MlaMoeLM(nn.Module):
 
     def __call__(self, tokens, deterministic: bool = True):
         cfg = self.cfg
-        seq = tokens.shape[1] - 1 - cfg.mtp_layers
+        batch, seq = tokens.shape[0], tokens.shape[1] - 1 - cfg.mtp_layers
         hidden, stats, kda = self._hidden(tokens, deterministic)
         ids = tokens - cfg.rows[0]
-
-        def cross_entropy(mdl, h, targets):
-            logits = mdl._logits(h)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            hit = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-            return (lse - hit).mean(axis=-1)  # per sequence
-
-        # the logits are the step's largest arrays: recompute them in the
-        # backward pass rather than keep two (tokens, rows) float32 arrays
-        if cfg.grad_ckpt:
-            cross_entropy = nn.remat(cross_entropy)
+        # each head's share of the scalar a token: the mean over sequences
+        # and positions, the MTP head's times its weight
+        shares = [1.0] + [cfg.mtp_loss_weight] * cfg.mtp_layers
+        totals, losses = [], []
         with jax.named_scope(SCOPE_LM_HEAD):
-            losses = [cross_entropy(self, h, ids[:, 1 + i : seq + 1 + i])
-                      for i, h in enumerate(hidden)]
+            # the kernel enters in the compute dtype, as a ``Proj``'s does: its
+            # gradient is one rounding of a float32 sum, as the plain product's
+            kernel = self.head.kernel.astype(cfg.compute_dtype)
+            for i, (h, share) in enumerate(zip(hidden, shares)):
+                total, nll = head_loss(
+                    self.ln(h).reshape(batch * seq, cfg.dim), kernel,
+                    ids[:, 1 + i : seq + 1 + i].reshape(batch * seq),
+                    jnp.full((batch * seq,), share / (batch * seq), jnp.float32))
+                totals.append(total)
+                losses.append(nll.reshape(batch, seq).mean(axis=-1))  # per sequence
         per_sample = losses[0]
         out = {"loss_trunk": losses[0].mean()}
         if cfg.mtp_layers:
             per_sample = per_sample + cfg.mtp_loss_weight * losses[1]
             out["loss_mtp"] = losses[1].mean()
-        out |= {"loss": per_sample.mean(), "loss_per_sample": per_sample}
+        # ``loss`` carries the gradient; the per-sequence values are values only
+        out |= {"loss": sum(totals), "loss_per_sample": per_sample}
         table = jnp.stack(list(stats.values()))  # (expert layers, counters)
         for name, st in stats.items():
             out |= {f"moe_{c}_{name}": st[j] for j, c in enumerate(cfg.moe_counters)}

@@ -100,7 +100,7 @@ SCOPE_EXPERTS = "experts"  # grouped products over the experts held, and their S
 SCOPE_SHARED_EXPERT = "shared_expert"  # the expert every token passes
 SCOPE_DENSE_MLP = "dense_mlp"  # the SwiGLU MLP of a leading dense layer
 SCOPE_MTP_MERGE = "mtp_merge"  # multi-token prediction: norms, concatenation, W_eh
-SCOPE_LM_HEAD = "lm_head"  # final norm, logits over the rows held, cross-entropy
+SCOPE_LM_HEAD = "lm_head"  # final norm; a tile of tokens at a time (ops/head_loss.py): logits over the rows held, loss, and both gradient products, all in the forward pass
 # ... and in its linear-attention (Kimi delta attention) layers. A block of
 # that kind has no mla_latent / rope / attn_core / attn_out. An MLA block's
 # head-wise gate lies under attn_out, the expert groups' choice under router.

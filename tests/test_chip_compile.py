@@ -159,6 +159,26 @@ def program_bytes(compiled) -> int:
             - m.alias_size_in_bytes)
 
 
+def assert_the_head_walks_its_tokens_in_tiles(text: str, cfg, lm) -> None:
+    """A compiled step's text passes ``chip_smoke``'s own check of the head
+    (three products a head, all in the forward pass, nothing over the rows
+    held larger than a tile's float32 logits or the kernel: PR 41; four
+    products with the logits recomputed under a remat) and holds no array of
+    all the step's tokens over the rows held, in any dtype."""
+    from jumbo_mae_tpu_tpu.ops.head_loss import head_tile
+
+    batch, seq, rows = cfg.run.train_batch_size, cfg.data.seq_len, lm.rows[1]
+    assert jax.default_backend() == "tpu"  # compile_lm_step's patch: the sized check runs
+    chip_smoke.check_step_runs_the_head_three_times(
+        {"train_step": SimpleNamespace(as_text=lambda: text)}, lm, batch * seq)
+    tile = head_tile(batch * seq, rows)
+    assert tile < batch * seq and (batch * seq) % tile == 0
+    for whole in (f"[{batch},{seq},{rows}]", f"[{batch * seq},{rows}]",
+                  f"[{rows},{batch * seq}]", f"[{batch * seq // tile},{tile},{rows}]"):
+        assert whole not in text, whole
+    assert f"f32[{tile},{rows}]" in text  # one tile's logits
+
+
 def test_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):
     """The real cut of the shipped recipe (680 M parameters, 2 x 8192 tokens)
     through the trainer's own step factory, for a described v5e: the flash
@@ -170,9 +190,11 @@ def test_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):
     run again; the backward is one kernel, PR 37), and what the step holds
     fits the chip with room: the six kept pairs, 6 x (134 217 728 + 2 097 152)
     B, are live at the program's peak, yet the heap this compile packs comes
-    to 11 282 566 144 B (11 290 151 936 with two backward kernels; 11 567 921
-    664 with the forward run twice; 12 617 840 128 with the log-sum-exp kept
-    in the kernel's lane-padded layout); the bound is that reading + 1%."""
+    to 11 199 043 072 B (11 282 566 144 with the heads' whole float32 logits
+    and their recompute, before PR 41; 11 290 151 936 with two backward
+    kernels; 11 567 921 664 with the forward run twice; 12 617 840 128 with
+    the log-sum-exp kept in the kernel's lane-padded layout); the bound is
+    the reading before PR 41, which the tiled head may not pass."""
     cfg, lm, parameters, compiled = compile_lm_step(chip_smoke.LM_RECIPE, v5e_chip, monkeypatch)
     assert parameters == 680_437_760
     rows = cfg.run.train_batch_size
@@ -180,6 +202,7 @@ def test_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):
     assert " conditional(" not in text and "/guard/" in text
     assert chip_smoke.causal_kernel_calls(text) == {"fwd": 6, "bwd": 6}
     assert chip_smoke.rope_kernel_calls(text) == 0  # rope on adjacent pairs: not the kernel's
+    assert_the_head_walks_its_tokens_in_tiles(text, cfg, lm)  # two heads: six products
     assert "gmm" in text
     pairs = rows * cfg.data.seq_len * lm.experts_per_token
     assert pairs == 131_072
@@ -192,7 +215,7 @@ def test_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):
     assert len(loops) == 2 * 5, len(loops)  # forward and backward of five expert layers
     assert re.search(r'op_name="[^"]*/moe_dispatch/while/body/experts/[^"]*pallas_call"', text)
     held = program_bytes(compiled)
-    assert 6.8e9 < held < 11_282_566_144 * 1.01, held
+    assert 6.8e9 < held <= 11_282_566_144, held
 
 
 # -------------------------------------------- chip_smoke, rehearsed on CPU

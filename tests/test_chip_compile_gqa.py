@@ -12,12 +12,19 @@ import re
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
 import chip_smoke
-from test_chip_compile import compile_lm_step, program_bytes, v5e_chip  # noqa: F401 - fixture
+from test_chip_compile import (  # noqa: F401 - fixture
+    assert_the_head_walks_its_tokens_in_tiles,
+    compile_lm_step,
+    program_bytes,
+    v5e_chip,
+)
 
 RECIPE = str(chip_smoke.REPO / "recipes" / "pretrain_laguna_xs2_share.yaml")
 # what one AOT compile of this step read (PERF.md, PR 37: one backward causal
 # kernel; 13 059 776 000 with two, PR 35; 13 096 821 760 before the rope
 # kernel, PR 33), and the chip's own line: 16 GiB less what the runtime keeps
+# ... before the head's loss walked its tokens in tiles (PR 41); the step reads
+# 12 991 600 640 since, and the bound is the older reading with no slack
 PROGRAM_BYTES, CHIP_BYTES = 13_058_227_712, 16.9e9
 
 
@@ -41,6 +48,7 @@ def test_grouped_query_language_model_step_compiles_for_v5e_and_fits(v5e_chip, m
     text = compiled.as_text()
     assert " conditional(" not in text and "/guard/" in text
     assert chip_smoke.causal_kernel_calls(text) == {"fwd": 8, "bwd": 8}
+    assert_the_head_walks_its_tokens_in_tiles(text, cfg, lm)
     by_kind = {scope: len(re.findall(
         rf'custom-call\([^\n]*/{scope}/causal_attention_\w+/pallas_call"', text))
         for scope in ("attn_core", "swa_core")}
@@ -67,4 +75,4 @@ def test_grouped_query_language_model_step_compiles_for_v5e_and_fits(v5e_chip, m
              if " while(" in line and '/moe/moe_dispatch/while"' in line]
     assert len(loops) == 2 * 7, len(loops)  # forward and backward of seven expert layers
     held = program_bytes(compiled)
-    assert 7.6e9 < held < min(PROGRAM_BYTES * 1.01, CHIP_BYTES), held
+    assert 7.6e9 < held <= min(PROGRAM_BYTES, CHIP_BYTES), held

@@ -11,13 +11,20 @@ import os
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
 import chip_smoke
-from test_chip_compile import compile_lm_step, program_bytes, v5e_chip  # noqa: F401 - fixture
+from test_chip_compile import (  # noqa: F401 - fixture
+    assert_the_head_walks_its_tokens_in_tiles,
+    compile_lm_step,
+    program_bytes,
+    v5e_chip,
+)
 
 RECIPE = str(chip_smoke.REPO / "recipes" / "pretrain_ling3_flash_ep64.yaml")
 # what one AOT compile of this step read (PERF.md, PR 37: one backward causal
 # kernel whose key/value outputs are sequence-long blocks; 15 168 317 440 with
 # two, PR 32; 15 875 868 160 with the chunk scan in place of the kernels, PR
 # 31), and the chip's own line: 16 GiB less what the runtime keeps
+# ... before the head's loss walked its tokens in tiles (PR 41); the step reads
+# 14 999 557 632 since, and the bound is the older reading with no slack
 PROGRAM_BYTES, CHIP_BYTES = 15_168_285_184, 16.9e9
 
 
@@ -38,6 +45,7 @@ def test_hybrid_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypa
     assert " conditional(" not in text and "/guard/" in text
     assert chip_smoke.causal_kernel_calls(text) == {"fwd": 1, "bwd": 1}
     assert chip_smoke.rope_kernel_calls(text) == 0  # rope on adjacent pairs: not the kernel's
+    assert_the_head_walks_its_tokens_in_tiles(text, cfg, lm)
     assert "gmm" in text
     assert chip_smoke.kda_kernel_calls(text) == {"fwd": 2 * lm.kda_layers, "bwd": lm.kda_layers,
                                                  "loops": 0}
@@ -50,4 +58,4 @@ def test_hybrid_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypa
              if " while(" in line and '/moe/moe_dispatch/while"' in line]
     assert len(loops) == 2 * 6, len(loops)  # forward and backward of six expert layers
     held = program_bytes(compiled)
-    assert 8.2e9 < held < min(PROGRAM_BYTES * 1.01, CHIP_BYTES), held
+    assert 8.2e9 < held <= min(PROGRAM_BYTES, CHIP_BYTES), held

@@ -12,12 +12,19 @@ import re
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
 import chip_smoke
-from test_chip_compile import compile_lm_step, program_bytes, v5e_chip  # noqa: F401 - fixture
+from test_chip_compile import (  # noqa: F401 - fixture
+    assert_the_head_walks_its_tokens_in_tiles,
+    compile_lm_step,
+    program_bytes,
+    v5e_chip,
+)
 
 RECIPE = str(chip_smoke.REPO / "recipes" / "pretrain_solar_open2_share.yaml")
 # what one AOT compile of this step read (PERF.md, PR 38; 15 756 047 360 with
 # 16 heads held, 16 421 990 912 with 32), the ladder's line (no nearer the
 # chip's limit than the fullest accepted cell), and the chip's own
+# ... before the head's loss walked its tokens in tiles (PR 41); the step reads
+# 14 020 593 664 since, and the bound is the older reading with no slack
 PROGRAM_BYTES, LADDER_BYTES, CHIP_BYTES = 14_154_523_648, 15.2e9, 16.9e9
 
 
@@ -42,6 +49,7 @@ def test_linear_and_grouped_query_step_compiles_for_v5e_and_fits(v5e_chip, monke
     assert len(re.findall(r'custom-call\([^\n]*/attn_core/causal_attention_\w+/pallas_call"',
                           text)) == 2
     assert chip_smoke.rope_kernel_calls(text) == 0
+    assert_the_head_walks_its_tokens_in_tiles(text, cfg, lm)
     assert not re.search(r'op_name="[^"]*/rope[/"]', text)
     assert lm.attn_heads() == {"full_attention": (8, 64), "kda": (8, 64)}
     h, e = lm.kda_heads, lm.kda_head_dim
@@ -53,4 +61,4 @@ def test_linear_and_grouped_query_step_compiles_for_v5e_and_fits(v5e_chip, monke
              if " while(" in line and '/moe/moe_dispatch/while"' in line]
     assert len(loops) == 2 * 4, len(loops)  # forward and backward of four expert layers
     held = program_bytes(compiled)
-    assert 8.4e9 < held < min(PROGRAM_BYTES * 1.01, LADDER_BYTES, CHIP_BYTES), held
+    assert 8.4e9 < held <= min(PROGRAM_BYTES, LADDER_BYTES, CHIP_BYTES), held

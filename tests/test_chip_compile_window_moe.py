@@ -14,11 +14,18 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 import pytest
 
 import chip_smoke
-from test_chip_compile import compile_lm_step, program_bytes, v5e_chip  # noqa: F401 - fixture
+from test_chip_compile import (  # noqa: F401 - fixture
+    assert_the_head_walks_its_tokens_in_tiles,
+    compile_lm_step,
+    program_bytes,
+    v5e_chip,
+)
 
 RECIPE = str(chip_smoke.REPO / "recipes" / "pretrain_smallthinker_21b_share.yaml")
 # what one AOT compile of this step read (PERF.md, PR 40), the ladder's line
 # (no nearer the chip's limit than the fullest accepted cell), and the chip's own
+# ... before the head's loss walked its tokens in tiles (PR 41); the step reads
+# 10 352 944 640 since, and the bound is the older reading with no slack
 PROGRAM_BYTES, LADDER_BYTES, CHIP_BYTES = 13_045_875_712, 15.2e9, 16.9e9
 
 
@@ -45,6 +52,7 @@ def test_window_and_rope_free_full_step_compiles_for_v5e_and_fits(v5e_chip, monk
     assert " conditional(" not in text and "/guard/" in text
     assert lm.kinds == ("full_attention",) + ("sliding_attention",) * 3
     assert chip_smoke.causal_kernel_calls(text) == {"fwd": 4, "bwd": 4}
+    assert_the_head_walks_its_tokens_in_tiles(text, cfg, lm)
     core = lambda scope: len(re.findall(
         rf'custom-call\([^\n]*/{scope}/causal_attention_\w+/pallas_call"', text))
     assert (core("attn_core"), core("swa_core")) == (2, 6)
@@ -61,4 +69,4 @@ def test_window_and_rope_free_full_step_compiles_for_v5e_and_fits(v5e_chip, monk
              if " while(" in line and '/moe/moe_dispatch/while"' in line]
     assert len(loops) == 2 * 4, len(loops)  # forward and backward of four expert layers
     held = program_bytes(compiled)
-    assert 8.4e9 < held < min(PROGRAM_BYTES * 1.01, LADDER_BYTES, CHIP_BYTES), held
+    assert 8.4e9 < held <= min(PROGRAM_BYTES, LADDER_BYTES, CHIP_BYTES), held
