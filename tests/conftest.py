@@ -8,7 +8,9 @@ in ``chip_smoke.py``; ``tests/test_chip_compile.py`` keeps the compile-only
 part (AOT for a described v5e) and a CPU rehearsal of the script's phases.
 """
 
+import json
 import os
+from pathlib import Path
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -34,6 +36,49 @@ import sys  # noqa: E402
 sys.path.insert(0, _repo_root)
 
 import pytest  # noqa: E402
+
+# ---------------------------------------------------------- the files' order
+UNKNOWN_FILE_SECONDS = 60.0  # a file the table lacks: neither last nor first
+WORKERS = 6  # the tier-1 command's ``-n 6``
+
+
+def file_seconds() -> dict[str, float]:
+    """Test file -> seconds of its cases in one whole run's junit: the table
+    ``python tools/file_seconds.py <junit.xml>`` writes, and no run of the tests."""
+    return json.loads((Path(__file__).parent / "file_seconds.json").read_text())
+
+
+def order_files(files: list[str], seconds: dict[str, float]) -> list[str]:
+    """The six longest files, then the six lightest, then the rest longest
+    first: a pure function of the names and the table (every worker collects
+    for itself, and xdist aborts if two disagree)."""
+    ranked = sorted(files, key=lambda f: (-seconds.get(f, UNKNOWN_FILE_SECONDS), f))
+    first, rest = ranked[:WORKERS], ranked[WORKERS:]
+    return first + rest[-WORKERS:] + rest[:-WORKERS]
+
+
+def order_items(ids: list[str], seconds: dict[str, float]) -> list[int]:
+    """Positions of ``ids`` in the order they run: whole files as
+    ``order_files`` says, a file's cases together and as collected."""
+    by_file: dict[str, list[int]] = {}
+    for i, nodeid in enumerate(ids):
+        by_file.setdefault(nodeid.split("::", 1)[0], []).append(i)
+    return [i for f in order_files(list(by_file), seconds) for i in by_file[f]]
+
+
+# ``--dist loadfile`` hands a worker its next file when two cases of its
+# current one are left, and xdist's own order is most cases first: here the
+# heaviest files have the fewest, so they ran last and alone (a whole run's
+# junit replayed: 1305 s that way, 1179 s longest first, 1153 s evenly
+# shared). The six light files are what a first file of one or two cases gets
+# queued behind it before anything runs.
+def pytest_configure(config):
+    if hasattr(config.option, "loadscopereorder"):  # absent under -p no:xdist
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(items):
+    items[:] = [items[i] for i in order_items([item.nodeid for item in items], file_seconds())]
 
 
 @pytest.fixture(scope="session")

@@ -116,9 +116,11 @@ def test_causal_kernels_compile_for_v5e(v5e_chip, shape, variant):
     assert compiled.as_text().count("tpu_custom_call") == (1 if variant == "fwd" else 2)
 
 
-def compile_lm_step(recipe: str, chip, monkeypatch):
+def compile_lm_step(recipe: str, chip, monkeypatch, depth_cut: list[str] | None = None):
     """A language recipe's real step through the trainer's own step factory,
-    compiled for the described ``chip``: ``(cfg, lm, parameters, compiled)``."""
+    compiled for the described ``chip``: ``(cfg, lm, parameters, compiled)``.
+    ``depth_cut`` overrides the recipe's layer counts and nothing else of it
+    (tier-1's compiles: one block of every kind the family has)."""
     from jumbo_mae_tpu_tpu.cli.train import build_model
     from jumbo_mae_tpu_tpu.config import load_config
     from jumbo_mae_tpu_tpu.parallel import MeshConfig, create_mesh
@@ -129,7 +131,7 @@ def compile_lm_step(recipe: str, chip, monkeypatch):
     # jax.default_backend() is the CPU here; the program picks its kernels by
     # it, so the test answers for the described chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = load_config(recipe)
+    cfg = load_config(recipe, depth_cut)
     mesh = create_mesh(MeshConfig(data=1, fsdp=1), devices=list(chip.device_set))
     model, lm, _ = build_model(cfg)
     tx = make_optimizer(cfg.optim, cfg.run.train_batch_size, num_layers=lm.layers)
@@ -179,41 +181,93 @@ def assert_the_head_walks_its_tokens_in_tiles(text: str, cfg, lm) -> None:
     assert f"f32[{tile},{rows}]" in text  # one tile's logits
 
 
-def test_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):
-    """The real cut of the shipped recipe (680 M parameters, 2 x 8192 tokens)
-    through the trainer's own step factory, for a described v5e: the flash
-    and grouped-product kernels are in it, the guard adds no ``conditional``,
-    the expert layers walk their held pairs in a loop and build nothing a row
-    wide for all 131 072 (token, expert) pairs, each of the causal core's two
-    kernels runs once a block (five layers + the MTP block: a rematted block
-    keeps the forward kernel's output and log-sum-exp, so the forward is not
-    run again; the backward is one kernel, PR 37), and what the step holds
-    fits the chip with room: the six kept pairs, 6 x (134 217 728 + 2 097 152)
-    B, are live at the program's peak, yet the heap this compile packs comes
-    to 11 199 043 072 B (11 282 566 144 with the heads' whole float32 logits
-    and their recompute, before PR 41; 11 290 151 936 with two backward
-    kernels; 11 567 921 664 with the forward run twice; 12 617 840 128 with
-    the log-sum-exp kept in the kernel's lane-padded layout); the bound is
-    the reading before PR 41, which the tiled head may not pass."""
-    cfg, lm, parameters, compiled = compile_lm_step(chip_smoke.LM_RECIPE, v5e_chip, monkeypatch)
-    assert parameters == 680_437_760
-    rows = cfg.run.train_batch_size
-    text = compiled.as_text()
+def assert_the_step_is_built_a_block_at_a_time(text: str, cfg, lm) -> None:
+    """What a compiled step's text holds in every family, counted from ``lm``'s
+    own lists so that a depth cut is held to what the whole recipe is: the
+    guard adds no ``conditional``; each softmax block (the MTP module's too)
+    runs each of the two causal kernels once, a window layer's under
+    ``swa_core`` and every other under ``attn_core`` (a rematted block keeps
+    the forward kernel's output and log-sum-exp; the backward is one kernel,
+    PR 37); each linear-attention block runs the forward chunk kernel twice
+    and the backward once with no loop left under ``kda_core``; each block
+    with a rotate-half rope turns its q and its k through the rope kernel
+    three times (forward, rematted, transposed); the heads walk their tokens
+    in tiles; and every expert layer walks its held pairs in one loop each
+    way through the grouped-product kernel."""
+    from jumbo_mae_tpu_tpu.models.lm import GQA_KINDS
+
     assert " conditional(" not in text and "/guard/" in text
-    assert chip_smoke.causal_kernel_calls(text) == {"fwd": 6, "bwd": 6}
-    assert chip_smoke.rope_kernel_calls(text) == 0  # rope on adjacent pairs: not the kernel's
-    assert_the_head_walks_its_tokens_in_tiles(text, cfg, lm)  # two heads: six products
+    sliding = lm.kinds.count("sliding_attention")
+    softmax = lm.layers - lm.kda_layers + lm.mtp_layers
+    assert chip_smoke.causal_kernel_calls(text) == {"fwd": softmax, "bwd": softmax}
+    by_scope = {scope: len(re.findall(
+        rf'custom-call\([^\n]*/{scope}/causal_attention_\w+/pallas_call"', text))
+        for scope in ("attn_core", "swa_core")}
+    assert by_scope == {"attn_core": 2 * (softmax - sliding), "swa_core": 2 * sliding}
+    assert chip_smoke.kda_kernel_calls(text) == {"fwd": 2 * lm.kda_layers, "bwd": lm.kda_layers,
+                                                 "loops": 0}
+    roped = sum(kind in GQA_KINDS and lm.rope(kind) is not None for kind in lm.kinds)
+    assert chip_smoke.rope_kernel_calls(text) == roped * 2 * 3
+    assert_the_head_walks_its_tokens_in_tiles(text, cfg, lm)
     assert "gmm" in text
-    pairs = rows * cfg.data.seq_len * lm.experts_per_token
+    loops = [line for line in text.splitlines()
+             if " while(" in line and '/moe/moe_dispatch/while"' in line]
+    assert len(loops) == 2 * (lm.layers - lm.first_k_dense + lm.mtp_layers), len(loops)
+
+
+# tier-1's compile of a family: the dense block and one expert block, the
+# smallest depth with a block of every kind (and the MTP module, which the
+# recipe has at any depth)
+DEPTH_CUT = ["model.lm.layers=2"]
+
+
+def assert_the_all_mla_step(text: str, cfg, lm) -> None:
+    """The flash and grouped-product kernels are in the step, each block's
+    as ``assert_the_step_is_built_a_block_at_a_time`` counts them, and the
+    expert layers build nothing a row wide for all 131 072 (token, expert)
+    pairs."""
+    assert_the_step_is_built_a_block_at_a_time(text, cfg, lm)
+    pairs = cfg.run.train_batch_size * cfg.data.seq_len * lm.experts_per_token
     assert pairs == 131_072
     for wide in (f"[{pairs},{lm.dim}]", f"[{pairs},{2 * lm.expert_hidden}]",
                  f"[{pairs},{lm.expert_hidden}]",
                  f"[{pairs // lm.experts_per_token},{lm.experts_per_token},{lm.dim}]"):
         assert wide not in text, wide
-    loops = [line for line in text.splitlines()
-             if " while(" in line and '/moe/moe_dispatch/while"' in line]
-    assert len(loops) == 2 * 5, len(loops)  # forward and backward of five expert layers
     assert re.search(r'op_name="[^"]*/moe_dispatch/while/body/experts/[^"]*pallas_call"', text)
+
+
+def test_language_model_step_compiles_for_v5e_at_cut_depth(v5e_chip, monkeypatch):
+    """The shipped recipe at two of its five layers (the dense block, one
+    expert block, the MTP block; widths, sequence, experts and kernels as
+    published), for a described v5e: every structural assertion of the full
+    compile, which is ``slow``."""
+    cfg, lm, _, compiled = compile_lm_step(chip_smoke.LM_RECIPE, v5e_chip, monkeypatch, DEPTH_CUT)
+    assert (lm.layers, lm.first_k_dense, lm.mtp_layers) == (2, 1, 1)
+    assert_the_all_mla_step(compiled.as_text(), cfg, lm)
+
+
+# slow, as the other four families' full compiles: 166 s of one worker, and
+# the chip run of every cell on every PR is the stronger reading of "fits".
+# Run by hand after a change to models/lm.py, ops/ or a language recipe:
+# pytest -m slow tests/test_chip_compile*.py
+@pytest.mark.slow
+def test_language_model_step_compiles_for_v5e_and_fits(v5e_chip, monkeypatch):
+    """The real cut of the shipped recipe (680 M parameters, 2 x 8192 tokens)
+    through the trainer's own step factory, for a described v5e: what
+    ``assert_the_all_mla_step`` holds (five layers + the MTP block: six calls
+    of each causal kernel, five expert layers' loops), and what the step
+    holds fits the chip with room: the six kept pairs, 6 x (134 217 728 +
+    2 097 152) B, are live at the program's peak, yet the heap this compile
+    packs comes to 11 199 043 072 B (11 282 566 144 with the heads' whole
+    float32 logits and their recompute, before PR 41; 11 290 151 936 with two
+    backward kernels; 11 567 921 664 with the forward run twice;
+    12 617 840 128 with the log-sum-exp kept in the kernel's lane-padded
+    layout); the bound is the reading before PR 41, which the tiled head may
+    not pass."""
+    cfg, lm, parameters, compiled = compile_lm_step(chip_smoke.LM_RECIPE, v5e_chip, monkeypatch)
+    assert parameters == 680_437_760
+    assert (lm.layers, lm.first_k_dense, lm.mtp_layers) == (5, 1, 1)
+    assert_the_all_mla_step(compiled.as_text(), cfg, lm)  # two heads: six products
     held = program_bytes(compiled)
     assert 6.8e9 < held <= 11_282_566_144, held
 

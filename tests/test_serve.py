@@ -554,8 +554,10 @@ def test_scale_down_drains_never_kills_in_flight():
 def test_scale_down_refuses_below_one_and_times_out_busy():
     reg = MetricsRegistry()
     release = threading.Event()
+    entered = threading.Semaphore(0)
 
     def stuck_run(eng, batch, metas):
+        entered.release()
         release.wait(timeout=10)
         return {"y": batch[:, 0, 0, 0].astype(np.float64)}
 
@@ -563,12 +565,20 @@ def test_scale_down_refuses_below_one_and_times_out_busy():
     try:
         with pytest.raises(ValueError):
             rs.scale_to(0)
-        futs = [rs.submit(_img()) for _ in range(4)]
+        # a replica shows as busy from its run on, not while its worker still
+        # gathers a batch: wait for each to be inside its run (on a loaded
+        # machine the last slot was still gathering, looked idle and was
+        # removed). Once the first is stuck, the next request queues behind
+        # it and the one after goes to the other replica.
+        futs = [rs.submit(_img())]
+        assert entered.acquire(timeout=30)
+        futs += [rs.submit(_img()) for _ in range(3)]
+        assert entered.acquire(timeout=30)
         # both replicas busy: a tiny drain budget can't free the last slot
         report = rs.scale_to(1, drain_timeout_s=0.05)
         assert report["to"] == 2  # refused, not forced
         release.set()
-        done, _ = wait(futs, timeout=10)
+        done, _ = wait(futs, timeout=30)
         assert len(done) == 4
         assert all(f.exception() is None for f in futs)
     finally:
