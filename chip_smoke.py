@@ -231,7 +231,7 @@ def _check_against_reference(label, key, fn, ref_fn, args, worst, *, interpret: 
 
 def phase_kernels(shapes=KERNEL_SHAPES, *, interpret: bool = False) -> dict:
     """Pallas flash attention forward+backward, plain and ``_with_lse``,
-    executed and compared with ``ops.flash_attention.xla_attention``; plus
+    executed and compared with ``ops.attention.xla_attention``; plus
     the one-hot masking gather against the XLA gather, bit for bit.
 
     ``interpret`` exists for the CPU rehearsal in the tests; ``main`` never
@@ -240,7 +240,7 @@ def phase_kernels(shapes=KERNEL_SHAPES, *, interpret: bool = False) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from jumbo_mae_tpu_tpu.ops.flash_attention import xla_attention
+    from jumbo_mae_tpu_tpu.ops.attention import xla_attention
     from jumbo_mae_tpu_tpu.ops.pallas.attention import (
         pallas_flash_attention,
         pallas_flash_attention_with_lse,
@@ -314,7 +314,7 @@ def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, *,
     import jax
     import jax.numpy as jnp
 
-    from jumbo_mae_tpu_tpu.ops.flash_attention import xla_causal_attention
+    from jumbo_mae_tpu_tpu.ops.attention import xla_causal_attention
     from jumbo_mae_tpu_tpu.ops.grouped_matmul import grouped_matmul
     from jumbo_mae_tpu_tpu.ops.pallas.attention import pallas_causal_attention
 

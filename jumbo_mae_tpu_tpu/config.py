@@ -40,8 +40,8 @@ class ModelConfig:
     overrides: dict[str, Any] = field(default_factory=dict)
     # decoder (pretrain only). The common knobs are first-class fields; every
     # other DecoderConfig field (dropout/droppath/layerscale/grad_ckpt/
-    # remat_policy/attn_impl/ring_inner) is reachable via ``dec_overrides``,
-    # mirroring the encoder's ``overrides`` (parity: the reference's
+    # remat_policy) is reachable via ``dec_overrides``, mirroring the
+    # encoder's ``overrides`` (parity: the reference's
     # --dec-dropout/--dec-droppath/--dec-layerscale flags,
     # /root/reference/src/main_pretrain.py).
     dec_layers: int = 8

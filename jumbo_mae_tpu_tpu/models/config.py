@@ -16,7 +16,6 @@ import jax.numpy as jnp
 
 Posemb = Literal["learnable", "sincos2d"]
 Pooling = Literal["cls", "gap"]
-AttnImpl = Literal["einsum", "flash", "ring", "auto"]
 MaskModeT = Literal["shared", "per_sample"]
 # rematerialization policy under grad_ckpt=True:
 #   "none" — recompute the whole block; keep only the causal attention core's
@@ -73,8 +72,8 @@ class JumboViTConfig:
     """Encoder configuration.
 
     Capability parity with ``ViTBase`` (``/root/reference/src/modeling.py:35``)
-    plus TPU-first knobs: compute ``dtype`` (bfloat16 by default — MXU-native),
-    ``attn_impl`` selection, and a per-sample masking mode option.
+    plus TPU-first knobs: compute ``dtype`` (bfloat16 by default — MXU-native)
+    and a per-sample masking mode option.
     """
 
     layers: int = 12
@@ -104,11 +103,6 @@ class JumboViTConfig:
 
     # TPU-first knobs
     dtype: str = "bfloat16"  # compute dtype; params always float32
-    attn_impl: AttnImpl = "auto"
-    # attn_impl="ring" only: per-hop lowering — "einsum" (O((S/n)²) local
-    # scores) or "flash" (Pallas kernels + differentiable lse merge,
-    # O(S/n) score memory; falls back to einsum off-TPU)
-    ring_inner: str = "einsum"
 
     def __post_init__(self):
         if self.heads <= 0 or self.dim % self.heads:
@@ -169,8 +163,6 @@ class DecoderConfig:
     remat_policy: RematPolicy = "none"
 
     dtype: str = "bfloat16"
-    attn_impl: AttnImpl = "auto"
-    ring_inner: str = "einsum"
 
     def __post_init__(self):
         if self.heads <= 0 or self.dim % self.heads:

@@ -9,8 +9,9 @@ FeedForward, ViTLayer, JumboLayer, LinearCLS), designed TPU-first:
   float32, but the materialized score/prob tensors follow the compute dtype
   (halves the O(S²) HBM traffic under bf16; exact under f32 compute, which
   is what every parity test runs — see PERF_ARCHIVE.md);
-- attention implementation switchable between a fused Pallas flash kernel and
-  the plain einsum path (the einsum path is also the parity oracle in tests).
+- attention's lowering (the einsum form here, a Pallas flash kernel, ring
+  attention over a split sequence) is ``ops/attention.py``'s to choose, from
+  what a call can observe (the einsum path is also the parity oracle in tests).
 
 Parameter naming is semantic (q/k/v/out, fc1/fc2, ln1/ln2/ln3, ls1/ls2/ls3)
 rather than the reference's wq/w1/norm1/scale1; ``tools/`` converters map
@@ -30,42 +31,10 @@ from flax.linen.dtypes import promote_dtype
 from jumbo_mae_tpu_tpu.models.config import DecoderConfig, JumboViTConfig
 from jumbo_mae_tpu_tpu.obs.trace import SCOPE_ATTN_CORE
 from jumbo_mae_tpu_tpu.ops import shared_grad
+from jumbo_mae_tpu_tpu.ops.attention import attention, lowering_here
 from jumbo_mae_tpu_tpu.ops.posemb import sincos2d_positional_embedding
 
 TRUNC_NORMAL = init.truncated_normal(0.02)
-
-# attn_impl="auto" switches einsum → Pallas flash at this sequence length.
-# The v5e-measured crossover sits between 199 (einsum 1.7× faster) and 787
-# (flash 1.7× faster); 512 splits it conservatively.
-AUTO_FLASH_MIN_SEQ = 512
-
-
-def resolve_attn_impl(
-    impl: str,
-    *,
-    backend: str,
-    seq_len: int,
-    dropout: float,
-    deterministic: bool,
-) -> str:
-    """Resolve ``attn_impl="auto"`` to a concrete backend per call shape.
-
-    Measured crossover on v5e (PERF_ARCHIVE.md, round 5,
-    fwd+bwd ms): einsum wins at MAE-224 shapes (seq 199: 5.2 vs 8.7),
-    the Pallas kernels win from long-context lengths up (seq 787: 9.0 vs
-    15.3; seq 3139: 24.7 vs 45.8) now that they use bf16 MXU-rate
-    operands and full-row blocks. dropout>0 training still needs
-    einsum's materialized probs (flash has no probability dropout).
-    Explicit impl choices pass through untouched.
-    """
-    if impl != "auto":
-        return impl
-    use_flash = (
-        backend == "tpu"
-        and seq_len >= AUTO_FLASH_MIN_SEQ
-        and (dropout == 0.0 or deterministic)
-    )
-    return "flash" if use_flash else "einsum"
 
 ConfigT = Any  # JumboViTConfig | DecoderConfig — same attribute surface
 
@@ -120,60 +89,21 @@ class Attention(nn.Module):
         k = dense("k")(x)
         v = dense("v")(x)
 
-        # Masked attention (token-packed serving's block-diagonal segment
-        # mask) exists only on the einsum path: the flash/ring kernels take
-        # no mask operand, and silently dropping one would leak tokens
-        # across segments.
-        if mask is not None and cfg.attn_impl in ("flash", "ring"):
-            raise ValueError(
-                f"attn_impl={cfg.attn_impl!r} has no attention-mask "
-                "support; packed/masked attention requires the einsum path "
-                "(attn_impl='einsum' or 'auto')"
-            )
-        # The flash/ring paths have no attention-probability dropout; any
-        # dropout>0 must take the einsum path so training semantics don't
-        # silently change.
-        if cfg.attn_impl in ("flash", "ring") and cfg.dropout > 0.0 and not deterministic:
-            # Both are explicit requests — "ring" for sequence parallelism,
-            # "flash" for O(S) score memory; silently degrading either to
-            # the O(S²) einsum path would defeat the reason it was chosen.
-            # Deterministic (inference) calls are fine: dropout is inactive,
-            # so a model trained with einsum+dropout can still evaluate with
-            # flash/ring.
-            raise ValueError(
-                f"attn_impl={cfg.attn_impl!r} has no attention-probability "
-                "dropout; set dropout=0.0 to train (droppath regularization "
-                "still applies)"
-            )
-        impl = resolve_attn_impl(
-            cfg.attn_impl,
-            backend=jax.default_backend(),
-            seq_len=x.shape[1],
-            dropout=cfg.dropout,
-            deterministic=deterministic,
+        # The probabilities themselves are needed where a mask is given
+        # (token-packed serving's block-diagonal segment mask) or dropout is
+        # active in this call; only the einsum form below has them, and the
+        # rule (ops/attention.py) then answers with it.
+        how = lowering_here(
+            x.shape[1],
+            probs_needed=mask is not None or (cfg.dropout > 0.0 and not deterministic),
         )
-        if mask is not None:
-            impl = "einsum"  # auto: the only mask-capable path
 
         # z_head_major tracks each branch's output layout: (B,H,S,D) for the
-        # einsum path, (B,S,H,D) for flash/ring — set alongside z so a new
-        # branch can't silently mismatch the out-projection's axes.
+        # einsum path, (B,S,H,D) for the kernels and the ring — set alongside
+        # z so a new branch can't silently mismatch the out-projection's axes.
         with jax.named_scope(SCOPE_ATTN_CORE):
-            if impl == "ring":
-                # Sequence parallelism: tokens shard over the ambient mesh's
-                # "seq" axis, K/V ring-rotate over ICI (parallel/ring_attention).
-                from jumbo_mae_tpu_tpu.parallel.ring_attention import (
-                    ring_self_attention,
-                )
-
-                z, z_head_major = (
-                    ring_self_attention(q, k, v, inner=cfg.ring_inner),
-                    False,
-                )
-            elif impl == "flash":
-                from jumbo_mae_tpu_tpu.ops.flash_attention import flash_attention
-
-                z, z_head_major = flash_attention(q, k, v), False
+            if how != "einsum":
+                z, z_head_major = attention(q, k, v), False
             else:
                 # Scores materialize in the compute dtype; the MXU still
                 # accumulates the dot in f32, and softmax still computes in f32

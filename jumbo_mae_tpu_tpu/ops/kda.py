@@ -40,7 +40,7 @@ and the products with the state take operands in the compute dtype and
 accumulate in float32.
 
 **Schedule.** One algorithm, two schedules of it, chosen by what the code
-can observe (as ``resolve_attn_impl`` and ``grouped_matmul(impl="auto")``
+can observe (as ``ops/attention.lowering`` and ``grouped_matmul(impl="auto")``
 choose): on a TPU, where ``d_k`` and ``d_v`` are multiples of 128 and the
 sub-block one of 16, two Pallas kernels (``ops/pallas/kda.py``) under a VJP
 of their own; elsewhere (the CPU's tests, toy widths) a ``lax.scan`` over

@@ -67,8 +67,8 @@ def ring_attention(
             )
         # off-TPU there are no Mosaic kernels; silently running the Pallas
         # INTERPRETER would be orders of magnitude slower than the einsum
-        # inner — fall back like ops/flash_attention.py does
-        # (``interpret=True`` keeps the kernel path for CPU tests).
+        # inner — fall back to it (``interpret=True`` keeps the kernel path
+        # for CPU tests).
     n = jax.lax.psum(1, axis_name)
     bq, sq, h, d = q.shape
 
@@ -217,7 +217,7 @@ def ring_self_attention(
     shape = (mesh or jax.sharding.get_abstract_mesh()).shape
     n = shape.get(seq_axis, 1)
     if not n or n <= 1:
-        from jumbo_mae_tpu_tpu.ops.flash_attention import xla_attention
+        from jumbo_mae_tpu_tpu.ops.attention import xla_attention
 
         return xla_attention(q, k, v)
 

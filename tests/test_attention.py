@@ -1,13 +1,16 @@
-"""Attention implementations must agree: einsum (parity oracle) vs blockwise
-XLA vs the Pallas kernel (interpreter mode on CPU)."""
+"""Attention implementations must agree: einsum (parity oracle) vs the Pallas
+kernel (interpreter mode on CPU); and the rule that chooses between them."""
+
+import ast
+import itertools
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jumbo_mae_tpu_tpu.ops.blockwise_attention import blockwise_attention
-from jumbo_mae_tpu_tpu.ops.flash_attention import xla_attention
+from jumbo_mae_tpu_tpu.ops.attention import AUTO_FLASH_MIN_SEQ, lowering, xla_attention
 from jumbo_mae_tpu_tpu.ops.pallas.attention import pallas_flash_attention
 
 
@@ -16,54 +19,6 @@ def qkv(b=2, s=128, h=4, d=32, seed=0, dtype=jnp.float32):
     shape = (b, s, h, d)
     q, k, v = (jax.random.normal(kk, shape, dtype) for kk in ks)
     return q * d**-0.5, k, v
-
-
-class TestBlockwise:
-    @pytest.mark.parametrize("block_k", [32, 64, 128])
-    def test_matches_naive(self, block_k):
-        q, k, v = qkv()
-        ref = xla_attention(q, k, v)
-        got = blockwise_attention(q, k, v, block_k=block_k)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
-
-    def test_ragged_seq_padding(self):
-        q, k, v = qkv(s=100)  # not divisible by block
-        ref = xla_attention(q, k, v)
-        got = blockwise_attention(q, k, v, block_k=64)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
-
-    def test_gradients_match_naive(self):
-        q, k, v = qkv(s=64)
-
-        def loss_naive(q, k, v):
-            return (xla_attention(q, k, v) ** 2).sum()
-
-        def loss_block(q, k, v):
-            return (blockwise_attention(q, k, v, block_k=16) ** 2).sum()
-
-        g_ref = jax.grad(loss_naive, argnums=(0, 1, 2))(q, k, v)
-        g_got = jax.grad(loss_block, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g_got, g_ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
-
-    def test_bias(self):
-        q, k, v = qkv(s=64)
-        bias = jax.random.normal(jax.random.key(7), (1, 1, 64, 64))
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) + bias
-        probs = jax.nn.softmax(logits, -1)
-        ref = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        got = blockwise_attention(q, k, v, block_k=16, bias=bias)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
-
-    def test_bias_with_padding(self):
-        # full-length key axis bias + seq_k not divisible by block_k
-        q, k, v = qkv(s=100)
-        bias = jax.random.normal(jax.random.key(8), (1, 1, 100, 100))
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) + bias
-        probs = jax.nn.softmax(logits, -1)
-        ref = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        got = blockwise_attention(q, k, v, block_k=64, bias=bias)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
 
 
 class TestPallasKernel:
@@ -126,7 +81,7 @@ _HOSTILE_ENV = {
 }
 
 # forward + gradient as a jaxpr (kernel bodies, block shapes and residual
-# buffers are in its text; nothing compiles), and what "auto" resolves to.
+# buffers are in its text; nothing compiles), and what the rule answers.
 # seq 300 at block 256: 128-lane padding gives 384, padding to the block 512
 _KERNEL_PROGRAM = {
     "flash": """
@@ -157,10 +112,10 @@ def test_kernel_program_ignores_the_environment(kernel):
 
     code = (
         "import jax, jax.numpy as jnp\n"
-        "from jumbo_mae_tpu_tpu.models.layers import resolve_attn_impl\n"
+        "from jumbo_mae_tpu_tpu.ops.attention import lowering\n"
         "from jumbo_mae_tpu_tpu.ops.pallas import attention as A\n"
-        "print(resolve_attn_impl('auto', backend='tpu', seq_len=300, dropout=0.0,"
-        " deterministic=True))\n" + _KERNEL_PROGRAM[kernel]
+        "print(lowering(backend='tpu', seq_len=300, probs_needed=False, seq_shards=1))\n"
+        + _KERNEL_PROGRAM[kernel]
     )
     clean = {k: v for k, v in os.environ.items() if not k.startswith("JUMBO_")}
     texts = []
@@ -179,27 +134,44 @@ def test_kernel_program_ignores_the_environment(kernel):
     assert texts[0] == texts[1]
 
 
-def test_resolve_attn_impl_auto_policy():
-    """The auto policy (round 5): flash on TPU at long sequence unless
-    dropout is active in training; einsum otherwise; explicit impls pass
-    through untouched."""
-    from jumbo_mae_tpu_tpu.models.layers import (
-        AUTO_FLASH_MIN_SEQ,
-        resolve_attn_impl,
-    )
+PACKAGE = Path(__file__).resolve().parent.parent / "jumbo_mae_tpu_tpu"
 
-    r = lambda **kw: resolve_attn_impl(
-        kw.pop("impl", "auto"),
-        backend=kw.pop("backend", "tpu"),
-        seq_len=kw.pop("seq_len", AUTO_FLASH_MIN_SEQ),
-        dropout=kw.pop("dropout", 0.0),
-        deterministic=kw.pop("deterministic", False),
-    )
-    assert r() == "flash"                                   # long seq, tpu
-    assert r(seq_len=AUTO_FLASH_MIN_SEQ - 1) == "einsum"    # short seq
-    assert r(backend="cpu") == "einsum"                     # not tpu
-    assert r(dropout=0.1) == "einsum"                       # train dropout
-    assert r(dropout=0.1, deterministic=True) == "flash"    # eval dropout ok
-    assert r(impl="einsum", seq_len=4096) == "einsum"       # explicit wins
-    assert r(impl="flash", seq_len=8) == "flash"
-    assert r(impl="ring", backend="cpu") == "ring"
+
+@pytest.mark.parametrize(
+    "backend,seq_len,probs_needed,seq_shards",
+    itertools.product(("tpu", "cpu"), (199, 511, 512, 787), (False, True), (1, 2)),
+)
+def test_the_rule_answers_from_what_a_call_can_see(backend, seq_len, probs_needed, seq_shards):
+    """``ops/attention.lowering``, the one rule: the einsum form wherever the
+    probabilities themselves are needed (a mask, or dropout active in the
+    call); else the ring over a split sequence; else the flash kernels on the
+    TPU from 512 tokens up (round 5's two measured points, 199 and 787, lie
+    either side); else the einsum form. On an unsplit mesh that is what
+    ``attn_impl="auto"`` resolved to before the option went (PR 43)."""
+    got = lowering(backend=backend, seq_len=seq_len, probs_needed=probs_needed,
+                   seq_shards=seq_shards)
+    assert AUTO_FLASH_MIN_SEQ == 512
+    if probs_needed:
+        assert got == "einsum"
+    elif seq_shards > 1:
+        assert got == "ring"
+    else:
+        assert got == ("flash" if backend == "tpu" and seq_len >= 512 else "einsum")
+
+
+def test_the_language_models_import_nothing_from_the_vits_layers():
+    tree = ast.parse((PACKAGE / "models" / "lm.py").read_text())
+    imported = [n.module if isinstance(n, ast.ImportFrom) else a.name
+                for n in ast.walk(tree) if isinstance(n, (ast.Import, ast.ImportFrom))
+                for a in n.names]
+    assert imported and not [m for m in imported if m and m.endswith("models.layers")]
+
+
+def test_no_configuration_names_a_lowering():
+    """No class of the package (every configuration dataclass among them) has
+    a field named for the removed options."""
+    fields = {t.id for path in PACKAGE.rglob("*.py")
+              for cls in ast.walk(ast.parse(path.read_text())) if isinstance(cls, ast.ClassDef)
+              for stmt in cls.body if isinstance(stmt, ast.AnnAssign)
+              for t in [stmt.target] if isinstance(t, ast.Name)}
+    assert "dtype" in fields and not fields & {"attn_impl", "ring_inner"}

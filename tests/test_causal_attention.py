@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from jumbo_mae_tpu_tpu.models.config import maybe_remat
-from jumbo_mae_tpu_tpu.ops.flash_attention import xla_causal_attention
+from jumbo_mae_tpu_tpu.ops.attention import xla_causal_attention
 from jumbo_mae_tpu_tpu.ops.pallas.attention import (
     CAUSAL_LSE_NAME,
     CAUSAL_OUT_NAME,

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jumbo_mae_tpu_tpu.ops.flash_attention import causal_attention, xla_causal_attention
+from jumbo_mae_tpu_tpu.ops import attention as attention_ops
+from jumbo_mae_tpu_tpu.ops.attention import causal_attention, xla_causal_attention
 from jumbo_mae_tpu_tpu.ops.pallas import attention as pallas_attention
 from jumbo_mae_tpu_tpu.ops.pallas.attention import (
     CAUSAL_BLOCK,
@@ -258,10 +259,14 @@ def test_a_window_of_four_blocks_at_the_longest_row_one_span_holds():
 def test_the_dispatcher_hands_the_window_and_the_groups_to_either_form(monkeypatch):
     (q, _, k, _, v), _ = _inputs(6, 1, 4, 2, 24, False)
     want = _dense(q, None, k, None, v, 7)
-    np.testing.assert_allclose(causal_attention(q, None, k, None, v, impl="einsum", window=7),
+    np.testing.assert_allclose(causal_attention(q, None, k, None, v, window=7),  # the CPU's form
                                want, rtol=2e-5, atol=2e-6)
-    real = pallas_attention.pallas_causal_attention
+    real, calls = pallas_attention.pallas_causal_attention, []
     monkeypatch.setattr(pallas_attention, "pallas_causal_attention",
-                        lambda *xs, window=None: real(*xs, 8, True, window))
-    np.testing.assert_allclose(causal_attention(q, None, k, None, v, impl="flash", window=7),
+                        lambda *xs, window=None: calls.append(window) or real(*xs, 8, True, window))
+    # what the rule reads, as a chip would answer for a sequence this short
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(attention_ops, "AUTO_FLASH_MIN_SEQ", 1)
+    np.testing.assert_allclose(causal_attention(q, None, k, None, v, window=7),
                                want, rtol=2e-5, atol=2e-6)
+    assert calls == [7]

@@ -105,20 +105,18 @@ def test_loss_and_every_gradient_leaf_match_the_reference():
 
 @pytest.mark.parametrize("kind,heads", [("full_attention", 6), ("sliding_attention", 8)])
 def test_a_layer_through_the_interpreted_kernels_is_the_references(kind, heads, monkeypatch):
-    """One attention layer of each kind with the core resolved to the Pallas
-    kernels (interpreted, blocks of 8): the path the chip takes, against the
+    """One attention layer of each kind with the Pallas kernels (interpreted,
+    blocks of 8) for its core: the path the chip takes, against the
     reference's every-pair form."""
-    from jumbo_mae_tpu_tpu.ops.pallas import attention as pallas_attention
+    from jumbo_mae_tpu_tpu.ops.pallas.attention import pallas_causal_attention
 
     config, cfg, params, *_ = _setup()
     layer = config["layer_types"].index(kind)
-    real = pallas_attention.pallas_causal_attention
-    monkeypatch.setattr(pallas_attention, "pallas_causal_attention",
-                        lambda *xs, window=None: real(*xs, 8, True, window))
+    monkeypatch.setattr(lm, "causal_attention", lambda *xs, impl=None, window=None:
+                        pallas_causal_attention(*xs, 8, True, window))
     x = jax.random.normal(jax.random.key(3), (2, 27, cfg.dim), jnp.float32)
     p = params[f"block_{layer}"]["attn"]
-    module = lm.GroupedQueryAttention(cfg.replace(attn_impl="flash"), heads,
-                                      kind == "sliding_attention")
+    module = lm.GroupedQueryAttention(cfg, heads, kind == "sliding_attention")
     got = module.apply({"params": p}, x)
     ops = ref_model.Ops()
     for row in range(x.shape[0]):

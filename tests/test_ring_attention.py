@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jumbo_mae_tpu_tpu.ops.flash_attention import xla_attention
+from jumbo_mae_tpu_tpu.ops.attention import xla_attention
 from jumbo_mae_tpu_tpu.parallel import MeshConfig, create_mesh
 from jumbo_mae_tpu_tpu.parallel.ring_attention import (
     ring_attention_sharded,
@@ -75,22 +75,33 @@ def test_ring_self_attention_no_mesh_fallback():
     )
 
 
-def test_vit_forward_ring_equals_einsum(devices):
-    """Full Jumbo ViT forward with attn_impl='ring' under a seq-sharded mesh
-    must match the einsum implementation (uneven 3+16-token sequence)."""
+def test_vit_forward_ring_equals_einsum(devices, monkeypatch):
+    """Full Jumbo ViT forward under a seq-sharded mesh, where every layer's
+    attention is the ring, must match the einsum form the same model takes
+    with no mesh (uneven 3+16-token sequence)."""
+    import importlib
+
     from jumbo_mae_tpu_tpu.models import JumboViT, preset
+
+    calls = []
+    # by name: the package's attribute ``ring_attention`` is the function
+    ring_module = importlib.import_module("jumbo_mae_tpu_tpu.parallel.ring_attention")
+    real = ring_module.ring_self_attention
+    monkeypatch.setattr(ring_module, "ring_self_attention",
+                        lambda *xs, **kw: calls.append(kw) or real(*xs, **kw))
 
     mesh = create_mesh(MeshConfig(data=2, fsdp=1, seq=4))
     images = jnp.asarray(
         np.random.default_rng(0).integers(0, 255, (4, 32, 32, 3)), jnp.float32
     ) / 255.0
     cfg = preset("vit_t16", image_size=32, patch_size=8, labels=10, dtype="float32")
-    model_ein = JumboViT(cfg.replace(attn_impl="einsum"))
-    params = model_ein.init(jax.random.key(0), images)
-    want = model_ein.apply(params, images)
-    model_ring = JumboViT(cfg.replace(attn_impl="ring"))
+    model = JumboViT(cfg)
+    params = model.init(jax.random.key(0), images)
+    want = model.apply(params, images)
+    assert not calls
     with jax.sharding.set_mesh(mesh):
-        got = jax.jit(model_ring.apply)(params, images)
+        got = jax.jit(model.apply)(params, images)
+    assert len(calls) == cfg.layers
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
     )

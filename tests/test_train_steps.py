@@ -167,17 +167,15 @@ class TestPretrainStep:
 
     @pytest.mark.slow  # heavy compile; full suite covers it
     def test_seq_parallel_ring_matches_single_device(self):
-        # Sequence parallelism: same model weights, attn_impl="ring" on a
-        # (data=2, seq=4) mesh vs einsum on one device. Identical RNG streams
-        # → identical masking → losses must agree.
+        # Sequence parallelism: same model and weights on a (data=2, seq=4)
+        # mesh, whose split sequence makes attention the ring, vs einsum on
+        # one device. Identical RNG streams → identical masking → losses
+        # must agree.
         batch = batch_of(16)
         _, s1, _, step1 = build(
             MeshConfig(data=1, fsdp=1), pretrain_module(), "pretrain", batch=batch
         )
-        ring_module = MAEPretrainModel(
-            TINY.replace(mask_ratio=0.75, labels=None, attn_impl="ring"),
-            TINY_DEC.replace(attn_impl="ring"),
-        )
+        ring_module = pretrain_module()
         ref_losses = []
         for _ in range(2):
             s1, m1 = step1(s1, batch)
@@ -208,10 +206,7 @@ class TestPretrainStep:
         s1, m1 = step1(s1, batch)
         want = float(m1["loss"])
 
-        module = MAEPretrainModel(
-            TINY.replace(mask_ratio=0.75, labels=None, attn_impl="ring"),
-            TINY_DEC.replace(attn_impl="ring"),
-        )
+        module = pretrain_module()
         mesh = create_mesh(MeshConfig(data=1, fsdp=2, tensor=2, seq=2))
         tx = make_optimizer(OPT, global_batch_size=256)
         with jax.sharding.set_mesh(mesh):
