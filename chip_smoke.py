@@ -299,21 +299,28 @@ def phase_kernels(shapes=KERNEL_SHAPES, *, interpret: bool = False) -> dict:
 # (batch, heads, seq, qk width, shared rope width, v width[, key/value heads,
 # window]), at a length that is no multiple of the kernel's block: latent
 # attention's published head, and a grouped-query head of one score part
-# whose 512-token window is walked as a band of blocks
-CAUSAL_SHAPES = ((1, 4, 2148, 128, 64, 128), (1, 16, 2148, 128, 0, 128, 2, 512))
+# whose 512-token window is walked as a band of blocks, and a full one whose
+# heads are 64 wide (half a lane tile) at a group of 4
+CAUSAL_SHAPES = ((1, 4, 2148, 128, 64, 128), (1, 16, 2148, 128, 0, 128, 2, 512),
+                 (1, 8, 2148, 64, 0, 64, 2, None))
+# (batch, heads, seq, width) of the rotate-half rope kernel's operand: 128 and
+# 64 wide (ops/pallas/rope.py takes whole and half lane tiles)
+ROPE_SHAPES = ((1, 4, 2048, 128), (1, 4, 2048, 64))
 # (rows, k, n, group sizes): one expert takes most rows, one takes none
 GROUPED_SHAPE = (4096, 2048, 1536, (2900, 0, 517, 200, 33, 8, 1, 300))
 
 
-def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, *,
+def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, rope=ROPE_SHAPES, *,
                      interpret: bool = False) -> dict:
     """The causal flash kernels (unequal qk and v widths, the shared rope
-    key; grouped key/value heads under a window) forward+backward against the
-    einsum form, and the grouped product forward+backward against
+    key; grouped key/value heads under a window; 64-wide heads) forward+backward
+    against the einsum form, the rotate-half rope kernel against its
+    ``jax.numpy`` form, and the grouped product forward+backward against
     ``lax.ragged_dot``; worst relative errors."""
     import jax
     import jax.numpy as jnp
 
+    from jumbo_mae_tpu_tpu.models.lm import Rope, rope_half
     from jumbo_mae_tpu_tpu.ops.attention import xla_causal_attention
     from jumbo_mae_tpu_tpu.ops.grouped_matmul import grouped_matmul
     from jumbo_mae_tpu_tpu.ops.pallas.attention import pallas_causal_attention
@@ -345,6 +352,18 @@ def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, *,
                 weigh(lambda *xs, window=window: pallas_causal_attention(
                     *xs, block, interpret, window)),
                 weigh(lambda *xs, window=window: xla_causal_attention(*xs, window)), args)
+
+    for b, h, s, d in rope:
+        keys = jax.random.split(jax.random.key(d), 2)
+        x = jax.random.normal(keys[0], (b, h, s, d)).astype(jnp.bfloat16)
+        w = jax.random.normal(keys[1], (b, h, s, d))
+        turn = Rope(rope_theta=1e6)
+        # a 3-D operand takes the jax.numpy form on every backend
+        compare(f"rope@{s}x{d}",
+                lambda x, w=w: ((rope_half(x, turn, interpret=interpret).astype(jnp.float32)
+                                 * w).sum(), None),
+                lambda x, w=w: ((jnp.stack([rope_half(row, turn) for row in x])
+                                 .astype(jnp.float32) * w).sum(), None), (x,))
 
     m, k, n, sizes = grouped
     keys = jax.random.split(jax.random.key(m), 3)
@@ -430,14 +449,17 @@ def head_product_calls(text: str, rows: int) -> dict:
     return {**calls, "widest_bytes": widest}
 
 
-def check_step_runs_the_head_three_times(programs: dict, lm, tokens: int) -> dict:
+def check_step_runs_the_head_three_times(programs: dict, lm, tokens: int, seq: int) -> dict:
     """The step program among ``programs`` runs each head's product three
     times, all in the forward pass (logits, dX and dW a tile of tokens at a
     time: ``ops/head_loss.py``), never again under a remat or in the backward
     pass, on every backend: the tiles are a loop XLA compiles. On the chip,
     at the recipe's own sizes, it holds nothing over the rows held that is
     larger than one tile's float32 logits or the float32 kernel (at the
-    rehearsal's toy widths other axes share the rows' length)."""
+    rehearsal's toy widths other axes share the rows' length; so they do
+    in a recipe that holds as many rows as a sequence has tokens, where an
+    activation cannot be told from the logits by its shape and the size is
+    not checked)."""
     import jax
 
     from jumbo_mae_tpu_tpu.ops.head_loss import head_tile
@@ -448,7 +470,7 @@ def check_step_runs_the_head_three_times(programs: dict, lm, tokens: int) -> dic
     check({k: calls[k] for k in want} == want,
           f"the step runs the head's product {calls}, not {want}")
     most = 4 * rows * max(head_tile(tokens, rows), lm.dim)
-    check(jax.default_backend() != "tpu" or 0 < calls["widest_bytes"] <= most,
+    check(jax.default_backend() != "tpu" or rows == seq or 0 < calls["widest_bytes"] <= most,
           f"the step holds {calls['widest_bytes']} B over the rows held, over {most}")
     return calls
 
@@ -495,8 +517,9 @@ def check_step_runs_each_causal_kernel_once_a_block(programs: dict, lm) -> dict:
 
     check("train_step" in programs, "cli.train noted no step program")
     calls = causal_kernel_calls(programs["train_step"].as_text())
-    # a linear-attention block has no causal core
-    want = lm.layers - lm.kda_layers + lm.mtp_layers if jax.default_backend() == "tpu" else 0
+    # a linear-attention block and a short-convolution block have no causal core
+    cores = sum(kind not in ("kda", "conv") for kind in lm.kinds) + lm.mtp_layers
+    want = cores if jax.default_backend() == "tpu" else 0
     check(set(calls.values()) == {want},
           f"the step calls the causal kernels {calls}, not {want} times each")
     return calls
@@ -531,7 +554,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     kda_calls = check_step_runs_the_kda_kernels(programs, lm)
     rope_calls = check_step_runs_the_rope_kernel(programs, lm)
     head_calls = check_step_runs_the_head_three_times(
-        programs, lm, cfg.run.train_batch_size * cfg.data.seq_len)
+        programs, lm, cfg.run.train_batch_size * cfg.data.seq_len, cfg.data.seq_len)
     programs.clear()  # or the step's executable outlives the phase
     records = _read_metrics(out_dir / cfg.run.name)
     losses = _logged_losses(records, 1, steps, "lm_train")
@@ -586,6 +609,9 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
                        in lm.attn_pairs(cfg.data.seq_len).items()},
         "attn_heads": {kind: {"held": held, "published": published}
                        for kind, (held, published) in lm.attn_heads().items()},
+        "layers_by_kind": lm.layers_by_kind,
+        "qk_norm": lm.qk_norm,
+        "tie_embeddings": lm.tie_embeddings,
         "moe_held_share_min_max": [round(min(share), 4), round(max(share), 4)],
         "moe_imbalance_max": round(max(r["train/moe_imbalance"] for r in by_step.values()), 3),
         **family,

@@ -950,9 +950,13 @@ def train(cfg: TrainConfig) -> dict:
             for kind, counts in enc_cfg.attn_heads().items()
             for what, count in zip(("held", "published"), counts)
         }
-        logger.log(heads, step=start_step)
+        # and the trunk blocks of each mixer kind (a conv block has no heads)
+        layers = {f"train/layers_{kind}": n for kind, n in enc_cfg.layers_by_kind.items()}
+        logger.log(heads | layers, step=start_step)
         if is_main:
             print(f"[train] attention heads a kind: {heads}")
+            print(f"[train] blocks a mixer kind: {layers}; qk_norm {enc_cfg.qk_norm}, "
+                  f"tie_embeddings {enc_cfg.tie_embeddings}")
             # static too: the expert layers' variants
             print(f"[train] expert layers: the router reads {enc_cfg.router_input} and scores "
                   f"{enc_cfg.router_scoring}; an expert's gate is {enc_cfg.expert_act}")

@@ -1,7 +1,8 @@
-"""Decoder-only sparse-expert language models, five families from one set of
-blocks; each trunk block's attention is one of four kinds, and
-``MlaMoeConfig.kinds`` is the one list that says which (a family is a way to
-fill it). **All-MLA** (the DeepSeek-V3 family's block, as ``JoyAI-LLM-Flash``'s
+"""Decoder-only sparse-expert language models, six families from one set of
+blocks; each trunk block's token mixer is one of five kinds — four of
+attention and the gated short convolution — and ``MlaMoeConfig.kinds`` is the
+one list that says which (a family is a way to fill it). **All-MLA** (the
+DeepSeek-V3 family's block, as ``JoyAI-LLM-Flash``'s
 ``config.json`` sizes it): multi-head latent attention in every block,
 sigmoid-routed experts beside a shared expert, one multi-token prediction
 (MTP) module. **Hybrid** (``Ling-3.0-flash``, ``model_type: bailing_hybrid``):
@@ -24,12 +25,43 @@ smallthinker_21b_instruct``): grouped-query blocks again, one rope-free
 ``full_attention`` block to three ``sliding_attention`` blocks with rope, no
 gate on the attention output, and an expert layer of the other variants
 below (``router_input``, ``router_scoring``, ``expert_act``, no shared
-expert, no dense layer). The
+expert, no dense layer). **Short convolution beside grouped-query**
+(``LFM2-24B-A2B``, ``model_type: lfm2_moe``): ``layer_types`` names
+``"conv"`` three layers in four — a mixer that is neither attention nor a
+recurrence with a matrix state, with no heads, no rope and no score — and
+``full_attention`` the fourth, with 64-wide heads and a per-head RMSNorm on
+``q`` and ``k`` (``qk_norm``); sigmoid-and-bias routing, no shared expert,
+and a head that is the embedding's rows (``tie_embeddings``). The
 defaults are the first family's; its parameter tree, scopes and program do
 not depend on the others' fields.
 
 Pre-norm residual blocks with RMSNorm (eps ``rms_eps``): ``x += A_i(norm(x))``,
 ``x += F_i(norm(x))``. No bias anywhere.
+
+The LFM2 family whole (``d = dim``, every projection without bias, RMSNorm
+with ``rms_eps`` = the source's ``norm_eps``). Block ``i``: ``h = x +
+mixer_i(RMSNorm_op(x))``, ``out = h + ffn_i(RMSNorm_ffn(h))`` (``ln1``,
+``ln2``); ``ffn_i`` is the dense SwiGLU of ``dense_hidden`` for ``i <
+first_k_dense`` (``num_dense_layers``), the expert layer after. After the
+last block one RMSNorm (``ln``; the source's ``embedding_norm``), then the
+head. **``conv`` mixer** (``ShortConv``): ``z = u W_in`` with ``W_in`` (d,
+3d); ``B, C, x̃`` = the three d-wide thirds of ``z`` in that order; ``c_t =
+Σ_{j=0..K−1} w_j ⊙ (B ⊙ x̃)_{t−K+1+j}`` with zero history before the row's
+first position (one document a sequence), ``w`` (K, d) one filter a channel,
+``K = conv_taps`` (the source's ``conv_L_cache``, 3), no activation; ``y =
+(C ⊙ c) W_out``, ``W_out`` (d, d). The filter is ``ops/kda.causal_conv`` with
+no activation: float32 inside, its gradient written out from ``B ⊙ x̃`` and
+``w`` alone. **``full_attention`` mixer**: the grouped-query attention below
+with ``qk_norm``: ``q ← RMSNorm_e(q)``, ``k ← RMSNorm_e(k)`` per head over
+``e = head_dim``, each with its own learnt scale of ``e``, before the rope
+and before the ``e^-½``; rotate-half rope on all ``e`` dimensions; no gate.
+**Expert layer**: the ``sigmoid_bias`` rule below, ``n_group`` 1, weights
+``factor · s_i / Σ_chosen s`` (the source divides by ``Σ_chosen s + 1e-6``:
+the sum of four sigmoids is above 1e-2 wherever float32 can tell, and the
+code here adds nothing), no shared expert. **Head**: logits ``=
+RMSNorm(h) Eᵀ`` over the rows of the embedding ``E`` held: with
+``tie_embeddings`` there is no ``head`` parameter, and ``E``'s gradient is
+the lookup's scatter plus the head's ``dW`` transposed.
 
 MLA: ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` -> heads x (nope ‖ rope) — or
 ``q = x W_q`` where ``q_lora_rank`` is None; ``[c_kv ‖ k_pe] = x W_kva``,
@@ -41,7 +73,9 @@ adjacent pairs; causal ``z = softmax(q kᵀ (nope + rope)^-½) v``; with
 Grouped-query attention, block ``i`` with ``H = heads_per_layer[i]``, ``G =
 kv_heads``, ``d = head_dim``: ``q = x W_q`` -> (H, d), ``k = x W_k``, ``v = x
 W_v`` -> (G, d); query head ``h`` reads key/value head ``h // (H / G)``. No
-q/k norm. A kind whose ``rope_parameters`` entry is None has no rotary
+q/k norm unless ``qk_norm`` (then a per-head RMSNorm of ``q`` and of ``k``,
+a learnt scale of ``d`` each, before the rope). A kind whose
+``rope_parameters`` entry is None has no rotary
 embedding: ``q`` and ``k`` go to the core as projected. Otherwise, rotary
 embedding with the **rotate-half pairing** (dimension ``j``
 with ``j + r/2``) on the first ``r = partial_rotary_factor · d`` dimensions
@@ -54,7 +88,8 @@ ln(L₀ / (2π β_fast)) / (2 ln θ)⌋``, ``high = ⌈r ln(L₀ / (2π β_slow)
 ``inv_freq_j = (1 − γ_j) f_j + γ_j f_j / factor``, and multiplies ``cos`` and
 ``sin`` by ``attention_factor``. In float32, rounded once to the compute
 dtype (``rope_half``): on the TPU, where ``q`` and ``k`` are head-major with
-``d`` a whole number of 128-lane tiles and a sequence that cuts into blocks
+``d`` a whole number of 128-lane tiles (or the half tile of a 64-wide head)
+and a sequence that cuts into blocks
 of 16 rows, one Pallas pass that reads a block, turns it in VMEM and writes
 it, and is its own transpose with the sines negated
 (``ops/pallas/rope.py``); anywhere else the same formula in ``jax.numpy``.
@@ -178,13 +213,16 @@ from jumbo_mae_tpu_tpu.obs.trace import (
     SCOPE_MTP_MERGE,
     SCOPE_ROPE,
     SCOPE_ROUTER,
+    SCOPE_SCONV_IN,
+    SCOPE_SCONV_MIX,
+    SCOPE_SCONV_OUT,
     SCOPE_SHARED_EXPERT,
     SCOPE_SWA_CORE,
 )
 from jumbo_mae_tpu_tpu.ops.attention import causal_attention
 from jumbo_mae_tpu_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, grouped_outer
 from jumbo_mae_tpu_tpu.ops.head_loss import head_loss
-from jumbo_mae_tpu_tpu.ops.kda import causal_conv_silu, kda_chunked
+from jumbo_mae_tpu_tpu.ops.kda import causal_conv, kda_chunked
 
 # the counters an expert layer reports, in the order of its stats vector
 MOE_COUNTERS = ("rows_min", "rows_mean", "rows_max", "imbalance", "held_share", "dropped",
@@ -199,6 +237,9 @@ KDA_COUNTERS = ("state_absmax", "decay_mean", "beta_max", "neg_eig_share")
 KDA_UNIT_EPS = 1e-6  # under the root of q's and k's L2 norm; not the RMSNorms' rms_eps
 GQA_KINDS = ("full_attention", "sliding_attention")
 ATTENTION_KINDS = ("kda", "mla", *GQA_KINDS)
+# a block's token mixer: one of the attention kinds, or the gated short
+# convolution, which has no heads, no rope and no (query, key) pairs
+MIXER_KINDS = (*ATTENTION_KINDS, "conv")
 
 
 @dataclass(frozen=True)
@@ -286,7 +327,7 @@ class MlaMoeConfig:
     # the clamped SwiGLU is not implemented: a non-zero limit is refused
     expert_swiglu_limit: float = 0.0
     shared_expert_swiglu_limit: float = 0.0
-    # each block's attention kind, of ATTENTION_KINDS; None = by layer_group_size
+    # each block's mixer kind, of MIXER_KINDS; None = by layer_group_size
     # (``kinds``). A grouped-query block i has heads_per_layer[i] query heads
     # (``heads`` where the list is None) over kv_heads key/value heads of
     # head_dim; a kind's rope_parameters entry may be None: no rotary embedding
@@ -303,6 +344,12 @@ class MlaMoeConfig:
     router_input: str = "ffn_norm"  # or "block_input": before the input norm and attention
     router_scoring: str = "sigmoid_bias"  # or "softmax_topk": softmax over the chosen logits
     expert_act: str = "silu"  # or "relu"
+    # the short-convolution family: a "conv" entry of layer_types is the gated
+    # short-convolution mixer of conv_taps taps (conv_L_cache); a per-head
+    # RMSNorm on a grouped-query block's q and k; the head is the embedding
+    conv_taps: int = 3
+    qk_norm: bool = False
+    tie_embeddings: bool = False
 
     grad_ckpt: bool = True
     remat_policy: RematPolicy = "none"
@@ -359,10 +406,10 @@ class MlaMoeConfig:
         if not (len(self.layer_types) == len(heads) == self.layers):
             raise ValueError(f"layer_types and heads_per_layer must name each of the "
                              f"{self.layers} layers")
-        if (set(self.layer_types) - set(ATTENTION_KINDS)
+        if (set(self.layer_types) - set(MIXER_KINDS)
                 or not {self.layer_types[i] for i in grouped} <= dict(ropes).keys() <= set(
                     GQA_KINDS)):
-            raise ValueError(f"layer_types name the kinds {ATTENTION_KINDS}, and rope_parameters "
+            raise ValueError(f"layer_types name the kinds {MIXER_KINDS}, and rope_parameters "
                              f"each of {GQA_KINDS} among them (a Rope, or None for no rotation)")
         if any(heads[i] % self.kv_heads for i in grouped):
             raise ValueError(f"query heads {heads} are no multiple of {self.kv_heads} "
@@ -384,7 +431,7 @@ class MlaMoeConfig:
 
     @property
     def kinds(self) -> tuple[str, ...]:
-        """Each trunk block's attention kind, of ``ATTENTION_KINDS``: the one
+        """Each trunk block's mixer kind, of ``MIXER_KINDS``: the one
         list every reader goes by. ``layer_types`` where given; else filled by
         the hybrid family's rule (block ``i`` is MLA when ``i + 1`` is a
         multiple of ``layer_group_size``, KDA otherwise; 0: every block MLA)."""
@@ -425,7 +472,8 @@ class MlaMoeConfig:
 
         held = {}
         for i, kind in enumerate(self.kinds + ("mla",) * self.mtp_layers):
-            held.setdefault(kind, of_block(i, kind))
+            if kind != "conv":  # a short-convolution block has no heads
+                held.setdefault(kind, of_block(i, kind))
         published = dict(self.heads_published or ())
         return {kind: (n, published.get(kind, n)) for kind, n in sorted(held.items())}
 
@@ -436,11 +484,16 @@ class MlaMoeConfig:
         the mask keeps (``ops/pallas/attention.causal_pairs``). Static."""
         from jumbo_mae_tpu_tpu.ops.pallas.attention import causal_pairs
 
-        kinds = set(self.kinds) - {"kda"}
+        kinds = set(self.kinds) - {"kda", "conv"}  # neither has (query, key) pairs
         if self.mtp_layers:
             kinds.add("mla")
         window = lambda kind: self.sliding_window if kind == "sliding_attention" else None
         return {kind: causal_pairs(seq, window(kind)) for kind in sorted(kinds)}
+
+    @property
+    def layers_by_kind(self) -> dict:
+        """``{kind: trunk blocks of that mixer kind}``, sorted. Static."""
+        return {kind: self.kinds.count(kind) for kind in sorted(set(self.kinds))}
 
     @property
     def moe_counters(self) -> tuple[str, ...]:
@@ -581,8 +634,11 @@ class GroupedQueryAttention(nn.Module):
         h, g, d = self.heads, cfg.kv_heads, cfg.head_dim
         rope = cfg.rope("sliding_attention" if self.sliding else "full_attention")
         with jax.named_scope(SCOPE_GQA_PROJ):
-            q = Proj((cfg.dim, h, d), "bsd,dhe->bhse", cfg, name="q")(x) * d**-0.5
-            k = Proj((cfg.dim, g, d), "bsd,dhe->bhse", cfg, name="k")(x)
+            # with qk_norm: per head, over head_dim, one learnt scale each
+            norm = lambda name, t: (RMSNorm(cfg.rms_eps, cfg.compute_dtype, name=name)(t)
+                                    if cfg.qk_norm else t)
+            q = norm("q_norm", Proj((cfg.dim, h, d), "bsd,dhe->bhse", cfg, name="q")(x)) * d**-0.5
+            k = norm("k_norm", Proj((cfg.dim, g, d), "bsd,dhe->bhse", cfg, name="k")(x))
             v = Proj((cfg.dim, g, d), "bsd,dhe->bhse", cfg, name="v")(x)
             if cfg.attn_gate:
                 gate = Proj((cfg.dim, h), "bsd,dh->bhs", cfg, name="gate")(x)
@@ -598,6 +654,28 @@ class GroupedQueryAttention(nn.Module):
             if cfg.attn_gate:
                 z = _head_gate(z, gate)
             return Proj((h, d, cfg.dim), "bhsd,hdm->bsm", cfg, name="out")(z)
+
+
+class ShortConv(nn.Module):
+    """The gated short-convolution mixer (module docstring): ``y = (C ⊙
+    conv_K(B ⊙ x̃)) W_out`` with ``B, C, x̃`` the thirds of ``x W_in``; a
+    depth-wise causal filter of ``cfg.conv_taps`` taps, no activation, no
+    heads."""
+
+    cfg: MlaMoeConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, d = self.cfg, self.cfg.dim
+        with jax.named_scope(SCOPE_SCONV_IN):
+            z = Proj((d, 3 * d), "bsd,de->bse", cfg, name="in_proj")(x)
+        with jax.named_scope(SCOPE_SCONV_MIX):
+            b, c, u = z[..., :d], z[..., d:2 * d], z[..., 2 * d:]
+            make = lambda key: {"kernel": _normal(cfg)(key, (cfg.conv_taps, d), jnp.float32)}
+            w = self.param("conv", make)["kernel"]  # a leaf conv/kernel: one filter a channel
+            y = c * causal_conv(b * u, w, None)
+        with jax.named_scope(SCOPE_SCONV_OUT):
+            return Proj((d, d), "bsd,de->bse", cfg, name="out_proj")(y)
 
 
 class LatentAttention(nn.Module):
@@ -698,7 +776,7 @@ class KdaAttention(nn.Module):
                     key, (cfg.kda_conv, h, dh), f32, -bound, bound)}
                 return self.param(name, make)["kernel"]
 
-            q, k, v = (causal_conv_silu(u, filt(f"{n}_conv")) for n, u in
+            q, k, v = (causal_conv(u, filt(f"{n}_conv"), "silu") for n, u in
                        (("q", q), ("k", k), ("v", v)))
         with jax.named_scope(SCOPE_KDA_GATE):
             q = (_unit_norm(q, KDA_UNIT_EPS) * dh**-0.5).astype(dtype)
@@ -963,9 +1041,10 @@ class SparseExperts(nn.Module):
 
 
 class Block(nn.Module):
-    """One pre-norm residual block: attention of ``kind``
-    (``MlaMoeConfig.attention_kind``: latent, linear, or grouped-query of
-    ``heads`` query heads, full or sliding), then the dense MLP
+    """One pre-norm residual block: the mixer of ``kind``
+    (``MlaMoeConfig.attention_kind``: latent, linear, grouped-query of
+    ``heads`` query heads, full or sliding, or the gated short convolution),
+    then the dense MLP
     (``sparse=False``) or the expert layer, whose router reads the block's
     input where ``router_input`` says so. Returns ``(x, stats,
     kda_stats)``: the expert layer's counters and the linear-attention
@@ -988,6 +1067,8 @@ class Block(nn.Module):
             x = x + y
         elif self.kind == "mla":
             x = x + LatentAttention(cfg, name="attn")(norm("ln1")(x))
+        elif self.kind == "conv":
+            x = x + ShortConv(cfg, name="conv")(norm("ln1")(x))
         else:
             attn = GroupedQueryAttention(cfg, self.heads, self.kind == "sliding_attention",
                                          name="attn")
@@ -1028,7 +1109,8 @@ class MlaMoeLM(nn.Module):
         self.blocks = [block(cfg, sparse=i >= cfg.first_k_dense, kind=cfg.kinds[i],
                              heads=heads[i], name=f"block_{i}") for i in range(cfg.layers)]
         self.ln = RMSNorm(cfg.rms_eps, cfg.compute_dtype, name="ln")
-        self.head = Proj((cfg.dim, cfg.rows[1]), "bsd,dv->bsv", cfg, name="head")
+        if not cfg.tie_embeddings:
+            self.head = Proj((cfg.dim, cfg.rows[1]), "bsd,dv->bsv", cfg, name="head")
         if cfg.mtp_layers:
             self.mtp_embed_norm = RMSNorm(cfg.rms_eps, cfg.compute_dtype, name="mtp_embed_norm")
             self.mtp_hidden_norm = RMSNorm(cfg.rms_eps, cfg.compute_dtype, name="mtp_hidden_norm")
@@ -1062,8 +1144,17 @@ class MlaMoeLM(nn.Module):
             hidden.append(y)
         return hidden, stats, kda
 
+    def _head_kernel(self):
+        """The head's (dim, rows held) kernel in the compute dtype: its own
+        parameter, or with ``tie_embeddings`` the embedding's rows
+        transposed, so that the embedding's gradient is the lookup's and the
+        head's ``dW`` added."""
+        kernel = self.embedding.T if self.cfg.tie_embeddings else self.head.kernel
+        return kernel.astype(self.cfg.compute_dtype)
+
     def _logits(self, h):
-        return self.head(self.ln(h)).astype(jnp.float32)
+        h = self.ln(h).astype(self.cfg.compute_dtype)
+        return jnp.einsum("bsd,dv->bsv", h, self._head_kernel()).astype(jnp.float32)
 
     def logits(self, tokens, deterministic: bool = True):
         with jax.named_scope(SCOPE_LM_HEAD):
@@ -1081,7 +1172,7 @@ class MlaMoeLM(nn.Module):
         with jax.named_scope(SCOPE_LM_HEAD):
             # the kernel enters in the compute dtype, as a ``Proj``'s does: its
             # gradient is one rounding of a float32 sum, as the plain product's
-            kernel = self.head.kernel.astype(cfg.compute_dtype)
+            kernel = self._head_kernel()
             for i, (h, share) in enumerate(zip(hidden, shares)):
                 total, nll = head_loss(
                     self.ln(h).reshape(batch * seq, cfg.dim), kernel,

@@ -126,8 +126,12 @@ def lm_flops_per_token(cfg, seq: int, *, training: bool = True) -> float:
     the recurrence's three products with its (d_k, d_v) state, ``6 · d_k ·
     d_v`` a head, whatever ``seq`` and the chunking; a routed expert is
     counted for the share of (token, expert) pairs expected here (``k · held
-    / experts``); the embedding is a lookup and the short convolutions are
-    elementwise; backward = 2 x forward, recomputation not counted."""
+    / experts``); a gated short-convolution block (kind ``conv``) counts its
+    two projections, ``dim → 3 · dim`` and ``dim → dim``, and has no term
+    that grows with ``seq``; the embedding is a lookup and the short
+    convolutions, their gates and a q/k norm are elementwise; a tied head's
+    product is counted as an untied one's (tying saves parameters, not
+    products); backward = 2 x forward, recomputation not counted."""
     d, h = cfg.dim, cfg.heads
     qk, dv = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim
     query = d * h * qk if cfg.q_lora_rank is None else d * cfg.q_lora_rank + cfg.q_lora_rank * h * qk
@@ -165,10 +169,13 @@ def lm_flops_per_token(cfg, seq: int, *, training: bool = True) -> float:
         return 2 * (d * hq * e + 2 * d * g * e + gate + hq * e * d) + 2 * keys * hq * 2 * e
 
     kinds = cfg.kinds
+    short_conv = 2 * (d * 3 * d + d * d)  # W_in and W_out
     fwd = (
         (kinds.count("mla") + cfg.mtp_layers) * (latent + core)
-        + sum(grouped_query(i) for i, kind in enumerate(kinds) if kind not in ("mla", "kda"))
+        + sum(grouped_query(i) for i, kind in enumerate(kinds)
+              if kind not in ("mla", "kda", "conv"))
         + cfg.kda_layers * linear
+        + kinds.count("conv") * short_conv
         + dense_layers * gated(cfg.dense_hidden)
         + sparse_layers * sparse
         + (1 + cfg.mtp_layers) * head

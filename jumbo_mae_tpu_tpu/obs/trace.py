@@ -115,6 +115,12 @@ SCOPE_KDA_OUT = "kda_out"  # output norm and head-wise gate (also under kda_gate
 # two cores: a full layer's is attn_core, as every causal core's.
 SCOPE_GQA_PROJ = "gqa_proj"  # the q, k, v and head-gate projections of x
 SCOPE_SWA_CORE = "swa_core"  # a sliding-window layer's causal kernels and what feeds them
+# ... and in its gated short-convolution layers (``models/lm.ShortConv``). A
+# block of that kind has no attention scope at all: no projection to heads,
+# no rope, no core, no attn_out.
+SCOPE_SCONV_IN = "sconv_in"  # W_in: the block input to the three gates' 3 x dim columns
+SCOPE_SCONV_MIX = "sconv_mix"  # B ⊙ x̃, the depth-wise causal filter's taps, C ⊙: elementwise
+SCOPE_SCONV_OUT = "sconv_out"  # W_out
 
 
 def _span_hist(name: str, registry):

@@ -1,5 +1,9 @@
 """The gated delta rule with a per-channel decay (Kimi Delta Attention's
-core), in chunked form, and the causal depthwise convolution that feeds it.
+core), in chunked form, and the causal depthwise convolution that feeds it —
+``causal_conv``, one function with the activation an argument, which serves
+two families of layer: with SiLU the linear-attention layers' q, k and v
+(``models/lm.KdaAttention``), with none the gated short-convolution mixer's
+three-tap filter (``models/lm.ShortConv``).
 
 Per head, with a state ``S`` of shape (d_k, d_v) that starts at zero::
 
@@ -93,27 +97,33 @@ def _causal_conv(x, w):
     return sum(padded[..., j:j + seq, :] * w[j][..., None, :] for j in range(taps))
 
 
-@jax.custom_vjp
-def causal_conv_silu(x, w):
-    """``silu(Σ_j w_j ⊙ x_{t−K+1+j})`` along the axis before the last, zero
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def causal_conv(x, w, act: str | None = "silu"):
+    """``act(Σ_j w_j ⊙ x_{t−K+1+j})`` along the axis before the last, zero
     history before the first position: ``x`` (..., seq, width), one filter of
     ``K`` taps a channel, ``w`` (K, ..., width) broadcast against ``x``
-    without its sequence axis. Float32 inside. The gradient is written out
-    (the same shifted sums run backwards, from ``x`` and ``w`` alone), so
-    that the backward pass is a few fused loops and keeps no activation."""
-    return jax.nn.silu(_causal_conv(x, w)).astype(x.dtype)
+    without its sequence axis; ``act`` is ``"silu"`` (the linear-attention
+    layers' q, k, v) or None (the gated short-convolution mixer's filter: no
+    activation). Float32 inside. The gradient is written out (the same
+    shifted sums run backwards, from ``x`` and ``w`` alone), so that the
+    backward pass is a few fused loops and keeps no activation."""
+    z = _causal_conv(x, w)
+    return (jax.nn.silu(z) if act == "silu" else z).astype(x.dtype)
 
 
-def _conv_fwd(x, w):
-    return causal_conv_silu(x, w), (x, w)
+def _conv_fwd(x, w, act):
+    return causal_conv(x, w, act), (x, w)
 
 
-def _conv_bwd(residuals, dy):
+def _conv_bwd(act, residuals, dy):
     x, w = residuals
     taps, seq = w.shape[0], x.shape[-2]
-    z = _causal_conv(x, w)
-    s = jax.nn.sigmoid(z)
-    dz = dy.astype(jnp.float32) * s * (1.0 + z * (1.0 - s))
+    if act == "silu":
+        z = _causal_conv(x, w)
+        s = jax.nn.sigmoid(z)
+        dz = dy.astype(jnp.float32) * s * (1.0 + z * (1.0 - s))
+    else:
+        dz = dy.astype(jnp.float32)
     ahead = jnp.pad(dz, [(0, 0)] * (x.ndim - 2) + [(0, taps - 1), (0, 0)])
     wf = w.astype(jnp.float32)
     dx = sum(ahead[..., taps - 1 - j:taps - 1 - j + seq, :] * wf[j][..., None, :]
@@ -124,7 +134,7 @@ def _conv_bwd(residuals, dy):
     return dx.astype(x.dtype), dw.astype(w.dtype)
 
 
-causal_conv_silu.defvjp(_conv_fwd, _conv_bwd)
+causal_conv.defvjp(_conv_fwd, _conv_bwd)
 
 
 def _unit_lower_inverse(lower, base: int = 16):

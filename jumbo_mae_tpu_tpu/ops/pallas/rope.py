@@ -35,10 +35,14 @@ VMEM_BYTES = 32 * 1024 * 1024
 def rope_blocks(heads: int, seq: int, d: int) -> tuple[int, int] | None:
     """``(heads, positions)`` of a grid step for a (·, heads, seq, d) operand,
     or None where the kernel does not take the shape: the last axis must be
-    whole 128-lane tiles and the sequence must cut into blocks of whole
-    sublane tiles of any dtype (16 rows)."""
-    if d % 128 or seq % 16:
+    whole 128-lane tiles, or the half tile of a 64-wide head (Mosaic rotates
+    a 64-lane row within its own 64 lanes; a block then fills half of each
+    vector register it takes, and the element bound below counts it double),
+    and the sequence must cut into blocks of whole sublane tiles of any dtype
+    (16 rows)."""
+    if (d % 128 and d != 64) or seq % 16:
         return None
+    d = max(d, 128)  # what a row takes of VMEM
     sb = max(n for n in range(16, min(seq, SEQ_BLOCK) + 1, 16) if seq % n == 0)
     hb = max((n for n in range(1, heads + 1) if heads % n == 0 and n * sb * d <= BLOCK_ELEMENTS),
              default=1)

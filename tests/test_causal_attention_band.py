@@ -109,6 +109,24 @@ def test_grouped_windowed_kernels_match_every_pair_forward_and_backward(h, g, se
         np.testing.assert_allclose(e, r, rtol=2e-4, atol=2e-5)
 
 
+def test_heads_64_wide_at_a_group_of_four_match_every_pair_forward_and_backward():
+    """The short-convolution family's attention layers: 8 query heads over 2
+    key/value heads (a group of 4) whose q, k and v are 64 wide — half a lane
+    tile — no window, a padded tail; and the backward kernel's span rule
+    counts a 64-wide accumulator as the whole tile it takes."""
+    h, g, seq, block = 8, 2, 70, 16
+    args, w = _inputs(seq + h, 2, h, g, seq, False, d_a=64, d_v=64)
+    kernel = lambda *xs: pallas_causal_attention(*xs, block, True, None)
+    out, want = kernel(*args), jax.jit(lambda *xs: _dense(*xs, None))(*args)
+    assert out.shape == want.shape == (2, h, seq, 64)
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-6)
+    grad = lambda fn: jax.jit(jax.grad(lambda *xs: (fn(*xs) * w).sum(), argnums=(0, 2, 4)))(*args)
+    for a, r in zip(grad(kernel), grad(lambda *xs: _dense(*xs, None)), strict=True):
+        np.testing.assert_allclose(a, r, rtol=2e-4, atol=2e-5)
+    assert _causal_span(8, 1024, (64, 64), 2) == _causal_span(8, 1024, (128, 128), 2) == 8
+    assert causal_pairs(8192) == (36 * 1024 * 1024, 8192 * 8193 // 2)
+
+
 def _eqns(jaxpr):
     """Every equation of a jaxpr and of the jaxprs nested in it."""
     for eqn in jaxpr.eqns:

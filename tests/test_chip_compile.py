@@ -172,7 +172,7 @@ def assert_the_head_walks_its_tokens_in_tiles(text: str, cfg, lm) -> None:
     batch, seq, rows = cfg.run.train_batch_size, cfg.data.seq_len, lm.rows[1]
     assert jax.default_backend() == "tpu"  # compile_lm_step's patch: the sized check runs
     chip_smoke.check_step_runs_the_head_three_times(
-        {"train_step": SimpleNamespace(as_text=lambda: text)}, lm, batch * seq)
+        {"train_step": SimpleNamespace(as_text=lambda: text)}, lm, batch * seq, seq)
     tile = head_tile(batch * seq, rows)
     assert tile < batch * seq and (batch * seq) % tile == 0
     for whole in (f"[{batch},{seq},{rows}]", f"[{batch * seq},{rows}]",
@@ -185,7 +185,8 @@ def assert_the_step_is_built_a_block_at_a_time(text: str, cfg, lm) -> None:
     """What a compiled step's text holds in every family, counted from ``lm``'s
     own lists so that a depth cut is held to what the whole recipe is: the
     guard adds no ``conditional``; each softmax block (the MTP module's too)
-    runs each of the two causal kernels once, a window layer's under
+    runs each of the two causal kernels once (a short-convolution block
+    none), a window layer's under
     ``swa_core`` and every other under ``attn_core`` (a rematted block keeps
     the forward kernel's output and log-sum-exp; the backward is one kernel,
     PR 37); each linear-attention block runs the forward chunk kernel twice
@@ -198,7 +199,7 @@ def assert_the_step_is_built_a_block_at_a_time(text: str, cfg, lm) -> None:
 
     assert " conditional(" not in text and "/guard/" in text
     sliding = lm.kinds.count("sliding_attention")
-    softmax = lm.layers - lm.kda_layers + lm.mtp_layers
+    softmax = lm.layers - lm.kda_layers - lm.kinds.count("conv") + lm.mtp_layers
     assert chip_smoke.causal_kernel_calls(text) == {"fwd": softmax, "bwd": softmax}
     by_scope = {scope: len(re.findall(
         rf'custom-call\([^\n]*/{scope}/causal_attention_\w+/pallas_call"', text))
@@ -316,10 +317,11 @@ def test_kernels_phase_rehearsal_interpreted():
 def test_lm_kernels_phase_rehearsal_interpreted():
     got = chip_smoke.phase_lm_kernels(
         causal=((1, 2, 40, 16, 8, 16), (1, 6, 40, 16, 0, 16, 2, 21)),
-        grouped=(64, 32, 24, (41, 0, 9, 6)), interpret=True)
+        grouped=(64, 32, 24, (41, 0, 9, 6)), rope=((1, 2, 32, 128), (1, 2, 32, 64)),
+        interpret=True)
     assert got["mosaic_custom_call"] is False
     assert set(got["max_rel_err_vs_xla"]) == {"causal@40x16+8/16", "causal@40x16+0/16g3w21",
-                                              "grouped@64x32x24"}
+                                              "rope@32x128", "rope@32x64", "grouped@64x32x24"}
     assert max(got["max_rel_err_vs_xla"].values()) < chip_smoke.KERNEL_REL_TOL
 
 
@@ -331,7 +333,8 @@ def test_lm_train_phase_counts_the_causal_kernels_in_the_step(monkeypatch, forwa
     call = ('  %k.{i} = bf16[2,4]{{1,0}} custom-call(%a), custom_call_target="tpu_custom_call", '
             'metadata={{op_name="jit(_train_step)/{phase}/block_{i}/attn/attn_core/'
             'causal_attention_{kernel}/pallas_call" stack_frame_id=1}}\n')
-    lm = SimpleNamespace(layers=2, mtp_layers=1, kda_layers=0)
+    # a short-convolution block among them has no causal core and adds no call
+    lm = SimpleNamespace(kinds=("mla", "conv", "mla"), mtp_layers=1)
     text = "".join(
         call.format(i=i, phase=phase, kernel=kernel)
         for i in range(3)

@@ -107,6 +107,26 @@ def _window_and_routed_from_the_input(checked, published, records):
     assert [r for r in records if "train/moe_act_zero_share_l2" in r]
 
 
+def _short_conv_and_grouped_query(checked, published, records):
+    """9 blocks: dense conv, full attention, conv x3, full attention, conv
+    x3; 4 query heads over 2 key/value heads with q/k norms, a tied head: a
+    conv block has no heads, no pairs and no rope, and the static counters
+    say how many blocks are of each kind."""
+    assert checked["kda_kernel_calls"] == {"fwd": 0, "bwd": 0, "loops": 0}
+    assert checked["layers_by_kind"] == {"conv": 7, "full_attention": 2}
+    assert checked["qk_norm"] is True and checked["tie_embeddings"] is True
+    assert set(checked["attn_pairs"]) == {"full_attention"}
+    assert checked["attn_pairs"]["full_attention"]["needed"] == 24 * 25 // 2
+    assert checked["attn_heads"] == {"full_attention": {"held": 4, "published": 4}}
+    assert checked["head_product_calls"]["fwd"] == 3
+    (logged,) = [r for r in records if "train/layers_conv" in r]
+    assert (logged["train/layers_conv"], logged["train/layers_full_attention"]) == (7, 2)
+    assert {"imbalance", "rounds_l1", "rounds_l8"} <= set(published["train_moe"])
+    # block 0 has the dense MLP (the registry outlives a run, the log does not)
+    assert not [r for r in records if "train/moe_rounds_l0" in r]
+    assert [r for r in records if "train/moe_rounds_l8" in r]
+
+
 CASES = [
     pytest.param("pretrain_joyai_flash_ep16", _toy(
         16, layers=2, heads=2, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
@@ -125,6 +145,8 @@ CASES = [
     pytest.param("pretrain_smallthinker_21b_share", _toy(
         24, heads=7, kv_heads=1, head_dim=16, sliding_window=11),
         _window_and_routed_from_the_input, id="smallthinker_21b"),
+    pytest.param("pretrain_lfm2_24b_share", _toy(24, heads=4, kv_heads=2, head_dim=16),
+                 _short_conv_and_grouped_query, id="lfm2_24b"),
 ]
 
 

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from jumbo_mae_tpu_tpu.ops import kda
-from jumbo_mae_tpu_tpu.ops.kda import causal_conv_silu, kda_chunked
+from jumbo_mae_tpu_tpu.ops.kda import causal_conv, kda_chunked
 
 
 def literal(q, k, v, g, beta):
@@ -289,7 +289,7 @@ def test_unit_lower_inverse(n):
 def test_causal_convolution_against_a_loop():
     x = jax.random.normal(jax.random.key(0), (2, 3, 11, 5))
     w = jax.random.normal(jax.random.key(1), (4, 3, 5))
-    got = causal_conv_silu(x, w)
+    got = causal_conv(x, w)
     want = np.zeros(x.shape, np.float32)
     for t in range(11):
         for j in range(4):
@@ -302,9 +302,9 @@ def test_causal_convolution_against_a_loop():
         jnp.pad(x, ((0, 0), (0, 0), (3 - j, 0), (0, 0)))[:, :, :11] * w[j][:, None, :]
         for j in range(4)))
     weight = jax.random.normal(jax.random.key(2), x.shape)
-    for got_g, want_g in zip(jax.grad(lambda *a: (causal_conv_silu(*a) * weight).sum(), (0, 1))(x, w),
+    for got_g, want_g in zip(jax.grad(lambda *a: (causal_conv(*a) * weight).sum(), (0, 1))(x, w),
                              jax.grad(lambda *a: (plain(*a) * weight).sum(), (0, 1))(x, w)):
         np.testing.assert_allclose(got_g, want_g, rtol=1e-5, atol=1e-5)
     # nothing of a later position reaches an earlier one
-    moved = causal_conv_silu(x.at[:, :, 6:].add(1.0), w)
+    moved = causal_conv(x.at[:, :, 6:].add(1.0), w)
     np.testing.assert_array_equal(np.asarray(moved[:, :, :6]), np.asarray(got[:, :, :6]))
