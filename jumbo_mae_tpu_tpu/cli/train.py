@@ -931,9 +931,10 @@ def train(cfg: TrainConfig) -> dict:
         wandb_id=run.wandb_id,
     )
     if run.mode == "lm":
-        # static, from the causal kernels' block tables: the score entries a
-        # (head, sequence) of each attention kind visits, and those its mask
-        # keeps; logged once, beside the steps' train/moe_* counters
+        # static, from the causal kernels' block tables and their plan of a
+        # masked pair's sub-tiles: the score entries a (head, sequence) of each
+        # attention kind computes, and those its mask keeps; logged once,
+        # beside the steps' train/moe_* counters
         pairs = {
             f"train/attn_pairs_{what}_{kind}": count
             for kind, counts in enc_cfg.attn_pairs(cfg.data.seq_len).items()

@@ -480,8 +480,10 @@ class MlaMoeConfig:
     def attn_pairs(self, seq: int) -> dict:
         """``{kind: (visited, needed)}`` for each kind of softmax attention
         among the blocks: the score entries of one (head, sequence) of
-        ``seq`` tokens that the causal kernels' block tables walk, and those
-        the mask keeps (``ops/pallas/attention.causal_pairs``). Static."""
+        ``seq`` tokens that the causal kernels compute (the block pairs their
+        tables walk; of a pair a mask cuts, the sub-tiles that hold a visible
+        entry), and those the mask keeps
+        (``ops/pallas/attention.causal_pairs``). Static."""
         from jumbo_mae_tpu_tpu.ops.pallas.attention import causal_pairs
 
         kinds = set(self.kinds) - {"kda", "conv"}  # neither has (query, key) pairs

@@ -32,6 +32,7 @@ automatically, at some efficiency cost).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -439,11 +440,19 @@ pallas_flash_attention_with_lse.defvjp(_vjp_lse_fwd, _vjp_lse_bwd)
 # (``_lower_triangle``: the whole triangle on or below the diagonal, or with a
 # window of ``w`` tokens the band of it that ``w`` reaches back into), so a
 # block outside the band costs neither a DMA nor a grid step: a windowed
-# layer is O(seq · window). Only the band's two edges mask, with a local
-# iota: the diagonal blocks (``row >= col``) and the trailing blocks that the
-# window's far edge cuts (``row − col < w`` in absolute positions). Running
-# maximum, denominator and the output accumulator live in VMEM scratch across
-# the key blocks of a query block; scores never leave VMEM.
+# layer is O(seq · window). Only the band's two edges are cut by a mask: the
+# diagonal blocks (``row >= col``) and the trailing blocks that the window's
+# far edge cuts (``row − col < w`` in absolute positions), at distances from
+# the diagonal known when a kernel is built (``_cuts``). A cut pair is not
+# computed whole under its mask: its grid step, tables and DMAs are a whole
+# pair's, but the step's body runs as strips of query rows, each over only
+# the sub-tiles that hold an entry it sees (``_strips``; the side from the
+# block, ``_sub_tile``), and only the sub-tiles the mask's edge crosses are
+# compared with a local iota — a hidden entry's probability and ``ds`` are
+# exact zeros, so leaving it out only reorders a row's float32 sums. A pair
+# no mask cuts is one strip with no compare. Running maximum, denominator and
+# the output accumulator live in VMEM scratch across the key blocks of a
+# query block; scores never leave VMEM.
 #
 # A sequence that is no multiple of the block is padded with zero rows: a
 # pad key lies after every real query, so causality already hides it, and a
@@ -513,24 +522,111 @@ def _lower_triangle(n: int, *, reach: int | None = None):
     return np.asarray(qi, np.int32), np.asarray(kj, np.int32)
 
 
-def _scores(qa, qb, ka, kb, diagonal: bool, edge=None):
-    """(block, block) float32 scores of one block pair. On a diagonal block
-    the entries above the diagonal are −inf; with ``edge`` so are those with
-    ``row − col >= edge`` (the window's far side, in the pair's own rows and
-    columns)."""
+def _whole(block: int) -> tuple[int, int]:
+    """The ``(lo, hi)`` no entry of a block pair falls outside: no mask."""
+    return 1 - block, block
+
+
+def _cuts(block: int, window: int | None) -> dict[int, tuple[int, int]]:
+    """The block pairs a mask cuts, by their distance ``i − j`` from the
+    diagonal, each with the ``(lo, hi)`` of its mask in the pair's own rows and
+    columns: an entry is visible iff ``lo <= row − col < hi``. The diagonal
+    (distance 0: ``row >= col``, and the window's far edge too where the
+    window is shorter than the block) and the trailing pairs the window's far
+    edge cuts, at most two distances (``window // block … _reach``). Every
+    other pair the tables walk is visible whole."""
+    if window is None:
+        return {0: (0, block)}
+    cuts = {0: (0, min(window, block))}
+    for apart in range(max(window // block, 1), _reach(window, block) + 1):
+        cuts[apart] = 1 - block, window - apart * block
+    return cuts
+
+
+def _sub_tile(block: int) -> int:
+    """The side of the sub-tiles a masked block pair is computed in
+    (``_strips``), from the block alone: a quarter of the block where that is
+    a whole number of 128-lane tiles (a strip's rows are then whole sublane
+    tiles of float32 and of bf16 too, and its score columns whole lanes),
+    else half of it, else the block (one strip: the whole pair under its
+    mask, as the interpreter's tests at blocks of 16 and the 128-blocks of a
+    short window run)."""
+    return next((block // n for n in (4, 2) if block % (n * 128) == 0), block)
+
+
+class _Strip(NamedTuple):
+    """Query rows ``row .. row_end`` of a block pair and the key columns ``col
+    .. col_end`` of the sub-tiles that hold an entry they see; of those,
+    ``clear .. clear_end`` lie in sub-tiles visible whole, the mask crosses
+    the rest."""
+    row: int
+    row_end: int
+    col: int
+    col_end: int
+    clear: int
+    clear_end: int
+
+
+def _strips(block: int, tile: int, lo: int, hi: int) -> tuple[_Strip, ...]:
+    """How a block pair under the mask ``lo <= row − col < hi`` is computed:
+    as strips of ``tile`` query rows, each over only the ``tile``-wide
+    sub-tiles that hold a visible entry (the visible ``row − col`` are one
+    interval, so they are consecutive, and so are those visible whole). A
+    strip that sees nothing in the pair is left out. On a diagonal block
+    strip ``r`` holds columns ``0 .. (r + 1) · tile`` and its last sub-tile is
+    masked; under a far edge of 0 columns ``r · tile .. block`` and its first.
+    A pair the mask leaves whole is one strip, the block. Static: both kernels
+    and ``causal_pairs`` read it."""
+    if (lo, hi) == _whole(block):
+        return (_Strip(0, block, 0, block, 0, block),)
+    strips = []
+    for row in range(0, block, tile):
+        # the sub-tile at ``col`` holds row − col in row − col ± (tile − 1)
+        held = [col for col in range(0, block, tile)
+                if row - col + tile - 1 >= lo and row - col - tile + 1 < hi]
+        if not held:
+            continue
+        clear = [col for col in held if row - col - tile + 1 >= lo and row - col + tile - 1 < hi]
+        end = held[-1] + tile
+        strips.append(_Strip(row, row + tile, held[0], end,
+                             *((clear[0], clear[-1] + tile) if clear else (end, end))))
+    return tuple(strips)
+
+
+def _masked(s, row: int, col: int, lo: int, hi: int):
+    """``s`` with −inf where ``lo <= row − col < hi`` fails; its first entry
+    is the pair's (``row``, ``col``). A bound no entry of ``s`` reaches is not
+    compared."""
+    rows, cols = s.shape
+    below, beyond = row - (col + cols - 1) < lo, row + rows - 1 - col >= hi
+    if not (below or beyond):
+        return s
+    # row − col of an entry, less that of s[0, 0]
+    apart = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+             - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    keep = apart < hi - (row - col) if beyond else None
+    if below:
+        near = apart >= lo - (row - col)
+        keep = near if keep is None else keep & near
+    return jnp.where(keep, s, NEG_INF)
+
+
+def _scores(qa, qb, ka, kb, strip: _Strip, lo: int, hi: int):
+    """Float32 scores of one strip of a block pair: its query rows against
+    the key columns ``strip`` holds, −inf where the pair's mask hides an
+    entry. Only the sub-tiles the mask crosses are compared."""
     dims = (((1,), (1,)), ((), ()))
     s = jax.lax.dot_general(qa, ka, dims, preferred_element_type=jnp.float32)
     if qb is not None:
         s = s + jax.lax.dot_general(qb, kb, dims, preferred_element_type=jnp.float32)
-    if diagonal or edge is not None:
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        keep = row >= col if diagonal else None
-        if edge is not None:
-            near = row - col < edge
-            keep = near if keep is None else keep & near
-        s = jnp.where(keep, s, NEG_INF)
-    return s
+    row, _, col, col_end, clear, clear_end = strip
+    # the columns before, in and after the sub-tiles visible whole, which
+    # ``_masked`` finds no bound to compare in
+    pieces = [(a, b) for a, b in [(col, clear), (clear, clear_end), (clear_end, col_end)] if b > a]
+    if len(pieces) == 1:
+        return _masked(s, row, col, lo, hi)
+    return jnp.concatenate([_masked(s[:, a - col:b - col], row, a, lo, hi) for a, b in pieces],
+                           axis=1)
 
 
 def _parts(refs, two_part: bool):
@@ -542,8 +638,8 @@ def _parts(refs, two_part: bool):
     return (qa, None, ka, None, v, *refs[3:])
 
 
-def _read(ref, dtype):
-    return None if ref is None else ref[...].astype(dtype)
+def _read(ref, dtype, rows):
+    return None if ref is None else ref[rows, :].astype(dtype)
 
 
 def _first_key_block(i, *, block: int, window: int | None):
@@ -551,26 +647,24 @@ def _first_key_block(i, *, block: int, window: int | None):
     return 0 if window is None else jnp.maximum(i - _reach(window, block), 0)
 
 
-def _diagonal_edge(*, block: int, window: int | None):
-    """The window's edge on a diagonal block: there only where the window is
-    shorter than the block."""
-    return window if window is not None and window < block else None
-
-
 def _off_diagonal(i, j, step, *, block: int, window: int | None):
-    """Run ``step(edge)`` for a pair below the diagonal (``j < i``): with no
-    mask where the whole block is visible, with the window's edge where the
-    window ends inside it."""
+    """Run ``step(lo, hi)`` for a pair below the diagonal (``j < i``): with
+    bounds no entry falls outside where the whole block is visible, and where
+    the window ends inside it one branch a distance it does so at
+    (``_cuts``), so that each has bounds known when the kernel is built."""
+    whole = functools.partial(step, *_whole(block))
     if window is None:
-        pl.when(j < i)(lambda: step(None))
+        pl.when(j < i)(whole)
         return
     clear = window // block  # pairs fewer blocks apart than this lie wholly inside the window
     if clear > 1:
-        pl.when((j < i) & (i - j < clear))(lambda: step(None))
-    pl.when((j < i) & (i - j >= clear))(lambda: step(window - (i - j) * block))
+        pl.when((j < i) & (i - j < clear))(whole)
+    for apart, bounds in _cuts(block, window).items():
+        if apart:
+            pl.when(i - j == apart)(functools.partial(step, *bounds))
 
 
-def _causal_fwd_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int,
+def _causal_fwd_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int, tile: int,
                        window: int | None):
     qa_ref, qb_ref, ka_ref, kb_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = _parts(
         refs, two_part)
@@ -584,27 +678,36 @@ def _causal_fwd_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int,
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    def step(diagonal: bool, edge=None):
-        s = _scores(_read(qa_ref, mm), _read(qb_ref, mm), _read(ka_ref, mm), _read(kb_ref, mm),
-                    diagonal, edge)
-        m_prev = m_sc[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_sc[...] = alpha * l_sc[...] + p.sum(axis=-1, keepdims=True)
-        acc_sc[...] = alpha * acc_sc[...] + jax.lax.dot_general(
-            p.astype(mm), v_ref[...].astype(mm), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_sc[...] = m_new
+    def step(lo: int, hi: int):
+        # every strip's scores before the first softmax: in this order the
+        # compiler overlaps the strips' chains (maximum, exponential, product)
+        # better than a strip at a time (PERF.md §6, PR 45)
+        strips = _strips(block, tile, lo, hi)
+        at = [(pl.ds(strip.row, strip.row_end - strip.row),
+               pl.ds(strip.col, strip.col_end - strip.col)) for strip in strips]
+        scores = [_scores(_read(qa_ref, mm, rows), _read(qb_ref, mm, rows), _read(ka_ref, mm, keys),
+                          _read(kb_ref, mm, keys), strip, lo, hi)
+                  for strip, (rows, keys) in zip(strips, at)]
+        for s, (rows, keys) in zip(scores, at):
+            m_prev = m_sc[rows, :]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_sc[rows, :] = alpha * l_sc[rows, :] + p.sum(axis=-1, keepdims=True)
+            acc_sc[rows, :] = alpha * acc_sc[rows, :] + jax.lax.dot_general(
+                p.astype(mm), _read(v_ref, mm, keys), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_sc[rows, :] = m_new
 
-    # a row that a trailing block hides whole leaves 1s in p at the running
-    # maximum −1e30; the diagonal block, always visited and never all hidden,
-    # scales them away by alpha = 0
-    _off_diagonal(i, j, lambda edge: step(False, edge), block=block, window=window)
+    # a row that a trailing block hides whole, in a strip that is run, leaves
+    # 1s in p at the running maximum −1e30; the diagonal block, always visited
+    # and never all hidden, scales them away by alpha = 0. A strip that sees
+    # nothing of the pair is not run and leaves its rows' state as it was
+    _off_diagonal(i, j, step, block=block, window=window)
 
     @pl.when(j == i)  # the diagonal is the last key block of a query block
     def _():
-        step(True, _diagonal_edge(block=block, window=window))
+        step(*_cuts(block, window)[0])
         l = l_sc[...]
         o_ref[...] = (acc_sc[...] / l).astype(o_ref.dtype)
         lse_ref[...] = jnp.broadcast_to(m_sc[...] + jnp.log(l), lse_ref.shape)
@@ -639,7 +742,8 @@ def _backward_walk(n: int, *, reach: int | None, group: int, span: int):
 
 
 def _causal_bwd_kernel(qi_ref, kj_ref, _member_ref, at_ref, *refs, two_part: bool, block: int,
-                       window: int | None, span: int):  # the member is the index maps' alone
+                       tile: int, window: int | None, span: int):
+    # the member is the index maps' alone
     (qa_ref, qb_ref, ka_ref, kb_ref, v_ref, do_ref, lse_ref, dd_ref, *outs) = _parts(
         refs, two_part)
     if two_part:
@@ -649,8 +753,6 @@ def _causal_bwd_kernel(qi_ref, kj_ref, _member_ref, at_ref, *refs, two_part: boo
     t = pl.program_id(2)
     i, j, at = qi_ref[t], kj_ref[t], at_ref[t]
     mm = qa_ref.dtype
-    # the key block's rows of the span-long accumulators
-    keys = pl.ds(pl.multiple_of(j % span * block, block), block)
 
     @pl.when(at & ROW_FIRST != 0)
     def _():
@@ -668,26 +770,32 @@ def _causal_bwd_kernel(qi_ref, kj_ref, _member_ref, at_ref, *refs, two_part: boo
         dka_sc[...] = jnp.zeros(dka_sc.shape, jnp.float32)
         dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
 
-    def step(diagonal: bool, edge=None):
-        qa, qb, ka, kb = (_read(ref, mm) for ref in (qa_ref, qb_ref, ka_ref, kb_ref))
-        do = do_ref[...].astype(mm)
-        s = _scores(qa, qb, ka, kb, diagonal, edge)
-        p = jnp.exp(s - lse_ref[...][:, :1])
-        dp = jax.lax.dot_general(
-            do, v_ref[...].astype(mm), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - dd_ref[...][:, :1])).astype(mm)
-        to_keys, to_queries = (((0,), (0,)), ((), ())), (((1,), (0,)), ((), ()))
-        dot = functools.partial(jax.lax.dot_general, preferred_element_type=jnp.float32)
-        dv_sc[keys, :] += dot(p.astype(mm), do, to_keys)
-        dka_sc[keys, :] += dot(ds, qa, to_keys)
-        dqa_sc[...] += dot(ds, ka, to_queries)
-        if two_part:
-            dkb_ref[keys, :] += dot(ds, qb, to_keys)
-            dqb_sc[...] += dot(ds, kb, to_queries)
+    to_keys, to_queries = (((0,), (0,)), ((), ())), (((1,), (0,)), ((), ()))
+    dot = functools.partial(jax.lax.dot_general, preferred_element_type=jnp.float32)
 
-    _off_diagonal(i, j, lambda edge: step(False, edge), block=block, window=window)
-    pl.when(j == i)(lambda: step(True, _diagonal_edge(block=block, window=window)))
+    def step(lo: int, hi: int):
+        for strip in _strips(block, tile, lo, hi):
+            side = strip.row_end - strip.row  # the tile, or the block: both offsets' divisor
+            rows = pl.ds(strip.row, side)
+            cols = pl.ds(strip.col, strip.col_end - strip.col)
+            # the strip's key rows of the span-long accumulators
+            keys = pl.ds(pl.multiple_of(j % span * block + strip.col, side),
+                         strip.col_end - strip.col)
+            qa, qb, do = (_read(ref, mm, rows) for ref in (qa_ref, qb_ref, do_ref))
+            ka, kb, v = (_read(ref, mm, cols) for ref in (ka_ref, kb_ref, v_ref))
+            s = _scores(qa, qb, ka, kb, strip, lo, hi)
+            p = jnp.exp(s - lse_ref[rows, :][:, :1])
+            dp = dot(do, v, (((1,), (1,)), ((), ())))
+            ds = (p * (dp - dd_ref[rows, :][:, :1])).astype(mm)
+            dv_sc[keys, :] += dot(p.astype(mm), do, to_keys)
+            dka_sc[keys, :] += dot(ds, qa, to_keys)
+            dqa_sc[rows, :] += dot(ds, ka, to_queries)
+            if two_part:
+                dkb_ref[keys, :] += dot(ds, qb, to_keys)
+                dqb_sc[rows, :] += dot(ds, kb, to_queries)
+
+    _off_diagonal(i, j, step, block=block, window=window)
+    pl.when(j == i)(functools.partial(step, *_cuts(block, window)[0]))
 
     @pl.when(at & ROW_LAST != 0)
     def _():
@@ -704,15 +812,20 @@ def _causal_bwd_kernel(qi_ref, kj_ref, _member_ref, at_ref, *refs, two_part: boo
 def causal_block(seq: int, window: int | None) -> int:
     """The kernels' block for a sequence and a window: ``CAUSAL_BLOCK`` where
     the whole triangle is walked; under a window the block that costs a
-    windowed layer least on the chip (at 1024 a 512-token window computes
-    3.9 x the score entries it needs, at 512 2.0 x, at 256 1.5 x, against
-    more and smaller grid steps; PERF.md §6, PR 37, one layer's forward +
-    backward at 64 heads over 8, 2 x 8192 tokens: 1024 → 25.36 ms, 512 →
-    21.15, 256 → 30.78; with PR 33's two backward kernels 31.05, 25.12,
-    37.65)."""
-    if window is None or window >= seq:
+    windowed layer least on the chip. One layer's forward + backward at 64
+    heads over 8, 2 x 8192 tokens, a 512-token window, at blocks 1024 · 512 ·
+    256: with every masked pair computed whole (3.9 x, 2.0 x, 1.5 x the score
+    entries the mask keeps) 25.34 · 21.09 · 30.80 ms (PERF.md §6, PR 45; PR 37
+    read 25.36 · 21.15 · 30.78, PR 33's two backward kernels 31.05 · 25.12 ·
+    37.65), so 512 won; with a masked pair run as strips over its sub-tiles
+    that hold a visible entry (1.5 x, 1.25 x, 1.25 x) **15.46** · 18.66 ·
+    29.57: the strips take most of a large block's waste and none of a small
+    block's grid steps, so from a window of half a block on (timed at 512 and
+    at 4096) the block is ``CAUSAL_BLOCK``. A shorter window, timed under
+    neither form, keeps the whole 128-lane tiles of its own length."""
+    if window is None or window >= min(seq, CAUSAL_BLOCK // 2):
         return CAUSAL_BLOCK
-    return min(CAUSAL_BLOCK, max(128, window // 128 * 128))
+    return max(128, window // 128 * 128)
 
 
 def _causal_plan(seq: int, block: int) -> tuple[int, int]:
@@ -732,11 +845,17 @@ def _causal_band(seq: int, window: int | None, block: int | None):
 
 
 def causal_pairs(seq: int, window: int | None = None, block: int | None = None) -> tuple[int, int]:
-    """(visited, needed) score entries of one (head, sequence): those in the
-    block pairs the kernels' tables walk, and those the mask keeps
-    (``min(i + 1, window)`` keys for query ``i``). Static, from the tables."""
+    """(visited, needed) score entries of one (head, sequence): those the
+    kernels compute — the block pairs their tables walk, whole where no mask
+    cuts them and else the sub-tiles of ``_strips``' plan — and those the mask
+    keeps (``min(i + 1, window)`` keys for query ``i``). Static, from the
+    tables and the plan the kernels read."""
     s_pad, block, window, reach = _causal_band(seq, window, block)
-    visited = len(_lower_triangle(s_pad // block, reach=reach)[0]) * block * block
+    qi, kj = _lower_triangle(s_pad // block, reach=reach)
+    cuts, tile = _cuts(block, window), _sub_tile(block)
+    visited = sum((strip.row_end - strip.row) * (strip.col_end - strip.col)
+                  for apart in (qi - kj).tolist()
+                  for strip in _strips(block, tile, *cuts.get(apart, _whole(block))))
     w = seq if window is None else window
     return visited, w * (w + 1) // 2 + (seq - w) * w
 
@@ -799,6 +918,24 @@ def _causal_call(kernel, tables, grid, operands, outputs, scratch, *, interpret,
     )(*tables, *(x for x, _ in operands))
 
 
+def _traced_once(fn, static_argnums):
+    """``fn`` whose calls under a trace go through an inlined ``jit``: a step
+    program calls a kernel once a layer with the same shapes and static
+    arguments, and tracing a kernel's body costs tenths of a second of set-up
+    (the strips of its masked steps twice the whole pairs'), so the layers
+    after the first take the first's jaxpr, eqn for eqn what tracing them
+    again would give. A call on arrays runs ``fn`` as it is, op by op."""
+    staged = jax.jit(fn, static_argnums=static_argnums, inline=True)
+
+    @functools.wraps(fn)
+    def call(*args):
+        traced = any(isinstance(x, jax.core.Tracer) for x in args)
+        return (staged if traced else fn)(*args)
+
+    call.clear_cache = staged.clear_cache
+    return call
+
+
 def _causal_shape(qa, ka, block, window):
     """``(batch, query heads, group, seq, padded seq, block, window, reach)``
     of a call."""
@@ -812,6 +949,7 @@ def _causal_operands(named, s_pad, spec):
     return [(_pad_rows(x, s_pad), spec(x.shape[-1], kind)) for x, kind in named if x is not None]
 
 
+@functools.partial(_traced_once, static_argnums=(5, 6, 7))
 def _causal_fwd(qa, qb, ka, kb, v, block, interpret, window=None):
     b, h, group, s, s_pad, block, window, reach = _causal_shape(qa, ka, block, window)
     spec = functools.partial(_pair_spec, block=block, group=group, members=False)
@@ -820,7 +958,7 @@ def _causal_fwd(qa, qb, ka, kb, v, block, interpret, window=None):
     out = lambda w, dtype: (jax.ShapeDtypeStruct((b, h, s_pad, w), dtype), spec(w, "q"))
     o, lse = _causal_call(
         functools.partial(_causal_fwd_kernel, two_part=qb is not None, block=block,
-                          window=window),
+                          tile=_sub_tile(block), window=window),
         tables, (b, h, len(tables[0])),
         _causal_operands([(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k")],
                          s_pad, spec),
@@ -856,6 +994,7 @@ def _causal_span(n: int, block: int, widths, itemsize: int,
     return -(-n // -(-n // fits))
 
 
+@functools.partial(_traced_once, static_argnums=(8, 9, 10))
 def _causal_bwd(qa, qb, ka, kb, v, o, lse, g, block, interpret, window=None):
     b, h, group, s, s_pad, block, window, reach = _causal_shape(qa, ka, block, window)
     two_part = qb is not None
@@ -882,8 +1021,8 @@ def _causal_bwd(qa, qb, ka, kb, v, o, lse, g, block, interpret, window=None):
     partial = jnp.float32 if spans > 1 else qa.dtype
     f32 = lambda rows, w: pltpu.VMEM((rows, w), jnp.float32)
     outs = _causal_call(
-        functools.partial(_causal_bwd_kernel, two_part=two_part, block=block, window=window,
-                          span=span),
+        functools.partial(_causal_bwd_kernel, two_part=two_part, block=block,
+                          tile=_sub_tile(block), window=window, span=span),
         tables, (b, h // group, len(tables[0])),
         _causal_operands([(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k"),
                           (g, "q"), (lse, "q"), (dd, "q")], s_pad, spec),

@@ -1,8 +1,9 @@
 """The causal flash kernels' generalisations — key/value heads that a group
 of query heads shares, a window walked as a band of block pairs, a score of
 one part, a backward kernel whose key/value accumulators span the sequence or
-a share of it — in interpret mode against the einsum form, forward and
-backward; and the static count of what the band's tables visit."""
+a share of it, a masked block pair computed as row strips over the sub-tiles
+that hold a visible entry — in interpret mode against the einsum form, forward
+and backward; and the static count of what the kernels compute."""
 
 import functools
 
@@ -23,8 +24,11 @@ from jumbo_mae_tpu_tpu.ops.pallas.attention import (
     SPAN_LAST,
     _backward_walk,
     _causal_span,
+    _cuts,
     _lower_triangle,
     _reach,
+    _strips,
+    _sub_tile,
     causal_block,
     causal_pairs,
     pallas_causal_attention,
@@ -124,7 +128,20 @@ def test_heads_64_wide_at_a_group_of_four_match_every_pair_forward_and_backward(
     for a, r in zip(grad(kernel), grad(lambda *xs: _dense(*xs, None)), strict=True):
         np.testing.assert_allclose(a, r, rtol=2e-4, atol=2e-5)
     assert _causal_span(8, 1024, (64, 64), 2) == _causal_span(8, 1024, (128, 128), 2) == 8
-    assert causal_pairs(8192) == (36 * 1024 * 1024, 8192 * 8193 // 2)
+    # 28 whole pairs and 8 diagonal ones at 10 of their 16 sub-tiles
+    assert causal_pairs(8192) == (33 * 1024 * 1024, 8192 * 8193 // 2)
+
+
+@pytest.fixture
+def fresh_traces():
+    """A kernel's call is traced once a shape and its static arguments (a
+    ``jit``, inlined): a test that steers a rule the trace reads starts from no
+    trace and leaves none behind."""
+    clear = lambda: (pallas_attention._causal_fwd.clear_cache(),
+                     pallas_attention._causal_bwd.clear_cache())
+    clear()
+    yield clear
+    clear()
 
 
 def _eqns(jaxpr):
@@ -139,8 +156,8 @@ def _eqns(jaxpr):
 @pytest.mark.parametrize("span", [1, 2, 3])
 @pytest.mark.parametrize("h,g,window,two_part", [(6, 2, None, True), (6, 2, 40, False),
                                                  (2, 2, 24, True)])
-def test_accumulators_that_outgrow_the_budget_split_the_keys_into_spans(monkeypatch, h, g, window,
-                                                                        two_part, span):
+def test_accumulators_that_outgrow_the_budget_split_the_keys_into_spans(monkeypatch, fresh_traces,
+                                                                        h, g, window, two_part, span):
     """The shape rule's other path: with a budget the sequence-long
     accumulators do not fit, the same kernel walks the keys a span at a time
     and a query block's gradient is the sum of its spans' shares; every
@@ -157,6 +174,7 @@ def test_accumulators_that_outgrow_the_budget_split_the_keys_into_spans(monkeypa
     monkeypatch.setattr(pallas_attention, "_causal_span",
                         functools.partial(_causal_span, budget=budget))
     jax.clear_caches()  # the rule is read when the call is traced
+    fresh_traces()
     split = grad(*args)
     for a, b in zip(split, whole, strict=True):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-6)
@@ -239,18 +257,29 @@ def test_the_band_holds_each_pair_with_a_visible_entry_once(n, block, window, ba
 def test_the_pairs_the_tables_visit_against_those_the_mask_keeps():
     """The cell's shapes: a full layer's triangle of 1024-blocks, and a
     512-token window as a band of blocks of 1024, 512 and 256 against the
-    whole triangle (ISSUE 33's readings 9.3, 3.9, 2.0, 1.5, 8.3)."""
+    whole triangle (ISSUE 33's readings of whole block pairs were 9.3, 3.9,
+    2.0, 1.5, 8.3; every pair of a 512-band at block 512 is masked, and by
+    the sub-tiles computed — 10 of 16 on a diagonal block, 3 of 4 at halves —
+    the readings are 8.5, 1.5, 1.25, 1.25)."""
     needed = 512 * 513 // 2 + (8192 - 512) * 512
-    assert causal_pairs(8192) == (36 * 1024 * 1024, 8192 * 8193 // 2)
-    for block, pairs, ratio in [(1024, 15, 3.87), (512, 31, 2.0), (256, 93, 1.5)]:
+    assert causal_pairs(8192) == (33 * 1024 * 1024, 8192 * 8193 // 2)
+    # (block, pairs walked, their sub-tiles computed, of a side, visited / needed)
+    for block, pairs, tiles, tile, ratio in [(1024, 15, 8 * 9 + 7 * 3, 256, 1.5),
+                                             (512, 31, 31 * 10, 128, 1.25),
+                                             (256, 93, 31 * 4 + 31 * 3 + 31 * 3, 128, 1.25)]:
         visited, kept = causal_pairs(8192, 512, block)
-        assert (visited, kept) == (pairs * block * block, needed)
+        assert len(_lower_triangle(8192 // block, reach=_reach(512, block))[0]) == pairs
+        assert (visited, kept, _sub_tile(block)) == (tiles * tile * tile, needed, tile)
         assert visited / kept == pytest.approx(ratio, rel=5e-3)
-    assert causal_pairs(8192)[0] / needed == pytest.approx(9.29, rel=1e-3)
+    assert causal_pairs(8192)[0] / needed == pytest.approx(8.52, rel=1e-3)
     assert 8192 * 8193 // 2 / needed == pytest.approx(8.26, rel=1e-3)
     # the block follows from what the code can observe; a window the sequence
     # never reaches is no window
     assert causal_block(8192, None) == causal_block(400, 512) == CAUSAL_BLOCK
+    # under strips the whole block wins from a window of half a block on
+    # (PERF.md §6, PR 45); a shorter window keeps whole lane tiles of its length
+    assert causal_block(8192, 512) == causal_block(8192, 700) == CAUSAL_BLOCK
+    assert [causal_block(8192, w) for w in (511, 300, 130, 100)] == [384, 256, 128, 128]
     assert causal_pairs(8192, 512) == causal_pairs(8192, 512, causal_block(8192, 512))
     assert causal_pairs(400, 512) == causal_pairs(400)
     brute = sum(min(i + 1, 37) for i in range(300))
@@ -261,13 +290,16 @@ def test_a_window_of_four_blocks_at_the_longest_row_one_span_holds():
     """16 384 tokens under a 4096-token window (``smallthinker_pretrain_1x16k``):
     the block stays 1024, a query block reaches four key blocks back, three of
     them wholly inside the window, and the band visits 70 block pairs of the
-    triangle's 136 for 58.7 M needed entries of 134.2 M (1.25 x what the mask
-    keeps: at 8192 tokens the same window keeps 75% of a full layer's pairs,
-    here 44%); the backward kernel's accumulators fit one span."""
+    triangle's 136 for 58.7 M needed entries of 134.2 M; the 16 diagonal pairs
+    and the 12 the window's edge cuts are computed at 10 of their 16 sub-tiles,
+    59.5 pairs' worth (1.0625 x what the mask keeps, 1.25 x by whole pairs: at
+    8192 tokens the same window keeps 75% of a full layer's pairs, here 44%);
+    the backward kernel's accumulators fit one span."""
     assert causal_block(16384, 4096) == CAUSAL_BLOCK and _reach(4096, CAUSAL_BLOCK) == 4
     needed = 4096 * 4097 // 2 + (16384 - 4096) * 4096
-    assert causal_pairs(16384, 4096) == (70 * 1024 * 1024, needed) == (73_400_320, 58_722_304)
-    assert causal_pairs(16384) == (136 * 1024 * 1024, 16384 * 16385 // 2)
+    assert causal_pairs(16384, 4096) == ((42 * 16 + 28 * 10) * 256 * 256, needed) == (
+        62_390_272, 58_722_304)
+    assert causal_pairs(16384) == (130 * 1024 * 1024, 16384 * 16385 // 2)
     assert needed / (16384 * 16385 // 2) == pytest.approx(0.4375, abs=1e-4)
     qi, kj = _lower_triangle(16, reach=4)
     assert len(qi) == 70 and max(qi - kj) == 4
@@ -288,3 +320,182 @@ def test_the_dispatcher_hands_the_window_and_the_groups_to_either_form(monkeypat
     np.testing.assert_allclose(causal_attention(q, None, k, None, v, window=7),
                                want, rtol=2e-5, atol=2e-6)
     assert calls == [7]
+
+
+# ------------------------------------------------ a masked pair as row strips
+
+
+def _visible(block, window, apart):
+    """(block, block) bools: which entries of the pair ``apart`` blocks below
+    the diagonal a query sees, from positions alone."""
+    at = np.arange(block)
+    back = apart * block + at[:, None] - at[None, :]
+    return (back >= 0) & (back < (window or np.inf))
+
+
+# (block, window): no window; one inside the block, of one block, of whole
+# blocks, and cut at two distances (one tile-aligned, one not, one a token past
+# a block); the real kernels' blocks with the recipes' windows
+PLANS = [(16, None), (16, 13), (16, 5), (16, 16), (16, 32), (16, 40), (16, 37), (16, 17),
+         (32, 5), (32, 70), (512, 512), (1024, 4096), (1024, None), (256, 300), (384, 400)]
+
+
+@pytest.mark.parametrize("strips", [1, 2, 4, 8])
+@pytest.mark.parametrize("block,window", PLANS)
+def test_the_plan_of_strips_against_every_entry_of_the_mask(block, window, strips):
+    """For every distance the band walks: a pair no mask cuts is one strip, the
+    block; a cut pair's strips cover every entry the mask keeps, compute no
+    sub-tile that is hidden whole, and compare no more than the sub-tiles the
+    mask crosses; a strip that sees nothing is left out."""
+    tile = block // strips
+    reach = _reach(window, block) if window else 3
+    cuts = _cuts(block, window)
+    assert set(cuts) <= set(range(reach + 1)) and len(cuts) <= 3
+    for apart in range(reach + 1):
+        visible = _visible(block, window, apart)
+        assert visible.any()  # the tables walk no pair that is hidden whole
+        assert (apart in cuts) == (not visible.all())
+        lo, hi = cuts.get(apart, (1 - block, block))
+        at = np.arange(block)
+        np.testing.assert_array_equal(
+            visible, (at[:, None] - at[None, :] >= lo) & (at[:, None] - at[None, :] < hi))
+        plan = _strips(block, tile, lo, hi)
+        if apart not in cuts:
+            assert plan == ((0, block, 0, block, 0, block),)
+            continue
+        computed, compared = np.zeros_like(visible), np.zeros_like(visible)
+        for strip in plan:
+            rows = slice(strip.row, strip.row_end)
+            assert strip.row_end - strip.row == tile and strip.row % tile == 0
+            assert all(x % tile == 0 for x in strip[2:])
+            assert strip.col <= strip.clear <= strip.clear_end <= strip.col_end
+            assert not computed[rows].any()  # a strip once
+            computed[rows, strip.col:strip.col_end] = True
+            compared[rows, strip.col:strip.clear] = True
+            compared[rows, strip.clear_end:strip.col_end] = True
+        assert not (visible & ~computed).any()
+        assert visible[computed & ~compared].all()
+        tiles = lambda x: x.reshape(strips, tile, strips, tile).transpose(0, 2, 1, 3).reshape(
+            strips, strips, -1)
+        np.testing.assert_array_equal(tiles(computed).any(-1), tiles(visible).any(-1))
+        np.testing.assert_array_equal(tiles(compared).any(-1),
+                                      tiles(visible).any(-1) & ~tiles(visible).all(-1))
+
+
+def test_the_sub_tile_follows_from_the_block():
+    """A quarter of the block where that is whole 128-lane tiles, else half,
+    else the block: the blocks ``causal_block`` gives, and the interpreter's."""
+    assert [_sub_tile(b) for b in (1024, 512, 768, 256, 896, 384, 128, 64, 16)] == [
+        256, 128, 384, 128, 896, 384, 128, 64, 16]
+    for seq, window in [(8192, None), (8192, 512), (16384, 4096), (8192, 300), (8192, 130)]:
+        block = causal_block(seq, window)
+        assert block % _sub_tile(block) == 0 and _sub_tile(block) % 128 == 0
+
+
+def _forced(monkeypatch, fresh_traces, strips):
+    """The kernels at ``strips`` strips a masked pair, whatever the block (the
+    rule is read when a call is traced); returns the tiles the kernels' plans
+    are then asked for."""
+    fresh_traces()
+    monkeypatch.setattr(pallas_attention, "_sub_tile", lambda block: block // strips)
+    real, tiles = pallas_attention._strips, []
+    monkeypatch.setattr(pallas_attention, "_strips",
+                        lambda block, tile, lo, hi: tiles.append(tile) or real(block, tile, lo, hi))
+    return tiles
+
+
+# (query heads, key/value heads, seq, block, window, two_part, head width,
+# strips): one and two score parts; groups of 1, 7, 8 and (64 wide) 4; no
+# window, one inside the block (and inside a clamped block), of one block, of
+# whole blocks, one cut at two distances over a padded tail; sequences that
+# are no multiple of the block
+STRIPPED = [(3, 3, 40, 16, None, True, 16, 4), (7, 1, 100, 16, 64, False, 16, 2),
+            (8, 1, 70, 16, None, False, 16, 4), (8, 2, 70, 16, None, False, 64, 2),
+            (6, 2, 40, 16, 13, True, 16, 4), (6, 2, 40, 16, 13, False, 16, 2),
+            (8, 2, 48, 16, 16, False, 16, 4), (8, 2, 48, 16, 16, True, 16, 2),
+            (6, 2, 70, 16, 40, True, 16, 4), (6, 2, 70, 16, 37, False, 16, 4),
+            (8, 2, 24, 32, 5, True, 16, 4), (6, 2, 50, 16, 33, False, 16, 8)]
+
+
+def _forward_and_gradients(args, w, block, window):
+    """Output, log-sum-exp and the gradients of ``(out * w).sum()`` through the
+    custom VJP's two rules: each kernel once."""
+    def both(*xs):
+        o, residuals = pallas_attention._causal_vjp_fwd(*xs, block, True, window)
+        grads = pallas_attention._causal_vjp_bwd(block, True, window, residuals, w)
+        return o, residuals[6], [g for g in grads if g is not None]
+    return jax.jit(both)(*args)
+
+
+@pytest.mark.parametrize("h,g,seq,block,window,two_part,width,strips", STRIPPED)
+def test_strips_of_a_masked_pair_match_the_einsum_form_and_the_one_strip_body(
+        monkeypatch, fresh_traces, h, g, seq, block, window, two_part, width, strips):
+    """Forward output, log-sum-exp and every gradient of the kernels that
+    compute a masked pair as strips: against the einsum form within the
+    file's tolerances, and against the one-strip body (the whole pair under
+    its mask) to float32 rounding — a hidden entry's probability and ``ds``
+    are exact zeros, so leaving it out only reorders a row's sums."""
+    args, w = _inputs(seq + h, 1, h, g, seq, two_part, d_a=width, d_v=width if width == 64 else 12)
+    given = tuple(i for i, a in enumerate(args) if a is not None)
+    assert _sub_tile(block) == block
+    one = _forward_and_gradients(args, w, block, window)
+    tiles = _forced(monkeypatch, fresh_traces, strips)
+    o, lse, grads = _forward_and_gradients(args, w, block, window)
+    assert set(tiles) == {block // strips}  # both kernels were built from the plan of strips
+    assert causal_pairs(seq, window, block)[0] < len(_lower_triangle(
+        -(-seq // block), reach=_reach(window, block) if window and window < seq else None)[0]
+    ) * block * block  # which leaves something out
+    np.testing.assert_allclose(o, xla_causal_attention(*args, window), rtol=2e-5, atol=2e-6)
+    ref = jax.jit(jax.grad(lambda *xs: (xla_causal_attention(*xs, window) * w).sum(),
+                           argnums=given))(*args)
+    for a, r in zip(grads, ref, strict=True):
+        np.testing.assert_allclose(a, r, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(o, one[0], rtol=1e-5, atol=2e-6)
+    np.testing.assert_allclose(lse[..., :seq], one[1][..., :seq], rtol=1e-6, atol=2e-6)
+    for a, b in zip(grads, one[2], strict=True):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("h,g,window,two_part", [(6, 2, None, True), (6, 2, 40, False)])
+def test_strips_under_more_than_one_span(monkeypatch, fresh_traces, h, g, window, two_part):
+    """The strips' key rows of the span-long accumulators: with a budget that
+    splits five key blocks into spans of two, the stripped kernels' gradients
+    equal the one-span, one-strip call's."""
+    seq, block = 70, 16
+    args, w = _inputs(seq + h, 1, h, g, seq, two_part)
+    widths = (16, 12, 8) if two_part else (16, 12)
+    one = _forward_and_gradients(args, w, block, window)
+    budget = next(b for b in range(0, 1 << 20, 4096) if _causal_span(5, block, widths, 4, b) == 2)
+    monkeypatch.setattr(pallas_attention, "_causal_span",
+                        functools.partial(_causal_span, budget=budget))
+    _forced(monkeypatch, fresh_traces, 4)
+    _, _, grads = _forward_and_gradients(args, w, block, window)
+    for a, b in zip(grads, one[2], strict=True):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-6)
+
+
+# (seq, window, block): the recipes' layers, a padded tail, and the
+# interpreter's blocks (one strip: the count is the walk's)
+COUNTED = [(8192, None, None), (16384, None, None), (8192, 512, None), (16384, 4096, None),
+           (8192, 512, 1024), (8192, 512, 256), (2148, None, None), (2148, 512, None),
+           (8192, 300, None), (70, 40, 16), (24, None, 128), (300, 37, 16)]
+
+
+@pytest.mark.parametrize("seq,window,block", COUNTED)
+def test_the_counter_is_the_sum_of_the_plans_sub_tiles(seq, window, block):
+    """``visited`` from the masks alone: a pair of the walk that no mask cuts
+    whole, a cut pair by its sub-tiles that hold a visible entry; ``needed`` is
+    the mask's own count, as before."""
+    visited, needed = causal_pairs(seq, window, block)
+    s_pad, blk, win, reach = pallas_attention._causal_band(seq, window, block)
+    tile = _sub_tile(blk)
+    n, per = blk // tile, {}
+    for apart in range((reach if reach is not None else s_pad // blk - 1) + 1):
+        visible = _visible(blk, win, apart)
+        held = visible.reshape(n, tile, n, tile).any(axis=(1, 3)).sum() * tile * tile
+        per[apart] = blk * blk if visible.all() else held
+    qi, kj = _lower_triangle(s_pad // blk, reach=reach)
+    assert visited == sum(per[a] for a in (qi - kj).tolist())
+    assert visited <= len(qi) * blk * blk and (tile < blk) == (visited < len(qi) * blk * blk)
+    w = min(window or seq, seq)
+    assert needed == sum(min(i + 1, w) for i in range(seq)) <= visited

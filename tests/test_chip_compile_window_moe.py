@@ -50,8 +50,10 @@ def assert_the_window_and_rope_free_full_step(text: str, cfg, lm) -> None:
     assert (rows, seq) == (1, 16384)
     assert lm.kinds[0] == "full_attention" and lm.rope("full_attention") is None
     assert not re.search(r'op_name="[^"]*block_0/attn/rope[/"]', text)
-    assert lm.attn_pairs(seq) == {"full_attention": (142_606_336, 134_225_920),
-                                  "sliding_attention": (73_400_320, 58_722_304)}
+    # 136 and 70 block pairs walked; the 16 diagonal ones, and the 12 the
+    # window's edge cuts, at 10 of their 16 sub-tiles: 130 and 59.5 pairs' worth
+    assert lm.attn_pairs(seq) == {"full_attention": (136_314_880, 134_225_920),
+                                  "sliding_attention": (62_390_272, 58_722_304)}
     for wide in (f"[{rows},{lm.heads},{seq},{seq}]", f"[{lm.heads},{seq},{seq}]", f"[{seq},{seq}]"):
         assert wide not in text, wide
     assert "/shared_expert/" not in text and "/dense_mlp/" not in text

@@ -13,6 +13,8 @@ import pytest
 import chip_smoke
 from test_chip_compile import watch  # noqa: F401 - fixture
 
+from jumbo_mae_tpu_tpu.ops.pallas.attention import _sub_tile
+
 RECIPES = chip_smoke.REPO / "recipes"
 # every recipe is cut the same way: 8 sequences a step, a 64-row slice of a
 # 512-row vocabulary, width 32, 16 experts top-4 of which 4 are held
@@ -32,7 +34,9 @@ def _all_mla(checked, published, records):
     assert 0.1 < checked["moe_held_share_min_max"][0] <= checked["moe_held_share_min_max"][1] < 0.5
     assert checked["mfu_trainer_reported"] is None  # a CPU count is not a device rate
     assert checked["kda_kernel_calls"] == {"fwd": 0, "bwd": 0, "loops": 0}
+    # 16 tokens are one clamped block of 128: under 256 a masked pair is one strip
     assert checked["attn_pairs"] == {"mla": {"visited": 128 * 128, "needed": 16 * 17 // 2}}
+    assert _sub_tile(128) == 128
     assert {"imbalance", "held_share", "dropped", "rounds", "rows_max_l1", "rows_min_mtp",
             "rounds_l1", "rounds_mtp"} <= set(published["train_moe"])
     assert published["train_moe"]["rounds"] == 1

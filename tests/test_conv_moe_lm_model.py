@@ -25,6 +25,7 @@ from benchmarks.reference import params as ref_params
 from jumbo_mae_tpu_tpu.models import lm
 from jumbo_mae_tpu_tpu.models.lm import MlaMoeConfig, MlaMoeLM, ShortConv, SparseExperts
 from jumbo_mae_tpu_tpu.ops import kda
+from jumbo_mae_tpu_tpu.ops.pallas.attention import _sub_tile
 
 DRIVER = harness.load_module("drivers", "conv_moe_lm_steps")
 CELL = "lfm2_24b_pretrain_2x8k"
@@ -93,7 +94,9 @@ def test_the_tiny_cut_holds_a_block_of_every_kind_and_a_tied_head():
     assert cfg.qk_norm and cfg.tie_embeddings and cfg.shared_hidden == 0
     # a conv block has no heads and no (query, key) pairs
     assert cfg.attn_heads() == {"full_attention": (4, 4)}
+    # one clamped block of 128, which a masked pair computes whole (one strip)
     assert cfg.attn_pairs(24) == {"full_attention": (128 * 128, 24 * 25 // 2)}
+    assert _sub_tile(128) == 128
     # the other families' defaults stay theirs
     assert (MlaMoeConfig().qk_norm, MlaMoeConfig().tie_embeddings) == (False, False)
     assert MlaMoeConfig().layers_by_kind == {"mla": 40}

@@ -366,7 +366,9 @@ def test_token_flops_at_16k_a_window_layer_is_44_percent_of_a_full_one():
     assert flops.needed_pairs(config, "sliding_attention", 8192) / (8192 * 8193 // 2) \
         == pytest.approx(0.75, abs=2e-4)  # at 8192 the window shows little
     assert cfg.attn_pairs(16384) == {
-        "full_attention": (136 * 1024 * 1024, full), "sliding_attention": (70 * 1024 * 1024, window)}
+        # the 16 diagonal pairs, and the window's 12 cut ones, at 10 of 16 sub-tiles
+        "full_attention": (130 * 1024 * 1024, full),
+        "sliding_attention": (70 * 1024 * 1024 - 28 * 6 * 256 * 256, window)}
     for seq in (16384, 8192, 1000):
         assert lm_flops_per_token(cfg, seq) == pytest.approx(flops.token_step(config, seq), rel=1e-12)
     assert 16384 * flops.token_step(config, 16384) == pytest.approx(34.70e12, rel=1e-3)
