@@ -306,23 +306,30 @@ CAUSAL_SHAPES = ((1, 4, 2148, 128, 64, 128), (1, 16, 2148, 128, 0, 128, 2, 512),
 # (batch, heads, seq, width) of the rotate-half rope kernel's operand: 128 and
 # 64 wide (ops/pallas/rope.py takes whole and half lane tiles)
 ROPE_SHAPES = ((1, 4, 2048, 128), (1, 4, 2048, 64))
+# (batch, heads, seq, width) of the linear-attention layers' short-convolution
+# kernels' operand (ops/pallas/short_conv.py): two head blocks of four
+# sequence blocks each
+CONV_SHAPES = ((1, 16, 2048, 128),)
 # (rows, k, n, group sizes): one expert takes most rows, one takes none
 GROUPED_SHAPE = (4096, 2048, 1536, (2900, 0, 517, 200, 33, 8, 1, 300))
 
 
-def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, rope=ROPE_SHAPES, *,
-                     interpret: bool = False) -> dict:
+def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, rope=ROPE_SHAPES,
+                     conv=CONV_SHAPES, *, interpret: bool = False) -> dict:
     """The causal flash kernels (unequal qk and v widths, the shared rope
     key; grouped key/value heads under a window; 64-wide heads) forward+backward
     against the einsum form, the rotate-half rope kernel against its
-    ``jax.numpy`` form, and the grouped product forward+backward against
-    ``lax.ragged_dot``; worst relative errors."""
+    ``jax.numpy`` form, the short-convolution kernels (q's form, with the
+    norm, and v's, without) forward+backward against ``short_conv_plain``,
+    and the grouped product forward+backward
+    against ``lax.ragged_dot``; worst relative errors."""
     import jax
     import jax.numpy as jnp
 
     from jumbo_mae_tpu_tpu.models.lm import Rope, rope_half
     from jumbo_mae_tpu_tpu.ops.attention import xla_causal_attention
     from jumbo_mae_tpu_tpu.ops.grouped_matmul import grouped_matmul
+    from jumbo_mae_tpu_tpu.ops.kda import short_conv, short_conv_plain
     from jumbo_mae_tpu_tpu.ops.pallas.attention import pallas_causal_attention
 
     worst: dict[str, float] = {}
@@ -364,6 +371,21 @@ def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, rope=ROPE_SHAP
                                  * w).sum(), None),
                 lambda x, w=w: ((jnp.stack([rope_half(row, turn) for row in x])
                                  .astype(jnp.float32) * w).sum(), None), (x,))
+
+    for b, h, s, d in conv:
+        keys = jax.random.split(jax.random.key(h), 3)
+        x = jax.random.normal(keys[0], (b, h, s, d)).astype(jnp.bfloat16)
+        taps = jax.random.uniform(keys[1], (4, h, d), jnp.float32, -0.5, 0.5)
+        w = jax.random.normal(keys[2], (b, h, s, d))
+        for name, scale in (("q", d**-0.5), ("v", None)):
+            # interpret or not, the op's own rule sends this shape to the kernels on the chip
+            compare(f"short_conv_{name}@{s}x{d}",
+                    lambda x, taps, scale=scale, w=w: (
+                        (short_conv(x, taps, scale, interpret=interpret).astype(jnp.float32)
+                         * w).sum(), None),
+                    lambda x, taps, scale=scale, w=w: (
+                        (short_conv_plain(x, taps, scale).astype(jnp.float32) * w).sum(), None),
+                    (x, taps))
 
     m, k, n, sizes = grouped
     keys = jax.random.split(jax.random.key(m), 3)
@@ -407,6 +429,18 @@ def kda_kernel_calls(text: str) -> dict:
     calls = {k: len(re.findall(rf'custom-call\([^\n]*/kda_chunk_{k}/pallas_call"', text))
              for k in ("fwd", "bwd")}
     return {**calls, "loops": len(re.findall(r' while\([^\n]*/attn/kda_core/[^"\n]*while"', text))}
+
+
+def short_conv_kernel_calls(text: str) -> dict:
+    """How often a compiled program's text runs the linear-attention layers'
+    short-convolution kernels (``ops/pallas/short_conv.py``), by phase:
+    ``{"fwd": the forward kernel in the forward pass, "recompute": under a
+    block's remat, "bwd": the backward kernel}``."""
+    names = re.findall(r'custom-call\([^\n]*op_name="([^"]*)/kda_short_conv_(fwd|bwd)/pallas_call"',
+                       text)
+    return {"fwd": sum(k == "fwd" and "rematted_computation" not in path for path, k in names),
+            "recompute": sum(k == "fwd" and "rematted_computation" in path for path, k in names),
+            "bwd": sum(k == "bwd" for _, k in names)}
 
 
 def rope_kernel_calls(text: str) -> int:
@@ -507,6 +541,19 @@ def check_step_runs_the_kda_kernels(programs: dict, lm) -> dict:
     return calls
 
 
+def check_step_runs_the_short_conv_kernels(programs: dict, lm) -> dict:
+    """The step program among ``programs`` sends q, k and v of each of
+    ``lm``'s linear-attention blocks through the short-convolution kernels:
+    three forward calls a block, three under its remat, three backward. Off
+    the chip the filter, SiLU and norm are the plain composition: no kernel."""
+    import jax
+
+    calls = short_conv_kernel_calls(programs["train_step"].as_text())
+    want = dict.fromkeys(calls, 3 * lm.kda_layers if jax.default_backend() == "tpu" else 0)
+    check(calls == want, f"the step runs the short-convolution kernels {calls}, not {want}")
+    return calls
+
+
 def check_step_runs_each_causal_kernel_once_a_block(programs: dict, lm) -> dict:
     """The step program among ``programs`` runs each of the causal core's
     kernels once for each of ``lm``'s latent-attention blocks: a rematted block keeps the
@@ -552,6 +599,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     lm = MlaMoeConfig(**cfg.model.lm)
     calls = check_step_runs_each_causal_kernel_once_a_block(programs, lm)
     kda_calls = check_step_runs_the_kda_kernels(programs, lm)
+    conv_calls = check_step_runs_the_short_conv_kernels(programs, lm)
     rope_calls = check_step_runs_the_rope_kernel(programs, lm)
     head_calls = check_step_runs_the_head_three_times(
         programs, lm, cfg.run.train_batch_size * cfg.data.seq_len, cfg.data.seq_len)
@@ -603,6 +651,7 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         "moe_rounds": 1,
         "causal_kernel_calls": calls,
         "kda_kernel_calls": kda_calls,
+        "short_conv_kernel_calls": conv_calls,
         "rope_kernel_calls": rope_calls,
         "head_product_calls": head_calls,
         "attn_pairs": {kind: {"visited": visited, "needed": needed} for kind, (visited, needed)

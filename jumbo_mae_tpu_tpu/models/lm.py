@@ -106,7 +106,8 @@ configuration or recipe here sets the two apart: only the test cuts do) with
 causal depthwise convolution of ``kda_conv`` taps, one filter a channel, zero
 history before the sequence (one document a sequence), then SiLU:
 ``u_t = silu(Σ_j c_j ⊙ ũ_{t−K+1+j})``; ``q̂ = q / ‖q‖₂ · d_k^-½``, ``k̂ = k /
-‖k‖₂`` (eps 1e-6; no norm of ``v``); the per-channel log-decay in its safe
+‖k‖₂`` (eps 1e-6; no norm of ``v``; filter, SiLU and norm are one function,
+``ops/kda.short_conv``, one Pallas pass a tensor on the TPU); the per-channel log-decay in its safe
 form, ``g_t = lower_bound · sigmoid(exp(A_log_h) · (x W_f + dt_bias))`` with
 one ``A_log`` a head and one ``dt_bias`` a channel, so ``g`` lies in
 ``(lower_bound, 0)`` (``kda_gate: "safe"``), or with no floor ``g_t =
@@ -222,7 +223,7 @@ from jumbo_mae_tpu_tpu.obs.trace import (
 from jumbo_mae_tpu_tpu.ops.attention import causal_attention
 from jumbo_mae_tpu_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, grouped_outer
 from jumbo_mae_tpu_tpu.ops.head_loss import head_loss
-from jumbo_mae_tpu_tpu.ops.kda import causal_conv, kda_chunked
+from jumbo_mae_tpu_tpu.ops.kda import causal_conv, kda_chunked, short_conv
 
 # the counters an expert layer reports, in the order of its stats vector
 MOE_COUNTERS = ("rows_min", "rows_mean", "rows_max", "imbalance", "held_share", "dropped",
@@ -234,7 +235,6 @@ ACT_ZERO_COUNTER = "act_zero_share"
 KDA_COUNTERS = ("state_absmax", "decay_mean", "beta_max", "neg_eig_share")
 
 
-KDA_UNIT_EPS = 1e-6  # under the root of q's and k's L2 norm; not the RMSNorms' rms_eps
 GQA_KINDS = ("full_attention", "sliding_attention")
 ATTENTION_KINDS = ("kda", "mla", *GQA_KINDS)
 # a block's token mixer: one of the attention kinds, or the gated short
@@ -718,12 +718,6 @@ def _head_gate(z, gate):
     return z * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None].astype(z.dtype)
 
 
-def _unit_norm(x, eps: float):
-    """``x / sqrt(Σ x² + eps)`` over the last axis, float32 inside."""
-    xf = x.astype(jnp.float32)
-    return xf * jax.lax.rsqrt(jnp.sum(jnp.square(xf), axis=-1, keepdims=True) + eps)
-
-
 def _decay_bias_init(key, shape, a_log, lower_bound):
     """``dt_bias`` drawn so that the decay a step at a zero gate input,
     ``exp(lower_bound · sigmoid(exp(A_log) · dt_bias))``, leaves ``1 − α``
@@ -778,11 +772,10 @@ class KdaAttention(nn.Module):
                     key, (cfg.kda_conv, h, dh), f32, -bound, bound)}
                 return self.param(name, make)["kernel"]
 
-            q, k, v = (causal_conv(u, filt(f"{n}_conv"), "silu") for n, u in
-                       (("q", q), ("k", k), ("v", v)))
+            # filter, SiLU and (q, k) the L2 norm with its scale: one pass a tensor
+            q, k, v = (short_conv(u, filt(f"{n}_conv"), scale) for n, u, scale in
+                       (("q", q, dh**-0.5), ("k", k, 1.0), ("v", v, None)))
         with jax.named_scope(SCOPE_KDA_GATE):
-            q = (_unit_norm(q, KDA_UNIT_EPS) * dh**-0.5).astype(dtype)
-            k = _unit_norm(k, KDA_UNIT_EPS).astype(dtype)
             rates = (0.25, 1.0) if safe else (1.0, 16.0)  # exp(A_log)'s range: assumed
             a_log = self.param("A_log", lambda key, shape: jnp.log(
                 jax.random.uniform(key, shape, f32, *rates)), (h,))

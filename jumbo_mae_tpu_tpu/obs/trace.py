@@ -105,8 +105,8 @@ SCOPE_LM_HEAD = "lm_head"  # final norm; a tile of tokens at a time (ops/head_lo
 # that kind has no mla_latent / rope / attn_core / attn_out. An MLA block's
 # head-wise gate lies under attn_out, the expert groups' choice under router.
 SCOPE_KDA_PROJ = "kda_proj"  # the projections of x: q, k, v, decay gate, beta, output gate (a gate's two factors where it goes through a rank)
-SCOPE_KDA_CONV = "kda_conv"  # the causal depthwise convolutions of q, k, v and their SiLU
-SCOPE_KDA_GATE = "kda_gate"  # L2 norms, log-decay, beta; under kda_out: output norm and output gate
+SCOPE_KDA_CONV = "kda_conv"  # q, k, v through ops/kda.short_conv: causal depthwise filter, SiLU, q's and k's L2 norm
+SCOPE_KDA_GATE = "kda_gate"  # log-decay, beta, the counters; under kda_out: output norm and output gate
 SCOPE_KDA_CORE = "kda_core"  # (q, k, v, g, beta) -> o: the chunked gated delta rule
 SCOPE_KDA_OUT = "kda_out"  # output norm and head-wise gate (also under kda_gate), W_o
 # ... and in its grouped-query layers (full or sliding-window softmax attention

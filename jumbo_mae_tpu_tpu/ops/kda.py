@@ -3,7 +3,9 @@ core), in chunked form, and the causal depthwise convolution that feeds it —
 ``causal_conv``, one function with the activation an argument, which serves
 two families of layer: with SiLU the linear-attention layers' q, k and v
 (``models/lm.KdaAttention``), with none the gated short-convolution mixer's
-three-tap filter (``models/lm.ShortConv``).
+three-tap filter (``models/lm.ShortConv``). ``short_conv`` is what the
+linear-attention layers call: that filter, SiLU and q's and k's L2 norm, on
+the TPU one Pallas pass a tensor each way (``ops/pallas/short_conv.py``).
 
 Per head, with a state ``S`` of shape (d_k, d_v) that starts at zero::
 
@@ -135,6 +137,67 @@ def _conv_bwd(act, residuals, dy):
 
 
 causal_conv.defvjp(_conv_fwd, _conv_bwd)
+
+# under the root of q's and k's L2 norm; not the RMSNorms' rms_eps
+UNIT_EPS = 1e-6
+
+
+def short_conv_plain(x, w, unit_scale: float | None = None):
+    """``short_conv`` as ``jax.numpy`` has it: the filter and SiLU rounded to
+    ``x``'s dtype, then, where ``unit_scale`` is a number, ``y / sqrt(Σ y² +
+    UNIT_EPS)`` over the last axis times it, float32 inside."""
+    y = causal_conv(x, w, "silu")
+    if unit_scale is None:
+        return y
+    yf = y.astype(jnp.float32)
+    unit = yf * jax.lax.rsqrt(jnp.sum(jnp.square(yf), axis=-1, keepdims=True) + UNIT_EPS)
+    return (unit * unit_scale).astype(x.dtype)
+
+
+def short_conv(x, w, unit_scale: float | None = None, *, interpret: bool = False):
+    """What a linear-attention layer does to q, k or v between its projection
+    and the chunk kernels: ``causal_conv(x, w, "silu")`` and, where
+    ``unit_scale`` is a number (q: ``d ** -0.5``, k: 1; v: None), the L2 norm
+    over the last axis times it, in ``x``'s dtype. ``x`` (batch, heads, seq,
+    d), ``w`` (taps, heads, d).
+
+    The schedule follows the backend and the shapes, as ``kda_chunked``'s
+    does: on a TPU, for a shape ``ops/pallas/short_conv.short_conv_blocks``
+    takes, one Pallas pass forward and one backward (from ``x``, ``w`` and the
+    cotangent alone, as ``causal_conv`` keeps them), the plain form's rounding
+    points kept; elsewhere ``short_conv_plain``, which JAX differentiates.
+    ``interpret`` runs the kernels in the Pallas interpreter (tests)."""
+    from jumbo_mae_tpu_tpu.ops.pallas.short_conv import HISTORY, short_conv_blocks
+
+    suits = (x.ndim == 4 and w.shape == (w.shape[0], x.shape[1], x.shape[3])
+             and w.shape[0] <= HISTORY + 1 and short_conv_blocks(*x.shape[1:]) is not None)
+    if interpret and not suits:
+        raise ValueError(f"the kernels do not take x {x.shape} with w {w.shape}")
+    if suits and (interpret or jax.default_backend() == "tpu"):
+        return _short_conv_kernels(x, w, unit_scale, interpret)
+    return short_conv_plain(x, w, unit_scale)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _short_conv_kernels(x, w, unit_scale, interpret):
+    from jumbo_mae_tpu_tpu.ops.pallas.short_conv import short_conv_forward
+
+    return short_conv_forward(x, w, unit_scale, UNIT_EPS, interpret=interpret)
+
+
+def _short_conv_fwd(x, w, unit_scale, interpret):
+    return _short_conv_kernels(x, w, unit_scale, interpret), (x, w)
+
+
+def _short_conv_bwd(unit_scale, interpret, residuals, dy):
+    from jumbo_mae_tpu_tpu.ops.pallas.short_conv import short_conv_backward
+
+    x, w = residuals
+    dx, dw = short_conv_backward(x, w, dy, unit_scale, UNIT_EPS, interpret=interpret)
+    return dx, dw.astype(w.dtype)
+
+
+_short_conv_kernels.defvjp(_short_conv_fwd, _short_conv_bwd)
 
 
 def _unit_lower_inverse(lower, base: int = 16):

@@ -190,7 +190,9 @@ def assert_the_step_is_built_a_block_at_a_time(text: str, cfg, lm) -> None:
     ``swa_core`` and every other under ``attn_core`` (a rematted block keeps
     the forward kernel's output and log-sum-exp; the backward is one kernel,
     PR 37); each linear-attention block runs the forward chunk kernel twice
-    and the backward once with no loop left under ``kda_core``; each block
+    and the backward once with no loop left under ``kda_core``, and sends its
+    q, k and v through the short-convolution kernels forward, rematted and
+    backward (PR 46); each block
     with a rotate-half rope turns its q and its k through the rope kernel
     three times (forward, rematted, transposed); the heads walk their tokens
     in tiles; and every expert layer walks its held pairs in one loop each
@@ -207,6 +209,8 @@ def assert_the_step_is_built_a_block_at_a_time(text: str, cfg, lm) -> None:
     assert by_scope == {"attn_core": 2 * (softmax - sliding), "swa_core": 2 * sliding}
     assert chip_smoke.kda_kernel_calls(text) == {"fwd": 2 * lm.kda_layers, "bwd": lm.kda_layers,
                                                  "loops": 0}
+    assert chip_smoke.short_conv_kernel_calls(text) == dict.fromkeys(
+        ("fwd", "recompute", "bwd"), 3 * lm.kda_layers)
     roped = sum(kind in GQA_KINDS and lm.rope(kind) is not None for kind in lm.kinds)
     assert chip_smoke.rope_kernel_calls(text) == roped * 2 * 3
     assert_the_head_walks_its_tokens_in_tiles(text, cfg, lm)
@@ -318,10 +322,11 @@ def test_lm_kernels_phase_rehearsal_interpreted():
     got = chip_smoke.phase_lm_kernels(
         causal=((1, 2, 40, 16, 8, 16), (1, 6, 40, 16, 0, 16, 2, 21)),
         grouped=(64, 32, 24, (41, 0, 9, 6)), rope=((1, 2, 32, 128), (1, 2, 32, 64)),
-        interpret=True)
+        conv=((1, 2, 48, 128),), interpret=True)
     assert got["mosaic_custom_call"] is False
     assert set(got["max_rel_err_vs_xla"]) == {"causal@40x16+8/16", "causal@40x16+0/16g3w21",
-                                              "rope@32x128", "rope@32x64", "grouped@64x32x24"}
+                                              "rope@32x128", "rope@32x64", "grouped@64x32x24",
+                                              "short_conv_q@48x128", "short_conv_v@48x128"}
     assert max(got["max_rel_err_vs_xla"].values()) < chip_smoke.KERNEL_REL_TOL
 
 

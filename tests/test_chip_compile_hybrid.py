@@ -34,6 +34,10 @@ PROGRAM_BYTES, CHIP_BYTES = 15_168_285_184, 16.9e9
 # tier-1's compile: one linear-attention block (the dense one) and one MLA
 # block with experts, the period cut from six to two with the depth
 DEPTH_CUT = ["model.lm.layers=2", "model.lm.layer_group_size=2"]
+# what the cut's compile read before the short-convolution kernels (PR 45's
+# tree; 6 402 869 760 with them, PR 46: the filter's and the norms' float32
+# temporaries are gone), the bound with no slack
+CUT_PROGRAM_BYTES = 7_628_414_976
 
 
 def assert_the_hybrid_step(text: str, cfg, lm) -> None:
@@ -55,7 +59,8 @@ def test_hybrid_language_model_step_compiles_for_v5e_at_cut_depth(v5e_chip, monk
     tokens: every structural assertion of the full compile, which is ``slow``."""
     cfg, lm, _, compiled = compile_lm_step(RECIPE, v5e_chip, monkeypatch, DEPTH_CUT)
     assert (lm.kinds, lm.first_k_dense) == (("kda", "mla"), 1)
-    assert_the_hybrid_step(compiled.as_text(), cfg, lm)
+    assert_the_hybrid_step(compiled.as_text(), cfg, lm)  # 3 · 3 · 3 short-convolution calls
+    assert program_bytes(compiled) <= CUT_PROGRAM_BYTES
 
 
 # slow: 215 s of one worker; the chip run of every cell covers "fits". By hand

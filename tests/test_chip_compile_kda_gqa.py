@@ -34,6 +34,9 @@ PROGRAM_BYTES, LADDER_BYTES, CHIP_BYTES = 14_154_523_648, 15.2e9, 16.9e9
 # tier-1's compile: the grouped-query block and one of the three
 # linear-attention blocks, both with experts
 DEPTH_CUT = ["model.lm.layers=2", "model.lm.layer_types=[full_attention, kda]"]
+# what the cut's compile read before the short-convolution kernels (PR 45's
+# tree; 9 404 760 064 with them, PR 46), the bound with no slack
+CUT_PROGRAM_BYTES = 10_697_937_408
 
 
 def assert_the_linear_and_grouped_query_step(text: str, cfg, lm) -> None:
@@ -59,7 +62,8 @@ def test_linear_and_grouped_query_step_compiles_for_v5e_at_cut_depth(v5e_chip, m
     tokens: every structural assertion of the full compile, which is ``slow``."""
     cfg, lm, _, compiled = compile_lm_step(RECIPE, v5e_chip, monkeypatch, DEPTH_CUT)
     assert (lm.kinds, lm.first_k_dense) == (("full_attention", "kda"), 0)
-    assert_the_linear_and_grouped_query_step(compiled.as_text(), cfg, lm)
+    assert_the_linear_and_grouped_query_step(compiled.as_text(), cfg, lm)  # 3 · 3 · 3 short-conv calls
+    assert program_bytes(compiled) <= CUT_PROGRAM_BYTES
 
 
 # slow: 154 s of one worker; the chip run of every cell covers "fits". By hand

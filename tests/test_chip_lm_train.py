@@ -46,6 +46,8 @@ def _hybrid(checked, published, records):
     """7 blocks: dense KDA, KDA, KDA, KDA, KDA, MLA, KDA; the
     linear-attention counters beside the experts'."""
     assert checked["kda_kernel_calls"] == {"fwd": 0, "bwd": 0, "loops": 3 * 6}  # off the chip: the scan
+    # ... and the plain filter, SiLU and norm where the chip runs one kernel a tensor
+    assert checked["short_conv_kernel_calls"] == {"fwd": 0, "recompute": 0, "bwd": 0}
     assert 0 < checked["kda_state_absmax_max"] < 10
     low, high = checked["kda_decay_mean_min_max"]
     assert 0.9 < low <= high < 1.0
