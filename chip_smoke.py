@@ -312,12 +312,20 @@ ROPE_SHAPES = ((1, 4, 2048, 128), (1, 4, 2048, 64))
 CONV_SHAPES = ((1, 16, 2048, 128),)
 # (rows, k, n, group sizes): one expert takes most rows, one takes none
 GROUPED_SHAPE = (4096, 2048, 1536, (2900, 0, 517, 200, 33, 8, 1, 300))
+# (batch, heads, key/value heads, clean tokens a sequence, width, diffusion
+# block) of the causal kernels under the block-diffusion pattern, every row
+# against the einsum form: a short row that is no multiple of the kernel's
+# block, and the SDAR cell's own shape (2 x 8192 rows a sequence, 32 heads
+# over 4: all 80 block pairs of the 8-block tables, the 24 cut ones among them)
+BLOCKDIFF_SHAPES = ((1, 8, 1, 2148, 128, 4), (1, 32, 4, 8192, 128, 4))
 
 
 def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, rope=ROPE_SHAPES,
-                     conv=CONV_SHAPES, *, interpret: bool = False) -> dict:
+                     conv=CONV_SHAPES, blockdiff=BLOCKDIFF_SHAPES, *,
+                     interpret: bool = False) -> dict:
     """The causal flash kernels (unequal qk and v widths, the shared rope
-    key; grouped key/value heads under a window; 64-wide heads) forward+backward
+    key; grouped key/value heads under a window; 64-wide heads; a clean and a
+    noisy copy under the block-diffusion pattern) forward+backward
     against the einsum form, the rotate-half rope kernel against its
     ``jax.numpy`` form, the short-convolution kernels (q's form, with the
     norm, and v's, without) forward+backward against ``short_conv_plain``,
@@ -359,6 +367,32 @@ def phase_lm_kernels(causal=CAUSAL_SHAPES, grouped=GROUPED_SHAPE, rope=ROPE_SHAP
                 weigh(lambda *xs, window=window: pallas_causal_attention(
                     *xs, block, interpret, window)),
                 weigh(lambda *xs, window=window: xla_causal_attention(*xs, window)), args)
+
+    for b, h, g, s, d, unit in blockdiff:
+        keys = jax.random.split(jax.random.key(s + unit), 4)
+        bf = lambda k, shape, scale=1.0: (jax.random.normal(k, shape) * scale).astype(jnp.bfloat16)
+        # scores of deviation 6: a row's output is then a few keys' values however many it
+        # sees, and a key wrongly seen or hidden moves some late row's output by its size
+        # (under scores of deviation 1 a late row is a mean of thousands that 4 keys cannot move)
+        q, k, v = bf(keys[0], (b, h, 2 * s, d), 6 * d**-0.5), bf(keys[1], (b, g, 2 * s, d)), bf(
+            keys[2], (b, g, 2 * s, d))
+        w = jax.random.normal(keys[3], (b, h, 2 * s, d))
+        block = None if not interpret else 16
+
+        def kernels(q, k, v, unit=unit, w=w, block=block):
+            o = pallas_causal_attention(q, None, k, None, v, block, interpret, None, unit)
+            return (o.astype(jnp.float32) * w).sum(), o
+
+        def einsum(q, k, v, unit=unit, w=w, group=h // g):
+            # a query head at a time: one head's (2 s, 2 s) scores are 1 GB at the cell's shape
+            one = jax.checkpoint(lambda x: xla_causal_attention(
+                x[0][:, None], None, x[1][:, None], None, x[2][:, None], None, unit)[:, 0])
+            heads = lambda x: jnp.moveaxis(x, 1, 0)
+            o = heads(jax.lax.map(one, (heads(q), heads(jnp.repeat(k, group, axis=1)),
+                                        heads(jnp.repeat(v, group, axis=1)))))
+            return (o.astype(jnp.float32) * w).sum(), o
+
+        compare(f"blockdiff@2x{s}x{d}g{h // g}b{unit}", kernels, einsum, (q, k, v))
 
     for b, h, s, d in rope:
         keys = jax.random.split(jax.random.key(d), 2)
@@ -420,6 +454,15 @@ def causal_kernel_calls(text: str) -> dict:
     two kernels: ``{"fwd": n, "bwd": n}``."""
     return {k: len(re.findall(rf'custom-call\([^\n]*/causal_attention_{k}/pallas_call"', text))
             for k in ("fwd", "bwd")}
+
+
+def bd_kernel_calls(text: str) -> dict:
+    """How often a compiled program's text calls each of the causal core's two
+    kernels under the block-diffusion pattern, that is inside the ``bd_core``
+    scope: ``{"fwd": n, "bwd": n}``; 0 in every causal family's step."""
+    return {k: len(re.findall(
+        rf'custom-call\([^\n]*/bd_core/[^\n"]*causal_attention_{k}/pallas_call"', text))
+        for k in ("fwd", "bwd")}
 
 
 def kda_kernel_calls(text: str) -> dict:
@@ -572,6 +615,39 @@ def check_step_runs_each_causal_kernel_once_a_block(programs: dict, lm) -> dict:
     return calls
 
 
+def check_step_runs_the_block_diffusion_core(programs: dict, lm) -> dict:
+    """The step program's text calls the causal kernels under the ``bd_core``
+    scope once each a block of a block-diffusion model on the TPU (the
+    rematted forward dead, as the causal core's: PR 30), and never in a
+    causal family's step or off the chip."""
+    import jax
+
+    calls = bd_kernel_calls(programs["train_step"].as_text())
+    on_chip = bool(lm.diffusion_block) and jax.default_backend() == "tpu"
+    want = lm.layers if on_chip else 0
+    check(calls == {"fwd": want, "bwd": want},
+          f"the step calls the causal kernels under bd_core {calls}, not {want} times each")
+    return calls
+
+
+@contextlib.contextmanager
+def one_noise_draw():
+    """Inside, every step of a block-diffusion model draws its noise from one
+    key, whatever its ``noise`` stream hands it: a batch's second visit is then
+    under the levels and masks of its first, and its loss has to fall as a
+    causal model's does. The program has no option for this; the smoke puts
+    itself between the model and ``ops/masking.block_noise``."""
+    import jax
+    from jumbo_mae_tpu_tpu.models import lm
+
+    real = lm.block_noise
+    lm.block_noise = lambda key, *sizes: real(jax.random.key(0), *sizes)
+    try:
+        yield
+    finally:
+        lm.block_noise = real
+
+
 def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: int) -> dict:
     """``cli.train`` on the language-model recipe for ``steps`` steps of
     seeded tokens, every step's metrics logged: every loss finite; each of
@@ -583,26 +659,35 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     block, the rope kernel six times a grouped-query block and each head's
     product three times, all in the forward pass; where the
     recipe has linear-attention layers, their counters are logged on every
-    step and their states stay bounded. ``recipe`` is any language family's
+    step and their states stay bounded. A block-diffusion recipe's steps all
+    draw one noise (``one_noise_draw``), so that its loss falls from visit to
+    visit too; its first loss and masked share are held to the objective's,
+    its core's kernels counted under ``bd_core``, and the one round is not
+    asked of it (below). ``recipe`` is any language family's
     (``--lm-recipe``)."""
     from jumbo_mae_tpu_tpu.cli import train as cli_train
     from jumbo_mae_tpu_tpu.models.lm import MlaMoeConfig
     from jumbo_mae_tpu_tpu.obs.trace import format_setup_report, keeping_programs, setup_report
+    from jumbo_mae_tpu_tpu.ops.masking import BLOCK_NOISE_EPS
 
+    cfg = _load(recipe, overrides)
+    lm = MlaMoeConfig(**cfg.model.lm)
     before, t0 = _registry_snapshot(), time.perf_counter()
-    with keeping_programs() as programs:  # the trainer's step dies with its loop
+    # the trainer's step dies with its loop; a block-diffusion recipe's steps all
+    # draw one noise, so that a batch's second visit is under its first's masks
+    with keeping_programs() as programs, (
+            one_noise_draw() if lm.diffusion_block else contextlib.nullcontext()):
         cli_train.main(_train_argv(recipe, overrides, out_dir))
     after = _registry_snapshot()
     for line in format_setup_report(setup_report(t0), min_s=0.25):
         print(f"[lm_train] {line}", flush=True)  # this phase's records alone, set-up and steps
-    cfg = _load(recipe, overrides)
-    lm = MlaMoeConfig(**cfg.model.lm)
     calls = check_step_runs_each_causal_kernel_once_a_block(programs, lm)
     kda_calls = check_step_runs_the_kda_kernels(programs, lm)
     conv_calls = check_step_runs_the_short_conv_kernels(programs, lm)
     rope_calls = check_step_runs_the_rope_kernel(programs, lm)
     head_calls = check_step_runs_the_head_three_times(
         programs, lm, cfg.run.train_batch_size * cfg.data.seq_len, cfg.data.seq_len)
+    bd_calls = check_step_runs_the_block_diffusion_core(programs, lm)
     programs.clear()  # or the step's executable outlives the phase
     records = _read_metrics(out_dir / cfg.run.name)
     losses = _logged_losses(records, 1, steps, "lm_train")
@@ -613,10 +698,31 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
     check(sorted(by_step) == list(range(1, steps + 1)), "missing expert counters")
     check(all(r["train/moe_dropped"] == 0 for r in by_step.values()), "an expert layer dropped pairs")
     rounds = sorted({r["train/moe_rounds"] for r in by_step.values()})
-    check(rounds == [1], f"an expert layer's held pairs took other than one round: {rounds}")
+    family = {}  # the family's own counters, where it has any
+    if lm.diffusion_block:
+        # the first loss is what seeded weights give whatever the noise, ln(rows) times a
+        # mean of masked / t that is 1 in expectation, and the masked share a half, each
+        # as near as the step's count of diffusion blocks allows (the weights' spread is
+        # 1.2 / sqrt(blocks) of the loss, the levels' 0.3 / sqrt(blocks))
+        blocks = cfg.run.train_batch_size * cfg.data.seq_len // lm.diffusion_block
+        first = losses[1] / math.log(lm.rows[1])
+        check(abs(first - 1.0) < max(0.1, 6.0 / math.sqrt(blocks)),
+              f"the first loss is {first:.3f} x ln(rows held)")
+        masked = [r.get("train/bd_masked_share") for r in by_step.values()]
+        mean = (1 + BLOCK_NOISE_EPS) / 2  # of t ~ U[eps, 1]
+        check(all(m is not None and abs(m - mean) < max(0.02, 2.0 / math.sqrt(blocks))
+                  for m in masked),
+              f"the masked share is missing or far from {mean:.3f}: {masked}")
+        family = {"bd_masked_share_min_max": [round(min(masked), 4), round(max(masked), 4)],
+                  "diffusion_block": lm.diffusion_block}
+        # no check of one round: the recipe's q/k norm scales start at 1 (the program has
+        # no option for them), and from seeded weights the masked rows, a quarter of all
+        # and one embedding row, then route alike and can fill a chunk twice (PERF.md §6,
+        # PR 47: 1 or 2 rounds on the chip); the benchmark's cell seeds its own weights
+    else:
+        check(rounds == [1], f"an expert layer's held pairs took other than one round: {rounds}")
     skipped = _delta(before, after, "train_steps_skipped_total", "")
     check(skipped == 0, f"{skipped} step(s) skipped by the divergence guard")
-    family = {}  # the family's own counters, where it has any
     if lm.kda_layers:
         states = [r.get("train/kda_state_absmax") for r in by_step.values()]
         check(all(s is not None and 0 < s < 100 for s in states),
@@ -648,8 +754,9 @@ def phase_lm_train(recipe: str, overrides: list[str], out_dir: Path, *, steps: i
         "loss_after_one_cycle": round(losses[1 + cycle], 4),
         "loss_last": round(losses[steps], 4),
         "moe_dropped": 0,
-        "moe_rounds": 1,
+        "moe_rounds": int(rounds[-1]),
         "causal_kernel_calls": calls,
+        "bd_kernel_calls": bd_calls,
         "kda_kernel_calls": kda_calls,
         "short_conv_kernel_calls": conv_calls,
         "rope_kernel_calls": rope_calls,

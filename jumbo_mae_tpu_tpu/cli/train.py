@@ -182,8 +182,9 @@ def _mode_inputs(cfg: TrainConfig) -> tuple[str, ...]:
 
 def _token_row(cfg: TrainConfig) -> int:
     """Ids a batch row holds in mode ``lm``: the trained tokens, the next
-    one, and one more per multi-token-prediction module."""
-    return cfg.data.seq_len + 1 + MlaMoeConfig(**cfg.model.lm).mtp_layers
+    one, and one more per multi-token-prediction module; a block-diffusion
+    model's, the clean tokens alone (``MlaMoeConfig.token_row``)."""
+    return MlaMoeConfig(**cfg.model.lm).token_row(cfg.data.seq_len)
 
 
 def _zero_batch(cfg: TrainConfig, rows: int) -> dict:
@@ -209,8 +210,12 @@ def _synthetic(cfg: TrainConfig, per_process: int, num_labels: int, *, seed: int
                grad_accum: int = 1):
     """The mode's seeded synthetic batches."""
     if cfg.run.mode == "lm":
+        lm = MlaMoeConfig(**cfg.model.lm)
+        first, rows = lm.rows
+        # a block-diffusion model's last row held is its mask id: no document holds it
         return token_batches(
-            per_process, _token_row(cfg), vocab_rows=MlaMoeConfig(**cfg.model.lm).rows,
+            per_process, _token_row(cfg),
+            vocab_rows=(first, rows - 1) if lm.diffusion_block else (first, rows),
             grad_accum=grad_accum, seed=seed,
         )
     return synthetic_batches(

@@ -1,4 +1,4 @@
-"""Decoder-only sparse-expert language models, six families from one set of
+"""Decoder-only sparse-expert language models, seven families from one set of
 blocks; each trunk block's token mixer is one of five kinds — four of
 attention and the gated short convolution — and ``MlaMoeConfig.kinds`` is the
 one list that says which (a family is a way to fill it). **All-MLA** (the
@@ -31,9 +31,34 @@ expert, no dense layer). **Short convolution beside grouped-query**
 recurrence with a matrix state, with no heads, no rope and no score — and
 ``full_attention`` the fourth, with 64-wide heads and a per-head RMSNorm on
 ``q`` and ``k`` (``qk_norm``); sigmoid-and-bias routing, no shared expert,
-and a head that is the embedding's rows (``tie_embeddings``). The
+and a head that is the embedding's rows (``tie_embeddings``). **Block
+diffusion** (``SDAR-30B-A3B-Chat``, ``model_type: sdar_moe``): a grouped-query
+trunk of ``full_attention`` blocks with ``qk_norm`` and softmax-routed experts,
+trained with ``diffusion_block`` = ``B`` > 0 not on the next token but as a
+block-diffusion model (below). The
 defaults are the first family's; its parameter tree, scopes and program do
 not depend on the others' fields.
+
+**Block diffusion** (BD3-LMs' vectorised training, as SDAR uses it). A
+sequence ``x`` of ``L`` tokens lies in blocks of ``B``; a step draws ``t ~
+U[eps, 1]`` a (sequence, block) (``eps`` is ``ops/masking.BLOCK_NOISE_EPS``, a
+constant of the objective and no option) and masks each token of the
+block independently with probability ``t``: its id becomes the mask id, the
+last vocabulary row held, which no document holds (``ops/masking.block_noise``,
+from the step's ``noise`` stream; evaluation draws from a fixed key). The
+trunk runs once over ``[x ; x_t]``, ``2 L`` rows a sequence, the clean copy
+first: every token-wise part (embedding, norms, projections, router, experts)
+sees ``2 L`` rows; the rope sees positions ``0 .. L − 1`` twice (the head-major
+(batch, heads, 2 L, e) tensor read as (batch, 2 · heads, L, e): a reshape of
+the same bytes); the core sees the pair under the block-diffusion pattern
+(``ops/attention.block_diffusion_visible``): with ``b(i) = i // B``, a clean
+query sees the clean keys of ``b(j) <= b(i)``, a noisy query the clean keys of
+``b(j) < b(i)`` and the noisy keys of its own block, and a clean query never
+a noisy key. The head reads the noisy copy only, and the logits at a position
+predict that position's own token (no shift): loss ``= 1 / (batch · L) ·
+Σ_{masked i} (1 / t_{b(i)}) · (−log softmax(head(h_i))[x_i])``, through
+``ops/head_loss.head_loss`` with the weights ``masked / (t · batch · L)``. A
+batch row holds the ``L`` clean ids and nothing else.
 
 Pre-norm residual blocks with RMSNorm (eps ``rms_eps``): ``x += A_i(norm(x))``,
 ``x += F_i(norm(x))``. No bias anywhere.
@@ -199,6 +224,8 @@ from jumbo_mae_tpu_tpu.models.config import RematPolicy, maybe_remat
 from jumbo_mae_tpu_tpu.obs.trace import (
     SCOPE_ATTN_CORE,
     SCOPE_ATTN_OUT,
+    SCOPE_BD_CORE,
+    SCOPE_BD_NOISE,
     SCOPE_DENSE_MLP,
     SCOPE_EMBED,
     SCOPE_EXPERTS,
@@ -224,6 +251,7 @@ from jumbo_mae_tpu_tpu.ops.attention import causal_attention
 from jumbo_mae_tpu_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul, grouped_outer
 from jumbo_mae_tpu_tpu.ops.head_loss import head_loss
 from jumbo_mae_tpu_tpu.ops.kda import causal_conv, kda_chunked, short_conv
+from jumbo_mae_tpu_tpu.ops.masking import block_noise
 
 # the counters an expert layer reports, in the order of its stats vector
 MOE_COUNTERS = ("rows_min", "rows_mean", "rows_max", "imbalance", "held_share", "dropped",
@@ -279,8 +307,11 @@ class Rope:
 class MlaMoeConfig:
     """Sizes as ``config.json`` names them (``JoyAI-LLM-Flash`` defaults: the
     all-MLA family), plus what this chip holds of them; the hybrid family's
-    fields below ``init_std``, the grouped-query family's below those. The
-    name is the first family's: the class holds all four (a rename would
+    fields below ``init_std``, the grouped-query family's below those, then
+    the expert layer's variants (the fifth family's, SmallThinker's), the
+    short-convolution family's and, last, the block-diffusion family's
+    (``SDAR``: the seventh, a training objective over a grouped-query trunk).
+    The name is the first family's: the class holds all seven (a rename would
     touch every recipe's reader and test for no behaviour)."""
 
     vocab_size: int = 129280
@@ -350,6 +381,9 @@ class MlaMoeConfig:
     conv_taps: int = 3
     qk_norm: bool = False
     tie_embeddings: bool = False
+    # the block-diffusion family (module docstring): the diffusion block's
+    # length, a power of two (0: a causal next-token model, as every other family)
+    diffusion_block: int = 0
 
     grad_ckpt: bool = True
     remat_policy: RematPolicy = "none"
@@ -377,6 +411,14 @@ class MlaMoeConfig:
             raise ValueError("softmax_topk routing has no group limit: n_group must be 1")
         if self.mtp_layers not in (0, 1):
             raise ValueError("mtp_layers must be 0 or 1")
+        if self.diffusion_block:
+            b = self.diffusion_block
+            if b < 0 or b & (b - 1):
+                raise ValueError(f"diffusion_block {b} must be a power of two")
+            if set(self.kinds) != {"full_attention"} or self.mtp_layers:
+                raise ValueError("a block-diffusion model's blocks are full_attention, with no "
+                                 "MTP module: the pattern over the clean and the noisy copy "
+                                 "is the grouped-query core's alone")
         if self.expert_swiglu_limit or self.shared_expert_swiglu_limit:
             raise ValueError("a non-zero SwiGLU limit (the clamped SwiGLU) is not implemented")
         if self.n_routed_experts % self.n_group or not 0 < self.topk_group <= self.n_group:
@@ -428,6 +470,18 @@ class MlaMoeConfig:
     @property
     def rows(self) -> tuple[int, int]:
         return self.vocab_rows or (0, self.vocab_size)
+
+    @property
+    def mask_id(self) -> int:
+        """The id a masked token of a block-diffusion model's noisy copy
+        takes: the last vocabulary row held, which no document holds."""
+        return self.rows[0] + self.rows[1] - 1
+
+    def token_row(self, seq: int) -> int:
+        """Ids a batch row holds for ``seq`` trained tokens: those, the next
+        one and one more a multi-token-prediction module; a block-diffusion
+        model's row is the clean tokens alone (nothing is shifted)."""
+        return seq if self.diffusion_block else seq + 1 + self.mtp_layers
 
     @property
     def kinds(self) -> tuple[str, ...]:
@@ -483,9 +537,13 @@ class MlaMoeConfig:
         ``seq`` tokens that the causal kernels compute (the block pairs their
         tables walk; of a pair a mask cuts, the sub-tiles that hold a visible
         entry), and those the mask keeps
-        (``ops/pallas/attention.causal_pairs``). Static."""
+        (``ops/pallas/attention.causal_pairs``). A block-diffusion model has
+        one kind, ``"block_diffusion"``: the pair of copies of a sequence of
+        ``seq`` clean tokens under its pattern. Static."""
         from jumbo_mae_tpu_tpu.ops.pallas.attention import causal_pairs
 
+        if self.diffusion_block:
+            return {"block_diffusion": causal_pairs(seq, diffusion=self.diffusion_block)}
         kinds = set(self.kinds) - {"kda", "conv"}  # neither has (query, key) pairs
         if self.mtp_layers:
             kinds.add("mla")
@@ -646,12 +704,23 @@ class GroupedQueryAttention(nn.Module):
                 gate = Proj((cfg.dim, h), "bsd,dh->bhs", cfg, name="gate")(x)
         if rope is not None:  # a kind without rotary embedding opens no rope scope
             with jax.named_scope(SCOPE_ROPE):
-                q, k = rope_half(q, rope), rope_half(k, rope)
-        with jax.named_scope(SCOPE_SWA_CORE if self.sliding else SCOPE_ATTN_CORE):
-            # impl=None: the op asks its rule; the keyword is what the
-            # benchmark's mutation tests require of this call (ops/attention.py)
-            z = causal_attention(q, None, k, None, v, impl=None,
-                                 window=cfg.sliding_window if self.sliding else None)
+                if cfg.diffusion_block:
+                    # the rows are a clean and a noisy copy at the same positions:
+                    # each head's two copies read as two heads of half the rows
+                    q, k = (rope_half(t.reshape(t.shape[0], 2 * t.shape[1], -1, d),
+                                      rope).reshape(t.shape) for t in (q, k))
+                else:
+                    q, k = rope_half(q, rope), rope_half(k, rope)
+        if cfg.diffusion_block:
+            with jax.named_scope(SCOPE_BD_CORE):
+                z = causal_attention(q, None, k, None, v, impl=None,
+                                     diffusion=cfg.diffusion_block)
+        else:
+            with jax.named_scope(SCOPE_SWA_CORE if self.sliding else SCOPE_ATTN_CORE):
+                # impl=None: the op asks its rule; the keyword is what the
+                # benchmark's mutation tests require of this call (ops/attention.py)
+                z = causal_attention(q, None, k, None, v, impl=None,
+                                     window=cfg.sliding_window if self.sliding else None)
         with jax.named_scope(SCOPE_ATTN_OUT):
             if cfg.attn_gate:
                 z = _head_gate(z, gate)
@@ -1080,7 +1149,12 @@ class Block(nn.Module):
 class MlaMoeLM(nn.Module):
     """``__call__(tokens)`` with ``tokens`` (batch, seq + 1 + mtp_layers)
     int32 ids from the vocabulary rows held: the training loss and the
-    step's counters. ``logits(tokens)`` returns both heads' logits.
+    step's counters. ``logits(tokens)`` returns both heads' logits. A
+    block-diffusion model (``cfg.diffusion_block``) takes (batch, seq) clean
+    ids and a noise key — ``noise_key``, else the ``noise`` stream's when it
+    trains and a fixed key when it does not (``deterministic``: evaluation) —
+    and ``logits`` returns one array over the 2 · seq rows, the clean copy's
+    first (module docstring).
 
     Under the ``lm_head`` scope the training path runs the final norm and,
     a head, ``ops/head_loss.head_loss``: a tile of tokens at a time the
@@ -1116,11 +1190,27 @@ class MlaMoeLM(nn.Module):
         with jax.named_scope(SCOPE_EMBED):
             return self.embedding[ids].astype(self.cfg.compute_dtype)
 
+    def _noise(self, tokens, deterministic: bool, noise_key):
+        """A block-diffusion step's draw for the clean ``tokens`` (batch, seq):
+        the noisy copy's ids, the loss's weight a position, ``masked / (t ·
+        batch · seq)``, and the share of the positions that are masked."""
+        cfg = self.cfg
+        batch, seq = tokens.shape
+        with jax.named_scope(SCOPE_BD_NOISE):
+            if noise_key is None:  # evaluation sees the same noise every time
+                noise_key = jax.random.key(0) if deterministic else self.make_rng("noise")
+            t, masked = block_noise(noise_key, batch, seq, cfg.diffusion_block)
+            noisy = jnp.where(masked, cfg.mask_id, tokens)
+            weights = masked / (jnp.repeat(t, cfg.diffusion_block, axis=1) * (batch * seq))
+            return noisy, weights, masked.mean(dtype=jnp.float32)
+
     def _hidden(self, tokens, deterministic: bool):
         """Both heads' last hidden states ``[trunk, mtp?]``, the expert
-        layers' stats ``{name: vector}`` and the linear-attention layers'."""
+        layers' stats ``{name: vector}`` and the linear-attention layers'. A
+        block-diffusion model's ``tokens`` are the 2 · seq ids a row, the
+        clean copy's and then the noisy one's, and every one is embedded."""
         cfg = self.cfg
-        seq = tokens.shape[1] - 1 - cfg.mtp_layers
+        seq = tokens.shape[1] if cfg.diffusion_block else tokens.shape[1] - 1 - cfg.mtp_layers
         ids = tokens - cfg.rows[0]
         x = self._embed(ids[:, :seq])
         stats, kda = {}, {}
@@ -1139,6 +1229,21 @@ class MlaMoeLM(nn.Module):
             hidden.append(y)
         return hidden, stats, kda
 
+    def _moe_counters(self, stats) -> dict:
+        """The expert layers' counters ``{name: vector}`` as the step's
+        outputs: each layer's, and the step's own summaries of them."""
+        cfg = self.cfg
+        out = {}
+        table = jnp.stack(list(stats.values()))  # (expert layers, counters)
+        for name, st in stats.items():
+            out |= {f"moe_{c}_{name}": st[j] for j, c in enumerate(cfg.moe_counters)}
+        col = {c: table[:, j] for j, c in enumerate(cfg.moe_counters)}
+        out |= {"moe_imbalance": col["imbalance"].max(), "moe_held_share": col["held_share"].mean(),
+                "moe_dropped": col["dropped"].sum(), "moe_rounds": col["rounds"].max()}
+        if ACT_ZERO_COUNTER in col:
+            out[f"moe_{ACT_ZERO_COUNTER}"] = col[ACT_ZERO_COUNTER].mean()
+        return out
+
     def _head_kernel(self):
         """The head's (dim, rows held) kernel in the compute dtype: its own
         parameter, or with ``tie_embeddings`` the embedding's rows
@@ -1151,12 +1256,33 @@ class MlaMoeLM(nn.Module):
         h = self.ln(h).astype(self.cfg.compute_dtype)
         return jnp.einsum("bsd,dv->bsv", h, self._head_kernel()).astype(jnp.float32)
 
-    def logits(self, tokens, deterministic: bool = True):
+    def logits(self, tokens, deterministic: bool = True, *, noise_key=None):
+        if self.cfg.diffusion_block:
+            noisy, _, _ = self._noise(tokens, deterministic, noise_key)
+            tokens = jnp.concatenate([tokens, noisy], axis=1)
         with jax.named_scope(SCOPE_LM_HEAD):
             return [self._logits(h) for h in self._hidden(tokens, deterministic)[0]]
 
-    def __call__(self, tokens, deterministic: bool = True):
+    def _diffusion_loss(self, tokens, deterministic: bool, noise_key):
+        """``__call__`` of a block-diffusion model: ``(out, stats)``."""
         cfg = self.cfg
+        batch, seq = tokens.shape
+        noisy, weights, masked_share = self._noise(tokens, deterministic, noise_key)
+        (h,), stats, _ = self._hidden(jnp.concatenate([tokens, noisy], axis=1), deterministic)
+        with jax.named_scope(SCOPE_LM_HEAD):
+            # the noisy copy's rows alone; a position's target is its own clean id
+            total, nll = head_loss(
+                self.ln(h[:, seq:]).reshape(batch * seq, cfg.dim), self._head_kernel(),
+                (tokens - cfg.rows[0]).reshape(batch * seq), weights.reshape(batch * seq))
+            per_sample = batch * (weights * nll.reshape(batch, seq)).sum(axis=-1)  # values only
+        return {"loss_trunk": per_sample.mean(), "loss": total, "loss_per_sample": per_sample,
+                "bd_masked_share": masked_share}, stats
+
+    def __call__(self, tokens, deterministic: bool = True, *, noise_key=None):
+        cfg = self.cfg
+        if cfg.diffusion_block:
+            out, stats = self._diffusion_loss(tokens, deterministic, noise_key)
+            return out | self._moe_counters(stats)
         batch, seq = tokens.shape[0], tokens.shape[1] - 1 - cfg.mtp_layers
         hidden, stats, kda = self._hidden(tokens, deterministic)
         ids = tokens - cfg.rows[0]
@@ -1182,14 +1308,7 @@ class MlaMoeLM(nn.Module):
             out["loss_mtp"] = losses[1].mean()
         # ``loss`` carries the gradient; the per-sequence values are values only
         out |= {"loss": sum(totals), "loss_per_sample": per_sample}
-        table = jnp.stack(list(stats.values()))  # (expert layers, counters)
-        for name, st in stats.items():
-            out |= {f"moe_{c}_{name}": st[j] for j, c in enumerate(cfg.moe_counters)}
-        col = {c: table[:, j] for j, c in enumerate(cfg.moe_counters)}
-        out |= {"moe_imbalance": col["imbalance"].max(), "moe_held_share": col["held_share"].mean(),
-                "moe_dropped": col["dropped"].sum(), "moe_rounds": col["rounds"].max()}
-        if ACT_ZERO_COUNTER in col:
-            out[f"moe_{ACT_ZERO_COUNTER}"] = col[ACT_ZERO_COUNTER].mean()
+        out |= self._moe_counters(stats)
         if kda:
             for name, st in kda.items():
                 out |= {f"kda_{c}_{name}": st[j] for j, c in enumerate(KDA_COUNTERS)}

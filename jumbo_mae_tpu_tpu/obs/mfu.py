@@ -131,8 +131,17 @@ def lm_flops_per_token(cfg, seq: int, *, training: bool = True) -> float:
     that grows with ``seq``; the embedding is a lookup and the short
     convolutions, their gates and a q/k norm are elementwise; a tied head's
     product is counted as an untied one's (tying saves parameters, not
-    products); backward = 2 x forward, recomputation not counted."""
+    products); backward = 2 x forward, recomputation not counted.
+
+    A block-diffusion model (``cfg.diffusion_block`` = ``B`` > 0) runs a clean
+    and a noisy copy of every sequence through the trunk, and a token here is
+    a clean token, of which a sequence has ``seq``: its token-wise products
+    (projections, router, experts) are counted twice, once a copy; the core
+    over the ``seq² + seq · B`` entries the pattern shows (``seq + B`` keys a
+    clean token, both copies' queries together); the head once, since it
+    reads the noisy copy alone."""
     d, h = cfg.dim, cfg.heads
+    copies = 2 if cfg.diffusion_block else 1
     qk, dv = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim
     query = d * h * qk if cfg.q_lora_rank is None else d * cfg.q_lora_rank + cfg.q_lora_rank * h * qk
     latent = 2 * (
@@ -165,8 +174,11 @@ def lm_flops_per_token(cfg, seq: int, *, training: bool = True) -> float:
         hq, g, e = cfg.query_heads(layer), cfg.kv_heads, cfg.head_dim
         w = min(cfg.sliding_window, seq) if cfg.kinds[layer] == "sliding_attention" else seq
         keys = (w * (w + 1) / 2 + (seq - w) * w) / seq  # mean keys a query sees
+        if cfg.diffusion_block:  # a clean token's two queries together
+            keys = seq + cfg.diffusion_block
         gate = d * hq if cfg.attn_gate else 0
-        return 2 * (d * hq * e + 2 * d * g * e + gate + hq * e * d) + 2 * keys * hq * 2 * e
+        return (copies * 2 * (d * hq * e + 2 * d * g * e + gate + hq * e * d)
+                + 2 * keys * hq * 2 * e)
 
     kinds = cfg.kinds
     short_conv = 2 * (d * 3 * d + d * d)  # W_in and W_out
@@ -176,8 +188,8 @@ def lm_flops_per_token(cfg, seq: int, *, training: bool = True) -> float:
               if kind not in ("mla", "kda", "conv"))
         + cfg.kda_layers * linear
         + kinds.count("conv") * short_conv
-        + dense_layers * gated(cfg.dense_hidden)
-        + sparse_layers * sparse
+        + copies * dense_layers * gated(cfg.dense_hidden)
+        + copies * sparse_layers * sparse
         + (1 + cfg.mtp_layers) * head
         + cfg.mtp_layers * 2 * (2 * d) * d  # W_eh
     )

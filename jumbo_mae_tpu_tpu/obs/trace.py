@@ -121,6 +121,11 @@ SCOPE_SWA_CORE = "swa_core"  # a sliding-window layer's causal kernels and what 
 SCOPE_SCONV_IN = "sconv_in"  # W_in: the block input to the three gates' 3 x dim columns
 SCOPE_SCONV_MIX = "sconv_mix"  # B ⊙ x̃, the depth-wise causal filter's taps, C ⊙: elementwise
 SCOPE_SCONV_OUT = "sconv_out"  # W_out
+# ... and where it is trained as a block-diffusion model (``diffusion_block`` >
+# 0): a grouped-query block's core is bd_core and never attn_core, which stays
+# the causal models'; the noise is drawn once a step, outside the blocks.
+SCOPE_BD_CORE = "bd_core"  # the causal kernels under the block-diffusion pattern over the clean and the noisy copy, and what feeds them
+SCOPE_BD_NOISE = "bd_noise"  # a noise level a (sequence, block), the tokens masked, the noisy ids, the loss's 1/t weights, the masked share
 
 
 def _span_hist(name: str, registry):

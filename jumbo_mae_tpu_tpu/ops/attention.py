@@ -6,11 +6,15 @@ Two entries, both over pre-scaled queries (already times ``head_dim**-0.5``):
   tensors (the ViT's; the reference materializes full (B,H,N,N) scores,
   ``/root/reference/src/modeling.py:136-137`` — fine at N=197, fatal for
   long context);
-- **causal**, ``causal_attention(q_a, q_b, k_a, k_b, v, impl=None, window=)`` over
-  head-major (batch, heads, seq, d) tensors (the language models'): a score
-  of one part or of two (the second with a key all heads share: latent
-  attention's rotary columns), key/value heads that a group of query heads
-  shares, and an optional window of tokens a query looks back over.
+- **causal**, ``causal_attention(q_a, q_b, k_a, k_b, v, impl=None, window=,
+  diffusion=)`` over head-major (batch, heads, seq, d) tensors (the language
+  models'): a score of one part or of two (the second with a key all heads
+  share: latent attention's rotary columns), key/value heads that a group of
+  query heads shares, and one of three visibility patterns: every earlier
+  key; an optional window of tokens a query looks back over; or, with
+  ``diffusion`` = a block length, the block-diffusion pattern over a row
+  that holds a clean and a noisy copy of a sequence
+  (``block_diffusion_visible``).
 
 Neither is handed a lowering by the program. ``lowering`` is the rule, a function of what a call
 can observe; the entries read those things where the call is traced (the
@@ -81,7 +85,21 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     return xla_attention(q, k, v)
 
 
-def xla_causal_attention(q_a, q_b, k_a, k_b, v, window: int | None = None) -> jax.Array:
+def block_diffusion_visible(rows: int, block: int) -> jax.Array:
+    """The (rows, rows) boolean mask of the block-diffusion pattern: the row
+    holds a clean copy of a sequence of ``L = rows / 2`` tokens and then a
+    noisy copy of it, and with ``b(i) = (i mod L) // block`` key ``j`` is
+    visible to query ``i`` iff both are clean and ``b(j) <= b(i)``, or ``i``
+    is noisy, ``j`` clean and ``b(j) < b(i)``, or both are noisy and ``b(j) ==
+    b(i)``. A clean query sees no noisy key."""
+    at = jnp.arange(rows)
+    noisy, place = at >= rows // 2, at % (rows // 2) // block
+    (q_noisy, k_noisy), (b_q, b_k) = ((x[:, None], x[None, :]) for x in (noisy, place))
+    return jnp.where(q_noisy, jnp.where(k_noisy, b_k == b_q, b_k < b_q), ~k_noisy & (b_k <= b_q))
+
+
+def xla_causal_attention(q_a, q_b, k_a, k_b, v, window: int | None = None,
+                         diffusion: int | None = None) -> jax.Array:
     """The einsum form of :func:`causal_attention`: the (seq, seq) scores
     exist, so it is for the CPU's tests and short sequences only."""
     group = q_a.shape[1] // k_a.shape[1]
@@ -90,7 +108,10 @@ def xla_causal_attention(q_a, q_b, k_a, k_b, v, window: int | None = None) -> ja
     s = jnp.einsum("bhqd,bhkd->bhqk", q_a, k_a, preferred_element_type=jnp.float32)
     if q_b is not None:
         s = s + jnp.einsum("bhqd,bkd->bhqk", q_b, k_b, preferred_element_type=jnp.float32)
-    keep = jnp.tril(jnp.ones(s.shape[-2:], bool))
+    if diffusion is not None:
+        keep = block_diffusion_visible(s.shape[-1], diffusion)
+    else:
+        keep = jnp.tril(jnp.ones(s.shape[-2:], bool))
     if window is not None:
         keep = keep & ~jnp.tril(keep, -window)  # row − col < window
     probs = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1).astype(v.dtype)
@@ -98,15 +119,22 @@ def xla_causal_attention(q_a, q_b, k_a, k_b, v, window: int | None = None) -> ja
 
 
 def causal_attention(q_a, q_b, k_a, k_b, v, *, impl: str | None = None,
-                     window: int | None = None) -> jax.Array:
+                     window: int | None = None, diffusion: int | None = None) -> jax.Array:
     """Causal softmax(q_a·k_aᵀ + q_b·k_bᵀ)·v, head-major: ``q_a`` (batch,
     heads, seq, d_a), ``k_a`` (batch, kv heads, seq, d_a) and ``v`` (batch, kv
     heads, seq, d_v) with ``kv heads`` a divisor of ``heads`` (query head ``h``
     reads key/value head ``h // (heads / kv heads)``); ``q_b`` (batch, heads,
     seq, d_b) and ``k_b`` (batch, seq, d_b) shared by all heads, or both None
     for a score of one part; queries pre-scaled. With ``window``, query ``i``
-    sees keys ``i − window + 1 .. i``. The family has no lowering for a split
-    sequence and no caller that needs the probabilities.
+    sees keys ``i − window + 1 .. i``. With ``diffusion`` = ``B`` (a block
+    length, a power of two; no window then) the ``seq`` rows are a clean copy
+    of ``seq / 2`` tokens and then a noisy copy, and visibility is
+    ``block_diffusion_visible``'s: which of the three patterns a call runs
+    under is its caller's configuration (``MlaMoeConfig.diffusion_block``),
+    never an option here, and the Pallas kernels cut their block pairs by it
+    where they are built (``ops/pallas/attention._diffusion_cuts``). The
+    family has no lowering for a split sequence and no caller that needs the
+    probabilities.
 
     ``impl`` is None everywhere in the program: the rule is asked here. The
     keyword stays because the benchmark's own mutation tests
@@ -119,5 +147,5 @@ def causal_attention(q_a, q_b, k_a, k_b, v, *, impl: str | None = None,
     if impl == "flash":
         from jumbo_mae_tpu_tpu.ops.pallas.attention import pallas_causal_attention
 
-        return pallas_causal_attention(q_a, q_b, k_a, k_b, v, window=window)
-    return xla_causal_attention(q_a, q_b, k_a, k_b, v, window)
+        return pallas_causal_attention(q_a, q_b, k_a, k_b, v, window=window, diffusion=diffusion)
+    return xla_causal_attention(q_a, q_b, k_a, k_b, v, window, diffusion)

@@ -88,6 +88,23 @@ def random_masking(
     raise ValueError(f"unknown masking mode: {mode!r}")
 
 
+BLOCK_NOISE_EPS = 1e-3  # the least noise level a diffusion block draws (SDAR's, BD3-LMs')
+
+
+def block_noise(rng: jax.Array, batch: int, length: int, block: int,
+                eps: float = BLOCK_NOISE_EPS) -> tuple[jax.Array, jax.Array]:
+    """Block diffusion's noise for ``batch`` sequences of ``length`` tokens cut
+    into blocks of ``block``: a level ``t ~ U[eps, 1]`` a (sequence, block),
+    and each token of the block masked independently with probability ``t``.
+    Returns ``(t, masked)``: (batch, length / block) float32 and (batch,
+    length) bool. ``rng`` is split once: the first key draws the levels, the
+    second one uniform a token, masked where it is below its block's level."""
+    level_key, token_key = jax.random.split(rng)
+    t = jax.random.uniform(level_key, (batch, length // block), jnp.float32, eps, 1.0)
+    u = jax.random.uniform(token_key, (batch, length), jnp.float32)
+    return t, u < jnp.repeat(t, block, axis=1)
+
+
 # --------------------------------------------------------------------------
 # Mask algebra (parity: ``/root/reference/src/utils_mae.py:24-49``). Masks are
 # float arrays with 1.0 at MASKED positions. The reference fork never calls

@@ -418,7 +418,8 @@ pallas_flash_attention_with_lse.defvjp(_vjp_lse_fwd, _vjp_lse_bwd)
 
 # ---------------------------------------------------------------------------
 # Causal form, with query/key and value head widths that differ, key/value
-# heads that a group of query heads shares, and an optional window.
+# heads that a group of query heads shares, and one of three visibility
+# patterns: every earlier key, a window of them, or block diffusion's.
 #
 # The score of a (query, key) pair is ``q_a·k_aᵀ``, a per-head part of width
 # ``d_a``, plus — where ``q_b``/``k_b`` are given — a part whose key is one
@@ -454,10 +455,33 @@ pallas_flash_attention_with_lse.defvjp(_vjp_lse_fwd, _vjp_lse_bwd)
 # the output accumulator live in VMEM scratch across the key blocks of a
 # query block; scores never leave VMEM.
 #
+# The third pattern is block diffusion's (``diffusion`` = the diffusion
+# block's length ``B``, a power of two): the row holds a clean copy of a
+# sequence of ``L`` tokens and then a noisy copy of it, ``n = L / block`` key
+# blocks each, and with ``b(i) = (i mod L) // B`` a clean query sees the clean
+# keys of ``b(j) <= b(i)``, a noisy query the clean keys of ``b(j) < b(i)`` and
+# the noisy keys of ``b(j) == b(i)``, its own diffusion block both ways. The
+# same kernels walk another set of pairs (``_two_copies``: a clean query block
+# its ``i + 1`` clean key blocks, a noisy one those and its own: ``n (n + 1) +
+# n`` pairs, all on or below the diagonal of the ``2 n`` x ``2 n`` grid, the
+# diagonal a query block's last, so the walk's order, the accumulators and the
+# spans below carry over) and cut three kinds of pair (``_diffusion_cuts``): a
+# clean block against itself (the staircase ``col // B <= row // B``), a noisy
+# block against the clean block at its own place (``col // B < row // B``) and
+# against itself (``col // B == row // B``). None is a function of ``row −
+# col``; each is ``lo <= row // B − col // B < hi``, the causal cuts' form with
+# the distance counted in diffusion blocks, so ``_strips`` plans a cut pair in
+# units of ``B`` (a staircase pair runs 10 of its 16 sub-tiles, a noisy
+# block's own 4) and ``_masked`` compares the same local iotas shifted by
+# ``log2 B``. Which pair is cut how is decided where a kernel is built, from
+# ``diffusion`` alone (``_off_diagonal``, ``_diagonal``); ``B`` has to divide
+# the sub-tile, and a longer diffusion block is refused.
+#
 # A sequence that is no multiple of the block is padded with zero rows: a
 # pad key lies after every real query, so causality already hides it, and a
 # pad query's output is sliced off, so its cotangent is zero and every
-# backward contribution from it vanishes.
+# backward contribution from it vanishes. (Two copies are padded each by
+# itself: ``_causal_band``.)
 #
 # The backward pass is one kernel (``causal_attention_bwd``): a visited block
 # pair's scores, probabilities, ``dO·Vᵀ`` and ``ds`` are formed once and all of
@@ -522,9 +546,44 @@ def _lower_triangle(n: int, *, reach: int | None = None):
     return np.asarray(qi, np.int32), np.asarray(kj, np.int32)
 
 
+def _two_copies(n: int):
+    """The block pairs of the block-diffusion pattern as two int32 tables over
+    the ``2 n`` blocks of a row that holds a clean copy of a sequence, ``n``
+    blocks, and then a noisy one: a clean query block ``i`` its clean key
+    blocks ``j <= i``; a noisy query block ``n + i`` the clean key blocks ``j
+    <= i`` and then its own, ``n + i``. Query block outer, its key blocks
+    rising: every pair lies on or below the diagonal of the ``2 n`` x ``2 n``
+    grid, a query block's first key block is 0 and its last the diagonal's,
+    as in ``_lower_triangle``."""
+    pairs = [(i, j) for i in range(n) for j in range(i + 1)]
+    pairs += [(n + i, j) for i in range(n) for j in (*range(i + 1), n + i)]
+    qi, kj = zip(*pairs)
+    return np.asarray(qi, np.int32), np.asarray(kj, np.int32)
+
+
+def _pair_tables(n: int, *, reach: int | None, diffusion: int | None):
+    """The walk's tables over the ``n`` blocks of a (padded) row: the
+    triangle or its band, or with ``diffusion`` the two copies' pattern."""
+    return _lower_triangle(n, reach=reach) if diffusion is None else _two_copies(n // 2)
+
+
 def _whole(block: int) -> tuple[int, int]:
     """The ``(lo, hi)`` no entry of a block pair falls outside: no mask."""
     return 1 - block, block
+
+
+def _diffusion_cuts(block: int, unit: int) -> dict[str, tuple[int, int, int]]:
+    """The three kinds of pair a block-diffusion mask cuts, each with the
+    ``(lo, hi, unit)`` of its mask: an entry is visible iff ``lo <= row // unit
+    − col // unit < hi``, ``unit`` the diffusion block's length, a power of two.
+    ``"clean"``, a clean block against itself: the staircase ``col // unit <=
+    row // unit``; ``"strict"``, a noisy block against the clean block at its
+    own place: ``col // unit < row // unit``; ``"own"``, a noisy block against
+    itself: the block diagonal ``col // unit == row // unit``. None is a
+    function of ``row − col``: a pair's ``unit`` says in what the distance is
+    counted, and 1 is the causal kinds'."""
+    far = block // unit  # past every entry's distance
+    return {"clean": (0, far, unit), "strict": (1, far, unit), "own": (0, 1, unit)}
 
 
 def _cuts(block: int, window: int | None) -> dict[int, tuple[int, int]]:
@@ -567,8 +626,10 @@ class _Strip(NamedTuple):
     clear_end: int
 
 
-def _strips(block: int, tile: int, lo: int, hi: int) -> tuple[_Strip, ...]:
-    """How a block pair under the mask ``lo <= row − col < hi`` is computed:
+def _strips(block: int, tile: int, lo: int, hi: int, unit: int = 1) -> tuple[_Strip, ...]:
+    """How a block pair under the mask ``lo <= row − col < hi`` (with ``unit``
+    > 1: ``lo <= row // unit − col // unit < hi``, the same plan in units of
+    ``unit`` rows and columns, which ``tile`` is whole numbers of) is computed:
     as strips of ``tile`` query rows, each over only the ``tile``-wide
     sub-tiles that hold a visible entry (the visible ``row − col`` are one
     interval, so they are consecutive, and so are those visible whole). A
@@ -577,6 +638,12 @@ def _strips(block: int, tile: int, lo: int, hi: int) -> tuple[_Strip, ...]:
     masked; under a far edge of 0 columns ``r · tile .. block`` and its first.
     A pair the mask leaves whole is one strip, the block. Static: both kernels
     and ``causal_pairs`` read it."""
+    if unit > 1:
+        if tile % unit:
+            raise ValueError(f"a diffusion block of {unit} tokens is no divisor of the "
+                             f"{tile}-wide sub-tiles a masked pair is computed in")
+        return tuple(_Strip(*(unit * at for at in strip))
+                     for strip in _strips(block // unit, tile // unit, lo, hi))
     if (lo, hi) == _whole(block):
         return (_Strip(0, block, 0, block, 0, block),)
     strips = []
@@ -593,17 +660,22 @@ def _strips(block: int, tile: int, lo: int, hi: int) -> tuple[_Strip, ...]:
     return tuple(strips)
 
 
-def _masked(s, row: int, col: int, lo: int, hi: int):
-    """``s`` with −inf where ``lo <= row − col < hi`` fails; its first entry
-    is the pair's (``row``, ``col``). A bound no entry of ``s`` reaches is not
+def _masked(s, row: int, col: int, lo: int, hi: int, unit: int = 1):
+    """``s`` with −inf where ``lo <= row − col < hi`` fails (with ``unit`` > 1,
+    a power of two that ``s``'s place and shape are whole numbers of: where
+    ``lo <= row // unit − col // unit < hi`` fails); its first entry is the
+    pair's (``row``, ``col``). A bound no entry of ``s`` reaches is not
     compared."""
-    rows, cols = s.shape
+    rows, cols = (n // unit for n in s.shape)
+    row, col = row // unit, col // unit  # the place and the shape in units
     below, beyond = row - (col + cols - 1) < lo, row + rows - 1 - col >= hi
     if not (below or beyond):
         return s
-    # row − col of an entry, less that of s[0, 0]
-    apart = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-             - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    # row − col of an entry, less that of s[0, 0]; in units, the local iotas shifted
+    at = [jax.lax.broadcasted_iota(jnp.int32, s.shape, axis) for axis in (0, 1)]
+    if unit > 1:
+        at = [jnp.right_shift(a, unit.bit_length() - 1) for a in at]
+    apart = at[0] - at[1]
     keep = apart < hi - (row - col) if beyond else None
     if below:
         near = apart >= lo - (row - col)
@@ -611,7 +683,7 @@ def _masked(s, row: int, col: int, lo: int, hi: int):
     return jnp.where(keep, s, NEG_INF)
 
 
-def _scores(qa, qb, ka, kb, strip: _Strip, lo: int, hi: int):
+def _scores(qa, qb, ka, kb, strip: _Strip, lo: int, hi: int, unit: int = 1):
     """Float32 scores of one strip of a block pair: its query rows against
     the key columns ``strip`` holds, −inf where the pair's mask hides an
     entry. Only the sub-tiles the mask crosses are compared."""
@@ -624,9 +696,9 @@ def _scores(qa, qb, ka, kb, strip: _Strip, lo: int, hi: int):
     # ``_masked`` finds no bound to compare in
     pieces = [(a, b) for a, b in [(col, clear), (clear, clear_end), (clear_end, col_end)] if b > a]
     if len(pieces) == 1:
-        return _masked(s, row, col, lo, hi)
-    return jnp.concatenate([_masked(s[:, a - col:b - col], row, a, lo, hi) for a, b in pieces],
-                           axis=1)
+        return _masked(s, row, col, lo, hi, unit)
+    return jnp.concatenate([_masked(s[:, a - col:b - col], row, a, lo, hi, unit)
+                            for a, b in pieces], axis=1)
 
 
 def _parts(refs, two_part: bool):
@@ -643,16 +715,27 @@ def _read(ref, dtype, rows):
 
 
 def _first_key_block(i, *, block: int, window: int | None):
-    """The first key block a query block's row of the tables visits."""
+    """The first key block a query block's row of the tables visits (0 under
+    the block-diffusion pattern too, which has no window)."""
     return 0 if window is None else jnp.maximum(i - _reach(window, block), 0)
 
 
-def _off_diagonal(i, j, step, *, block: int, window: int | None):
+def _off_diagonal(i, j, step, *, block: int, window: int | None,
+                  diffusion: tuple[int, int] | None = None):
     """Run ``step(lo, hi)`` for a pair below the diagonal (``j < i``): with
     bounds no entry falls outside where the whole block is visible, and where
     the window ends inside it one branch a distance it does so at
-    (``_cuts``), so that each has bounds known when the kernel is built."""
+    (``_cuts``), so that each has bounds known when the kernel is built. With
+    ``diffusion`` = (the diffusion block's length, key blocks a copy ``n``):
+    a query block at place ``i % n`` of its copy sees the clean blocks before
+    that place whole, and a noisy one (``i >= n``) the clean block at its own
+    place, ``j == i − n``, under the strict staircase (``_diffusion_cuts``)."""
     whole = functools.partial(step, *_whole(block))
+    if diffusion is not None:
+        unit, n = diffusion
+        pl.when(j < i % n)(whole)
+        pl.when(j == i - n)(functools.partial(step, *_diffusion_cuts(block, unit)["strict"]))
+        return
     if window is None:
         pl.when(j < i)(whole)
         return
@@ -664,8 +747,22 @@ def _off_diagonal(i, j, step, *, block: int, window: int | None):
             pl.when(i - j == apart)(functools.partial(step, *bounds))
 
 
+def _diagonal(i, step, *, block: int, window: int | None,
+              diffusion: tuple[int, int] | None = None):
+    """Run ``step`` for a query block's last pair, the diagonal one (``j ==
+    i``), under its mask: the causal cut (``_cuts``), or with ``diffusion`` a
+    clean block's staircase or a noisy block's block diagonal."""
+    if diffusion is None:
+        step(*_cuts(block, window)[0])
+        return
+    unit, n = diffusion
+    cuts = _diffusion_cuts(block, unit)
+    pl.when(i < n)(functools.partial(step, *cuts["clean"]))
+    pl.when(i >= n)(functools.partial(step, *cuts["own"]))
+
+
 def _causal_fwd_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int, tile: int,
-                       window: int | None):
+                       window: int | None, diffusion: tuple[int, int] | None = None):
     qa_ref, qb_ref, ka_ref, kb_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = _parts(
         refs, two_part)
     t = pl.program_id(2)
@@ -678,15 +775,15 @@ def _causal_fwd_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int, tile: 
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    def step(lo: int, hi: int):
+    def step(lo: int, hi: int, unit: int = 1):
         # every strip's scores before the first softmax: in this order the
         # compiler overlaps the strips' chains (maximum, exponential, product)
         # better than a strip at a time (PERF.md §6, PR 45)
-        strips = _strips(block, tile, lo, hi)
+        strips = _strips(block, tile, lo, hi, unit)
         at = [(pl.ds(strip.row, strip.row_end - strip.row),
                pl.ds(strip.col, strip.col_end - strip.col)) for strip in strips]
         scores = [_scores(_read(qa_ref, mm, rows), _read(qb_ref, mm, rows), _read(ka_ref, mm, keys),
-                          _read(kb_ref, mm, keys), strip, lo, hi)
+                          _read(kb_ref, mm, keys), strip, lo, hi, unit)
                   for strip, (rows, keys) in zip(strips, at)]
         for s, (rows, keys) in zip(scores, at):
             m_prev = m_sc[rows, :]
@@ -702,12 +799,15 @@ def _causal_fwd_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int, tile: 
     # a row that a trailing block hides whole, in a strip that is run, leaves
     # 1s in p at the running maximum −1e30; the diagonal block, always visited
     # and never all hidden, scales them away by alpha = 0. A strip that sees
-    # nothing of the pair is not run and leaves its rows' state as it was
-    _off_diagonal(i, j, step, block=block, window=window)
+    # nothing of the pair is not run and leaves its rows' state as it was.
+    # Under the block-diffusion pattern the first rows of a noisy block see
+    # nothing in the clean block at their own place, and their own block,
+    # the diagonal one, comes last in the same way
+    _off_diagonal(i, j, step, block=block, window=window, diffusion=diffusion)
 
     @pl.when(j == i)  # the diagonal is the last key block of a query block
     def _():
-        step(*_cuts(block, window)[0])
+        _diagonal(i, step, block=block, window=window, diffusion=diffusion)
         l = l_sc[...]
         o_ref[...] = (acc_sc[...] / l).astype(o_ref.dtype)
         lse_ref[...] = jnp.broadcast_to(m_sc[...] + jnp.log(l), lse_ref.shape)
@@ -718,14 +818,16 @@ def _causal_fwd_kernel(qi_ref, kj_ref, *refs, two_part: bool, block: int, tile: 
 ROW_FIRST, ROW_LAST, HEAD_FIRST, SPAN_FIRST, SPAN_LAST = 1, 2, 4, 8, 16
 
 
-def _backward_walk(n: int, *, reach: int | None, group: int, span: int):
+def _backward_walk(n: int, *, reach: int | None, group: int, span: int,
+                   diffusion: int | None = None):
     """The backward kernel's grid steps as four int32 tables: query block,
     key block, the group's member, and the step's bits. The ``n`` key blocks
-    lie in spans of ``span``; a span's pairs are walked once a member in
-    ``_lower_triangle``'s order (a query block's key blocks rising), so the
-    steps of a span are consecutive, within it a query head's, and within
-    those a query block's."""
-    visible = list(zip(*(table.tolist() for table in _lower_triangle(n, reach=reach))))
+    lie in spans of ``span``; a span's pairs are walked once a member in the
+    forward tables' order (``_pair_tables``: a query block's key blocks
+    rising), so the steps of a span are consecutive, within it a query
+    head's, and within those a query block's."""
+    visible = list(zip(*(table.tolist() for table in
+                         _pair_tables(n, reach=reach, diffusion=diffusion))))
     steps = []
     for at_span in range(-(-n // span)):
         pairs = [(i, j) for i, j in visible if j // span == at_span]
@@ -742,7 +844,8 @@ def _backward_walk(n: int, *, reach: int | None, group: int, span: int):
 
 
 def _causal_bwd_kernel(qi_ref, kj_ref, _member_ref, at_ref, *refs, two_part: bool, block: int,
-                       tile: int, window: int | None, span: int):
+                       tile: int, window: int | None, span: int,
+                       diffusion: tuple[int, int] | None = None):
     # the member is the index maps' alone
     (qa_ref, qb_ref, ka_ref, kb_ref, v_ref, do_ref, lse_ref, dd_ref, *outs) = _parts(
         refs, two_part)
@@ -773,8 +876,8 @@ def _causal_bwd_kernel(qi_ref, kj_ref, _member_ref, at_ref, *refs, two_part: boo
     to_keys, to_queries = (((0,), (0,)), ((), ())), (((1,), (0,)), ((), ()))
     dot = functools.partial(jax.lax.dot_general, preferred_element_type=jnp.float32)
 
-    def step(lo: int, hi: int):
-        for strip in _strips(block, tile, lo, hi):
+    def step(lo: int, hi: int, unit: int = 1):
+        for strip in _strips(block, tile, lo, hi, unit):
             side = strip.row_end - strip.row  # the tile, or the block: both offsets' divisor
             rows = pl.ds(strip.row, side)
             cols = pl.ds(strip.col, strip.col_end - strip.col)
@@ -783,7 +886,7 @@ def _causal_bwd_kernel(qi_ref, kj_ref, _member_ref, at_ref, *refs, two_part: boo
                          strip.col_end - strip.col)
             qa, qb, do = (_read(ref, mm, rows) for ref in (qa_ref, qb_ref, do_ref))
             ka, kb, v = (_read(ref, mm, cols) for ref in (ka_ref, kb_ref, v_ref))
-            s = _scores(qa, qb, ka, kb, strip, lo, hi)
+            s = _scores(qa, qb, ka, kb, strip, lo, hi, unit)
             p = jnp.exp(s - lse_ref[rows, :][:, :1])
             dp = dot(do, v, (((1,), (1,)), ((), ())))
             ds = (p * (dp - dd_ref[rows, :][:, :1])).astype(mm)
@@ -794,8 +897,9 @@ def _causal_bwd_kernel(qi_ref, kj_ref, _member_ref, at_ref, *refs, two_part: boo
                 dkb_ref[keys, :] += dot(ds, qb, to_keys)
                 dqb_sc[rows, :] += dot(ds, kb, to_queries)
 
-    _off_diagonal(i, j, step, block=block, window=window)
-    pl.when(j == i)(functools.partial(step, *_cuts(block, window)[0]))
+    _off_diagonal(i, j, step, block=block, window=window, diffusion=diffusion)
+    pl.when(j == i)(functools.partial(_diagonal, i, step, block=block, window=window,
+                                      diffusion=diffusion))
 
     @pl.when(at & ROW_LAST != 0)
     def _():
@@ -836,35 +940,76 @@ def _causal_plan(seq: int, block: int) -> tuple[int, int]:
     return _round_up(seq, block), block
 
 
-def _causal_band(seq: int, window: int | None, block: int | None):
+def _causal_band(seq: int, window: int | None, block: int | None, diffusion: int | None = None):
     """``(padded seq, block, window, reach)`` of a call: a window the sequence
-    never reaches is none, and ``block`` None is ``causal_block``'s."""
+    never reaches is none, and ``block`` None is ``causal_block``'s. With
+    ``diffusion`` the row is two copies of ``seq / 2`` tokens, each padded to
+    whole blocks by itself (a pad key of the clean copy lies in a later
+    diffusion block than every real query, of either copy: hidden; one of the
+    noisy copy in no real query's own)."""
+    if diffusion is not None:
+        if window is not None or diffusion & (diffusion - 1) or seq % (2 * diffusion):
+            raise ValueError(f"a block-diffusion row is two copies of whole diffusion blocks "
+                             f"of a power of two, with no window: {seq} rows, blocks of "
+                             f"{diffusion}, window {window}")
+        copy_pad, block = _causal_plan(seq // 2, block or CAUSAL_BLOCK)
+        return 2 * copy_pad, block, None, None
     window = None if window is None or window >= seq else window
     s_pad, block = _causal_plan(seq, block or causal_block(seq, window))
     return s_pad, block, window, None if window is None else _reach(window, block)
 
 
-def causal_pairs(seq: int, window: int | None = None, block: int | None = None) -> tuple[int, int]:
+def causal_pairs(seq: int, window: int | None = None, block: int | None = None,
+                 diffusion: int | None = None) -> tuple[int, int]:
     """(visited, needed) score entries of one (head, sequence): those the
     kernels compute — the block pairs their tables walk, whole where no mask
     cuts them and else the sub-tiles of ``_strips``' plan — and those the mask
-    keeps (``min(i + 1, window)`` keys for query ``i``). Static, from the
-    tables and the plan the kernels read."""
+    keeps (``min(i + 1, window)`` keys for query ``i``; with ``diffusion``,
+    where ``seq`` counts a copy's tokens, ``seq² + seq · diffusion`` over the
+    pair of copies: module comment). Static, from the tables and the plan the
+    kernels read."""
+    area = lambda cut: sum((strip.row_end - strip.row) * (strip.col_end - strip.col)
+                           for strip in _strips(block, tile, *cut))
+    if diffusion is not None:
+        s_pad, block, _, _ = _causal_band(2 * seq, None, block, diffusion)
+        n, tile, cuts = s_pad // block // 2, _sub_tile(block), _diffusion_cuts(block, diffusion)
+        # which pair is cut how: ``_off_diagonal``'s and ``_diagonal``'s rule, statically
+        kind = lambda i, j: (("clean" if i < n else "own") if i == j
+                             else "strict" if j == i - n else None)
+        visited = sum(area(cuts.get(kind(i, j), _whole(block)))
+                      for i, j in zip(*(table.tolist() for table in _two_copies(n))))
+        return visited, seq * seq + seq * diffusion
     s_pad, block, window, reach = _causal_band(seq, window, block)
     qi, kj = _lower_triangle(s_pad // block, reach=reach)
     cuts, tile = _cuts(block, window), _sub_tile(block)
-    visited = sum((strip.row_end - strip.row) * (strip.col_end - strip.col)
-                  for apart in (qi - kj).tolist()
-                  for strip in _strips(block, tile, *cuts.get(apart, _whole(block))))
+    visited = sum(area(cuts.get(apart, _whole(block))) for apart in (qi - kj).tolist())
     w = seq if window is None else window
     return visited, w * (w + 1) // 2 + (seq - w) * w
 
 
-def _pad_rows(x, to: int):
+def _pad_rows(x, to: int, copies: int = 1):
+    """``x`` with its rows (the axis before the last) padded to ``to`` with
+    zeros: at the end, or with ``copies`` at the end of each of the equal
+    copies the rows hold one after the other."""
     pad = to - x.shape[-2]
     if not pad:
         return x
+    if copies > 1:
+        lead, rows, width = x.shape[:-2], x.shape[-2] // copies, x.shape[-1]
+        x = _pad_rows(x.reshape(*lead, copies, rows, width), to // copies)
+        return x.reshape(*lead, to, width)
     return jnp.pad(x, ((0, 0),) * (x.ndim - 2) + ((0, pad), (0, 0)))
+
+
+def _real_rows(x, rows: int, padded: int, copies: int = 1):
+    """``_pad_rows``' inverse: the first ``rows`` rows of ``x``, or with
+    ``copies`` the first ``rows / copies`` of each of the copies its first
+    ``padded`` rows hold (a result may run past them: whole spans)."""
+    if copies == 1:
+        return x[..., :rows, :]
+    lead, width = x.shape[:-2], x.shape[-1]
+    x = x[..., :padded, :].reshape(*lead, copies, padded // copies, width)
+    return x[..., : rows // copies, :].reshape(*lead, rows, width)
 
 
 def _pair_spec(width: int, kind: str, *, block: int, group: int, members: bool,
@@ -936,38 +1081,46 @@ def _traced_once(fn, static_argnums):
     return call
 
 
-def _causal_shape(qa, ka, block, window):
+def _causal_shape(qa, ka, block, window, diffusion=None):
     """``(batch, query heads, group, seq, padded seq, block, window, reach)``
     of a call."""
     b, h, s, _ = qa.shape
-    return b, h, h // ka.shape[1], s, *_causal_band(s, window, block)
+    return b, h, h // ka.shape[1], s, *_causal_band(s, window, block, diffusion)
 
 
-def _causal_operands(named, s_pad, spec):
+def _causal_operands(named, s_pad, spec, copies: int = 1):
     """``(padded array, BlockSpec)`` of the ``(array, kind)`` in ``named``
     that are there."""
-    return [(_pad_rows(x, s_pad), spec(x.shape[-1], kind)) for x, kind in named if x is not None]
+    return [(_pad_rows(x, s_pad, copies), spec(x.shape[-1], kind))
+            for x, kind in named if x is not None]
 
 
-@functools.partial(_traced_once, static_argnums=(5, 6, 7))
-def _causal_fwd(qa, qb, ka, kb, v, block, interpret, window=None):
-    b, h, group, s, s_pad, block, window, reach = _causal_shape(qa, ka, block, window)
+def _in_kernel(diffusion: int | None, s_pad: int, block: int):
+    """What the kernels are told of a block-diffusion call: (the diffusion
+    block's length, key blocks a copy); None for the causal kinds."""
+    return None if diffusion is None else (diffusion, s_pad // block // 2)
+
+
+@functools.partial(_traced_once, static_argnums=(5, 6, 7, 8))
+def _causal_fwd(qa, qb, ka, kb, v, block, interpret, window=None, diffusion=None):
+    b, h, group, s, s_pad, block, window, reach = _causal_shape(qa, ka, block, window, diffusion)
     spec = functools.partial(_pair_spec, block=block, group=group, members=False)
-    tables = _lower_triangle(s_pad // block, reach=reach)
+    tables = _pair_tables(s_pad // block, reach=reach, diffusion=diffusion)
     d_v = v.shape[-1]
     out = lambda w, dtype: (jax.ShapeDtypeStruct((b, h, s_pad, w), dtype), spec(w, "q"))
     o, lse = _causal_call(
         functools.partial(_causal_fwd_kernel, two_part=qb is not None, block=block,
-                          tile=_sub_tile(block), window=window),
+                          tile=_sub_tile(block), window=window,
+                          diffusion=_in_kernel(diffusion, s_pad, block)),
         tables, (b, h, len(tables[0])),
         _causal_operands([(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k")],
-                         s_pad, spec),
+                         s_pad, spec, 1 if diffusion is None else 2),
         [out(d_v, qa.dtype), out(LANE, jnp.float32)],
         [pltpu.VMEM((block, 1), jnp.float32), pltpu.VMEM((block, 1), jnp.float32),
          pltpu.VMEM((block, d_v), jnp.float32)],
         interpret=interpret, name="causal_attention_fwd",
     )
-    return o[:, :, :s], lse[..., 0]
+    return _real_rows(o, s, s_pad, 1 if diffusion is None else 2), lse[..., 0]
 
 
 def _causal_span(n: int, block: int, widths, itemsize: int,
@@ -994,22 +1147,23 @@ def _causal_span(n: int, block: int, widths, itemsize: int,
     return -(-n // -(-n // fits))
 
 
-@functools.partial(_traced_once, static_argnums=(8, 9, 10))
-def _causal_bwd(qa, qb, ka, kb, v, o, lse, g, block, interpret, window=None):
-    b, h, group, s, s_pad, block, window, reach = _causal_shape(qa, ka, block, window)
+@functools.partial(_traced_once, static_argnums=(8, 9, 10, 11))
+def _causal_bwd(qa, qb, ka, kb, v, o, lse, g, block, interpret, window=None, diffusion=None):
+    b, h, group, s, s_pad, block, window, reach = _causal_shape(qa, ka, block, window, diffusion)
+    copies = 1 if diffusion is None else 2
     two_part = qb is not None
     d_a, d_v = qa.shape[-1], v.shape[-1]
     second = [qb.shape[-1]] if two_part else []
     n = s_pad // block
     span = _causal_span(n, block, (d_a, d_v, *second), qa.dtype.itemsize)
     spans = -(-n // span)
-    o, g = _pad_rows(o, s_pad), _pad_rows(g, s_pad)
+    o, g = _pad_rows(o, s_pad, copies), _pad_rows(g, s_pad, copies)
     # D = rowsum(dO ∘ O), as for the non-causal kernels: tiny, elementwise
     dd = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1, keepdims=True)
     dd = jnp.broadcast_to(dd, (b, h, s_pad, LANE))
     lse = jnp.broadcast_to(lse[..., None], (b, h, s_pad, LANE))
     spec = functools.partial(_pair_spec, block=block, group=group, members=True, span=span)
-    tables = _backward_walk(n, reach=reach, group=group, span=span)
+    tables = _backward_walk(n, reach=reach, group=group, span=span, diffusion=diffusion)
 
     def out(kind, w, dtype=qa.dtype):
         shape = {"dq": (spans, b, h), "dk": (b, h // group), "dk_head": (b, h)}[kind]
@@ -1022,10 +1176,11 @@ def _causal_bwd(qa, qb, ka, kb, v, o, lse, g, block, interpret, window=None):
     f32 = lambda rows, w: pltpu.VMEM((rows, w), jnp.float32)
     outs = _causal_call(
         functools.partial(_causal_bwd_kernel, two_part=two_part, block=block,
-                          tile=_sub_tile(block), window=window, span=span),
+                          tile=_sub_tile(block), window=window, span=span,
+                          diffusion=_in_kernel(diffusion, s_pad, block)),
         tables, (b, h // group, len(tables[0])),
         _causal_operands([(qa, "q"), (qb, "q"), (ka, "k"), (kb, "k_shared"), (v, "k"),
-                          (g, "q"), (lse, "q"), (dd, "q")], s_pad, spec),
+                          (g, "q"), (lse, "q"), (dd, "q")], s_pad, spec, copies),
         [*(out("dq", w, partial) for w in [d_a, *second]), out("dk", d_a),
          *(out("dk_head", w, jnp.float32) for w in second), out("dk", d_v)],
         [*(f32(block, w) for w in [d_a, *second]), f32(span * block, d_a), f32(span * block, d_v)],
@@ -1041,18 +1196,19 @@ def _causal_bwd(qa, qb, ka, kb, v, o, lse, g, block, interpret, window=None):
         its own (nor, under a window, those past its reach), and their blocks
         of its share were never written."""
         if spans == 1:
-            return dq[0, ..., :s, :]
+            return dq[0, ..., :s, :] if diffusion is None else rows(dq[0])
         met = np.zeros((spans, n), bool)
         met[tables[1] // span, tables[0]] = True
         met = jnp.asarray(np.repeat(met, block, axis=1))[:, None, None, :, None]
-        return jnp.where(met, dq, 0.0).sum(0).astype(qa.dtype)[..., :s, :]
+        return rows(jnp.where(met, dq, 0.0).sum(0).astype(qa.dtype))
 
+    rows = functools.partial(_real_rows, rows=s, padded=s_pad, copies=copies)
     if two_part:
-        dqb, dkb = whole(dqb), dkb.sum(axis=1).astype(kb.dtype)[..., :s, :]
-    return whole(dqa), dqb, dka[..., :s, :], dkb, dv[..., :s, :]
+        dqb, dkb = whole(dqb), rows(dkb.sum(axis=1).astype(kb.dtype))
+    return whole(dqa), dqb, rows(dka), dkb, rows(dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def pallas_causal_attention(
     q_a: jax.Array,
     q_b: jax.Array | None,
@@ -1062,9 +1218,14 @@ def pallas_causal_attention(
     block: int | None = None,
     interpret: bool = False,
     window: int | None = None,
+    diffusion: int | None = None,
 ) -> jax.Array:
     """Causal softmax(q_a·k_aᵀ + q_b·k_bᵀ)·v; queries pre-scaled. With
-    ``window``, query ``i`` sees keys ``i − window + 1 .. i`` only.
+    ``window``, query ``i`` sees keys ``i − window + 1 .. i`` only. With
+    ``diffusion`` = ``B``, the ``seq`` rows are a clean copy of ``seq / 2``
+    tokens and then a noisy one, and visibility is the block-diffusion
+    pattern's over diffusion blocks of ``B`` tokens (the section comment
+    above; ``B`` a power of two that divides ``seq / 2`` and the sub-tiles).
 
     ``q_a``: (batch, heads, seq, d_a); ``k_a``: (batch, kv heads, seq, d_a),
     ``kv heads`` a divisor of ``heads`` (query head ``h`` reads ``h // (heads
@@ -1074,19 +1235,20 @@ def pallas_causal_attention(
     None takes ``causal_block``'s for the shape. Forward and backward are
     Pallas kernels over the visible block pairs (see the section comment
     above)."""
-    return _causal_fwd(q_a, q_b, k_a, k_b, v, block, interpret, window)[0]
+    return _causal_fwd(q_a, q_b, k_a, k_b, v, block, interpret, window, diffusion)[0]
 
 
-def _causal_vjp_fwd(q_a, q_b, k_a, k_b, v, block=None, interpret=False, window=None):
-    o, lse = _causal_fwd(q_a, q_b, k_a, k_b, v, block, interpret, window)
+def _causal_vjp_fwd(q_a, q_b, k_a, k_b, v, block=None, interpret=False, window=None,
+                    diffusion=None):
+    o, lse = _causal_fwd(q_a, q_b, k_a, k_b, v, block, interpret, window, diffusion)
     # the primal output and the residual are the one named array
     o = checkpoint_name(o, CAUSAL_OUT_NAME)
     lse = checkpoint_name(lse, CAUSAL_LSE_NAME)
     return o, (q_a, q_b, k_a, k_b, v, o, lse)
 
 
-def _causal_vjp_bwd(block, interpret, window, residuals, g):
-    return _causal_bwd(*residuals, g, block, interpret, window)
+def _causal_vjp_bwd(block, interpret, window, diffusion, residuals, g):
+    return _causal_bwd(*residuals, g, block, interpret, window, diffusion)
 
 
 pallas_causal_attention.defvjp(_causal_vjp_fwd, _causal_vjp_bwd)

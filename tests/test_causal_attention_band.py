@@ -313,7 +313,8 @@ def test_the_dispatcher_hands_the_window_and_the_groups_to_either_form(monkeypat
                                want, rtol=2e-5, atol=2e-6)
     real, calls = pallas_attention.pallas_causal_attention, []
     monkeypatch.setattr(pallas_attention, "pallas_causal_attention",
-                        lambda *xs, window=None: calls.append(window) or real(*xs, 8, True, window))
+                        lambda *xs, window=None, diffusion=None: calls.append(window) or real(
+                            *xs, 8, True, window))
     # what the rule reads, as a chip would answer for a sequence this short
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(attention_ops, "AUTO_FLASH_MIN_SEQ", 1)
@@ -400,7 +401,7 @@ def _forced(monkeypatch, fresh_traces, strips):
     monkeypatch.setattr(pallas_attention, "_sub_tile", lambda block: block // strips)
     real, tiles = pallas_attention._strips, []
     monkeypatch.setattr(pallas_attention, "_strips",
-                        lambda block, tile, lo, hi: tiles.append(tile) or real(block, tile, lo, hi))
+                        lambda block, tile, *cut: tiles.append(tile) or real(block, tile, *cut))
     return tiles
 
 
@@ -422,7 +423,7 @@ def _forward_and_gradients(args, w, block, window):
     custom VJP's two rules: each kernel once."""
     def both(*xs):
         o, residuals = pallas_attention._causal_vjp_fwd(*xs, block, True, window)
-        grads = pallas_attention._causal_vjp_bwd(block, True, window, residuals, w)
+        grads = pallas_attention._causal_vjp_bwd(block, True, window, None, residuals, w)
         return o, residuals[6], [g for g in grads if g is not None]
     return jax.jit(both)(*args)
 

@@ -135,7 +135,7 @@ def compile_lm_step(recipe: str, chip, monkeypatch, depth_cut: list[str] | None 
     mesh = create_mesh(MeshConfig(data=1, fsdp=1), devices=list(chip.device_set))
     model, lm, _ = build_model(cfg)
     tx = make_optimizer(cfg.optim, cfg.run.train_batch_size, num_layers=lm.layers)
-    rows, length = cfg.run.train_batch_size, cfg.data.seq_len + 1 + lm.mtp_layers
+    rows, length = cfg.run.train_batch_size, lm.token_row(cfg.data.seq_len)
 
     def init():
         v = model.init(jax.random.key(0), jnp.zeros((rows, length), jnp.int32))
@@ -207,6 +207,7 @@ def assert_the_step_is_built_a_block_at_a_time(text: str, cfg, lm) -> None:
         rf'custom-call\([^\n]*/{scope}/causal_attention_\w+/pallas_call"', text))
         for scope in ("attn_core", "swa_core")}
     assert by_scope == {"attn_core": 2 * (softmax - sliding), "swa_core": 2 * sliding}
+    assert chip_smoke.bd_kernel_calls(text) == {"fwd": 0, "bwd": 0}  # a causal family: none under bd_core
     assert chip_smoke.kda_kernel_calls(text) == {"fwd": 2 * lm.kda_layers, "bwd": lm.kda_layers,
                                                  "loops": 0}
     assert chip_smoke.short_conv_kernel_calls(text) == dict.fromkeys(
@@ -322,9 +323,11 @@ def test_lm_kernels_phase_rehearsal_interpreted():
     got = chip_smoke.phase_lm_kernels(
         causal=((1, 2, 40, 16, 8, 16), (1, 6, 40, 16, 0, 16, 2, 21)),
         grouped=(64, 32, 24, (41, 0, 9, 6)), rope=((1, 2, 32, 128), (1, 2, 32, 64)),
-        conv=((1, 2, 48, 128),), interpret=True)
+        conv=((1, 2, 48, 128),), blockdiff=((1, 8, 1, 40, 16, 4), (1, 4, 2, 64, 16, 4)),
+        interpret=True)
     assert got["mosaic_custom_call"] is False
     assert set(got["max_rel_err_vs_xla"]) == {"causal@40x16+8/16", "causal@40x16+0/16g3w21",
+                                              "blockdiff@2x40x16g8b4", "blockdiff@2x64x16g2b4",
                                               "rope@32x128", "rope@32x64", "grouped@64x32x24",
                                               "short_conv_q@48x128", "short_conv_v@48x128"}
     assert max(got["max_rel_err_vs_xla"].values()) < chip_smoke.KERNEL_REL_TOL

@@ -133,6 +133,25 @@ def _short_conv_and_grouped_query(checked, published, records):
     assert [r for r in records if "train/moe_rounds_l8" in r]
 
 
+def _block_diffusion(checked, published, records):
+    """2 blocks of grouped-query attention with q/k norms, 8 query heads over
+    1 key/value head, every block sparse, trained on the masked tokens of a
+    noisy copy: 24 clean tokens a row in blocks of 4; the objective's counter
+    is logged and the static pair count is the pattern's."""
+    assert checked["kda_kernel_calls"] == {"fwd": 0, "bwd": 0, "loops": 0}
+    assert checked["bd_kernel_calls"] == {"fwd": 0, "bwd": 0}  # off the chip: the einsum form
+    assert checked["diffusion_block"] == 4 and checked["qk_norm"] is True
+    low, high = checked["bd_masked_share_min_max"]
+    assert 0.3 < low <= high < 0.7  # 48 blocks a step about the mean of U[0.001, 1)
+    assert checked["attn_pairs"] == {"block_diffusion": {"visited": 3 * 128 * 128,
+                                                         "needed": 24 * 24 + 24 * 4}}
+    assert checked["attn_heads"] == {"full_attention": {"held": 8, "published": 8}}
+    (logged,) = [r for r in records if "train/attn_pairs_needed_block_diffusion" in r]
+    assert logged["train/attn_pairs_visited_block_diffusion"] == 3 * 128 * 128
+    assert [r for r in records if "train/bd_masked_share" in r]
+    assert {"imbalance", "rounds_l0", "rounds_l1"} <= set(published["train_moe"])
+
+
 CASES = [
     pytest.param("pretrain_joyai_flash_ep16", _toy(
         16, layers=2, heads=2, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
@@ -153,6 +172,9 @@ CASES = [
         _window_and_routed_from_the_input, id="smallthinker_21b"),
     pytest.param("pretrain_lfm2_24b_share", _toy(24, heads=4, kv_heads=2, head_dim=16),
                  _short_conv_and_grouped_query, id="lfm2_24b"),
+    pytest.param("pretrain_sdar_30b_share", _toy(
+        24, layers=2, layer_types="[full_attention, full_attention]", heads=8, kv_heads=1,
+        head_dim=16), _block_diffusion, id="sdar_30b"),
 ]
 
 
@@ -168,6 +190,7 @@ def test_lm_train_phase_rehearsal(recipe, toy, family, tmp_path, watch, capsys):
     )
     (line,) = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
     checked = line["checked"]
+    # (a block-diffusion recipe's steps all draw one noise in the smoke: one_noise_draw)
     assert checked["loss_after_one_cycle"] < checked["loss_first"]
     assert checked["moe_dropped"] == 0 and checked["skipped_steps"] == 0
     # on the CPU the core resolves to its einsum form: no kernel in the step
