@@ -63,6 +63,23 @@ def _dense(q_a, q_b, k_a, k_b, v, window):
     return jnp.stack(heads, axis=1)
 
 
+def _given(args):
+    """Positions of the operands a case has (a one-part score has no ``q_b``, ``k_b``)."""
+    return tuple(i for i, a in enumerate(args) if a is not None)
+
+
+def _output_and_gradients(fn, args, w):
+    """``fn(*args)`` and the gradients of ``(fn(*args) * w).sum()`` by the
+    operands given, from one jitted program: a form is compiled once a case
+    (op by op, the head loop of ``_dense`` compiles a program an operation)."""
+    def both(*xs):
+        out = fn(*xs)
+        return (out * w).sum(), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(both, argnums=_given(args), has_aux=True))(*args)
+    return out, grads
+
+
 # (query heads, key/value heads, seq, block, window): groups of 1, 3 and 4; a
 # window that is no multiple of the block, one smaller than the block (and
 # than a block that is clamped to the sequence), one of whole blocks, one the
@@ -95,18 +112,14 @@ TWO_PART = [(6, 2, 40, 16, 13), (8, 2, 24, 32, 5), (8, 2, 48, 16, 32), (6, 2, 70
 def test_grouped_windowed_kernels_match_every_pair_forward_and_backward(h, g, seq, block, window,
                                                                          two_part):
     args, w = _inputs(seq + h, 2, h, g, seq, two_part)
-    given = tuple(i for i, a in enumerate(args) if a is not None)
-    kernel = lambda *xs: pallas_causal_attention(*xs, block, True, window)
-    out, want = kernel(*args), jax.jit(lambda *xs: _dense(*xs, window))(*args)
+    out, got = _output_and_gradients(
+        lambda *xs: pallas_causal_attention(*xs, block, True, window), args, w)
+    want, ref = _output_and_gradients(lambda *xs: _dense(*xs, window), args, w)
     assert out.shape == want.shape == (2, h, seq, 12)
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-6)
     # the CPU's einsum form takes the same three generalisations
-    np.testing.assert_allclose(xla_causal_attention(*args, window), want, rtol=2e-5, atol=2e-6)
-    # jitted: op by op, the head loop of ``_dense`` compiles a program an operation
-    grad = lambda fn: jax.jit(jax.grad(lambda *xs: (fn(*xs) * w).sum(), argnums=given))(*args)
-    got = grad(kernel)
-    ref = grad(lambda *xs: _dense(*xs, window))
-    ein = grad(lambda *xs: xla_causal_attention(*xs, window))
+    ein_out, ein = _output_and_gradients(lambda *xs: xla_causal_attention(*xs, window), args, w)
+    np.testing.assert_allclose(ein_out, want, rtol=2e-5, atol=2e-6)
     for a, e, r in zip(got, ein, ref, strict=True):
         assert a.shape == e.shape == r.shape
         np.testing.assert_allclose(a, r, rtol=2e-4, atol=2e-5)
@@ -120,12 +133,12 @@ def test_heads_64_wide_at_a_group_of_four_match_every_pair_forward_and_backward(
     counts a 64-wide accumulator as the whole tile it takes."""
     h, g, seq, block = 8, 2, 70, 16
     args, w = _inputs(seq + h, 2, h, g, seq, False, d_a=64, d_v=64)
-    kernel = lambda *xs: pallas_causal_attention(*xs, block, True, None)
-    out, want = kernel(*args), jax.jit(lambda *xs: _dense(*xs, None))(*args)
+    out, got = _output_and_gradients(
+        lambda *xs: pallas_causal_attention(*xs, block, True, None), args, w)
+    want, ref = _output_and_gradients(lambda *xs: _dense(*xs, None), args, w)
     assert out.shape == want.shape == (2, h, seq, 64)
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-6)
-    grad = lambda fn: jax.jit(jax.grad(lambda *xs: (fn(*xs) * w).sum(), argnums=(0, 2, 4)))(*args)
-    for a, r in zip(grad(kernel), grad(lambda *xs: _dense(*xs, None)), strict=True):
+    for a, r in zip(got, ref, strict=True):
         np.testing.assert_allclose(a, r, rtol=2e-4, atol=2e-5)
     assert _causal_span(8, 1024, (64, 64), 2) == _causal_span(8, 1024, (128, 128), 2) == 8
     # 28 whole pairs and 8 diagonal ones at 10 of their 16 sub-tiles
@@ -152,6 +165,27 @@ def _eqns(jaxpr):
             yield from _eqns(sub)
 
 
+SPAN_SEQ, SPAN_BLOCK = 70, 16  # five key blocks, the last padded
+
+
+def _span_case(h, g, window, two_part):
+    """The span cases' operands, and the jitted gradients of the kernels' call
+    by them (the rule is read when the call is traced)."""
+    args, w = _inputs(SPAN_SEQ + h, 2, h, g, SPAN_SEQ, two_part)
+    grad = jax.jit(jax.grad(
+        lambda *xs: (pallas_causal_attention(*xs, SPAN_BLOCK, True, window) * w).sum(),
+        argnums=_given(args)))
+    return args, grad
+
+
+@functools.cache
+def _one_span_gradients(h, g, window, two_part):
+    """What every span length of a case is compared with: the gradients under
+    the rule's own budget, which holds the five key blocks in one span."""
+    args, grad = _span_case(h, g, window, two_part)
+    return grad(*args)
+
+
 # key blocks a span of five: one, two (three spans, the last ragged), three (two spans)
 @pytest.mark.parametrize("span", [1, 2, 3])
 @pytest.mark.parametrize("h,g,window,two_part", [(6, 2, None, True), (6, 2, 40, False),
@@ -162,15 +196,12 @@ def test_accumulators_that_outgrow_the_budget_split_the_keys_into_spans(monkeypa
     accumulators do not fit, the same kernel walks the keys a span at a time
     and a query block's gradient is the sum of its spans' shares; every
     gradient equals the unsplit call's to float32 rounding."""
-    seq, block = 70, 16
-    args, w = _inputs(seq + h, 2, h, g, seq, two_part)
-    given = tuple(i for i, a in enumerate(args) if a is not None)
     widths = (16, 12, 8) if two_part else (16, 12)
-    assert _causal_span(5, block, widths, 4) == 5  # the rule's own budget: one span
-    budget = next(b for b in range(0, 1 << 20, 4096) if _causal_span(5, block, widths, 4, b) == span)
-    grad = jax.jit(jax.grad(lambda *xs: (pallas_causal_attention(*xs, block, True, window) * w).sum(),
-                            argnums=given))
-    whole = grad(*args)
+    assert _causal_span(5, SPAN_BLOCK, widths, 4) == 5  # the rule's own budget: one span
+    budget = next(b for b in range(0, 1 << 20, 4096)
+                  if _causal_span(5, SPAN_BLOCK, widths, 4, b) == span)
+    whole = _one_span_gradients(h, g, window, two_part)  # before the rule is steered
+    args, grad = _span_case(h, g, window, two_part)
     monkeypatch.setattr(pallas_attention, "_causal_span",
                         functools.partial(_causal_span, budget=budget))
     jax.clear_caches()  # the rule is read when the call is traced
@@ -437,7 +468,6 @@ def test_strips_of_a_masked_pair_match_the_einsum_form_and_the_one_strip_body(
     its mask) to float32 rounding — a hidden entry's probability and ``ds``
     are exact zeros, so leaving it out only reorders a row's sums."""
     args, w = _inputs(seq + h, 1, h, g, seq, two_part, d_a=width, d_v=width if width == 64 else 12)
-    given = tuple(i for i, a in enumerate(args) if a is not None)
     assert _sub_tile(block) == block
     one = _forward_and_gradients(args, w, block, window)
     tiles = _forced(monkeypatch, fresh_traces, strips)
@@ -446,9 +476,8 @@ def test_strips_of_a_masked_pair_match_the_einsum_form_and_the_one_strip_body(
     assert causal_pairs(seq, window, block)[0] < len(_lower_triangle(
         -(-seq // block), reach=_reach(window, block) if window and window < seq else None)[0]
     ) * block * block  # which leaves something out
-    np.testing.assert_allclose(o, xla_causal_attention(*args, window), rtol=2e-5, atol=2e-6)
-    ref = jax.jit(jax.grad(lambda *xs: (xla_causal_attention(*xs, window) * w).sum(),
-                           argnums=given))(*args)
+    want, ref = _output_and_gradients(lambda *xs: xla_causal_attention(*xs, window), args, w)
+    np.testing.assert_allclose(o, want, rtol=2e-5, atol=2e-6)
     for a, r in zip(grads, ref, strict=True):
         np.testing.assert_allclose(a, r, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(o, one[0], rtol=1e-5, atol=2e-6)

@@ -221,10 +221,10 @@ def assert_the_step_is_built_a_block_at_a_time(text: str, cfg, lm) -> None:
     assert len(loops) == 2 * (lm.layers - lm.first_k_dense + lm.mtp_layers), len(loops)
 
 
-# tier-1's compile of a family: the dense block and one expert block, the
-# smallest depth with a block of every kind (and the MTP module, which the
-# recipe has at any depth)
-DEPTH_CUT = ["model.lm.layers=2"]
+# tier-1's compile of this family: the dense block and the MTP module, whose
+# block is the trunk's expert block (``lm.block(cfg, sparse=True)``) and which
+# the recipe has at any depth: the smallest depth with a block of every kind
+DEPTH_CUT = ["model.lm.layers=1"]
 
 
 def assert_the_all_mla_step(text: str, cfg, lm) -> None:
@@ -243,12 +243,12 @@ def assert_the_all_mla_step(text: str, cfg, lm) -> None:
 
 
 def test_language_model_step_compiles_for_v5e_at_cut_depth(v5e_chip, monkeypatch):
-    """The shipped recipe at two of its five layers (the dense block, one
-    expert block, the MTP block; widths, sequence, experts and kernels as
+    """The shipped recipe at one of its five layers (the dense block, and the
+    MTP block for the expert blocks; widths, sequence, experts and kernels as
     published), for a described v5e: every structural assertion of the full
     compile, which is ``slow``."""
     cfg, lm, _, compiled = compile_lm_step(chip_smoke.LM_RECIPE, v5e_chip, monkeypatch, DEPTH_CUT)
-    assert (lm.layers, lm.first_k_dense, lm.mtp_layers) == (2, 1, 1)
+    assert (lm.layers, lm.first_k_dense, lm.mtp_layers) == (1, 1, 1)
     assert_the_all_mla_step(compiled.as_text(), cfg, lm)
 
 

@@ -27,8 +27,8 @@ RECIPE = str(chip_smoke.REPO / "recipes" / "pretrain_sdar_30b_share.yaml")
 # (no nearer the chip's limit than the fullest accepted cell), and the chip's own
 PROGRAM_BYTES, LADDER_BYTES, CHIP_BYTES = 12_894_764_544, 15.2e9, 16.9e9
 
-# tier-1's compile: two of the six layers, which are all alike
-DEPTH_CUT = ["model.lm.layers=2", "model.lm.layer_types=[full_attention, full_attention]"]
+# tier-1's compile: one of the six layers, which are all alike
+DEPTH_CUT = ["model.lm.layers=1", "model.lm.layer_types=[full_attention]"]
 
 
 def assert_the_block_diffusion_step(text: str, cfg, lm) -> None:
@@ -67,11 +67,11 @@ def assert_the_block_diffusion_step(text: str, cfg, lm) -> None:
 
 
 def test_block_diffusion_step_compiles_for_v5e_at_cut_depth(v5e_chip, monkeypatch):  # noqa: F811
-    """Two of the recipe's six layers at its published widths, 2 x 8192 clean
+    """One of the recipe's six layers at its published widths, 2 x 8192 clean
     tokens: every structural assertion of the full compile, which is
     ``slow``."""
     cfg, lm, _, compiled = compile_lm_step(RECIPE, v5e_chip, monkeypatch, DEPTH_CUT)
-    assert lm.layers_by_kind == {"full_attention": 2}
+    assert lm.layers_by_kind == {"full_attention": 1}
     assert_the_block_diffusion_step(compiled.as_text(), cfg, lm)
 
 

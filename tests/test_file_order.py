@@ -1,5 +1,9 @@
-"""The order ``tests/conftest.py`` gives the collected files, and its table."""
+"""The order ``tests/conftest.py`` gives the collected files, its table, and
+the limit it gives every case."""
 
+import re
+import signal
+import time
 from pathlib import Path
 
 import pytest
@@ -43,3 +47,37 @@ def test_every_file_of_the_committed_table_exists(conftest):
     assert table, "tests/file_seconds.json is empty"
     assert [f for f in table if not (REPO / f).is_file()] == []
     assert all(s >= 0 for s in table.values())
+
+
+def _timer_is_off():
+    return signal.getitimer(signal.ITIMER_REAL) == (0.0, 0.0)
+
+
+def test_a_case_that_outruns_its_limit_fails_with_its_own_name(conftest, monkeypatch):
+    monkeypatch.setattr(conftest, "CASE_LIMIT_S", 1.0)
+    before, t0 = signal.getsignal(signal.SIGALRM), time.monotonic()
+    with pytest.raises(pytest.fail.Exception,
+                       match=r"^t/a\.py::test_waits\[8k\] ran past its limit of 1 s$"):
+        with conftest.case_limit("t/a.py::test_waits[8k]"):
+            time.sleep(30)  # what waits here waits in Python: the handler cuts it
+    assert 0.9 < time.monotonic() - t0 < 10
+    # ... and only that case: the next starts with no timer and the former handler
+    assert _timer_is_off() and signal.getsignal(signal.SIGALRM) is before
+
+
+def test_a_case_inside_its_limit_is_untouched_and_leaves_no_timer(conftest, monkeypatch):
+    monkeypatch.setattr(conftest, "CASE_LIMIT_S", 1.0)
+    before = signal.getsignal(signal.SIGALRM)
+    with conftest.case_limit("t/a.py::test_quick"):
+        assert 0 < signal.getitimer(signal.ITIMER_REAL)[0] <= 1.0
+        assert signal.getsignal(signal.SIGALRM) is not before
+    assert _timer_is_off() and signal.getsignal(signal.SIGALRM) is before
+
+
+def test_every_case_runs_under_the_limit_and_its_alarm_names_it(conftest, request):
+    """This case, as any other: the autouse fixture armed the timer with the
+    one constant and a handler that fails the case by its node id."""
+    assert conftest.CASE_LIMIT_S == 600.0
+    assert 0 < signal.getitimer(signal.ITIMER_REAL)[0] <= conftest.CASE_LIMIT_S
+    with pytest.raises(pytest.fail.Exception, match=re.escape(request.node.nodeid) + " ran past"):
+        signal.getsignal(signal.SIGALRM)(signal.SIGALRM, None)

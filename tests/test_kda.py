@@ -6,6 +6,8 @@ the Pallas kernel in the interpreter); with no floor under them and ``beta`` up 
 −100 in the middle of a sub-block); the causal convolution against a loop;
 the triangular inverse against ``numpy``."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -50,28 +52,52 @@ def inputs(decays: str, seq: int = 150, d_k: int = 8, d_v: int = 6, seed: int = 
     return q, k, v, g, beta
 
 
+FIVE = (0, 1, 2, 3, 4)
+
+
+def loss(o, state, weight):
+    """The output against ``weight`` plus the final state's squares: every one
+    of the five inputs moves it, through both results."""
+    return (o * weight).sum() + jnp.square(state).sum()
+
+
+def scalar(fn, weight):
+    return lambda *a: loss(*fn(*a), weight)
+
+
+def weight_for(args):
+    return jax.random.normal(jax.random.key(9), args[2].shape)
+
+
+@functools.cache
+def recurrence(decays: str, **size):
+    """``(output, state, the five gradients of scalar(literal))`` at
+    ``inputs(decays, **size)``, as one program: what every chunking and
+    schedule of one set of inputs is held to."""
+    args = inputs(decays, **size)
+    weight = weight_for(args)
+
+    def both(*a):
+        o, state = literal(*a)
+        return loss(o, state, weight), (o, state)
+
+    (_, (o, state)), grads = jax.jit(jax.value_and_grad(both, FIVE, has_aux=True))(*args)
+    return o, state, grads
+
+
 @pytest.mark.parametrize("chunk,sub", [(64, 16), (128, 16), (32, 16), (8, 8)])
 @pytest.mark.parametrize("decays", ["mixed", "at_the_bound", "near_zero"])
 def test_chunked_form_is_the_recurrence(decays, chunk, sub):
     """150 positions: no multiple of any chunk, so the padded tail is in."""
     args = inputs(decays)
-    weight = jax.random.normal(jax.random.key(9), args[2].shape)
-
-    def scalar(fn):
-        def f(*a):
-            o, state = fn(*a)
-            return (o * weight).sum() + jnp.square(state).sum()
-        return f
-
     chunked = lambda *a: kda_chunked(*a, chunk=chunk, sub=sub, floor=FLOOR)
     o, state = chunked(*args)
-    want_o, want_state = literal(*args)
+    want_o, want_state, want = recurrence(decays)
     assert o.shape == want_o.shape and state.shape == want_state.shape
     np.testing.assert_allclose(o, want_o, rtol=0, atol=2e-6 * float(jnp.abs(want_o).max()))
     np.testing.assert_allclose(state, want_state, rtol=0,
                                atol=2e-6 * float(jnp.abs(want_state).max()))
-    got = jax.grad(scalar(chunked), argnums=(0, 1, 2, 3, 4))(*args)
-    want = jax.grad(scalar(literal), argnums=(0, 1, 2, 3, 4))(*args)
+    got = jax.grad(scalar(chunked, weight_for(args)), argnums=FIVE)(*args)
     for name, a, b in zip("q k v g beta".split(), got, want):
         assert bool(jnp.isfinite(a).all()), name
         assert float(jnp.abs(b).max()) > 0, name
@@ -98,7 +124,7 @@ def test_compute_dtype_operands_keep_a_float32_state():
     q, k, v, g, beta = inputs("mixed")
     low = lambda x: x.astype(jnp.bfloat16)
     o, state = kda_chunked(low(q), low(k), low(v), g, beta, floor=FLOOR)
-    want, _ = literal(q, k, v, g, beta)
+    want = recurrence("mixed")[0]
     assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
     gap = jnp.linalg.norm(o.astype(jnp.float32) - want) / jnp.linalg.norm(want)
     assert float(gap) < 2e-2
@@ -121,23 +147,17 @@ def test_kernel_path_is_the_recurrence_and_the_scan(decays):
     """Output, final state and all five gradients through the custom VJP,
     under a ``jax.checkpoint`` as the model's block runs it."""
     args = inputs(decays, **WIDE)
-    weight = jax.random.normal(jax.random.key(9), args[2].shape)
-
-    def scalar(fn):
-        def f(*a):
-            o, state = fn(*a)
-            return (o * weight).sum() + jnp.square(state).sum()
-        return f
-
+    weight = weight_for(args)
+    scan = lambda *a: kda_chunked(*a, chunk=64, floor=FLOOR)
+    *by_recurrence, recurrence_grads = recurrence(decays, **WIDE)
     o, state = kernel_path(*args)
-    for want_o, want_state in (literal(*args), kda_chunked(*args, chunk=64, floor=FLOOR)):
+    for want_o, want_state in (by_recurrence, scan(*args)):
         np.testing.assert_allclose(o, want_o, rtol=0, atol=3e-5 * float(jnp.abs(want_o).max()))
         np.testing.assert_allclose(state, want_state, rtol=0,
                                    atol=3e-5 * float(jnp.abs(want_state).max()))
-    five = (0, 1, 2, 3, 4)
-    got = jax.grad(scalar(jax.checkpoint(kernel_path)), argnums=five)(*args)
-    for other in (literal, lambda *a: kda_chunked(*a, chunk=64, floor=FLOOR)):
-        for name, a, b in zip("q k v g beta".split(), got, jax.grad(scalar(other), five)(*args)):
+    got = jax.grad(scalar(jax.checkpoint(kernel_path), weight), argnums=FIVE)(*args)
+    for want in (recurrence_grads, jax.grad(scalar(scan, weight), FIVE)(*args)):
+        for name, a, b in zip("q k v g beta".split(), got, want):
             assert bool(jnp.isfinite(a).all()), name
             np.testing.assert_allclose(a, b, rtol=0, atol=1e-4 * max(float(jnp.abs(b).max()), 0.1),
                                        err_msg=name)
@@ -187,7 +207,7 @@ def test_kernel_path_takes_compute_dtype_operands_and_keeps_a_float32_state(deca
     low = lambda x: x.astype(jnp.bfloat16)
     o, state = kernel_path(low(q), low(k), low(v), g, beta)
     scan_o, scan_state = kda_chunked(low(q), low(k), low(v), g, beta, floor=FLOOR)
-    want, want_state = literal(q, k, v, g, beta)
+    want, want_state, _ = recurrence(decays, **WIDE)
     assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
     gap = lambda a, b: float(jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b))
     assert gap(o, want) < 2e-2 and gap(state, want_state) < 2e-2
@@ -226,8 +246,7 @@ def test_a_step_of_minus_100_inside_a_sub_block_is_finite_and_the_recurrence(sch
     floor, given the same inputs, is not finite: the fault this form is for."""
     args = unbounded_inputs(8 if schedule == "scan" else 128)
     assert float(args[4].max()) > 1.9
-    weight = jax.random.normal(jax.random.key(9), args[2].shape)
-    scalar = lambda fn: lambda *a: (lambda o, s: (o * weight).sum() + jnp.square(s).sum())(*fn(*a))
+    weight = weight_for(args)
     how = dict(chunk=64, interpret=schedule == "kernels")
     free = jax.checkpoint(lambda *a: kda_chunked(*a, **how))  # floor=None: none is known
     o, state = free(*args)
@@ -236,9 +255,8 @@ def test_a_step_of_minus_100_inside_a_sub_block_is_finite_and_the_recurrence(sch
     np.testing.assert_allclose(o, want_o, rtol=0, atol=tol * float(jnp.abs(want_o).max()))
     np.testing.assert_allclose(state, want_state, rtol=0,
                                atol=tol * float(jnp.abs(want_state).max()))
-    five = (0, 1, 2, 3, 4)
-    for name, a, b in zip("q k v g beta".split(), jax.grad(scalar(free), five)(*args),
-                          jax.grad(scalar(literal), five)(*args)):
+    for name, a, b in zip("q k v g beta".split(), jax.grad(scalar(free, weight), FIVE)(*args),
+                          jax.grad(scalar(literal, weight), FIVE)(*args)):
         assert bool(jnp.isfinite(a).all()), name
         np.testing.assert_allclose(a, b, rtol=0, atol=4 * tol * max(float(jnp.abs(b).max()), 0.1),
                                    err_msg=name)

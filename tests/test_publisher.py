@@ -402,14 +402,10 @@ def test_cost_doctor_surfaces_publish_tenant(tmp_path):
     assert "top consumer: **publish**" in report
 
 
-@pytest.mark.slow
 def test_engine_cold_start_from_publish_artifact(tmp_path):
     """``InferenceEngine(ckpt=<publish artifact>)`` must resolve the chain
     and serve the published weights — a pool cold-starts straight from the
-    newest publish, bit-identical to hot-swapping the same artifact in.
-
-    slow: two engine builds + feature compiles; the CI publish-loop smoke
-    drives the same cold-start path end to end."""
+    newest publish, bit-identical to hot-swapping the same artifact in."""
     from pathlib import Path
 
     from jumbo_mae_tpu_tpu.config import load_config

@@ -239,16 +239,20 @@ def test_restart_backoff_recovery_and_generation():
     rs, reg = _pool(run, replicas=1, restart_backoff_s=0.05, max_retries=0)
     with pytest.raises(RetriesExhaustedError):
         rs.submit(_img()).result(timeout=5)
+    restarts = lambda: _counter(reg, "serve_replica_restarts_total",
+                                labels=("replica",), replica="r0")
     deadline = time.monotonic() + 10.0
     while time.monotonic() < deadline:
-        # "up" alone also holds before the crash is booked (the future fails first)
-        if rs.generation(0) == 1 and rs.stats()["replicas"]["r0"]["state"] == "up":
+        # "up" alone also holds before the crash is booked (the future fails
+        # first); and the supervisor installs and starts the new incarnation
+        # before it books the restart, so the counter is waited for too
+        if (rs.generation(0) == 1 and rs.stats()["replicas"]["r0"]["state"] == "up"
+                and restarts() == 1):
             break
         time.sleep(0.02)
     assert rs.generation(0) == 1  # new incarnation
     assert rs.submit(_img()).result(timeout=5) is not None
-    assert _counter(reg, "serve_replica_restarts_total",
-                    labels=("replica",), replica="r0") == 1
+    assert restarts() == 1
     rs.close()
 
 
